@@ -38,10 +38,12 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from incubator_brpc_tpu.parallel.compat import axis_size
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from incubator_brpc_tpu.parallel.collective import ring_stream
+
+# like every shard_map in this tree: the replication check stays off
+_shard_map = partial(jax.shard_map, check_vma=False)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -138,7 +140,7 @@ def _mlp_tp(w_in_l, w_out_l, x):
 def _ring_context(x: jnp.ndarray) -> jnp.ndarray:
     """Sequence-parallel global context via the sp ring (streaming RPC
     lowering): fold per-shard sequence means around the ring."""
-    sp = axis_size("sp")
+    sp = lax.axis_size("sp")
     local = jnp.mean(x, axis=1)  # (mb, d)
 
     def fold(acc, received):
@@ -156,7 +158,7 @@ def _moe(moe_w1, moe_w2, gate_w, x):
     rank-local experts, and exchanged back (all_to_all is an involution for
     equal tiles).
     """
-    ep = axis_size("ep")
+    ep = lax.axis_size("ep")
     e_local = moe_w1.shape[0]
     mb, sl, d = x.shape
     t = mb * sl
@@ -212,7 +214,7 @@ def _stage_fn(sp_params, heads, prefetch, x):
 def _pipeline(stage, xs):
     """GPipe over 'pp': scan of M + pp - 1 ticks; stage handoff is a
     ppermute ring (streaming-RPC frame to the right neighbor each tick)."""
-    pp = axis_size("pp")
+    pp = lax.axis_size("pp")
     sidx = lax.axis_index("pp")
     m = xs.shape[0]
     perm = [(i, (i + 1) % pp) for i in range(pp)]
@@ -288,15 +290,13 @@ def _microbatch_slicer(cfg: FabricNetConfig, mesh: jax.sharding.Mesh):
     global batch axis outside shard_map would gather a contiguous global
     block that lives on a subset of the dp/ep ranks instead."""
     x_spec, _ = batch_specs()
-    from incubator_brpc_tpu.parallel.compat import shard_map_compat
-
     m_slices = cfg.microbatches
 
     def body(x):
         bl = x.shape[0]
         return x.reshape(m_slices, bl // m_slices, *x.shape[1:])
 
-    return jax.jit(shard_map_compat(
+    return jax.jit(_shard_map(
         body,
         mesh=mesh,
         in_specs=(x_spec,),
@@ -307,9 +307,7 @@ def _microbatch_slicer(cfg: FabricNetConfig, mesh: jax.sharding.Mesh):
 def make_forward_step(cfg: FabricNetConfig, mesh: jax.sharding.Mesh):
     """Jitted sharded forward: (params, x) -> (B, S, d) output."""
     x_spec, _ = batch_specs()
-    from incubator_brpc_tpu.parallel.compat import shard_map_compat
-
-    fwd = shard_map_compat(
+    fwd = _shard_map(
         partial(_local_forward, cfg),
         mesh=mesh,
         in_specs=(param_specs(cfg.heads), x_spec),
@@ -338,8 +336,6 @@ def make_train_step(
       grads to ``"serialized"``.
     """
     x_spec, y_spec = batch_specs()
-    from incubator_brpc_tpu.parallel.compat import shard_map_compat
-
     if schedule not in ("fused", "serialized", "overlapped"):
         raise ValueError(f"unknown schedule {schedule!r}")
     if schedule != "fused":
@@ -357,7 +353,7 @@ def make_train_step(
         # so loss and grads are bit-identical between them.
         overlap = schedule == "overlapped"
         m_slices = cfg.microbatches
-        slice_loss = shard_map_compat(
+        slice_loss = _shard_map(
             partial(_slice_local_loss, cfg, overlap),
             mesh=mesh,
             in_specs=(param_specs(cfg.heads), x_spec, y_spec),
@@ -393,7 +389,7 @@ def make_train_step(
 
         return jax.jit(step, donate_argnums=(0,))
 
-    loss_fn = shard_map_compat(
+    loss_fn = _shard_map(
         partial(_local_loss, cfg),
         mesh=mesh,
         in_specs=(param_specs(cfg.heads), x_spec, y_spec),

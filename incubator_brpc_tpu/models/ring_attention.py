@@ -63,9 +63,7 @@ def ring_attention(
     may overlap the ring hop with the blockwise attention compute
     instead of serializing transfer-then-fold. Bit-identical output —
     the dataflow is unchanged, only the emission order moves."""
-    from incubator_brpc_tpu.parallel.compat import axis_size
-
-    sp = axis_size(axis)
+    sp = lax.axis_size(axis)
     idx = lax.axis_index(axis)
     perm = [(i, (i + 1) % sp) for i in range(sp)]
     b, t, h, d = q.shape
@@ -154,13 +152,12 @@ def make_ring_attention_step(
     kept sequence-only here since this layer IS the sp showcase)."""
     spec = P(None, "sp", None, None)
 
-    from incubator_brpc_tpu.parallel.compat import shard_map_compat
-
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         partial(ring_attention, axis="sp", causal=causal, prefetch=prefetch),
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=spec,
+        check_vma=False,
     )
     jitted = jax.jit(fn)
 

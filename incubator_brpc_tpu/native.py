@@ -522,6 +522,14 @@ def _build() -> bool:
             capture_output=True,
             timeout=120,
         )
+    except subprocess.CalledProcessError as e:
+        import logging
+
+        logging.getLogger(__name__).warning(
+            "building libtbutil.so failed; using the pure-Python plane:\n%s",
+            (e.stderr or b"").decode(errors="replace")[-2000:],
+        )
+        return False
     except (OSError, subprocess.SubprocessError):
         return False
     return os.path.exists(_LIB_PATH)

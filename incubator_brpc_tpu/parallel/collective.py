@@ -13,8 +13,6 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from incubator_brpc_tpu.parallel.compat import axis_size
-
 
 def fanout(x: jnp.ndarray, axis: str) -> jnp.ndarray:
     """ParallelChannel broadcast side: give every replica along ``axis`` the
@@ -57,7 +55,7 @@ def ring_stream(
     in-flight frame per neighbor, matching RdmaEndpoint's per-WR ack scheme
     (rdma_endpoint.h:176-195) with window=1.
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + 1) % n) for i in range(n)]
 
     def body(state, _):
@@ -76,7 +74,7 @@ def ring_allgather(x: jnp.ndarray, axis: str) -> jnp.ndarray:
 
     At hop k each rank holds the chunk that originated at rank (my - k) mod n.
     """
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     my = lax.axis_index(axis)
     out = jnp.zeros((n,) + x.shape, x.dtype)
 

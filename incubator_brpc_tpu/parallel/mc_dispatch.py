@@ -44,6 +44,7 @@ combo channel, and the transport picks the lowering.
 from __future__ import annotations
 
 import base64
+import contextlib
 import json
 import logging
 import threading
@@ -752,6 +753,16 @@ _chunk_ops_cache: Dict[tuple, tuple] = {}
 # checkpoint quantizers: (party ids, width, mode, block) -> jitted qz
 _ck_quant_cache: Dict[tuple, object] = {}
 _step_cache_lock = threading.Lock()  # guards ALL three caches (never nested)
+# One process may host several parties of a session (single-controller
+# runs, the in-process tests): their threads dispatch the same
+# multi-device collective program concurrently, and two devices that see
+# those launches in different orders each wait in a collective the other
+# has not reached.  Holding this across the (async) enqueue gives every
+# device queue the same order.  A process that addresses ONE device of
+# the party set (the one-party-per-process deployment) has no co-hosted
+# launch to order and never takes it.
+_launch_order = threading.Lock()
+_no_launch_order = contextlib.nullcontext()
 
 
 def _make_step(dm, mesh, sharding, party_ids):
@@ -769,8 +780,6 @@ def _make_step(dm, mesh, sharding, party_ids):
     import jax
     from jax.sharding import PartitionSpec as P
 
-    from incubator_brpc_tpu.parallel.compat import shard_map_compat
-
     key = (dm.fingerprint(), tuple(party_ids))
     with _step_cache_lock:
         cached = _step_cache.get(key)
@@ -782,9 +791,9 @@ def _make_step(dm, mesh, sharding, party_ids):
                 out, m = dm.kernel(data[0], ns[0])
                 return out[None], m[None]
 
-            wrapped = shard_map_compat(
+            wrapped = jax.shard_map(
                 body, mesh=mesh, in_specs=(P("par"), P("par")),
-                out_specs=(P("par"), P("par")),
+                out_specs=(P("par"), P("par")), check_vma=False,
             )
             cached = (
                 jax.jit(wrapped, out_shardings=(sharding, sharding)), dm
@@ -870,6 +879,29 @@ def _chunk_ready(arr) -> bool:
         return bool(fn())
     except Exception:  # noqa: BLE001 — runtime quirk: assume complete
         return True
+
+
+def _await_or_abort(arr, should_abort) -> None:
+    """Wait for ``arr`` as the blocking fetch would, but leave with
+    SessionAborted once the session aborts.  Dispatches are async, so a
+    survivor has usually queued every step before a peer dies; the
+    collective the dead party never joins then never completes, and the
+    runtime's own timeout (Gloo on the CPU mesh: 30 minutes) is no bound.
+    The abandoned buffers stay queued behind that collective; the healed
+    session runs over a different party set and does not wait for them.
+    Returns with EVERY addressable shard of ``arr`` complete (a
+    single-controller run holds them all: the checkpoint census counts a
+    step only once all of it is ready) and raises what the chain raised."""
+    import jax
+
+    delay = 50e-6
+    while should_abort is not None and not _chunk_ready(arr):
+        why = should_abort()
+        if why:
+            raise SessionAborted(why)
+        time.sleep(delay)
+        delay = min(delay * 2, 1e-3)
+    jax.block_until_ready(arr)
 
 
 def _validate_chunks(dm, chunks, service: str, method: str) -> int:
@@ -1122,9 +1154,10 @@ def run_dispatch_session(
             f"party {own_index} device {own_dev} is not addressable from "
             f"this process"
         )
+    n_addr = sum(1 for d in devices if d in addressable)
+    launch_order = _launch_order if n_addr > 1 else _no_launch_order
     ring = None
     if checkpoint_every and checkpoint_every > 0 and session_id:
-        n_addr = sum(1 for d in devices if d in addressable)
         # a quantized session retains QUANTIZED ring entries: the per-
         # entry device cost drops from width float32-bytes per row to
         # the wire footprint — deep rings get the same ~4x the wire got
@@ -1243,11 +1276,12 @@ def run_dispatch_session(
                 # between dispatches, with a clean ESESSION — dispatches
                 # are async (XLA pipelines them), so the check costs
                 # nothing and the party never enters a barrier its dead
-                # peer cannot join.  A party already blocked INSIDE one
-                # collective finishes that step first (or hits the
-                # runtime's own collective timeout) — the between-step
-                # check, every party's deadline watch, and the per-step
-                # watchdog are what bound the hang.
+                # peer cannot join.  Steps ALREADY dispatched when the
+                # peer died stay queued on the device until the
+                # runtime's own collective timeout; the host never waits
+                # on them — every blocking wait below is abort-aware
+                # (_await_or_abort), bounded by the abort broadcast,
+                # every party's deadline watch and the per-step watchdog.
                 if should_abort is not None:
                     why = should_abort()
                     if why:
@@ -1256,7 +1290,9 @@ def run_dispatch_session(
                 hook = _step_hook
                 if hook is not None:
                     hook(step_i, own_index, 0)  # chaos-drill seam
-                x, ns = step_fn(x, ns)  # chained: operands stay on-device
+                with launch_order:
+                    # chained: operands stay on-device
+                    x, ns = step_fn(x, ns)
                 completed = step_i + 1
                 if ring is not None and completed % checkpoint_every == 0:
                     # retaining the global arrays IS the checkpoint: the
@@ -1325,7 +1361,8 @@ def run_dispatch_session(
                     # every slice of step k+1 a dataflow edge on step
                     # k's chunk-0 program — partially re-serializing the
                     # overlap the schedule exists to remove
-                    new_x, _ = chunk_fn(xs[j], ns)
+                    with launch_order:
+                        new_x, _ = chunk_fn(xs[j], ns)
                     xs[j] = new_x
                     chunk_tally += 1
                     csp = _start_chunk_span(
@@ -1344,7 +1381,7 @@ def run_dispatch_session(
                     for j in chunk_order:
                         progress[0], progress[2] = step_i, j
                         progress[1] = time.monotonic()
-                        jax.block_until_ready(xs[j])
+                        _await_or_abort(xs[j], should_abort)
                         acked[j] = completed
                         _close_spans(pending_spans[j])
                 if ring is not None and completed % checkpoint_every == 0:
@@ -1369,6 +1406,7 @@ def run_dispatch_session(
         progress[0], progress[2] = steps, -1
         progress[1] = time.monotonic()
         own_row = own_n = None
+        _await_or_abort(x, should_abort)
         for s in x.addressable_shards:
             # a process can address several mesh devices (single-
             # controller runs): OUR shard is the one on devices[own_index]
@@ -1676,7 +1714,14 @@ def make_dispatch_handler(server):
     """Server half of ``_tpu_transport.collective_dispatch``: validate a
     session proposal against the local registry (accept phase — nothing
     runs), or bind the resolved kernel and run this party's side of the
-    lockstep chain (run phase), answering with the final shard."""
+    lockstep chain (run phase), answering with the final shard.
+
+    ``collective_max_concurrency`` admits RUNNING sessions here, not at
+    the method gate: the abort broadcast, the resume census and the
+    reshard fetches ride this same method and must reach a party whose
+    one admitted session is the chain they act on."""
+    run_limit = max(0, int(server.options.collective_max_concurrency))
+    run_slots = threading.BoundedSemaphore(run_limit) if run_limit else None
 
     def collective_dispatch(cntl, request: bytes) -> bytes:
         try:
@@ -1824,84 +1869,99 @@ def make_dispatch_handler(server):
             dispatch_rejects << 1
             cntl.set_failed(ErrorCode.EREQUEST, f"bad run fields: {e}")
             return b""
-        st = None
-        sock_hook = None
-        if session_id is not None and run_epoch <= aborted_epoch(session_id):
-            # the abort for this epoch already passed through here: a
-            # stale (reordered or retried) run proposal must not start a
-            # zombie chain no peer will ever join
+        if run_slots is not None and not run_slots.acquire(blocking=False):
             from incubator_brpc_tpu.utils.status import ErrorCode
 
             cntl.set_failed(
-                ErrorCode.ESESSION,
-                f"session aborted: run epoch {run_epoch} already "
-                "tombstoned on this party",
+                ErrorCode.ELIMIT,
+                f"{run_limit} collective session(s) already running here",
             )
             return b""
-        if session_id is not None:
-            deadline_ms = float(req.get("deadline_ms", 0) or 0)
-            if deadline_ms <= 0:
-                deadline_ms = float(get_flag("mc_dispatch_session_deadline_ms"))
-            deadline = (
-                time.monotonic() + deadline_ms / 1000.0 if deadline_ms > 0
-                else 0.0
-            )
-            st = _register_session(
-                session_id, party_ids, deadline, owner=server,
-                epoch=run_epoch,
-            )
-            sock = getattr(cntl, "_sock", None)
-            hooks = getattr(sock, "on_failed", None)
-            if hooks is not None:
-                # the proposer died with us mid-chain: its control
-                # connection failing IS the death signal (socket feedback)
-                def _proposer_died(_s, _sid=session_id, _ep=run_epoch):
-                    abort_session(
-                        _sid, "proposer connection died mid-session",
-                        epoch=_ep,
-                    )
-
-                hooks.append(_proposer_died)
-                sock_hook = (hooks, _proposer_died)
-
-        def _should_abort():
-            if st is None:
-                return None
-            if st.abort_event.is_set():
-                return st.abort_reason or "session aborted"
-            if st.deadline and time.monotonic() > st.deadline:
-                abort_session(
-                    st.session_id, "session deadline exceeded",
-                    epoch=st.epoch,
-                )
-                return "session deadline exceeded"
-            return None
-
-        quant_note = ""
-        if getattr(dm, "quant_mode", "none") != "none":
-            quant_note = f"quantize={dm.quant_mode}"
-        if chunk_order != list(range(chunks)):
-            # the proposer's topology-derived route, auditable per party
-            quant_note = (
-                quant_note + f" chunk_order={chunk_order}"
-            ).strip()
-        span = _start_session_span(
-            service, method, dm.fingerprint(), party_ids, own_index, steps,
-            trace_id=cntl.trace_id, parent_span_id=cntl.span_id,
-            resume_from=resume_from, extra=quant_note,
-            # the proposal rode in sampled (head-based): this party's
-            # session span must not drop to a dry local bucket, or the
-            # fleet-wide trace loses a whole party
-            forced=bool(
-                getattr(cntl.request_meta, "sampled", 0)
-                if cntl.request_meta is not None
-                else 0
-            ),
-        )
+        st = None
+        sock_hook = None
+        span = None
         try:
+            if (
+                session_id is not None
+                and run_epoch <= aborted_epoch(session_id)
+            ):
+                # the abort for this epoch already passed through here: a
+                # stale (reordered or retried) run proposal must not start a
+                # zombie chain no peer will ever join
+                from incubator_brpc_tpu.utils.status import ErrorCode
+
+                cntl.set_failed(
+                    ErrorCode.ESESSION,
+                    f"session aborted: run epoch {run_epoch} already "
+                    "tombstoned on this party",
+                )
+                return b""
+            if session_id is not None:
+                deadline_ms = float(req.get("deadline_ms", 0) or 0)
+                if deadline_ms <= 0:
+                    deadline_ms = float(
+                        get_flag("mc_dispatch_session_deadline_ms")
+                    )
+                deadline = (
+                    time.monotonic() + deadline_ms / 1000.0 if deadline_ms > 0
+                    else 0.0
+                )
+                st = _register_session(
+                    session_id, party_ids, deadline, owner=server,
+                    epoch=run_epoch,
+                )
+                sock = getattr(cntl, "_sock", None)
+                hooks = getattr(sock, "on_failed", None)
+                if hooks is not None:
+                    # the proposer died with us mid-chain: its control
+                    # connection failing IS the death signal (socket feedback)
+                    def _proposer_died(_s, _sid=session_id, _ep=run_epoch):
+                        abort_session(
+                            _sid, "proposer connection died mid-session",
+                            epoch=_ep,
+                        )
+
+                    hooks.append(_proposer_died)
+                    sock_hook = (hooks, _proposer_died)
+
+            def _should_abort():
+                if st.abort_event.is_set():
+                    return st.abort_reason or "session aborted"
+                if st.deadline and time.monotonic() > st.deadline:
+                    abort_session(
+                        st.session_id, "session deadline exceeded",
+                        epoch=st.epoch,
+                    )
+                    return "session deadline exceeded"
+                return None
+
+            quant_note = ""
+            if getattr(dm, "quant_mode", "none") != "none":
+                quant_note = f"quantize={dm.quant_mode}"
+            if chunk_order != list(range(chunks)):
+                # the proposer's topology-derived route, auditable per party
+                quant_note = (
+                    quant_note + f" chunk_order={chunk_order}"
+                ).strip()
+            span = _start_session_span(
+                service, method, dm.fingerprint(), party_ids, own_index, steps,
+                trace_id=cntl.trace_id, parent_span_id=cntl.span_id,
+                resume_from=resume_from, extra=quant_note,
+                # the proposal rode in sampled (head-based): this party's
+                # session span must not drop to a dry local bucket, or the
+                # fleet-wide trace loses a whole party
+                forced=bool(
+                    getattr(cntl.request_meta, "sampled", 0)
+                    if cntl.request_meta is not None
+                    else 0
+                ),
+            )
             own_row, own_n, elapsed = run_dispatch_session(
                 party_ids, own_index, dm, operands, steps,
-                service=service, method=method, should_abort=_should_abort,
+                service=service, method=method,
+                # no session state, nothing can abort it: the chain's
+                # waits are then the plain blocking ones
+                should_abort=_should_abort if st is not None else None,
                 session_id=session_id, resume_from=resume_from,
                 resume_state=resume_state,
                 checkpoint_every=checkpoint_every,
@@ -1951,6 +2011,8 @@ def make_dispatch_handler(server):
                     pass
             if st is not None:
                 _unregister_session(st)
+            if run_slots is not None:
+                run_slots.release()
         _end_session_span(span)
         return json.dumps(
             {

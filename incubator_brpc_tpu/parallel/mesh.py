@@ -17,7 +17,7 @@ recipe): the innermost axes (tp, sp) map to the fastest mesh dims.
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Sequence
+from typing import Dict, List, Optional, Sequence
 
 import jax
 import numpy as np
@@ -43,6 +43,32 @@ def default_axis_sizes(n_devices: int) -> Dict[str, int]:
             n //= 2
     sizes["dp"] *= n  # odd residue
     return sizes
+
+
+def covering_axis_sizes(n_devices: int) -> List[Dict[str, int]]:
+    """Factorings of ``n_devices`` such that EVERY fabric axis is >= 2 in
+    at least one of them, so each collective crosses devices somewhere
+    instead of riding a size-1 axis. Needs a multiple of four devices:
+    two meshes of three live axes from eight, three of two from four."""
+    if n_devices % 8 == 0:
+        k = n_devices // 8
+        live = [
+            {"dp": 2 * k, "pp": 2, "tp": 2},
+            {"dp": k, "pp": 2, "sp": 2, "ep": 2},
+        ]
+    elif n_devices % 4 == 0:
+        k = n_devices // 4
+        live = [
+            {"dp": 2 * k, "tp": 2},
+            {"dp": k, "pp": 2, "sp": 2},
+            {"dp": 2 * k, "ep": 2},
+        ]
+    else:
+        raise ValueError(
+            f"no factoring of {n_devices} devices makes every fabric axis "
+            "live; use a multiple of four"
+        )
+    return [{ax: sizes.get(ax, 1) for ax in FABRIC_AXES} for sizes in live]
 
 
 def make_fabric_mesh(

@@ -49,6 +49,7 @@ float32 data, exactly like the exact pmean.
 
 from __future__ import annotations
 
+import math
 import threading
 from typing import Dict, List, Optional, Tuple
 
@@ -58,10 +59,10 @@ QUANT_MODES = ("none", "int8", "int4")
 DEFAULT_BLOCK = 32  # float32 values per scale block
 _QMAX = {"int8": 127, "int4": 7}
 
-# exponent clamp: int8 storage and exp2() exactness both hold inside
-# the normal-float32 exponent range; a block whose amax sits outside it
-# quantizes to zeros (subnormal data) or saturates (near-f32-max data)
-_E_MIN, _E_MAX = -126, 127
+# exponent clamp: int8 storage, and 2^e / 2^-e both normal float32s (the
+# scales are assembled from exponent bits, _np_pow2/_jq_pow2); a block of
+# subnormal data clamps to _E_MIN and quantizes to zeros
+_E_MIN, _E_MAX = -126, 126
 
 
 def qmax_for(mode: str) -> int:
@@ -105,19 +106,31 @@ def wire_bytes(width: int, mode: str, block: int = DEFAULT_BLOCK) -> int:
 #
 # Host-side mirror of the jax arithmetic below, used by checkpoint
 # restore/reshard (parallel/mc_dispatch._restore_state dequantizes ring
-# shards on the host) and by tests as the oracle.  Every operation is
-# exact (comparisons, frexp, power-of-two scaling), so the two twins
-# agree BITWISE — the property the restore path depends on.
+# shards on the host) and by tests as the oracle.  The two twins agree
+# BITWISE — the property the restore path depends on — so every
+# operation in them is one every backend computes exactly: comparisons,
+# frexp, integer arithmetic on the exponent, and multiplication by a
+# power of two assembled from its exponent bits.  No division and no
+# exp2(): XLA may lower x/c to x*(1/c) and approximates exp2 (measured on
+# this jaxlib's CPU backend: exp2 of an integer is off for 221 of the
+# 254 normal exponents, |x|/127 for ~4% of values).
+
+
+def _np_pow2(e: np.ndarray) -> np.ndarray:
+    """Exactly 2^e as float32, for integer e in [-126, 127]."""
+    return ((e.astype(np.int32) + 127) << 23).astype(np.uint32).view(
+        np.float32
+    )
 
 
 def np_block_exponents(xf: np.ndarray, mode: str, block: int) -> np.ndarray:
     """Per-block power-of-two scale exponents: the smallest e with
-    ``amax / 2^e <= qmax``.  frexp gives amax/qmax = m * 2^ex with
-    m in [0.5, 1); ceil(log2) is ex except exactly at m == 0.5."""
-    qmax = _QMAX[mode]
-    xb = np.abs(xf.reshape(-1, block)).max(axis=1) / np.float32(qmax)
-    m, ex = np.frexp(xb.astype(np.float32))
-    e = ex - (m == np.float32(0.5))
+    ``amax <= qmax * 2^e``.  With amax = m·2^ex and qmax = mq·2^exq,
+    both mantissas in [0.5, 1), that is ``ex - exq``, plus one when
+    ``m > mq`` (an all-zero block has m = ex = 0 and gets ``-exq``)."""
+    mq, exq = math.frexp(_QMAX[mode])
+    m, ex = np.frexp(np.abs(xf.reshape(-1, block)).max(axis=1))
+    e = ex - exq + (m > np.float32(mq))
     return np.clip(e, _E_MIN, _E_MAX).astype(np.int8)
 
 
@@ -130,9 +143,8 @@ def np_quantize(
     xf = np.asarray(xf, dtype=np.float32).reshape(-1)
     qmax = _QMAX[mode]
     e = np_block_exponents(xf, mode, block)
-    scale = np.exp2(e.astype(np.float32))
     q = np.clip(
-        np.round(xf.reshape(-1, block) / scale[:, None]), -qmax, qmax
+        np.round(xf.reshape(-1, block) * _np_pow2(-e)[:, None]), -qmax, qmax
     ).astype(np.int8)
     q = q.reshape(-1)
     if mode == "int4":
@@ -151,7 +163,7 @@ def np_dequantize(
         hi = (u >> 4).astype(np.int16) - 8
         q = np.stack([lo, hi], axis=1).reshape(-1).astype(np.int8)
     q = np.asarray(q, dtype=np.int8)
-    scale = np.exp2(np.asarray(e, dtype=np.int8).astype(np.float32))
+    scale = _np_pow2(np.asarray(e, dtype=np.int8))
     return (
         q.reshape(-1, block).astype(np.float32) * scale[:, None]
     ).reshape(-1)
@@ -194,6 +206,16 @@ def pmean_error_bound(
 # -- the jax kernels -----------------------------------------------------------
 
 
+def _jq_pow2(e):
+    """jax twin of _np_pow2."""
+    import jax
+    import jax.numpy as jnp
+
+    return jax.lax.bitcast_convert_type(
+        (e.astype(jnp.int32) + 127) << 23, jnp.float32
+    )
+
+
 def _jq_quantize(xf, mode: str, block: int):
     """jax twin of np_quantize over a [rows, nfloats] float32 array:
     returns (wire values [rows, ...], exponents int8 [rows, nblocks])."""
@@ -202,14 +224,13 @@ def _jq_quantize(xf, mode: str, block: int):
     qmax = _QMAX[mode]
     rows = xf.shape[0]
     xb = xf.reshape(rows, -1, block)
-    amax = jnp.max(jnp.abs(xb), axis=-1) / jnp.float32(qmax)
-    m, ex = jnp.frexp(amax)
+    mq, exq = math.frexp(qmax)
+    m, ex = jnp.frexp(jnp.max(jnp.abs(xb), axis=-1))
     e = jnp.clip(
-        ex - (m == jnp.float32(0.5)).astype(ex.dtype), _E_MIN, _E_MAX
+        ex - exq + (m > jnp.float32(mq)).astype(ex.dtype), _E_MIN, _E_MAX
     ).astype(jnp.int8)
-    scale = jnp.exp2(e.astype(jnp.float32))
     q = jnp.clip(
-        jnp.round(xb / scale[..., None]), -qmax, qmax
+        jnp.round(xb * _jq_pow2(-e)[..., None]), -qmax, qmax
     ).astype(jnp.int8).reshape(rows, -1)
     if mode == "int4":
         u = (q.astype(jnp.int16) + 8).astype(jnp.uint8)
@@ -226,9 +247,9 @@ def _jq_dequantize(q, e, mode: str, block: int):
         lo = (q & 0xF).astype(jnp.int16) - 8
         hi = (q >> 4).astype(jnp.int16) - 8
         q = jnp.stack([lo, hi], axis=-1).reshape(rows, -1).astype(jnp.int8)
-    scale = jnp.exp2(e.astype(jnp.float32))
     return (
-        q.reshape(rows, -1, block).astype(jnp.float32) * scale[..., None]
+        q.reshape(rows, -1, block).astype(jnp.float32)
+        * _jq_pow2(e)[..., None]
     ).reshape(rows, -1)
 
 

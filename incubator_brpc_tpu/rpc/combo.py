@@ -103,8 +103,11 @@ class ParallelChannel:
     scheduled over the host plane (parallel/mc_dispatch.py) — one API,
     the transport picks the lowering. Every path runs the same jitted
     kernel over the same "par" axis, so fused, mc-lowered and host
-    fan-out produce byte-identical merged responses; any precondition
-    miss or dispatch failure falls back to the host path silently."""
+    fan-out produce byte-identical merged responses. A precondition that
+    does not hold chooses the host path — a choice from observed
+    geometry; once the device path is chosen, a failure of its program
+    fails the call with the program's text (a fan-out that quietly
+    succeeded instead would hide a device plane that cannot run)."""
 
     def __init__(self, fail_limit: int = -1, fuse_device_calls: bool = True):
         self.fail_limit = fail_limit
@@ -154,7 +157,19 @@ class ParallelChannel:
                 done(cntl)
             return cntl
         if self.fuse_device_calls and ndone >= 2:
-            fused = self._maybe_fused_device_call(service, method, request, plan, cntl)
+            try:
+                fused = self._maybe_fused_device_call(
+                    service, method, request, plan, cntl
+                )
+            except Exception as e:
+                logger.exception("fused collective dispatch failed")
+                cntl.set_failed(
+                    getattr(e, "error_code", ErrorCode.EINTERNAL),
+                    f"fused collective dispatch failed: {e!r}",
+                )
+                if done is not None:
+                    done(cntl)
+                return cntl
             if fused is not None:
                 fused_dispatches << 1
                 cntl.response_payload = fused
@@ -251,6 +266,7 @@ class ParallelChannel:
     ) -> Optional[bytes]:
         """One shard_map dispatch over the sub-channels' server devices, or
         None when the preconditions don't hold (host fan-out runs instead).
+        Raises what the device program raised once it was chosen.
 
         Preconditions: the method has a registered device kernel; every
         non-skipped sub-channel uses transport='tpu' and resolves a live
@@ -339,11 +355,8 @@ class ParallelChannel:
                     timeout_ms=cntl.timeout_ms,
                 )
             except Exception:
-                logger.exception(
-                    "mc collective lowering failed; using host fan-out"
-                )
                 _settle_probes()
-                return None
+                raise
             latency_us = (_time.perf_counter() - t0) * 1e6
             for pch, pds in probed:
                 if pch._lb is not None:
@@ -356,11 +369,8 @@ class ParallelChannel:
         try:
             rows_out, ns_out = self._fused_dispatch(dm, devices, requests)
         except Exception:
-            logger.exception(
-                "fused collective dispatch failed; using host fan-out"
-            )
             _settle_probes()
-            return None
+            raise
         # the servers DID serve this dispatch: settle each LB pick with the
         # real fused latency (the host path's per-sub feedback analog)
         latency_us = (_time.perf_counter() - t0) * 1e6
@@ -380,7 +390,6 @@ class ParallelChannel:
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
         from incubator_brpc_tpu.parallel import collective
-        from incubator_brpc_tpu.parallel.compat import shard_map_compat
 
         n = len(devices)
         key = (
@@ -403,11 +412,10 @@ class ParallelChannel:
                 return collective.fanout(out, "par"), collective.fanout(m, "par")
 
             # the all_gather makes outputs replicated, which the static
-            # replication check cannot always infer — compat turns it off
-            # under whichever spelling (check_vma/check_rep) this jax has
-            wrapped = shard_map_compat(
+            # replication check cannot always infer — turn it off
+            wrapped = jax.shard_map(
                 body, mesh=mesh, in_specs=(P("par"), P("par")),
-                out_specs=(P(), P()),
+                out_specs=(P(), P()), check_vma=False,
             )
             fused = jax.jit(wrapped)
             cached = (fused, data_sh, mesh, dm)

@@ -130,39 +130,15 @@ def _maybe_install_sigterm() -> None:
         )
 
 
-_warned_distributed_probe = False
-
-
 def _jax_distributed_initialized() -> bool:
     """True when this process joined a ``jax.distributed`` group — the
     deployment where cross-process collective sessions are meaningful.
-    Probes the coordination-service client only; never initializes a
-    backend (Server.start must stay cheap for pure-host servers)."""
+    Never imports jax (Server.start must stay cheap for pure-host
+    servers): a group cannot have been joined without it."""
     import sys
 
-    if "jax" not in sys.modules:
-        # jax.distributed cannot have been initialized without importing
-        # jax — and importing it here would cost seconds of startup (and
-        # can raise on a misconfigured accelerator runtime)
-        return False
-    try:
-        from jax._src import distributed
-
-        return distributed.global_state.client is not None
-    except (ImportError, AttributeError):
-        # private-API layout drift in a jax upgrade: don't silently strip
-        # the collective service from real distributed deployments — warn
-        # so the operator knows to pin enable_collective_service=True
-        global _warned_distributed_probe
-        if not _warned_distributed_probe:
-            _warned_distributed_probe = True
-            logger.warning(
-                "jax.distributed probe failed (private API moved?); "
-                "collective service auto-enable is off — set "
-                "ServerOptions(enable_collective_service=True) to force it",
-                exc_info=True,
-            )
-        return False
+    jax = sys.modules.get("jax")
+    return jax is not None and jax.distributed.is_initialized()
 
 
 class MethodStatus:
@@ -774,18 +750,16 @@ class Server:
                 )
             # the collective METHOD plane (general kernel dispatch) shares
             # the opt-in and the admission limit with the legacy session
-            # service — one deployment decision covers both
+            # service — one deployment decision covers both. Its handler
+            # applies the limit to RUN phases itself: the method also
+            # carries the abort/resume control traffic, which must land
+            # while the admitted session runs
             cd = f"{HANDSHAKE_SERVICE}.{DISPATCH_METHOD}"
             if cd not in self._methods:
                 self._methods.insert(
                     cd,
                     MethodProperty(
-                        make_dispatch_handler(self),
-                        MethodStatus(
-                            cd,
-                            max(0, self.options.collective_max_concurrency),
-                        ),
-                        cd,
+                        make_dispatch_handler(self), MethodStatus(cd, 0), cd
                     ),
                 )
         hs = f"{HANDSHAKE_SERVICE}.{HANDSHAKE_METHOD}"

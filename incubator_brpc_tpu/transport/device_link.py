@@ -132,10 +132,10 @@ class DeviceLink:
         """``host_loopback``: when both parties share ONE device the
         exchange is a pure swap — the peer's bytes are already on this
         host (they were queued here) and the consumer is this host's
-        messenger, so a device round trip would be two tunnel crossings
-        that move no information. Default (None) takes the fast path for
-        the shared-device geometry; tests pass False to force the jitted
-        on-device swap.
+        messenger, so a device round trip would be a host→HBM copy and a
+        readback that move no information. Default (None) takes the host
+        swap for the shared-device geometry; ``False`` forces the jitted
+        on-device swap (tests, ``chip_smoke.py``).
 
         ``ack_mode``: how the credit window learns about drained steps.
         'local' (default) gates on this process's shared delivery counter
@@ -257,17 +257,12 @@ class DeviceLink:
             return
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-        try:
-            from jax import shard_map  # JAX >= 0.8
-        except ImportError:  # pragma: no cover — older JAX
-            from jax.experimental.shard_map import shard_map
-
         mesh = Mesh(np.asarray(self.devices), ("link",))
         self._mesh = mesh
         self._sharding = NamedSharding(mesh, P("link"))
 
         def exchange(slots):
-            return shard_map(
+            return jax.shard_map(
                 lambda x: jax.lax.ppermute(x, "link", [(0, 1), (1, 0)]),
                 mesh=mesh,
                 in_specs=P("link"),
@@ -275,6 +270,17 @@ class DeviceLink:
             )(slots)
 
         self._step = jax.jit(exchange, out_shardings=self._sharding)
+
+    @property
+    def geometry(self) -> str:
+        """Which exchange ``_build_step`` chose from the device pair:
+        ``"host-swap"`` (one shared device: no dispatch, no readback),
+        ``"device-swap"`` (one shared device, jitted on-device swap) or
+        ``"ppermute"`` (two devices: the shard_map step over the link
+        mesh)."""
+        if self._step is None:
+            return "host-swap"
+        return "device-swap" if self._mesh is None else "ppermute"
 
     def _make_slots(self, rows: List[np.ndarray]):
         """Device-place both parties' outbound slots as one array sharded

@@ -172,10 +172,44 @@ class TestOverlapSchedule:
 
 
 def test_graft_entry_dryrun():
-    # the dryrun's multi-controller gate needs real cross-process
-    # collectives; probe that capability in seconds instead of letting
-    # the pair burn its whole handshake deadline on a backend without it
-    # (the driver still runs dryrun_multichip directly, probe-free)
+    import __graft_entry__ as ge
+
+    facts = ge.dryrun_multichip(8)
+    assert facts["fused_collective"] is True
+    assert len(facts["nparty_fabric_peers"]) == 7  # servers on devices 1-7
+    assert len(facts["meshes"]) == 2
+
+
+def test_graft_entry_dryrun_on_four_devices():
+    """A four-device host is the deployment: the N-party star and the
+    fused collective must RUN there (client on device 0, servers on 1-3),
+    not vanish behind a device-count gate, and every fabric axis must be
+    live in one of the meshes."""
+    import __graft_entry__ as ge
+
+    facts = ge.dryrun_multichip(4)
+    assert facts["fused_collective"] is True
+    assert len(facts["nparty_fabric_peers"]) == 3
+    assert len(set(facts["device_link"])) == 2
+    for axis in ("dp", "pp", "tp", "sp", "ep"):
+        assert any(m[axis] >= 2 for m in facts["meshes"]), axis
+
+
+def test_graft_entry_dryrun_takes_no_fewer_devices_than_asked():
+    """No fallback: asking for more devices than the process has is an
+    error, never a re-initialisation on something else."""
+    import pytest
+
+    import __graft_entry__ as ge
+
+    with pytest.raises(RuntimeError, match="found 8 cpu"):
+        ge.dryrun_multichip(16)
+
+
+def test_graft_entry_dryrun_multiprocess():
+    # the multi-controller gate needs real cross-process collectives;
+    # probe that capability in seconds instead of letting the pair burn
+    # its whole handshake deadline on a backend without it
     import pytest
 
     from incubator_brpc_tpu.transport.mc_worker import multiprocess_capable
@@ -184,7 +218,8 @@ def test_graft_entry_dryrun():
         pytest.skip("jax backend cannot run multi-process computations")
     import __graft_entry__ as ge
 
-    ge.dryrun_multichip(8)
+    facts = ge.dryrun_multiprocess()
+    assert facts["chaos_resume"]["byte_identical"]
 
 
 def test_graft_entry_single():

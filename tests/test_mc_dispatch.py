@@ -15,6 +15,7 @@ Two tiers:
 from __future__ import annotations
 
 import json
+import time
 
 import numpy as np
 import pytest
@@ -28,26 +29,8 @@ from incubator_brpc_tpu.transport.mc_worker import (
 _FABRIC_UNSUPPORTED = "Multiprocess computations aren't implemented"
 
 
-@pytest.fixture(scope="module")
-def shard_map_capable():
-    """In-process sessions dispatch shard_map over the virtual mesh; skip
-    the module in one cheap step where this jax cannot trace it at all
-    (the test_parallel.py probe pattern, via the compat seam)."""
-    import jax
-
-    from incubator_brpc_tpu.parallel.compat import resolve_shard_map
-
-    try:
-        resolve_shard_map()
-    except ImportError:
-        pytest.skip("no shard_map in this jax build")
-    if len(jax.devices()) < 4:
-        pytest.skip("needs a 4+ device mesh")
-    return True
-
-
 @pytest.fixture
-def registered_scale(shard_map_capable):
+def registered_scale():
     """("dsvc", "scale") bound to the psum+elementwise kernel in THIS
     process's registry (proposer and in-process servers share it)."""
     from incubator_brpc_tpu.rpc.device_method import (
@@ -204,6 +187,78 @@ class TestProposalValidation:
             for s in servers:
                 s.stop()
                 s.join(timeout=5)
+
+    def test_abort_lands_while_the_one_admitted_session_runs(
+        self, registered_scale, monkeypatch
+    ):
+        """``collective_max_concurrency`` (default 1) admits RUNNING
+        sessions; the abort broadcast rides the same method and must
+        reach a party whose one slot is held by the very chain it
+        aborts.  Gated at the method, the abort was refused ELIMIT and a
+        survivor of a peer death waited out its whole deadline."""
+        import base64
+        import threading
+
+        import jax
+
+        from incubator_brpc_tpu.parallel import mc_dispatch as mcd
+        from incubator_brpc_tpu.rpc import Controller, Server, ServerOptions
+        from incubator_brpc_tpu.utils.status import ErrorCode
+
+        entered = threading.Event()
+
+        def parked_chain(*a, should_abort=None, **kw):
+            entered.set()
+            deadline = time.monotonic() + 30
+            while time.monotonic() < deadline:
+                why = should_abort()
+                if why:
+                    raise mcd.SessionAborted(why)
+                time.sleep(0.01)
+            raise AssertionError("the abort never reached the chain")
+
+        monkeypatch.setattr(mcd, "run_dispatch_session", parked_chain)
+        dm = registered_scale
+        parties = [d.id for d in jax.devices()[:2]]
+        server = Server(ServerOptions(enable_collective_service=True))
+        assert server.start(0)
+        try:
+            (ch,) = _host_channels([server])
+
+            def run(sid):
+                return self._proposal(
+                    dm, parties, phase=None, session_id=sid,
+                    operands=[base64.b64encode(b"x").decode()] * 2,
+                )
+
+            done = threading.Event()
+            first = ch.call_method(
+                "_tpu_transport", "collective_dispatch", run("s-parked"),
+                cntl=Controller(timeout_ms=60000),
+                done=lambda c: done.set(),
+            )
+            assert entered.wait(10)
+            second = ch.call_method(
+                "_tpu_transport", "collective_dispatch", run("s-second"),
+                cntl=Controller(timeout_ms=30000),
+            )
+            assert second.error_code == ErrorCode.ELIMIT, second.error_text
+            abort = ch.call_method(
+                "_tpu_transport", "collective_dispatch",
+                json.dumps(
+                    {"phase": "abort", "session_id": "s-parked",
+                     "reason": "peer died", "epoch": 0}
+                ).encode(),
+                cntl=Controller(timeout_ms=30000),
+            )
+            assert abort.ok(), abort.error_text
+            assert json.loads(abort.response_payload) == {"aborted": True}
+            assert done.wait(10)
+            assert first.error_code == ErrorCode.ESESSION, first.error_text
+            assert "peer died" in first.error_text
+        finally:
+            server.stop()
+            server.join(timeout=5)
 
     def test_reject_counter_advances(self, registered_scale):
         import jax
@@ -422,7 +477,7 @@ class TestInProcessSessions:
         assert dispatch_sessions.get_value() >= sessions_before + 2
         assert _method_counter("dsvc", "scale").get_value() >= kernel_before + 2
 
-    def test_pmean_is_just_one_registered_method(self, shard_map_capable):
+    def test_pmean_is_just_one_registered_method(self):
         """mc_collective rides the plane: its resolver mints the pmean
         method per width, and run_collective_session converges to the
         global mean through mc_dispatch.run_dispatch_session."""
@@ -491,8 +546,9 @@ class TestInProcessSessions:
 class TestMcLoweringRouting:
     """ParallelChannel's plane choice, isolated from real links: stub
     sockets whose links look multi-controller (own_side set) must route
-    the call into mc_dispatch.lower_parallel_call; mixed planes and a
-    failing lowering must fall back to the host fan-out silently."""
+    the call into mc_dispatch.lower_parallel_call; mixed planes choose
+    the host fan-out; a lowering that was chosen and fails, fails the
+    call."""
 
     class _FakeLink:
         def __init__(self, dev, mc=True):
@@ -587,9 +643,12 @@ class TestMcLoweringRouting:
         assert getattr(cntl, "collective_fused", False) is False
         assert cntl.response_payload == b"host:reqhost:req"
 
-    def test_failed_lowering_falls_back_to_host(
+    def test_failed_lowering_fails_the_call(
         self, registered_scale, monkeypatch
     ):
+        """Every precondition held, so the device path was chosen: its
+        failure is the call's failure, text included — never a quiet
+        success over the host fan-out."""
         from incubator_brpc_tpu.parallel import mc_dispatch
         from incubator_brpc_tpu.rpc import Controller
 
@@ -598,12 +657,35 @@ class TestMcLoweringRouting:
 
         monkeypatch.setattr(mc_dispatch, "lower_parallel_call", fail_lower)
         pc = self._pc(registered_scale, [True, True])
+        seen = []
+        cntl = pc.call_method(
+            "dsvc", "scale", b"req", cntl=Controller(timeout_ms=5000),
+            done=seen.append,
+        )
+        assert cntl.failed() and seen == [cntl]
+        assert "peer rejected" in cntl.error_text
+        assert getattr(cntl, "collective_fused", False) is False
+        assert all(ch.host_calls == 0 for ch, _m, _r in pc._subs)
+
+    def test_failed_fused_program_fails_the_call(
+        self, registered_scale, monkeypatch
+    ):
+        """Same rule for the single-controller shard_map dispatch: a
+        compile refusal must surface, not vanish behind a fan-out."""
+        from incubator_brpc_tpu.rpc import Controller
+        from incubator_brpc_tpu.rpc.combo import ParallelChannel
+
+        def refuse(self, dm, devices, requests):
+            raise RuntimeError("XLA refused to compile the fused step")
+
+        monkeypatch.setattr(ParallelChannel, "_fused_dispatch", refuse)
+        pc = self._pc(registered_scale, [False, False])
         cntl = pc.call_method(
             "dsvc", "scale", b"req", cntl=Controller(timeout_ms=5000)
         )
-        assert cntl.ok(), cntl.error_text
-        assert getattr(cntl, "collective_fused", False) is False
-        assert cntl.response_payload == b"host:reqhost:req"
+        assert cntl.failed()
+        assert "XLA refused to compile" in cntl.error_text
+        assert all(ch.host_calls == 0 for ch, _m, _r in pc._subs)
 
 
 # -- the real deployment: separate OS processes --------------------------------

@@ -41,23 +41,8 @@ from incubator_brpc_tpu.transport.mc_worker import (
 )
 
 
-@pytest.fixture(scope="module")
-def shard_map_capable():
-    import jax
-
-    from incubator_brpc_tpu.parallel.compat import resolve_shard_map
-
-    try:
-        resolve_shard_map()
-    except ImportError:
-        pytest.skip("no shard_map in this jax build")
-    if len(jax.devices()) < 4:
-        pytest.skip("needs a 4+ device mesh")
-    return True
-
-
 @pytest.fixture
-def registered_chunkable(shard_map_capable):
+def registered_chunkable():
     """("dsvc", "scale") registered CHUNK-SAFE in this process's registry
     (psum + elementwise scale treats every width slice alike and passes
     n through — the chunk-safety contract)."""
@@ -185,7 +170,7 @@ class TestChunkedSessions:
 
         assert 0.0 <= overlap_ratio_gauge.get_value() <= 1.0
 
-    def test_proposer_rejects_unchunkable_kernel(self, shard_map_capable):
+    def test_proposer_rejects_unchunkable_kernel(self):
         """A method registered without chunkable=True cannot run chunked
         — the proposer validates against its own registry before any
         fan-out (a silently mis-chunked kernel would diverge, not
@@ -227,9 +212,7 @@ class TestChunkedSessions:
                 steps=1, proposer_index=0, chunks=MAX_CHUNKS + 1,
             )
 
-    def test_party_without_chunkable_registration_rejects(
-        self, shard_map_capable
-    ):
+    def test_party_without_chunkable_registration_rejects(self):
         """Chunk-safety is validated by EVERY party against its LOCAL
         registry, like the fingerprint: a server whose registration
         lacks the declaration cleanly rejects the run proposal before

@@ -36,22 +36,8 @@ def test_make_fabric_mesh():
     assert np.prod(list(mesh.shape.values())) == 8
 
 
-@pytest.fixture(scope="module")
-def shard_map_capable():
-    """Fast capability probe (the module-scoped gate test_mc_link.py uses
-    for its fabric pair): some environments ship a jax whose public
-    ``jax.shard_map`` entry point (or its ``check_vma`` kwarg) does not
-    exist — every collect below would fail identically, so skip them in
-    one cheap step instead of burning four collects on a doomed API."""
-    try:
-        jax.shard_map  # noqa: B018 — the probe IS the attribute access
-    except AttributeError:
-        pytest.skip("jax.shard_map unavailable in this jax build")
-    return True
-
-
 @pytest.fixture
-def flat_mesh(shard_map_capable):
+def flat_mesh():
     """One-axis view for collective semantics tests: all 8 devices on dp."""
     return make_fabric_mesh(8, axis_sizes={"dp": 8, "pp": 1, "tp": 1, "sp": 1, "ep": 1})
 

@@ -23,21 +23,6 @@ from incubator_brpc_tpu.parallel import quantized as Q
 WIDTH = 256  # 64 floats = 2 default blocks — small enough to jit fast
 
 
-@pytest.fixture(scope="module")
-def shard_map_capable():
-    import jax
-
-    from incubator_brpc_tpu.parallel.compat import resolve_shard_map
-
-    try:
-        resolve_shard_map()
-    except ImportError:
-        pytest.skip("no shard_map in this jax build")
-    if len(jax.devices()) < 4:
-        pytest.skip("needs a 4+ device mesh")
-    return True
-
-
 def _rows(n, nfloats, seed=5, scale=3.0):
     rng = np.random.default_rng(seed)
     return [
@@ -98,6 +83,45 @@ class TestQuantizerMath:
                 for j in range(chunks)
             ]
             assert np.concatenate(parts).tobytes() == full.tobytes()
+
+    @pytest.mark.parametrize("mode", ["int8", "int4"])
+    def test_jax_twin_agrees_bitwise_over_the_exponent_range(self, mode):
+        """The jitted quantizer and the numpy twin produce the same BYTES
+        for magnitudes across the float32 range, for an all-zero block,
+        and for blocks whose amax is exactly qmax·2^k (the boundary the
+        exponent rule turns on) — and the exponent is, exactly, the
+        smallest e with amax <= qmax·2^e.  The arithmetic is built to be
+        exact on any backend: no division, no exp2()."""
+        import jax
+
+        rng = np.random.default_rng(21)
+        n, block, qmax = 4096, Q.DEFAULT_BLOCK, Q.qmax_for(mode)
+        x = (
+            rng.standard_normal(n) * np.exp2(rng.integers(-100, 100, n))
+        ).astype(np.float32)
+        x[:block] = 0.0
+        for j, k in enumerate((-90, -1, 0, 5, 60), start=1):
+            x[j * block:(j + 1) * block] = 0.0
+            x[j * block] = np.float32(qmax) * np.float32(2.0) ** k
+        q, e = jax.jit(
+            lambda v: Q._jq_quantize(v[None, :], mode, block)
+        )(x)
+        nq, ne = Q.np_quantize(x, mode, block)
+        assert np.asarray(e[0]).tobytes() == ne.tobytes()
+        assert np.asarray(q[0]).tobytes() == nq.tobytes()
+        back = jax.jit(
+            lambda a, b: Q._jq_dequantize(a, b, mode, block)
+        )(q, e)
+        assert (
+            np.asarray(back[0]).tobytes()
+            == Q.np_dequantize(nq, ne, mode, block).tobytes()
+        )
+        # float64 holds amax and qmax·2^e exactly: the rule, checked
+        amax = np.abs(x.reshape(-1, block)).max(axis=1).astype(np.float64)
+        ef = ne.astype(np.float64)
+        live = amax > 0
+        assert (amax <= qmax * np.exp2(ef))[live].all()
+        assert (amax > qmax * np.exp2(ef - 1))[live].all()
 
     def test_wire_bytes_and_support(self):
         assert Q.wire_bytes(512, "none") == 512
@@ -175,7 +199,7 @@ class TestQuantizedSessions:
     """The quantize= knob end to end on the virtual mesh."""
 
     @pytest.fixture
-    def pmean_registered(self, shard_map_capable):
+    def pmean_registered(self):
         from incubator_brpc_tpu.parallel.mc_collective import _pmean_dm
         from incubator_brpc_tpu.rpc.device_method import (
             lookup_device_method,
@@ -303,7 +327,7 @@ class TestQuantizedSessions:
         with pytest.raises(ValueError, match="block alignment"):
             _validate_chunks(v8, 4, "_collective", "pmean")
 
-    def test_misdeclared_nonchunkable_variant_rejects(self, shard_map_capable):
+    def test_misdeclared_nonchunkable_variant_rejects(self):
         """A quantized variant registered WITHOUT the chunk-safety
         declaration rejects a chunked session cleanly pre-lockstep —
         at the proposer seam and at the handler seam alike."""
@@ -402,7 +426,7 @@ class TestQuantizedProposals:
     stamp."""
 
     @pytest.fixture
-    def server_and_channel(self, shard_map_capable):
+    def server_and_channel(self):
         from incubator_brpc_tpu.rpc import (
             Channel,
             Server,
@@ -591,9 +615,7 @@ class TestTopologySchedule:
         assert chunk_order == [0, 1, 2, 3]
         assert note == ""
 
-    def test_propose_dispatch_orders_by_synthetic_profile(
-        self, shard_map_capable
-    ):
+    def test_propose_dispatch_orders_by_synthetic_profile(self):
         """The acceptance check: a session proposed under skewed link
         telemetry demonstrably fans out slowest-first and front-loads
         that party's chunk slices — visible in the result's audit
@@ -672,7 +694,7 @@ class TestTopologySchedule:
 class TestLinkProfileAccessor:
     """DeviceLinkMap.link_profile(): the PR 1 recorders, structured."""
 
-    def test_live_link_profile(self, shard_map_capable):
+    def test_live_link_profile(self):
         from incubator_brpc_tpu.rpc import (
             Channel,
             ChannelOptions,

@@ -1407,14 +1407,6 @@ class TestSessionAbortChaosDrill:
     def mesh(self, tuned_flags):
         import jax
 
-        from incubator_brpc_tpu.parallel.compat import resolve_shard_map
-
-        try:
-            resolve_shard_map()
-        except ImportError:
-            pytest.skip("no shard_map in this jax build")
-        if len(jax.devices()) < 4:
-            pytest.skip("needs a 4+ device mesh")
         # breaker windows sized so the dead party's refused dials trip it
         # within a screenful of calls (the TestBrownoutRecovery tuning)
         tuned_flags("circuit_breaker_short_window_size", 30)
@@ -1640,7 +1632,9 @@ class TestLameDuck:
         ts = [threading.Thread(target=call) for _ in range(6)]
         for t in ts:
             t.start()
-        assert wait_until(lambda: srv._nprocessing > 0, timeout=5.0)
+        # all six admitted: on a loaded host a straggler that arrives
+        # after the drain starts is refused ELOGOFF like any new work
+        assert wait_until(lambda: srv._nprocessing == 6, timeout=5.0)
 
         drain = srv.enter_lame_duck(grace_s=10)
         assert drain is not None
@@ -2008,26 +2002,14 @@ class TestCheckpointRings:
             mcd.release_checkpoints(sid)
 
 
-def _shard_map_or_skip(min_devices=4):
-    import jax
-
-    from incubator_brpc_tpu.parallel.compat import resolve_shard_map
-
-    try:
-        resolve_shard_map()
-    except ImportError:
-        pytest.skip("no shard_map in this jax build")
-    if len(jax.devices()) < min_devices:
-        pytest.skip(f"needs a {min_devices}+ device mesh")
-    return jax.devices()
-
-
 class TestElasticSessionUnits:
     """run_dispatch_session's checkpoint/restore seam, driven directly
     (single process, all shards addressable)."""
 
     def test_resume_replays_only_steps_past_checkpoint(self):
-        devices = _shard_map_or_skip(3)
+        import jax
+
+        devices = jax.devices()
         from incubator_brpc_tpu.parallel import mc_dispatch as mcd
         from incubator_brpc_tpu.rpc.device_method import DeviceMethod
         from incubator_brpc_tpu.transport.mc_worker import (
@@ -2058,7 +2040,9 @@ class TestElasticSessionUnits:
             mcd.release_checkpoints(sid)
 
     def test_resume_point_equal_to_final_replays_nothing(self):
-        devices = _shard_map_or_skip(3)
+        import jax
+
+        devices = jax.devices()
         from incubator_brpc_tpu.parallel import mc_dispatch as mcd
         from incubator_brpc_tpu.rpc.device_method import DeviceMethod
         from incubator_brpc_tpu.transport.mc_worker import (
@@ -2088,7 +2072,9 @@ class TestElasticSessionUnits:
         """The reshard wire format: checkpoint_fetch's b64 rows restore a
         party with NO local ring (the replacement's bootstrap) and the
         replayed chain lands byte-identical."""
-        devices = _shard_map_or_skip(4)
+        import jax
+
+        devices = jax.devices()
         import base64
 
         from incubator_brpc_tpu.parallel import mc_dispatch as mcd
@@ -2127,7 +2113,9 @@ class TestElasticSessionUnits:
             mcd.release_checkpoints("t-unit-reshard-2")
 
     def test_missing_checkpoint_raises_lookup_error(self):
-        devices = _shard_map_or_skip(3)
+        import jax
+
+        devices = jax.devices()
         from incubator_brpc_tpu.parallel import mc_dispatch as mcd
         from incubator_brpc_tpu.rpc.device_method import DeviceMethod
         from incubator_brpc_tpu.transport.mc_worker import (
@@ -2161,14 +2149,6 @@ class TestElasticResumeChaosDrill:
     def mesh(self, tuned_flags):
         import jax
 
-        from incubator_brpc_tpu.parallel.compat import resolve_shard_map
-
-        try:
-            resolve_shard_map()
-        except ImportError:
-            pytest.skip("no shard_map in this jax build")
-        if len(jax.devices()) < 5:
-            pytest.skip("needs a 5+ device mesh (3 parties + spare)")
         tuned_flags("circuit_breaker_short_window_size", 30)
         tuned_flags("circuit_breaker_long_window_size", 300)
         tuned_flags("circuit_breaker_min_isolation_duration_ms", 60000)
@@ -2461,14 +2441,6 @@ class TestStepWatchdog:
     def mesh(self, tuned_flags):
         import jax
 
-        from incubator_brpc_tpu.parallel.compat import resolve_shard_map
-
-        try:
-            resolve_shard_map()
-        except ImportError:
-            pytest.skip("no shard_map in this jax build")
-        if len(jax.devices()) < 4:
-            pytest.skip("needs a 4+ device mesh")
         from incubator_brpc_tpu.rpc import device_method
         from incubator_brpc_tpu.rpc.device_method import (
             DeviceMethod,
