@@ -64,10 +64,20 @@ class Span:
     response_size: int = 0
     # (offset_us_from_start, text) — Span::Annotate analog
     annotations: List[Tuple[float, str]] = field(default_factory=list)
+    # time.monotonic_ns() at creation: the clock annotation offsets are
+    # taken on, shared with the load generator, the handler spans and
+    # (through its sync mark) the device trace. start_real_us stays the
+    # wall time for display and retention. Not part of a span's identity.
+    start_mono_ns: int = field(
+        default_factory=time.monotonic_ns, compare=False, repr=False
+    )
 
-    def annotate(self, text: str) -> None:
-        now_us = time.time() * 1e6
-        self.annotations.append((now_us - self.start_real_us, text))
+    def annotate(self, text: str, at_ns: Optional[int] = None) -> None:
+        """Note ``text`` at ``at_ns`` (a ``time.monotonic_ns()`` stamp
+        taken where the work happened), or now."""
+        if at_ns is None:
+            at_ns = time.monotonic_ns()
+        self.annotations.append(((at_ns - self.start_mono_ns) / 1e3, text))
 
 
 class _SpeedLimiter:
@@ -299,6 +309,7 @@ def span_to_dict(span: Span) -> dict:
         "log_id": span.log_id,
         "error_code": span.error_code,
         "start_real_us": span.start_real_us,
+        "start_mono_ns": span.start_mono_ns,
         "latency_us": span.latency_us,
         "request_size": span.request_size,
         "response_size": span.response_size,
@@ -443,6 +454,7 @@ def span_from_dict(d: dict) -> Optional[Span]:
             log_id=int(d.get("log_id", 0)),
             error_code=int(d.get("error_code", 0)),
             start_real_us=int(d.get("start_real_us", 0)),
+            start_mono_ns=int(d.get("start_mono_ns", 0)),
             latency_us=float(d.get("latency_us", 0.0)),
             request_size=int(d.get("request_size", 0)),
             response_size=int(d.get("response_size", 0)),

@@ -12,7 +12,11 @@ thread (detail/sampler.cpp), and a global expose/dump registry
 
 from incubator_brpc_tpu.bvar.variable import Variable, expose_registry, dump_exposed
 from incubator_brpc_tpu.bvar.reducer import Adder, Maxer, Miner, PassiveStatus
-from incubator_brpc_tpu.bvar.recorder import IntRecorder, LatencyRecorder
+from incubator_brpc_tpu.bvar.recorder import (
+    IntRecorder,
+    LatencyRecorder,
+    RecorderFeed,
+)
 from incubator_brpc_tpu.bvar.window import Window, PerSecond
 from incubator_brpc_tpu.bvar.percentile import Percentile
 
@@ -26,6 +30,7 @@ __all__ = [
     "PassiveStatus",
     "IntRecorder",
     "LatencyRecorder",
+    "RecorderFeed",
     "Window",
     "PerSecond",
     "Percentile",

@@ -63,6 +63,13 @@ class _SamplerThread:
 _sampler_thread = _SamplerThread()
 
 
+def sample_every_second(sampler) -> None:
+    """Have the global 1 Hz sampler thread call ``sampler._take_sample()``
+    (held weakly, like a Window): the place for combining work that the
+    write path should not pay for."""
+    _sampler_thread.register(sampler)
+
+
 class Window(Variable):
     """Value accumulated over the last ``window_size`` seconds of a reducer
     with an inverse op (e.g. Adder) — reference bvar::Window.

@@ -68,6 +68,9 @@ class Controller:
 
         # -- internals (owned by channel.py / server.py) --
         self._start_ts: float = 0.0
+        # server side: time.monotonic() when the messenger cut the request's
+        # frame off the wire (None where no messenger stamped it)
+        self._arrival_ts: Optional[float] = None
         self._deadline: float = 0.0
         self._done: Optional[Callable[["Controller"], None]] = None
         self._timer_ids: List[Any] = []

@@ -1232,6 +1232,7 @@ class Server:
         # answer in the protocol the request arrived in (the reference keys
         # SendRpcResponse off the request's protocol the same way)
         cntl._wire_protocol = getattr(frame, "wire_protocol", "tbus_std")
+        cntl._arrival_ts = getattr(frame, "arrival_ts", None)
         cntl._mark_start()
 
         # deadline propagation (reference RpcRequestMeta.timeout_ms +

@@ -19,6 +19,7 @@ HandleCompletion.
 from __future__ import annotations
 
 import threading
+import time
 from typing import Any, Callable, List, Optional
 
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
@@ -54,12 +55,10 @@ class _WatcherPool:
             self._cond.notify()
 
     def quiesce(self, timeout: float = 10.0) -> bool:
-        import time as _time
-
-        deadline = _time.monotonic() + timeout
+        deadline = time.monotonic() + timeout
         with self._cond:
             while self._jobs or self._active:
-                remaining = deadline - _time.monotonic()
+                remaining = deadline - time.monotonic()
                 if remaining <= 0:
                     return False
                 self._cond.wait(timeout=min(remaining, 0.1))
@@ -124,11 +123,17 @@ class DeviceCompletionButex(Butex):
         self,
         arrays: Any,
         on_complete: Optional[Callable[[Any, Optional[BaseException]], None]] = None,
+        stamps: Optional[List[int]] = None,
     ):
         """Watch a pytree of device arrays; when settled, value += 1 and
         waiters wake; on_complete(arrays, error_or_None) then runs on the
         watcher thread (guarded — a raising callback cannot strand waiters,
-        because the bump/wake already happened)."""
+        because the bump/wake already happened).
+
+        ``stamps``: a two-slot list the watcher fills with
+        ``time.monotonic_ns()`` before on_complete runs — [0] when a
+        watcher thread took the job (how long it queued behind the pool),
+        [1] when ``block_until_ready`` returned."""
         import jax
 
         with self._cb_lock:
@@ -136,10 +141,14 @@ class DeviceCompletionButex(Butex):
 
         def job() -> None:
             error: Optional[BaseException] = None
+            if stamps is not None:
+                stamps[0] = time.monotonic_ns()
             try:
                 jax.block_until_ready(arrays)
             except BaseException as e:  # noqa: BLE001 — device failure is data here
                 error = e
+            if stamps is not None:
+                stamps[1] = time.monotonic_ns()
             with self._cb_lock:
                 self._inflight -= 1
                 if error is not None:

@@ -30,7 +30,9 @@ step 5).
 
 from __future__ import annotations
 
+import itertools
 import threading
+from collections import deque
 from typing import Optional, Tuple
 
 import time as _time
@@ -39,7 +41,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from incubator_brpc_tpu.bvar import Adder, LatencyRecorder
+from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
 from incubator_brpc_tpu.ops import framing
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.device_butex import DeviceCompletionButex
@@ -48,8 +50,46 @@ from incubator_brpc_tpu.utils.status import ErrorCode
 MIN_BUCKET_WORDS = 64
 MAX_BUCKET_WORDS = 1 << 24  # 64 MiB of uint32
 
-device_calls = Adder(name="device_transport_calls")
+# credit held -> response parsed, per call: the total the stages split
 device_latency = LatencyRecorder(name="device_transport_latency")
+# One recorder per stage of a call through call_bytes (us, one sample per
+# completed call), so that their means add up to the time inside
+# call_bytes. docs/OBSERVABILITY.md has the table. Fed within the second
+# through _stage_feed, not by the caller.
+m_copy = LatencyRecorder(name="device_transport_copy_us")
+m_credit_wait = LatencyRecorder(name="device_transport_credit_wait_us")
+m_queue_wait = LatencyRecorder(name="device_transport_queue_wait_us")
+m_stack = LatencyRecorder(name="device_transport_stack_us")
+m_launch = LatencyRecorder(name="device_transport_launch_us")
+m_cq_wait = LatencyRecorder(name="device_transport_cq_wait_us")
+m_ready = LatencyRecorder(name="device_transport_ready_us")
+m_readback = LatencyRecorder(name="device_transport_readback_us")
+m_wake = LatencyRecorder(name="device_transport_wake_us")
+# messenger cut -> server_handler entered (the server's half of the host
+# plane in front of the device path)
+m_ingress = LatencyRecorder(name="device_transport_ingress_us")
+# per dispatch that reached the device, fed by the completion watcher
+m_dispatches = Adder(name="device_transport_dispatches")
+m_dispatch_rows = Adder(name="device_transport_dispatch_rows")
+m_dispatch_pad_rows = Adder(name="device_transport_dispatch_pad_rows")
+m_dispatch_words = Adder(name="device_transport_dispatch_words")
+
+
+# a completed call's stage times (ns, in this order) wait here for the
+# sampler thread: the write path is one append
+_stage_feed = RecorderFeed(
+    (recorder, 1e-3)
+    for recorder in (
+        m_copy, m_credit_wait, m_queue_wait, m_stack, m_launch,
+        m_cq_wait, m_ready, m_readback, m_wake, m_ingress,
+    )
+)
+
+
+def flush_stage_recorders() -> None:
+    """Feed the stage recorders now instead of within the second (tests,
+    a reader that wants the last calls counted)."""
+    _stage_feed.flush()
 
 
 def _bucket_words(n: int) -> int:
@@ -61,15 +101,53 @@ def _bucket_words(n: int) -> int:
     return b
 
 
+class _Dispatch:
+    """One (batch, bucket) program execution, shared by the calls stacked
+    into it. Its ``time.monotonic_ns()`` stamps are each written once, by
+    the thread that does the work: the drain or ``-tx`` thread up to
+    ``t_launched``, a completion watcher from there."""
+
+    __slots__ = (
+        "seq", "rows", "pad_rows", "bucket",
+        "t_batched", "t_stacked", "t_launched", "watcher", "t_readback",
+    )
+
+    def __init__(self, seq: int, rows: int, pad_rows: int, bucket: int):
+        self.seq = seq  # the endpoint's dispatch number
+        self.rows = rows  # calls stacked
+        self.pad_rows = pad_rows  # rows the program ran (next power of two)
+        self.bucket = bucket  # payload words per row
+        self.t_batched = _time.monotonic_ns()  # taken off the queue
+        self.t_stacked = 0  # rows copied into one array
+        self.t_launched = 0  # device_put and the program call returned
+        # DeviceCompletionButex.watch fills these: a watcher thread took
+        # the job, block_until_ready returned
+        self.watcher = [0, 0]
+        self.t_readback = 0  # device_get returned; 0 = never completed
+
+
 class _PendingCall:
-    __slots__ = ("ready", "response_words", "error_code", "error", "_t0")
+    """One call and its timeline: ``time.monotonic_ns()`` stamps written
+    by the caller's thread, plus the stamps of the dispatch it rode."""
+
+    __slots__ = (
+        "ready", "response_words", "error_code", "error",
+        "t_entry", "t_words", "t_credit", "t_enqueued", "dispatch",
+        "t_woke", "t_exit",
+    )
 
     def __init__(self):
         self.ready = Butex(0)
         self.response_words = None
         self.error_code = 0
         self.error: Optional[BaseException] = None
-        self._t0 = 0.0
+        self.t_entry = 0  # call_bytes entered (call_words: same as t_words)
+        self.t_words = 0  # payload is words: call_words entered
+        self.t_credit = 0  # credit held
+        self.t_enqueued = 0  # padded into its bucket and queued
+        self.dispatch: Optional[_Dispatch] = None
+        self.t_woke = 0  # caller running again after wait
+        self.t_exit = 0  # call_bytes returns (call_words: stays 0)
 
     def settle(self) -> None:
         self.ready.add(1)
@@ -79,7 +157,70 @@ class _PendingCall:
         while self.ready.load() == 0:
             if self.ready.wait(0, timeout=timeout) == ETIMEDOUT:
                 return False
+        if not self.t_woke:
+            self.t_woke = _time.monotonic_ns()
         return True
+
+    def completed(self) -> bool:
+        """The call went the whole way: through a dispatch to a readback
+        (whatever the response said). Only such calls are recorded."""
+        return self.dispatch is not None and self.dispatch.t_readback != 0
+
+    def timeline(self):
+        """``(name, monotonic_ns)`` of every stamp, in the order written."""
+        d = self.dispatch
+        return (
+            ("entry", self.t_entry),
+            ("words", self.t_words),
+            ("credit_held", self.t_credit),
+            ("enqueued", self.t_enqueued),
+            ("batched", d.t_batched),
+            ("stacked", d.t_stacked),
+            ("launched", d.t_launched),
+            ("cq_taken", d.watcher[0]),
+            ("ready", d.watcher[1]),
+            ("readback", d.t_readback),
+            ("woke", self.t_woke),
+            ("exit", self.t_exit),
+        )
+
+    def stages(self, ingress_ns: Optional[int]) -> tuple:
+        """The stage times of a call through call_bytes, ns, in
+        ``_stage_feed``'s recorders' order; but for ingress they add up
+        to ``t_exit - t_entry``."""
+        d = self.dispatch
+        t_cq, t_ready = d.watcher
+        return (
+            # the adapter's host copies: bytes to words, words into the
+            # zeroed bucket, response words back to bytes
+            (self.t_words - self.t_entry)
+            + (self.t_enqueued - self.t_credit)
+            + (self.t_exit - self.t_woke),
+            self.t_credit - self.t_words,
+            d.t_batched - self.t_enqueued,
+            d.t_stacked - d.t_batched,
+            d.t_launched - d.t_stacked,
+            t_cq - d.t_launched,
+            t_ready - t_cq,
+            d.t_readback - t_ready,
+            self.t_woke - d.t_readback,
+            ingress_ns,
+        )
+
+    def annotate(self, span) -> None:
+        """A sampled server span gets the call's stamps as annotations,
+        offsets on the span's monotonic clock; calls of one dispatch
+        share its number."""
+        d = self.dispatch
+        for name, at in self.timeline():
+            if not at:
+                continue
+            if name == "batched":
+                name = (
+                    f"batched dispatch={d.seq} rows={d.rows} "
+                    f"pad_rows={d.pad_rows} bucket={d.bucket}"
+                )
+            span.annotate("device " + name, at)
 
 
 class DeviceEndpoint:
@@ -92,8 +233,6 @@ class DeviceEndpoint:
         window_size: int = 8,
         max_batch: int = 16,
     ):
-        from collections import deque
-
         from incubator_brpc_tpu.models.tensor_echo import TensorEchoService
 
         self.service = service or TensorEchoService()
@@ -113,6 +252,7 @@ class DeviceEndpoint:
         self._queue = deque()  # (bucket, mid_u32, row, cid_u32, pending, n)
         self._qlock = threading.Lock()
         self._draining = False
+        self._dispatch_seq = itertools.count(1)
         # frame-building fused INTO the jitted program; the batched form
         # vmaps the same fused step over stacked rows (jit's per-shape
         # cache gives one compiled program per (batch, bucket) geometry —
@@ -172,12 +312,12 @@ class DeviceEndpoint:
         Returns a _PendingCall the caller can wait on; the credit is held
         until the response settles (the per-WR ack discipline)."""
         pending = _PendingCall()
+        pending.t_entry = pending.t_words = _time.monotonic_ns()
         if not self._acquire_credit(timeout):
             pending.error_code = ErrorCode.EOVERCROWDED
             pending.settle()
             return pending
-        device_calls << 1
-        pending._t0 = _time.monotonic()
+        pending.t_credit = _time.monotonic_ns()
         n = payload_words.shape[0]
         try:
             bucket = _bucket_words(max(1, n))
@@ -191,6 +331,7 @@ class DeviceEndpoint:
             return pending
         padded = np.zeros(bucket, dtype=np.uint32)
         padded[:n] = payload_words
+        pending.t_enqueued = _time.monotonic_ns()
         with self._qlock:
             self._queue.append(
                 (
@@ -256,13 +397,16 @@ class DeviceEndpoint:
         bpad = 1
         while bpad < b:
             bpad <<= 1
+        dispatch = _Dispatch(next(self._dispatch_seq), b, bpad, bucket)
         rows = np.zeros((bpad, bucket + 0), dtype=np.uint32)
         cids = np.zeros(bpad, dtype=np.uint32)
         mids = np.zeros(bpad, dtype=np.uint32)
-        for i, (_, mid, padded, cid, _p, _n) in enumerate(batch):
+        for i, (_, mid, padded, cid, pending, _n) in enumerate(batch):
             rows[i] = padded
             cids[i] = cid
             mids[i] = mid
+            pending.dispatch = dispatch
+        dispatch.t_stacked = _time.monotonic_ns()
         try:
             if bpad == 1:
                 response = self._program(  # single call: no vmap overhead
@@ -283,6 +427,7 @@ class DeviceEndpoint:
                 pending.error_code = ErrorCode.EINTERNAL
                 pending.settle()
             return
+        dispatch.t_launched = _time.monotonic_ns()
 
         def on_complete(arrays, error, _batch=batch, _single=(bpad == 1)):
             try:
@@ -291,6 +436,7 @@ class DeviceEndpoint:
                     host = np.asarray(jax.device_get(arrays))
             except Exception as e:  # noqa: BLE001 — fetch failed
                 error, host = e, None
+            dispatch.t_readback = _time.monotonic_ns()
             for i, (_, _mid, _padded, _cid, pending, n) in enumerate(_batch):
                 try:
                     if error is not None:
@@ -302,8 +448,8 @@ class DeviceEndpoint:
                         pending.error_code = int(err)
                         pending.response_words = words[:n]
                     device_latency << (
-                        _time.monotonic() - pending._t0
-                    ) * 1e6
+                        _time.monotonic_ns() - pending.t_credit
+                    ) / 1e3
                 except Exception as e:  # noqa: BLE001 — parse failed
                     pending.error = e
                     pending.error_code = ErrorCode.EINTERNAL
@@ -311,8 +457,17 @@ class DeviceEndpoint:
                 finally:
                     self._release_credit()
                     pending.settle()
+            # after the callers are awake: this thread is a pooled
+            # watcher, so the adders keep one agent each (the drain and
+            # -tx threads are born per dispatch)
+            m_dispatches << 1
+            m_dispatch_rows << dispatch.rows
+            m_dispatch_pad_rows << dispatch.pad_rows
+            m_dispatch_words << dispatch.pad_rows * dispatch.bucket
 
-        self._cq.watch(response, on_complete=on_complete)
+        self._cq.watch(
+            response, on_complete=on_complete, stamps=dispatch.watcher
+        )
 
     def call_bytes(
         self,
@@ -320,9 +475,14 @@ class DeviceEndpoint:
         method_id: int = 0,
         correlation_id: int = 1,
         timeout: Optional[float] = 10.0,
+        cntl=None,
     ) -> Tuple[int, bytes]:
         """Sync byte adapter: pad to words, run, trim the response to the
-        request's byte length (handlers are shape-preserving)."""
+        request's byte length (handlers are shape-preserving). ``cntl``:
+        the server-side controller of the RPC this call serves, if any —
+        its arrival stamp gives the ingress time, and its rpcz span, if
+        sampled, gets the call's timeline as annotations."""
+        t_entry = _time.monotonic_ns()
         nbytes = len(payload)
         pad = (-nbytes) % 4
         words = np.frombuffer(payload + b"\x00" * pad, dtype=np.uint32)
@@ -335,11 +495,24 @@ class DeviceEndpoint:
         remaining = None
         if deadline is not None:
             remaining = max(0.0, deadline - _time.monotonic())
+        pending.t_entry = t_entry
         if not pending.wait(remaining):
             return ErrorCode.ERPCTIMEDOUT, b""
-        if pending.error_code:
-            return pending.error_code, b""
-        return 0, pending.response_words.tobytes()[:nbytes]
+        out = b""
+        if not pending.error_code:
+            out = pending.response_words.tobytes()[:nbytes]
+        pending.t_exit = _time.monotonic_ns()
+        if pending.completed():
+            ingress_ns = span = None
+            if cntl is not None:
+                span = getattr(cntl, "_span", None)
+                arrival = getattr(cntl, "_arrival_ts", None)
+                if arrival is not None:
+                    ingress_ns = t_entry - int(arrival * 1e9)
+            _stage_feed.rows.append(pending.stages(ingress_ns))
+            if span is not None:
+                pending.annotate(span)
+        return pending.error_code, out
 
     def warm(self, payload_bytes: int, timeout: float = 300.0) -> None:
         """Compile every (batch, bucket) geometry this payload size can hit
@@ -385,6 +558,7 @@ class DeviceEndpoint:
                 method_id=method_id,
                 correlation_id=cntl.call_id or 1,
                 timeout=timeout,
+                cntl=cntl,
             )
             if code:
                 cntl.set_failed(code, f"device call failed ({code})")

@@ -51,7 +51,7 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, PerSecond
+from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, PerSecond, RecorderFeed
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.device_butex import DeviceCompletionButex
 from incubator_brpc_tpu.runtime.worker_pool import global_worker_pool
@@ -73,6 +73,9 @@ HANDSHAKE_METHOD = "handshake"
 
 link_steps = Adder(name="device_link_steps")
 link_bytes = Adder(name="device_link_bytes")
+# payload capacity of every slot side filled: set against device_link_bytes
+# it says how full the slots travel
+link_capacity = Adder(name="device_link_capacity_bytes")
 link_acks = Adder(name="device_link_ack_steps")  # wire-mode catch-up steps
 link_errors = Adder(name="device_link_errors")  # fail() calls, all links
 # send() attempts refused with EOVERCROWDED after a full window-stall wait
@@ -116,6 +119,22 @@ def _quiesce_links(timeout: float = 10.0) -> None:
 import atexit
 
 atexit.register(_quiesce_links)
+
+
+class _Step:
+    """One exchange step's timeline, ``time.monotonic_ns()`` stamps each
+    written once by the thread that does the work."""
+
+    __slots__ = ("t_dispatch", "interval_ns", "inflight", "t_launched", "watcher")
+
+    def __init__(self, t_dispatch: int, interval_ns: int, inflight: int):
+        self.t_dispatch = t_dispatch  # slots filled, seq taken
+        self.interval_ns = interval_ns  # since the drive's previous dispatch
+        self.inflight = inflight  # undrained steps, this one included
+        self.t_launched = 0  # _make_slots and the step call returned
+        # DeviceCompletionButex.watch fills these: a watcher thread took
+        # the job, block_until_ready returned
+        self.watcher = [0, 0]
 
 
 class DeviceLink:
@@ -180,11 +199,16 @@ class DeviceLink:
         self._cq = DeviceCompletionButex()
         self.socks: List[Optional["DeviceSocket"]] = [None, None]
         self._pool = global_worker_pool()
-        # -- per-link instrumentation (scraped at /brpc_metrics): the
-        # observable face of bench.py's link_stream_gbps — rtt per exchange
-        # step (dispatch -> in-order delivery), flush = the staging gather
-        # into a slot, pump = feeding delivered bytes into the messenger,
-        # plus bytes-per-second windows each way. Retired (hidden from the
+        # -- per-link instrumentation (scraped at /brpc_metrics): rtt per
+        # exchange step (dispatch -> end of its in-order delivery) and the
+        # stages that add up to it — launch (device placement + the step
+        # call), ready (watch -> block_until_ready returned), reorder_wait
+        # (ready -> its in-order delivery begins), readback (_rows_to_host),
+        # pump (feeding delivered bytes into the messenger). flush = the
+        # staging gather into a slot; dispatch_interval = the host time
+        # between one drive's consecutive dispatches; inflight_at_dispatch
+        # = how much of the window is in use (a count, not a time); plus
+        # bytes-per-second windows each way. Retired (hidden from the
         # registry) when the link dies so churning links don't accumulate.
         self.link_id = next(_link_ids)
         pfx = f"device_link_{self.link_id}"
@@ -192,11 +216,29 @@ class DeviceLink:
         self._m_in_bytes = Adder()
         self._m_rtt = LatencyRecorder(name=f"{pfx}_step_rtt_us")
         self._m_flush = LatencyRecorder(name=f"{pfx}_flush_us")
+        self._m_launch = LatencyRecorder(name=f"{pfx}_launch_us")
+        self._m_ready = LatencyRecorder(name=f"{pfx}_ready_us")
+        self._m_reorder_wait = LatencyRecorder(name=f"{pfx}_reorder_wait_us")
+        self._m_readback = LatencyRecorder(name=f"{pfx}_readback_us")
         self._m_pump = LatencyRecorder(name=f"{pfx}_pump_us")
+        self._m_dispatch_interval = LatencyRecorder(
+            name=f"{pfx}_dispatch_interval_us"
+        )
+        self._m_inflight = LatencyRecorder(name=f"{pfx}_inflight_at_dispatch")
         self._m_out_rate = PerSecond(self._m_out_bytes, name=f"{pfx}_out_bytes_second")
         self._m_in_rate = PerSecond(self._m_in_bytes, name=f"{pfx}_in_bytes_second")
+        # a delivered step's numbers (ns, but for the in-flight count) wait
+        # here for the sampler thread: nine feeds a step on the delivering
+        # thread would sit between one step and the next
+        self._step_feed = RecorderFeed((
+            (self._m_launch, 1e-3), (self._m_ready, 1e-3),
+            (self._m_reorder_wait, 1e-3), (self._m_readback, 1e-3),
+            (self._m_pump, 1e-3), (self._m_rtt, 1e-3),
+            (self._m_dispatch_interval, 1e-3), (self._m_inflight, 1),
+        ))
         self._metrics_retired = False
-        self._step_ts: Dict[int, float] = {}  # seq -> dispatch perf_counter
+        self._steps: Dict[int, _Step] = {}  # seq -> timeline, until delivered
+        self._last_dispatch_ns = 0  # this drive's previous dispatch; 0 = none
         self._build_step()
         with _links_lock:
             _all_links.add(self)
@@ -207,8 +249,11 @@ class DeviceLink:
         if self._metrics_retired:
             return
         self._metrics_retired = True
+        self._step_feed.flush()  # profile() still reads the recorders
         for v in (
-            self._m_rtt, self._m_flush, self._m_pump,
+            self._m_rtt, self._m_flush, self._m_launch, self._m_ready,
+            self._m_reorder_wait, self._m_readback, self._m_pump,
+            self._m_dispatch_interval, self._m_inflight,
             self._m_out_rate, self._m_in_rate,
         ):
             try:
@@ -364,6 +409,7 @@ class DeviceLink:
             if self._driving or self._closed:
                 return
             self._driving = True
+            self._last_dispatch_ns = 0  # the queue ran dry: no interval
         self._pool.spawn(self._drive)
 
     # -- the drainer (single-drainer discipline, like Socket's KeepWrite) ----
@@ -383,6 +429,19 @@ class DeviceLink:
         if self.ack_mode == "wire":
             return self._seq - self._peer_ack >= self.window
         return self._inflight >= self.window
+
+    def _take_seq_locked(self) -> tuple:
+        """Under the link lock, slots filled: take the next seq, count the
+        step in flight and start its timeline."""
+        seq = self._seq
+        self._seq += 1
+        self._inflight += 1
+        now = time.monotonic_ns()
+        last, self._last_dispatch_ns = self._last_dispatch_ns, now
+        step = self._steps[seq] = _Step(
+            now, now - last if last else 0, self._inflight
+        )
+        return seq, step
 
     def _drive(self) -> None:
         while True:
@@ -426,10 +485,7 @@ class DeviceLink:
                     need = None
                 if need is None:
                     rows = [self._fill_slot_locked(s) for s in (0, 1)]
-                    seq = self._seq
-                    self._seq += 1
-                    self._inflight += 1
-                    self._step_ts[seq] = time.perf_counter()
+                    seq, step = self._take_seq_locked()
             if need is not None:
                 if self.ack_mode == "wire":
                     self._wbutex.wait(need, timeout=1.0)
@@ -445,6 +501,9 @@ class DeviceLink:
                 # the synchronous delivery must fail the link, not strand
                 # _driving=True with the queue wedged.
                 link_steps << 1
+                step.t_launched = step.watcher[0] = step.watcher[1] = (
+                    time.monotonic_ns()
+                )
                 try:
                     self._on_step_done(seq, ("host", [rows[1], rows[0]]), None)
                 except Exception:
@@ -462,12 +521,14 @@ class DeviceLink:
                 with self._lock:
                     self._driving = False
                 return
+            step.t_launched = time.monotonic_ns()
             link_steps << 1
             self._cq.watch(
                 out,
                 on_complete=lambda arrays, error, _seq=seq: self._on_step_done(
                     _seq, arrays, error
                 ),
+                stamps=step.watcher,
             )
 
     def _fill_slot_locked(self, side: int) -> np.ndarray:
@@ -519,6 +580,7 @@ class DeviceLink:
         # gates on the values READ from received rows (_deliver).
         row[3] = self._next_deliver & 0xFFFFFFFF
         row[4] = flags
+        link_capacity << cap
         if used:
             link_bytes << used
             self._m_out_bytes << used
@@ -550,22 +612,39 @@ class DeviceLink:
                     arrays = self._reorder.pop(self._next_deliver, None)
                     if arrays is None:
                         return
-                    dispatched_at = self._step_ts.pop(self._next_deliver, None)
+                    step = self._steps.pop(self._next_deliver, None)
                     self._next_deliver += 1
                 self._deliver_tid = threading.get_ident()
-                t0 = time.perf_counter()
+                t_begin = t_host = time.monotonic_ns()
                 try:
-                    self._deliver(arrays)
+                    rows = self._rows_to_host(arrays)
+                    t_host = time.monotonic_ns()
+                    self._deliver(rows)
                 finally:
                     self._deliver_tid = None
-                    now = time.perf_counter()
-                    self._m_pump << (now - t0) * 1e6
-                    if dispatched_at is not None:
-                        self._m_rtt << (now - dispatched_at) * 1e6
+                    self._record_step(step, t_begin, t_host, time.monotonic_ns())
             with self._lock:
                 self._inflight -= 1
             self._wbutex.add(1)
             self._wbutex.wake_all()
+
+    def _record_step(self, step, t_begin: int, t_host: int, t_end: int) -> None:
+        """Hand a delivered step's timeline to the recorders' feed: launch,
+        ready, reorder_wait, readback and pump add up to step_rtt. ``step``
+        is None when fail() dropped the timelines under a late completion."""
+        if step is None:
+            return
+        t_ready = step.watcher[1]
+        self._step_feed.rows.append((
+            step.t_launched - step.t_dispatch,
+            t_ready - step.t_launched,
+            t_begin - t_ready,
+            t_host - t_begin,
+            t_end - t_host,
+            t_end - step.t_dispatch,
+            step.interval_ns or None,
+            step.inflight,
+        ))
 
     def _rows_to_host(self, arrays) -> List[np.ndarray]:
         import jax
@@ -582,10 +661,10 @@ class DeviceLink:
             rows[row] = np.asarray(shard.data).reshape(-1)
         return rows  # type: ignore[return-value]
 
-    def _deliver(self, arrays) -> None:
-        """One completed exchange: after the permute, side i's device holds
-        the PEER's outbound slot — feed it into side i's socket."""
-        rows = self._rows_to_host(arrays)
+    def _deliver(self, rows: List[np.ndarray]) -> None:
+        """One completed exchange, read back: after the permute, side i's
+        device holds the PEER's outbound slot — feed it into side i's
+        socket."""
         for side in (0, 1):
             row = rows[side]
             if row is None:
@@ -627,7 +706,7 @@ class DeviceLink:
             for side in (0, 1):
                 self._out[side].clear()
                 self._out_nbytes[side] = 0
-            self._step_ts.clear()
+            self._steps.clear()
         link_errors << 1
         self._retire_metrics()
         # party-death feedback for the collective fault plane: a session
@@ -662,6 +741,7 @@ class DeviceLink:
         scheduler needed a programmatic read (parallel/mc_dispatch).
         ``gbps`` sums both directions' measured bytes/s; a fresh link
         reads 0.0 until the 1 Hz bvar sampler has a window."""
+        self._step_feed.flush()  # a programmatic read counts the last steps
         out_bps = float(self._m_out_rate.get_value() or 0.0)
         in_bps = float(self._m_in_rate.get_value() or 0.0)
         return {

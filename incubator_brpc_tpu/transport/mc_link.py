@@ -251,12 +251,10 @@ class MultiControllerLink(DeviceLink):
                     else:
                         need = None
                         row = self._fill_slot_locked(self.own_side)
-                        seq = self._seq
-                        self._seq += 1
-                        self._inflight += 1
-                        # feeds the step_rtt_us summary exactly like the
-                        # base _drive: popped at in-order delivery
-                        self._step_ts[seq] = _time.perf_counter()
+                        # the step's timeline feeds the per-link
+                        # recorders exactly like the base _drive: popped
+                        # at in-order delivery
+                        seq, step = self._take_seq_locked()
             if finish:
                 self._finish_close()
                 return
@@ -292,12 +290,14 @@ class MultiControllerLink(DeviceLink):
                 with self._lock:
                     self._driving = False
                 return
+            step.t_launched = _time.monotonic_ns()
             link_steps << 1
             self._cq.watch(
                 out,
                 on_complete=lambda arrays, error, _seq=seq: self._on_step_done(
                     _seq, arrays, error
                 ),
+                stamps=step.watcher,
             )
 
     def _finish_close(self) -> None:

@@ -977,6 +977,7 @@ class NativeServerPlane:
                                 - (mono_anchor_ns - int(rec["start_ns"]))
                                 / 1e3
                             ),
+                            start_mono_ns=int(rec["start_ns"]),
                             latency_us=float(rec["latency_ns"]) / 1e3,
                             request_size=int(rec["request_size"]),
                             response_size=int(rec["response_size"]),

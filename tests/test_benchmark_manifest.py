@@ -1,0 +1,191 @@
+"""BENCHMARK.json against the files it names, inside tier-1: every cell
+resolves its configuration, traffic, deployment, reference and per-layer
+readers, and every reader of the program's own recorders (PR 25) gives the
+expected number from a hand-made run and ``None`` from a program that
+lacks the recorder. The benchmark's own tests are
+``python -m pytest benchmark/tests -q``; tier-1 does not collect those."""
+
+import json
+import os
+import sys
+import types
+
+import numpy as np
+import pytest
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+from benchmark import manifest, roofline, xplane  # noqa: E402
+
+with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
+    BENCH = json.load(_f)
+CELLS = [w["name"] for w in BENCH["workloads"]]
+ECHO_CELLS = ["echo_256b_c16", "echo_4m_c2", "echo_mixed_c16"]
+DEVICE_STAGES = (
+    "copy", "credit_wait", "queue_wait", "stack", "launch",
+    "cq_wait", "ready", "readback", "wake",
+)
+T_OPEN, T_CLOSE = 1_000_000_000, 21_000_000_000
+
+
+def recorder(count, mean_us):
+    return {"count": count, "sum": count * mean_us}
+
+
+def hand_made_run(counters: dict):
+    """What ``run.py`` hands a reader, by hand: 100 handler spans of 1 ms,
+    one device with 50 step executions of 2 us inside the window."""
+    t_in = T_OPEN + np.arange(100, dtype=np.int64) * 10_000_000
+    start = T_OPEN + np.arange(50, dtype=np.int64) * 1_000_000
+    steps = xplane.Events(["jit_step"] * 50, start, start + 2_000)
+    return types.SimpleNamespace(
+        counters=counters,
+        handler=np.stack([t_in, t_in + 1_000_000], axis=1),
+        devices={"/device:TPU:0": {"steps": steps}},
+        t_open=T_OPEN, t_close=T_CLOSE, window_s=20.0,
+        peaks={"hbm_bytes_per_s": 819e9},
+        traffic={"sizes": [256]}, done=np.zeros((100, 6)),
+    )
+
+
+# the program's counters over a window, as spans.delta would give them:
+# nine stages of 100 us each, so 900 of a 1,000 us handler are covered
+DEVICE = {f"device_transport_{s}_us": recorder(100, 100.0) for s in DEVICE_STAGES}
+DEVICE.update({
+    "device_transport_ingress_us": recorder(100, 2500.0),
+    "device_transport_dispatches": 40,
+    "device_transport_dispatch_rows": 100,
+    "device_transport_dispatch_pad_rows": 128,
+    "device_transport_dispatch_words": 128 * 64,
+})
+# two links: 3 is the busiest; 2 must not be read
+LINK = {
+    "device_link_2_step_rtt_us": recorder(5, 9e9),
+    "device_link_2_launch_us": recorder(5, 9e9),
+    "device_link_3_step_rtt_us": recorder(680, 4100.0),
+    "device_link_3_flush_us": recorder(1360, 40.0),
+    "device_link_3_launch_us": recorder(680, 1200.0),
+    "device_link_3_ready_us": recorder(680, 1500.0),
+    "device_link_3_reorder_wait_us": recorder(680, 100.0),
+    "device_link_3_readback_us": recorder(680, 800.0),
+    "device_link_3_pump_us": recorder(680, 500.0),
+    "device_link_3_dispatch_interval_us": recorder(640, 2000.0),
+    "device_link_3_inflight_at_dispatch": recorder(680, 2.5),
+    "device_link_bytes": 40 * (1 << 20),
+    "device_link_capacity_bytes": 2 * 680 * 65536,
+}
+HBM_DISPATCHED = (
+    100.0 * 4 * (2 * 128 * 64 + roofline.FRAME_HEADER_WORDS * 128)
+    / 819e9 / (50 * 2_000 / 1e9)
+)
+EXPECTED = {
+    **{f"device_{s}_us": (DEVICE, 100.0) for s in DEVICE_STAGES},
+    "device_path_unattributed_pct": (DEVICE, 10.0),
+    "host_plane_ingress_us": (DEVICE, 2500.0),
+    "dispatch_rows": (DEVICE, 2.5),
+    "dispatch_pad_pct": (DEVICE, 100.0 * 28 / 128),
+    "echo_step_hbm_pct_dispatched": (DEVICE, HBM_DISPATCHED),
+    "link_flush_us": (LINK, 40.0),
+    "link_launch_us": (LINK, 1200.0),
+    "link_ready_us": (LINK, 1500.0),
+    "link_reorder_wait_us": (LINK, 100.0),
+    "link_readback_us": (LINK, 800.0),
+    "link_pump_us": (LINK, 500.0),
+    "link_dispatch_interval_us": (LINK, 2000.0),
+    "link_window_used": (LINK, 2.5),
+    "link_slot_fill_pct": (LINK, 100.0 * 40 * (1 << 20) / (2 * 680 * 65536)),
+}
+# what the benchmark had before PR 25 reads no recorder this PR added
+OLDER = {
+    "host_plane_us", "device_path_us", "calls_per_dispatch",
+    "echo_step_kernel_us", "echo_step_hbm_pct", "link_step_rtt_us",
+    "link_steps_per_call", "device_idle_pct",
+}
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_cell_resolves_its_files_and_readers(name):
+    cell = manifest.Cell(BENCH, name)
+    assert cell.chips == cell.config["chips"]
+    assert hasattr(cell.deployment(), "Deployment")
+    assert cell.reference().expected(b"a", b"b") == (b"a", b"b")
+    assert cell.traffic["arrival"] in ("closed", "open")
+    reported = {m["name"] for m in cell.end_to_end}
+    assert "setup_s" in reported and len(reported) >= 2
+    assert cell.per_layer
+    for m in cell.per_layer:
+        assert callable(cell.reader(m["name"])), m["name"]
+        assert m["moves"] in reported, (name, m["name"])
+
+
+def test_every_metric_is_accounted_for():
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert len(names) == len(set(names))
+    assert OLDER | set(EXPECTED) <= set(names)  # a later PR may add more
+    with open(os.path.join(ROOT, "PERF.md"), encoding="utf-8") as f:
+        perf = f.read()
+    for m in BENCH["per_layer"]:
+        assert f"| {m['layer']} |" in perf, m["layer"]
+        if m["name"] in EXPECTED:
+            assert m["source"] == "program_counter"
+            assert f"`{m['name']}`" in perf, m["name"]  # PERF.md section 3 names it
+
+
+def test_new_metrics_report_in_the_cells_the_issue_gives_them():
+    cells = {m["name"]: m.get("workloads") for m in BENCH["per_layer"]}
+    for name in EXPECTED:
+        if name.startswith("link_"):
+            assert cells[name] == ["link_echo_ici_1m"], name
+        elif name == "dispatch_pad_pct":
+            assert cells[name] == ["echo_256b_c16", "echo_mixed_c16"]
+        else:
+            assert cells[name] == ECHO_CELLS, name
+
+
+@pytest.mark.parametrize("metric", sorted(EXPECTED))
+def test_reader_gives_the_expected_number(metric):
+    counters, expected = EXPECTED[metric]
+    read = manifest.load_module("layers", metric + ".py").read
+    assert read(hand_made_run(dict(counters))) == pytest.approx(expected)
+
+
+@pytest.mark.parametrize("metric", sorted(EXPECTED))
+def test_reader_gives_none_where_the_program_lacks_the_recorder(metric):
+    """The parent of PR 25 has none of these counters, and the driver runs
+    these readers over it: nothing to read is ``None``, never an error."""
+    read = manifest.load_module("layers", metric + ".py").read
+    before_pr25 = {
+        "device_transport_latency": recorder(100, 7000.0),
+        "device_transport_calls": 100,
+        "device_link_steps": 680,
+        "device_link_bytes": 40 * (1 << 20),
+        "device_link_3_step_rtt_us": recorder(680, 4100.0),
+        "device_link_3_flush_us": recorder(1360, 40.0)
+        if metric != "link_flush_us" else recorder(0, 0.0),
+        "device_link_3_pump_us": recorder(680, 1300.0)
+        if metric != "link_pump_us" else recorder(0, 0.0),
+    }
+    assert read(hand_made_run(before_pr25)) is None
+    assert read(hand_made_run({})) is None
+
+
+def test_unattributed_share_needs_every_stage_and_a_handler_span():
+    read = manifest.load_module("layers", "device_path_unattributed_pct.py").read
+    short = dict(DEVICE)
+    del short["device_transport_wake_us"]
+    assert read(hand_made_run(short)) is None
+    run = hand_made_run(dict(DEVICE))
+    run.handler = run.handler[:0]
+    assert read(run) is None
+
+
+def test_roofline_share_needs_device_time_and_peaks():
+    read = manifest.load_module("layers", "echo_step_hbm_pct_dispatched.py").read
+    run = hand_made_run(dict(DEVICE))
+    run.devices = {}
+    assert read(run) is None
+    run = hand_made_run(dict(DEVICE))
+    run.peaks = None  # a rehearsal on the CPU has no peaks
+    assert read(run) is None

@@ -102,3 +102,77 @@ def test_per_second_returns_float_fraction():
     time.sleep(0.05)
     v = ps.get_value()
     assert isinstance(v, float)
+
+
+class TestRecorderFeed:
+    """Rows appended on a hot path, fed to a row of LatencyRecorders in bulk."""
+
+    def test_flush_feeds_each_recorder_its_column(self):
+        import pytest
+
+        from incubator_brpc_tpu.bvar import LatencyRecorder, RecorderFeed
+
+        a, b, c = LatencyRecorder(), LatencyRecorder(), LatencyRecorder()
+        feed = RecorderFeed(((a, 1e-3), (b, 1e-3), (c, 1)))
+        for i in range(40):
+            feed.rows.append((1000 * (i + 1), None if i % 2 else 5000, 3))
+        assert a.count() == 0  # nothing until a flush
+        feed.flush()
+        assert (a.count(), b.count(), c.count()) == (40, 20, 40)  # None skipped
+        assert a.latency_sum() == pytest.approx(sum(range(1, 41)))  # ns -> us
+        assert a.max_latency() == pytest.approx(40.0)
+        assert b.latency() == pytest.approx(5.0)
+        assert c.latency_sum() == 120 and c.max_latency() == 3  # unscaled
+        # the reservoir saw one row of 16, real values all
+        assert sorted(a._percentile.merged_samples()) == [1.0, 17.0, 33.0]
+        feed.flush()  # nothing waits: nothing changes
+        assert a.count() == 40
+
+    def test_the_sampler_thread_feeds_within_the_second(self):
+        import time
+
+        from incubator_brpc_tpu.bvar import LatencyRecorder, RecorderFeed
+
+        rec = LatencyRecorder()
+        feed = RecorderFeed(((rec, 1),))
+        feed.rows.append((7,))
+        deadline = time.monotonic() + 5
+        while rec.count() == 0 and time.monotonic() < deadline:
+            time.sleep(0.02)
+        assert rec.count() == 1 and rec.latency_sum() == 7
+
+    def test_rows_appended_from_many_threads_are_each_fed_once(self):
+        import sys
+        import threading
+
+        from incubator_brpc_tpu.bvar import LatencyRecorder, RecorderFeed
+
+        rec = LatencyRecorder()
+        feed = RecorderFeed(((rec, 1),))
+        stop = threading.Event()
+
+        def flusher():
+            while not stop.is_set():
+                feed.flush()
+
+        def writer():
+            for _ in range(2000):
+                feed.rows.append((1,))
+
+        old = sys.getswitchinterval()
+        sys.setswitchinterval(1e-5)
+        try:
+            flushers = [threading.Thread(target=flusher) for _ in range(2)]
+            writers = [threading.Thread(target=writer) for _ in range(12)]
+            for t in flushers + writers:
+                t.start()
+            for t in writers:
+                t.join(30)
+            stop.set()
+            for t in flushers:
+                t.join(30)
+        finally:
+            sys.setswitchinterval(old)
+        assert not any(t.is_alive() for t in flushers + writers)
+        feed.flush()
+        assert rec.count() == 24000 and rec.latency_sum() == 24000

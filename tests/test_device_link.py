@@ -783,3 +783,121 @@ class TestDynamicPartitionFused:
             for s in servers:
                 s.stop()
                 s.join(timeout=5)
+
+
+def _link_recorders(link):
+    link._step_feed.flush()  # the sampler thread would, within the second
+    return {
+        name: getattr(link, "_m_" + name)
+        for name in (
+            "rtt", "flush", "launch", "ready", "reorder_wait", "readback",
+            "pump", "dispatch_interval", "inflight",
+        )
+    }
+
+
+class TestStepStageRecorders:
+    """The link's step split the way the endpoint's call is: launch, ready,
+    reorder_wait, readback and pump add up to step_rtt."""
+
+    def _stream(self, link, nbytes=48 * 1024):
+        sinks = [_CountingSink(), _CountingSink()]
+        from incubator_brpc_tpu.transport.device_link import DeviceSocket
+
+        for side in (0, 1):
+            DeviceSocket(link, side, messenger=sinks[side])
+        assert link.send(0, bytes(range(256)) * (nbytes // 256)) == 0
+        assert _wait(lambda: sinks[1].nbytes == nbytes, timeout=30.0)
+        assert _wait(lambda: link.inflight_steps == 0)
+        return sinks
+
+    @pytest.mark.parametrize("geometry", ["host-swap", "device-swap", "ppermute"])
+    def test_stages_add_up_to_the_step_round_trip(self, geometry):
+        import jax
+
+        from incubator_brpc_tpu.transport.device_link import DeviceLink
+
+        devs = jax.devices()
+        if geometry == "ppermute":
+            if len(devs) < 2:
+                pytest.skip("needs two devices")
+            link = DeviceLink(devs[:2], slot_words=1024, window=4)
+        else:
+            link = DeviceLink(
+                [devs[0], devs[0]], slot_words=1024, window=4,
+                host_loopback=(geometry == "host-swap"),
+            )
+        assert link.geometry == geometry
+        self._stream(link)
+        m = _link_recorders(link)
+        steps = m["rtt"].count()
+        assert steps >= 12  # 48 KiB through 4 KiB slots
+        for name in ("launch", "ready", "reorder_wait", "readback", "pump", "inflight"):
+            assert m[name].count() == steps, name
+        assert m["flush"].count() == 2 * steps  # both sides, every step
+        parts = sum(
+            m[name].latency_sum()
+            for name in ("launch", "ready", "reorder_wait", "readback", "pump")
+        )
+        assert parts == pytest.approx(m["rtt"].latency_sum(), rel=1e-6)
+        # one send, one drive: every step but the first has an interval
+        assert m["dispatch_interval"].count() == steps - 1
+        assert m["dispatch_interval"].latency_sum() > 0
+        # in flight at each dispatch, the new step included: 1..window
+        assert steps <= m["inflight"].latency_sum() <= 4 * steps
+        assert m["inflight"].max_latency() <= 4
+
+    def test_capacity_counts_every_slot_side_filled(self):
+        import jax
+
+        from incubator_brpc_tpu.transport.device_link import (
+            DeviceLink,
+            link_bytes,
+            link_capacity,
+        )
+
+        dev = jax.devices()[0]
+        link = DeviceLink([dev, dev], slot_words=1024, window=4)
+        before = (link_capacity.get_value(), link_bytes.get_value())
+        self._stream(link)
+        steps = _link_recorders(link)["rtt"].count()
+        assert link_capacity.get_value() - before[0] == 2 * steps * 4096
+        assert link_bytes.get_value() - before[1] == 48 * 1024
+
+    def test_pump_no_longer_holds_the_readback(self):
+        import jax
+
+        from incubator_brpc_tpu.transport.device_link import DeviceLink
+
+        dev = jax.devices()[0]
+        link = DeviceLink([dev, dev], slot_words=1024, window=2, host_loopback=False)
+        inner = link._rows_to_host
+
+        def slow(arrays):
+            time.sleep(0.02)
+            return inner(arrays)
+
+        link._rows_to_host = slow
+        self._stream(link, nbytes=8 * 1024)
+        link._step_feed.flush()
+        assert link._m_readback.latency() >= 20_000
+        assert link._m_pump.max_latency() < 20_000
+
+    def test_new_recorders_retire_with_the_link(self):
+        import jax
+
+        from incubator_brpc_tpu.bvar import expose_registry
+        from incubator_brpc_tpu.transport.device_link import DeviceLink
+
+        dev = jax.devices()[0]
+        link = DeviceLink([dev, dev], slot_words=1024)
+        pfx = f"device_link_{link.link_id}_"
+        names = {name[len(pfx):] for name, _ in expose_registry.snapshot(pfx)}
+        assert names == {
+            "step_rtt_us", "flush_us", "launch_us", "ready_us",
+            "reorder_wait_us", "readback_us", "pump_us",
+            "dispatch_interval_us", "inflight_at_dispatch",
+            "out_bytes_second", "in_bytes_second",
+        }
+        link.fail("retire")
+        assert not list(expose_registry.snapshot(pfx))

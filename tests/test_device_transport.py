@@ -165,3 +165,227 @@ class TestBatchedDispatch:
                 assert code == 1002, (i, code)  # ENOMETHOD, only this row
             else:
                 assert code == 0 and out == b"row%02d" % i, (i, code)
+
+
+def _transport_counters():
+    """count and sum of every stage recorder, value of every dispatch adder."""
+    from incubator_brpc_tpu.transport import device
+
+    recs = {
+        name: getattr(device, "m_" + name)
+        for name in (
+            "copy", "credit_wait", "queue_wait", "stack", "launch",
+            "cq_wait", "ready", "readback", "wake",
+        )
+    }
+    recs["latency"] = device.device_latency
+    device.flush_stage_recorders()  # the sampler thread would, within the second
+    out = {k: (r.count(), r.latency_sum()) for k, r in recs.items()}
+    for name in ("dispatches", "dispatch_rows", "dispatch_pad_rows", "dispatch_words"):
+        out[name] = getattr(device, "m_" + name).get_value()
+    return out
+
+
+def _wait_until(pred, timeout=10.0):
+    import time
+
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if pred():
+            return True
+        time.sleep(0.005)
+    return pred()
+
+
+STAGES = (
+    "copy", "credit_wait", "queue_wait", "stack", "launch",
+    "cq_wait", "ready", "readback", "wake",
+)
+
+
+class TestStageRecorders:
+    """One timeline per call, stamped where the work happens, fed to one
+    always-on recorder per stage when the call returns."""
+
+    def test_one_sample_per_call_and_the_dispatch_adders_add_up(self):
+        ep = DeviceEndpoint(window_size=8, max_batch=8)
+        sizes = (24, 300, 5000, 24, 300, 24)  # three buckets, mixed
+        for size in set(sizes):
+            ep.warm(size)
+        dispatched = []  # (calls, bucket) of every batch, from the inside
+        inner = ep._dispatch_batch
+
+        def recording(bucket, batch):
+            dispatched.append((len(batch), bucket))
+            inner(bucket, batch)
+
+        ep._dispatch_batch = recording
+        before = _transport_counters()
+        failures = []
+
+        def caller(i):
+            payload = bytes([i + 1]) * sizes[i % len(sizes)]
+            for _ in range(3):
+                code, out = ep.call_bytes(payload, timeout=60)
+                if code or out != payload:
+                    failures.append((i, code))
+
+        threads = [threading.Thread(target=caller, args=(i,)) for i in range(12)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(60)
+        assert not failures and not any(t.is_alive() for t in threads)
+        calls = 12 * 3
+        # the adders are fed by the watcher once its callers are awake
+        assert _wait_until(
+            lambda: _transport_counters()["dispatch_rows"]
+            - before["dispatch_rows"] == calls
+        )
+        after = _transport_counters()
+        for stage in STAGES + ("latency",):
+            assert after[stage][0] - before[stage][0] == calls, stage
+        pow2 = lambda b: 1 << (b - 1).bit_length()  # noqa: E731
+        assert sum(b for b, _ in dispatched) == calls
+        gained = {k: after[k] - before[k] for k in after if k.startswith("dispatch")}
+        assert gained["dispatches"] == len(dispatched)
+        assert gained["dispatch_rows"] == calls
+        assert gained["dispatch_pad_rows"] == sum(pow2(b) for b, _ in dispatched)
+        assert gained["dispatch_pad_rows"] >= gained["dispatch_rows"]
+        assert gained["dispatch_words"] == sum(
+            pow2(b) * bucket for b, bucket in dispatched
+        )
+
+    def test_stamps_are_monotone_along_a_call(self, endpoint):
+        import time
+
+        t0 = time.monotonic_ns()
+        pendings = [
+            endpoint.call_words(np.full(40, i, dtype=np.uint32), correlation_id=i + 1)
+            for i in range(4)
+        ]
+        for p in pendings:
+            assert p.wait(timeout=30) and p.completed()
+        t1 = time.monotonic_ns()
+        for p in pendings:
+            names, stamps = zip(*[(n, t) for n, t in p.timeline() if n != "exit"])
+            assert names == (
+                "entry", "words", "credit_held", "enqueued", "batched",
+                "stacked", "launched", "cq_taken", "ready", "readback", "woke",
+            )
+            assert all(stamps), names  # every stamp written
+            assert list(stamps) == sorted(stamps), list(zip(names, stamps))
+            assert t0 <= stamps[0] and stamps[-1] <= t1
+            assert p.t_exit == 0  # only the byte adapter returns
+            d = p.dispatch
+            assert 1 <= d.rows <= d.pad_rows and d.bucket == 64 and d.seq >= 1
+        # a second wait does not stamp again
+        woke = pendings[0].t_woke
+        assert pendings[0].wait(timeout=1) and pendings[0].t_woke == woke
+
+    def test_calls_of_one_dispatch_share_its_record(self):
+        ep = DeviceEndpoint(window_size=8, max_batch=8)
+        ep.warm(64)
+        with ep._qlock:  # hold the drain so that the four calls stack
+            ep._draining = True
+        pendings = [
+            ep.call_words(np.full(16, i, dtype=np.uint32), correlation_id=i + 1)
+            for i in range(4)
+        ]
+        ep._drain()
+        for p in pendings:
+            assert p.wait(timeout=30) and p.error_code == 0
+        assert len({id(p.dispatch) for p in pendings}) == 1
+        d = pendings[0].dispatch
+        assert (d.rows, d.pad_rows, d.bucket) == (4, 4, 64)
+
+    def test_stage_means_add_up_to_the_total(self, endpoint):
+        """queue_wait through readback is credit held -> response parsed
+        (device_transport_latency) but for the pad copy and the parse."""
+        endpoint.call_bytes(b"warm" * 8, timeout=30)
+        before = _transport_counters()
+        for i in range(30):
+            code, out = endpoint.call_bytes(b"%04d" % i * 8, timeout=30)
+            assert code == 0 and out == b"%04d" % i * 8
+        after = _transport_counters()
+        mean = lambda k: (  # noqa: E731
+            (after[k][1] - before[k][1]) / (after[k][0] - before[k][0])
+        )
+        inside = sum(
+            mean(k)
+            for k in ("queue_wait", "stack", "launch", "cq_wait", "ready", "readback")
+        )
+        total = mean("latency")
+        assert total > 0 and abs(inside - total) <= 0.10 * total, (inside, total)
+
+    def test_stage_times_reach_the_recorders_through_the_sampler(self):
+        """Ten feeds a call on the caller's thread cost 5% of the calls/s at
+        256 B (PR 25, on the chip): a completed call appends its numbers
+        and bvar's sampler thread feeds the recorders within the second."""
+        from incubator_brpc_tpu.transport import device
+
+        before = _transport_counters()
+        ingress = device.m_ingress.count()
+        row = (1000, 2000, 3000, 4000, 5000, 6000, 7000, 8000, 9000, None)
+        for _ in range(20):
+            device._stage_feed.rows.append(row)
+        device._stage_feed.rows.append(row[:-1] + (500,))  # came through a server
+        assert device.m_wake.count() == before["wake"][0]  # they wait
+        assert _wait_until(  # no flush of ours: the 1 Hz sampler feeds them
+            lambda: device.m_wake.count() - before["wake"][0] == 21, timeout=5
+        )
+        after = _transport_counters()
+        for i, stage in enumerate(STAGES):
+            assert after[stage][0] - before[stage][0] == 21, stage
+            total = after[stage][1] - before[stage][1]
+            assert total == pytest.approx(21 * (i + 1))  # ns in, us out
+        assert device.m_ingress.count() - ingress == 1
+        assert device.m_wake.max_latency() >= 9.0
+
+    def test_a_call_that_never_reached_the_device_records_nothing(self):
+        ep = DeviceEndpoint(window_size=1)
+        before = _transport_counters()
+        # oversize: the credit comes back, the pending settles with EREQUEST
+        p = ep.call_words(np.zeros((1 << 24) + 1, dtype=np.uint32), timeout=5)
+        assert p.wait(timeout=5) and p.error_code == ErrorCode.EREQUEST
+        assert not p.completed() and ep.inflight == 0
+        assert _transport_counters() == before
+
+    def test_device_transport_calls_is_gone(self):
+        from incubator_brpc_tpu.bvar import expose_registry
+        from incubator_brpc_tpu.transport import device
+
+        names = [name for name, _ in expose_registry.snapshot("device_transport")]
+        assert "device_transport_calls" not in names
+        assert not hasattr(device, "device_calls")
+        for stage in STAGES + ("ingress",):
+            assert f"device_transport_{stage}_us" in names
+        assert "device_transport_latency" in names
+
+    def test_server_handler_records_ingress_once_a_call(self, endpoint):
+        from incubator_brpc_tpu.rpc import Channel, Server
+        from incubator_brpc_tpu.transport.device import (
+            flush_stage_recorders,
+            m_ingress,
+        )
+
+        flush_stage_recorders()
+        server = Server()
+        server.add_service("tensor", {"echo": endpoint.server_handler()})
+        assert server.start(0)
+        try:
+            ch = Channel()
+            assert ch.init(f"127.0.0.1:{server.port}")
+            before = (m_ingress.count(), m_ingress.latency_sum())
+            for i in range(5):
+                cntl = ch.call_method("tensor", "echo", b"ingress-%d" % i)
+                assert cntl.ok(), cntl.error_text
+            flush_stage_recorders()
+            assert m_ingress.count() - before[0] == 5
+            # cut off the wire before the handler: a positive time, and
+            # far under a second on any machine
+            gained = m_ingress.latency_sum() - before[1]
+            assert 0 < gained < 5 * 1e6
+        finally:
+            server.stop()
+            server.join(timeout=5)

@@ -309,6 +309,7 @@ class TestDeviceLinkMetrics:
             cntl = ch.call_method("obsdemo", "echo", body)
             assert cntl.ok(), cntl.error_text
         link = ch._device_sock.link
+        link._step_feed.flush()  # the sampler thread would, within the second
         # direct bvar reads: latency recorders and byte counters advanced
         assert link._m_rtt.count() > 0
         assert link._m_flush.count() > 0
@@ -358,6 +359,133 @@ class TestDeviceLinkMetrics:
         from incubator_brpc_tpu.transport.device_link import link_errors
 
         assert link_errors.get_value() > 0
+
+
+class TestDeviceCallSpan:
+    """A sampled server span of a device call carries the call's stamps as
+    annotations on the monotonic clock; an unsampled call adds nothing."""
+
+    STAGES = [
+        "entry", "words", "credit_held", "enqueued", "batched", "stacked",
+        "launched", "cq_taken", "ready", "readback", "woke", "exit",
+    ]
+
+    @pytest.fixture(scope="class")
+    def device_server(self):
+        from incubator_brpc_tpu.transport.device import DeviceEndpoint
+
+        endpoint = DeviceEndpoint(window_size=4)
+        endpoint.warm(64)
+        server = Server()
+        server.add_service("tensor", {"echo": endpoint.server_handler()})
+        assert server.start(0)
+        yield server
+        server.stop()
+        server.join(timeout=5)
+
+    def _call(self, server, payload=b"span-me"):
+        ch = Channel()
+        assert ch.init(f"127.0.0.1:{server.port}")
+        cntl = ch.call_method("tensor", "echo", payload)
+        assert cntl.ok(), cntl.error_text
+        return cntl
+
+    def test_sampled_span_lists_the_stages_in_order(
+        self, device_server, tuned_flags
+    ):
+        from incubator_brpc_tpu.builtin.rpcz import span_store
+
+        tuned_flags("enable_rpcz", True)
+        tuned_flags("rpcz_samples_per_second", 10_000_000)
+        time.sleep(0.01)  # the token bucket fills at the new rate
+        span_store.clear()
+        t0 = time.monotonic_ns()
+        cntl = self._call(device_server)
+        t1 = time.monotonic_ns()
+        assert _wait(lambda: any(
+            sp.span_type == "server" and sp.method == "echo"
+            for sp in span_store.recent()
+        ))
+        span = [
+            sp for sp in span_store.recent()
+            if sp.span_type == "server" and sp.method == "echo"
+        ][0]
+        # the span is the RPC's own: the ids the wire carried
+        assert span.trace_id == cntl.trace_id
+        assert span.parent_span_id == cntl.span_id
+        assert span.service == "tensor"
+        texts = [text for _, text in span.annotations]
+        assert texts[0] == "processing"
+        device = [t.split()[1] for t in texts if t.startswith("device ")]
+        assert device == self.STAGES
+        batched = [t for t in texts if t.startswith("device batched")][0]
+        assert re.fullmatch(
+            r"device batched dispatch=\d+ rows=1 pad_rows=1 bucket=64", batched
+        )
+        # offsets: in order, from the span's monotonic start, inside the call
+        offsets = [off for off, _ in span.annotations]
+        assert offsets == sorted(offsets) and offsets[0] >= 0
+        assert t0 <= span.start_mono_ns <= t1
+        assert span.start_mono_ns + offsets[-1] * 1e3 <= t1
+        # the device path lies inside the span's own latency
+        assert offsets[-1] <= span.latency_us + 1000
+        # and /rpcz shows them
+        _, _, body = _fetch(device_server, "/rpcz?min_latency_us=0")
+        line = [
+            ln for ln in body.decode().splitlines()
+            if " server tensor.echo" in ln
+        ][0]
+        assert "device credit_held" in line and "device readback" in line
+        span_store.clear()
+
+    def test_unsampled_call_adds_nothing(
+        self, device_server, tuned_flags, monkeypatch
+    ):
+        from incubator_brpc_tpu.builtin import rpcz
+        from incubator_brpc_tpu.transport import device
+
+        tuned_flags("enable_rpcz", False)
+        rpcz.span_store.clear()
+        made = []
+        monkeypatch.setattr(
+            rpcz.Span, "__post_init__", lambda self: made.append(self),
+            raising=False,
+        )
+        monkeypatch.setattr(
+            device._PendingCall, "annotate",
+            lambda self, span: made.append(span),
+        )
+        self._call(device_server, b"no-span")
+        assert made == [] and len(rpcz.span_store) == 0
+
+
+class TestSpanClock:
+    def test_annotate_takes_offsets_from_the_monotonic_clock(self, monkeypatch):
+        from incubator_brpc_tpu.builtin import rpcz
+
+        before = time.monotonic_ns()
+        span = rpcz.Span(start_real_us=int(time.time() * 1e6))
+        assert before <= span.start_mono_ns <= time.monotonic_ns()
+        # the wall clock stepping back an hour moves no offset
+        real = time.time
+        monkeypatch.setattr(time, "time", lambda: real() - 3600.0)
+        span.annotate("now")
+        span.annotate("stamped", at_ns=span.start_mono_ns + 2_500_000)
+        (off_now, _), (off_at, text) = span.annotations
+        assert 0 <= off_now < 1e6
+        assert off_at == 2500.0 and text == "stamped"
+
+    def test_start_mono_ns_round_trips_and_is_not_identity(self):
+        from incubator_brpc_tpu.builtin.rpcz import (
+            Span,
+            span_from_dict,
+            span_to_dict,
+        )
+
+        span = Span(trace_id=5, span_id=6, start_real_us=10)
+        back = span_from_dict(span_to_dict(span))
+        assert back.start_mono_ns == span.start_mono_ns
+        assert Span(trace_id=5, span_id=6, start_real_us=10) == span
 
 
 # -- collective sessions ------------------------------------------------------

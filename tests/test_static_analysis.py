@@ -543,6 +543,45 @@ class TestRegistryRules:
         vs = registry_lint.check_bvars([p])
         assert not vs, _fmt(vs)
 
+    def test_device_families_are_held_to_the_whole_name(self, tmp_path):
+        """device_transport_* and device_link_* names are read by the
+        benchmark by name: a per-link name built from a local prefix is
+        resolved and must be in the document as device_link_<n>_<suffix>."""
+        p = self._pkg_file(
+            tmp_path, "bvars_mod.py",
+            'from incubator_brpc_tpu.bvar import Adder, LatencyRecorder\n'
+            'a = Adder(name="device_transport_not_a_documented_counter")\n'
+            'b = LatencyRecorder(name="device_transport_launch_us")\n'
+            'def make(link_id):\n'
+            '    pfx = f"device_link_{link_id}"\n'
+            '    c = LatencyRecorder(name=f"{pfx}_launch_us")\n'
+            '    d = LatencyRecorder(name=f"{pfx}_never_written_down_us")\n'
+            '    return c, d\n',
+        )
+        vs = registry_lint.check_bvars([p])
+        assert [v.rule for v in vs] == ["bvar-undocumented"] * 2, _fmt(vs)
+        assert "device_transport_not_a_documented_counter" in vs[0].message
+        assert "device_link_<n>_never_written_down_us" in vs[1].message
+
+    @pytest.mark.parametrize("name", [
+        "device_transport_copy_us", "device_transport_credit_wait_us",
+        "device_transport_queue_wait_us", "device_transport_stack_us",
+        "device_transport_launch_us", "device_transport_cq_wait_us",
+        "device_transport_ready_us", "device_transport_readback_us",
+        "device_transport_wake_us", "device_transport_ingress_us",
+        "device_transport_latency", "device_transport_dispatches",
+        "device_transport_dispatch_rows", "device_transport_dispatch_pad_rows",
+        "device_transport_dispatch_words", "device_link_capacity_bytes",
+        "device_link_<n>_step_rtt_us", "device_link_<n>_flush_us",
+        "device_link_<n>_launch_us", "device_link_<n>_ready_us",
+        "device_link_<n>_reorder_wait_us", "device_link_<n>_readback_us",
+        "device_link_<n>_pump_us", "device_link_<n>_dispatch_interval_us",
+        "device_link_<n>_inflight_at_dispatch",
+    ])
+    def test_device_path_name_is_documented(self, name):
+        with open(registry_lint.OBSERVABILITY_MD) as fh:
+            assert f"`{name}`" in fh.read()
+
 
 # ---------------------------------------------------------------------------
 # 3. sanitizer harness (slow; probe-gated like the multiprocess tiers)

@@ -14,7 +14,11 @@ appear in docs/OBSERVABILITY.md — those two prefixes are this repo's
 documented contract for the native plane and the multi-controller
 plane (``bvar-name``/``bvar-undocumented``).  Names built from
 f-strings or concatenation are checked by their literal prefix (the
-part before the first runtime placeholder).
+part before the first runtime placeholder).  The device path's families,
+``device_transport_*`` and ``device_link_*``, are held to the whole
+name: the benchmark's per-layer readers find them by name, so a
+per-link ``f"{pfx}_launch_us"`` has to be in the document as
+``device_link_<n>_launch_us``, not only its prefix.
 """
 
 from __future__ import annotations
@@ -47,6 +51,9 @@ _BVAR_CTORS = {
 }
 
 _PLACEHOLDER = "\x00"
+# families whose every name, not only its literal prefix, must be in the
+# document (with <n> where the name has a runtime part)
+_WHOLE_NAME_FAMILIES = ("device_transport_", "device_link_")
 _NAME_RE = re.compile(r"^[a-zA-Z_:][a-zA-Z0-9_:.]*$")
 
 
@@ -61,6 +68,16 @@ def _str_template(node: ast.AST, local: Dict[str, str]) -> Optional[str]:
         for v in node.values:
             if isinstance(v, ast.Constant) and isinstance(v.value, str):
                 parts.append(v.value)
+            elif (
+                isinstance(v, ast.FormattedValue)
+                and isinstance(v.value, ast.Name)
+                and v.value.id in local
+                and v.conversion == -1
+                and v.format_spec is None
+            ):
+                # f"{pfx}_rtt_us" with pfx = f"device_link_{n}" resolves
+                # through the local template
+                parts.append(local[v.value.id])
             else:
                 parts.append(_PLACEHOLDER)
         return "".join(parts)
@@ -270,13 +287,17 @@ def check_bvars(paths: Optional[List[str]] = None) -> List[Violation]:
                 continue
             prefix = template.split(_PLACEHOLDER, 1)[0]
             display = template.replace(_PLACEHOLDER, "{}")
-            if not (
-                prefix.startswith("native_") or prefix.startswith("mc_")
-            ):
+            whole_name = prefix.startswith(_WHOLE_NAME_FAMILIES)
+            if not (whole_name or prefix.startswith(("native_", "mc_"))):
                 continue
             if _PLACEHOLDER not in template:
                 documented = template in doc
                 what = f"bvar {template!r}"
+            elif whole_name:
+                # the document writes the runtime part as <n>
+                display = template.replace(_PLACEHOLDER, "<n>")
+                documented = display in doc
+                what = f"bvar family {display!r}"
             else:
                 # templated family: the literal prefix is the contract
                 documented = len(prefix) >= 8 and prefix in doc
@@ -285,8 +306,9 @@ def check_bvars(paths: Optional[List[str]] = None) -> List[Violation]:
                 out.append(
                     Violation(
                         "bvar-undocumented", path, line,
-                        f"{what} follows the native_*/mc_* convention but "
-                        "is not documented in docs/OBSERVABILITY.md",
+                        f"{what} belongs to a documented family "
+                        "(native_*, mc_*, device_transport_*, device_link_*) "
+                        "but is not in docs/OBSERVABILITY.md",
                     )
                 )
     return out
