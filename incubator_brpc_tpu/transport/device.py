@@ -40,6 +40,7 @@ import time as _time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
 from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
 from incubator_brpc_tpu.ops import framing
@@ -119,7 +120,7 @@ class _Dispatch:
         self.bucket = bucket  # payload words per row
         self.t_batched = _time.monotonic_ns()  # taken off the queue
         self.t_stacked = 0  # rows copied into one array
-        self.t_launched = 0  # device_put and the program call returned
+        self.t_launched = 0  # the program call, which stages the rows, returned
         # DeviceCompletionButex.watch fills these: a watcher thread took
         # the job, block_until_ready returned
         self.watcher = [0, 0]
@@ -224,7 +225,13 @@ class _PendingCall:
 
 
 class DeviceEndpoint:
-    """One device-resident service behind a credit window."""
+    """One device-resident service behind a credit window.
+
+    A dispatch launches with one call of the jitted step program on the
+    stacked host rows: the call stages its numpy arguments itself, onto
+    ``device`` (both programs pin ``in_shardings`` there), and nothing
+    else touches the device between rows stacked and the call's return
+    (``device_transport_launch_us``)."""
 
     def __init__(
         self,
@@ -256,13 +263,19 @@ class DeviceEndpoint:
         # frame-building fused INTO the jitted program; the batched form
         # vmaps the same fused step over stacked rows (jit's per-shape
         # cache gives one compiled program per (batch, bucket) geometry —
-        # the fixed-block discipline)
+        # the fixed-block discipline). in_shardings: a dispatch hands
+        # these the host arrays, which must land on self.device and not
+        # the default one, and must run the executable a caller warmed
+        # with arrays already committed there (without it each route
+        # compiles its own)
+        on_device = SingleDeviceSharding(self.device)
         self._program = jax.jit(
             lambda padded, cid_lo, mid: self.service.step(
                 framing.frame(
                     padded, (cid_lo, jnp.uint32(0)), method_id=mid
                 )
-            )
+            ),
+            in_shardings=on_device,
         )
         self._batch_program = jax.jit(
             jax.vmap(
@@ -271,7 +284,8 @@ class DeviceEndpoint:
                         padded, (cid_lo, jnp.uint32(0)), method_id=mid
                     )
                 )
-            )
+            ),
+            in_shardings=on_device,
         )
 
     # -- credit window (rdma_endpoint.h:176-195) ----------------------------
@@ -407,19 +421,14 @@ class DeviceEndpoint:
             mids[i] = mid
             pending.dispatch = dispatch
         dispatch.t_stacked = _time.monotonic_ns()
+        # the launch is the program call alone: it stages the host arrays
+        # itself. rows, cids and mids are this dispatch's own and are not
+        # written again (the runtime may still be reading them)
         try:
-            if bpad == 1:
-                response = self._program(  # single call: no vmap overhead
-                    jax.device_put(jnp.asarray(rows[0]), self.device),
-                    jnp.uint32(int(cids[0])),
-                    jnp.uint32(int(mids[0])),
-                )
+            if bpad == 1:  # single call: no vmap overhead
+                response = self._program(rows[0], cids[0], mids[0])
             else:
-                response = self._batch_program(
-                    jax.device_put(jnp.asarray(rows), self.device),
-                    jnp.asarray(cids),
-                    jnp.asarray(mids),
-                )
+                response = self._batch_program(rows, cids, mids)
         except Exception as e:  # dispatch failed: settle the whole batch
             for _, _mid, _padded, _cid, pending, _n in batch:
                 self._release_credit()
@@ -522,22 +531,15 @@ class DeviceEndpoint:
         does NOT reliably warm the larger geometries; this does."""
         n_words = max(1, (payload_bytes + 3) // 4)
         bucket = _bucket_words(n_words)
+        # host-typed arguments, as _dispatch_batch hands them
         row = np.zeros(bucket, dtype=np.uint32)
-        outs = [
-            self._program(
-                jax.device_put(jnp.asarray(row), self.device),
-                jnp.uint32(1),
-                jnp.uint32(0),
-            )
-        ]
+        outs = [self._program(row, np.uint32(1), np.uint32(0))]
         b = 2
         while b <= self.max_batch:
-            rows = np.zeros((b, bucket), dtype=np.uint32)
+            ids = np.zeros(b, dtype=np.uint32)
             outs.append(
                 self._batch_program(
-                    jax.device_put(jnp.asarray(rows), self.device),
-                    jnp.zeros(b, dtype=jnp.uint32),
-                    jnp.zeros(b, dtype=jnp.uint32),
+                    np.zeros((b, bucket), dtype=np.uint32), ids, ids
                 )
             )
             b <<= 1

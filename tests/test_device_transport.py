@@ -2,7 +2,10 @@
 brpc_rdma_unittest.cpp shape: endpoint rings, credit window, completion
 delivery; runs on the virtual CPU mesh devices here)."""
 
+import contextlib
+import os
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -389,3 +392,258 @@ class TestStageRecorders:
         finally:
             server.stop()
             server.join(timeout=5)
+
+
+def _stacked_batch(ep, bucket, b, methods=(0, 7)):
+    """A batch of ``b`` queue entries as ``call_words`` makes them (credit
+    held, row padded into its bucket), built here so that the batch size
+    is the test's and not the timing's. Correlation ids have the top bit
+    set; method ids alternate over ``methods``."""
+    from incubator_brpc_tpu.transport.device import _PendingCall
+
+    rng = np.random.default_rng(bucket * 131 + b)
+    batch, sent = [], []
+    for i in range(b):
+        assert ep._acquire_credit(5)
+        n = bucket - (i % 3)  # not every row fills its bucket
+        words = rng.integers(0, 1 << 32, size=n, dtype=np.uint32)
+        padded = np.zeros(bucket, dtype=np.uint32)
+        padded[:n] = words
+        mid = methods[i % len(methods)]
+        cid = 0x80000000 | (0x01010101 * (i + 1) & 0x7FFFFFFF)
+        pending = _PendingCall()
+        pending.t_credit = pending.t_enqueued = time.monotonic_ns()
+        batch.append((bucket, np.uint32(mid), padded, np.uint32(cid), pending, n))
+        sent.append((mid, cid, words))
+    return batch, sent
+
+
+class _Counting:
+    """Stands in for a module name (``device.jax``, ``device.jnp``): the
+    named attributes are wrapped to note when they were called, every
+    other attribute is the module's own."""
+
+    def __init__(self, module, names, calls):
+        self._module = module
+        for name in names:
+            setattr(self, name, self._noting(name, getattr(module, name), calls))
+
+    @staticmethod
+    def _noting(name, fn, calls):
+        def noted(*args, **kwargs):
+            calls.append((name, time.monotonic_ns()))
+            return fn(*args, **kwargs)
+
+        return noted
+
+    def __getattr__(self, name):
+        return getattr(self._module, name)
+
+
+@contextlib.contextmanager
+def _response_frames(ep):
+    """The device arrays each dispatch of ``ep`` hands its watcher."""
+    frames = []
+    watch = ep._cq.watch
+
+    def keeping(arrays, **kw):
+        frames.append(arrays)
+        return watch(arrays, **kw)
+
+    ep._cq.watch = keeping
+    try:
+        yield frames
+    finally:
+        ep._cq.watch = watch
+
+
+XOR = 0x5A5A5A5A
+
+
+@pytest.fixture(scope="module")
+def two_method_endpoint():
+    import jax.numpy as jnp
+
+    from incubator_brpc_tpu.models.tensor_echo import TensorEchoService
+
+    svc = TensorEchoService()
+    svc.add_method(7, lambda p: p ^ jnp.uint32(XOR))
+    return DeviceEndpoint(service=svc, window_size=16, max_batch=16)
+
+
+class TestLaunch:
+    """The launch of a dispatch is one call of the jitted program on the
+    stacked host rows (PR 26)."""
+
+    @pytest.mark.parametrize("bucket", [64, 4096])
+    @pytest.mark.parametrize("b", [1, 2, 3, 5, 16])
+    def test_every_row_of_a_batch_answers_its_own_request(
+        self, two_method_endpoint, b, bucket
+    ):
+        ep = two_method_endpoint
+        batch, sent = _stacked_batch(ep, bucket, b)
+        with _response_frames(ep) as frames:
+            ep._dispatch_batch(bucket, batch)
+        for entry in batch:
+            assert entry[4].wait(timeout=60)
+        assert ep.inflight == 0
+        (frame,) = frames
+        assert frame.devices() == {ep.device}
+        host = np.asarray(frame).reshape(-1, bucket + 8)
+        assert host.shape[0] == 1 << (b - 1).bit_length()
+        for i, (mid, cid, words) in enumerate(sent):
+            pending = batch[i][4]
+            assert pending.error is None and pending.error_code == 0, i
+            want = words ^ np.uint32(XOR) if mid == 7 else words
+            np.testing.assert_array_equal(pending.response_words, want)
+            assert pending.response_words.dtype == np.uint32
+            # the header the device wrote: this row's ids, whole
+            assert cid >= 1 << 31 and int(host[i, 3]) == cid, i
+            assert int(host[i, 4]) == 0 and int(host[i, 5]) == mid, i
+            d = pending.dispatch
+            assert (d.rows, d.bucket) == (b, bucket)
+            assert 0 < d.t_stacked <= d.t_launched <= d.t_readback
+
+    def test_the_rows_land_on_the_endpoints_device_not_the_default(self):
+        import jax
+
+        other = jax.devices()[-1]
+        assert other != jax.devices()[0]  # conftest: 8 virtual devices
+        ep = DeviceEndpoint(device=other, window_size=4, max_batch=4)
+        for b in (1, 3):
+            batch, sent = _stacked_batch(ep, 64, b, methods=(0,))
+            with _response_frames(ep) as frames:
+                ep._dispatch_batch(64, batch)
+            for (_, _, words), entry in zip(sent, batch):
+                assert entry[4].wait(timeout=60) and entry[4].error_code == 0
+                np.testing.assert_array_equal(entry[4].response_words, words)
+            assert [f.devices() for f in frames] == [{other}]
+
+    def test_nothing_but_the_program_call_between_stacked_and_launched(
+        self, two_method_endpoint, monkeypatch
+    ):
+        from incubator_brpc_tpu.transport import device
+
+        ep = two_method_endpoint
+        ep.warm(64 * 4)
+        calls = []
+        monkeypatch.setattr(
+            device, "jax", _Counting(device.jax, ("device_put",), calls)
+        )
+        monkeypatch.setattr(
+            device, "jnp",
+            _Counting(device.jnp, ("asarray", "array", "uint32", "zeros"), calls),
+        )
+        launched = []
+        for b in (1, 2, 5, 16):
+            batch, _ = _stacked_batch(ep, 64, b)
+            ep._dispatch_batch(64, batch)
+            for entry in batch:
+                assert entry[4].wait(timeout=60) and entry[4].error_code == 0
+            d = batch[0][4].dispatch
+            launched.append((d.t_stacked, d.t_launched))
+            assert 0 < d.t_stacked <= d.t_launched
+        # the proxies do see a call: the cold geometry traces the lambda
+        # (jnp.uint32(0), the id's high word) inside its program call
+        assert calls == []
+        batch, _ = _stacked_batch(ep, 128, 1)
+        ep._dispatch_batch(128, batch)
+        assert batch[0][4].wait(timeout=60)
+        assert [name for name, _ in calls] and {n for n, _ in calls} == {"uint32"}
+        for t0, t1 in launched:
+            assert not [c for c in calls if t0 <= c[1] <= t1]
+
+
+def _compiles(caplog):
+    """What ``jax.log_compiles`` said of the endpoint's two programs (the
+    harness's ``jnp.uint32(1)`` is a program of its own, once a process)."""
+    return [
+        r.getMessage() for r in caplog.records
+        if r.name == "jax._src.interpreters.pxla"
+        and r.getMessage().startswith("Compiling jit(<lambda>)")
+    ]
+
+
+def _harness_warm(ep, sizes, callers):
+    """The benchmark's own warm-up (``benchmark/deployments/device_echo.py``:
+    both programs by name, with arrays committed to the device), run on
+    ``ep`` through a stand-in for the deployment."""
+    import sys
+    import types
+
+    import jax
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from benchmark.deployments import device_echo
+
+    device_echo.Deployment.warm(
+        types.SimpleNamespace(endpoint=ep, _jax=jax),
+        {"callers": callers, "sizes": sizes},
+    )
+
+
+class TestWarmUpHolds:
+    """A dispatch of a warmed geometry compiles nothing, whichever route
+    warmed it."""
+
+    BUCKETS = (64, 1024)
+    BATCHES = (1, 2, 3, 4, 7, 8)
+
+    def _dispatch_all(self, ep):
+        for bucket in self.BUCKETS:
+            for b in self.BATCHES:
+                batch, sent = _stacked_batch(ep, bucket, b, methods=(0,))
+                ep._dispatch_batch(bucket, batch)
+                for (_, _, words), entry in zip(sent, batch):
+                    assert entry[4].wait(timeout=60)
+                    assert entry[4].error_code == 0
+                    np.testing.assert_array_equal(entry[4].response_words, words)
+
+    def _sizes(self, ep):
+        return ep._program._cache_size(), ep._batch_program._cache_size()
+
+    @pytest.mark.parametrize("device_index", [0, 3])
+    def test_after_warm(self, caplog, device_index):
+        import jax
+
+        ep = DeviceEndpoint(
+            device=jax.devices()[device_index], window_size=8, max_batch=8
+        )
+        with jax.log_compiles():
+            for bucket in self.BUCKETS:
+                ep.warm(bucket * 4)
+            assert len(_compiles(caplog)) == 2 * 4  # the detector detects
+            sizes = self._sizes(ep)
+            assert sizes == (2, 6)
+            caplog.clear()
+            self._dispatch_all(ep)
+        assert _compiles(caplog) == []
+        assert self._sizes(ep) == sizes
+
+    @pytest.mark.parametrize("device_index", [0, 3])
+    def test_after_the_harness_route(self, caplog, device_index):
+        """Committed arrays into both programs by name. The executables
+        are shared with the host-array route (``in_shardings``); jit's
+        fast path keeps one entry per argument kind, so ``_cache_size``
+        grows by that entry on the first dispatch of a geometry, without
+        a compile, and holds from there."""
+        import jax
+
+        ep = DeviceEndpoint(
+            device=jax.devices()[device_index], window_size=8, max_batch=8
+        )
+        with jax.log_compiles():
+            _harness_warm(ep, [4 * b for b in self.BUCKETS], callers=8)
+            assert len(_compiles(caplog)) == 2 * 4
+            warmed = self._sizes(ep)
+            assert warmed == (2, 6)
+            caplog.clear()
+            self._dispatch_all(ep)
+            assert _compiles(caplog) == []
+            sizes = self._sizes(ep)
+            assert sizes == (4, 12)  # one fast-path entry more a geometry
+            self._dispatch_all(ep)
+        assert _compiles(caplog) == []
+        assert self._sizes(ep) == sizes
