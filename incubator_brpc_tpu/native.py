@@ -84,6 +84,7 @@ FRAME_FN = ctypes.CFUNCTYPE(
     ctypes.c_void_p,  # meta ptr
     ctypes.c_size_t,  # meta len
     ctypes.c_void_p,  # body tb_iobuf* (ownership transfers)
+    ctypes.c_uint64,  # cut_ns: CLOCK_MONOTONIC when the frame was cut
 )
 HANDOFF_FN = ctypes.CFUNCTYPE(
     None,
@@ -573,6 +574,13 @@ def load():
 
 LIB = load()
 NATIVE_AVAILABLE = LIB is not None
+# The same library through ctypes.PyDLL, whose calls keep the interpreter
+# lock: for calls far shorter than a hand-over of the lock (a size, a few
+# hundred bytes copied, a free) made where giving it up stalls others — a
+# reactor's frame callback queues for the lock once a CDLL call, and with
+# 16 handler threads behind it each turn cost ~0.5 ms on the chip's host
+# (PERF.md, PR 27). Never for a call that can block or copy megabytes.
+LIB_HELD = _declare(ctypes.PyDLL(_LIB_PATH)) if NATIVE_AVAILABLE else None
 
 
 def monotonic_ns() -> int:

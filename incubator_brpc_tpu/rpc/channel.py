@@ -307,7 +307,8 @@ class ChannelOptions:
         # pack/write/read/match in C++ with the GIL released, one shared
         # connection with an elected completion-pump reader. Calls that
         # need Python-plane features (streams, backup, auth, compression,
-        # LB targets) silently use the regular path.
+        # LB targets) use the regular path, call by call. Where the
+        # library cannot be had at all, init() says so once.
         self.native_plane = native_plane
         # ssl.SSLContext for TLS to the server(s) (reference
         # ChannelOptions.ssl_options). TLS sockets pump ciphertext through
@@ -372,6 +373,17 @@ class Channel:
             self._single_server = str2endpoint(str(target))
         self._retry_budget = RetryBudget(float(get_flag("retry_budget_ratio")))
         self._init_done = True
+        if self._options.native_plane:
+            from incubator_brpc_tpu.transport import native_plane as np_mod
+
+            if not np_mod.NET_AVAILABLE:
+                # every call then takes the Python plane and gets the same
+                # answers: only this line tells which plane was measured
+                logger.warning(
+                    "Channel(native_plane=True) to %s cannot be honoured: "
+                    "libtbutil.so could not be built or loaded; calls take "
+                    "the Python plane", target,
+                )
         return True
 
     def init_with_lb(self, lb, options: Optional[ChannelOptions] = None) -> bool:

@@ -68,9 +68,18 @@ class Controller:
 
         # -- internals (owned by channel.py / server.py) --
         self._start_ts: float = 0.0
-        # server side: time.monotonic() when the messenger cut the request's
-        # frame off the wire (None where no messenger stamped it)
+        # server side: when the request's frame was cut off the wire, on
+        # time.monotonic()'s clock: the Python messenger's stamp, or
+        # tbnet's on the native plane (None where nobody stamped it)
         self._arrival_ts: Optional[float] = None
+        # native plane only: time.monotonic_ns() when the reactor's frame
+        # callback had the interpreter
+        self._plane_callback_ns: Optional[int] = None
+        # Server.process_request's controllers carry a list here, and
+        # Server._finish calls each entry with time.monotonic_ns() once
+        # the response has been handed to the connection's write. None:
+        # nobody will call (a handler then records without the way out)
+        self._after_send: Optional[List[Callable[[int], None]]] = None
         self._deadline: float = 0.0
         self._done: Optional[Callable[["Controller"], None]] = None
         self._timer_ids: List[Any] = []

@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Callable, Dict, Optional, Union
 
 from incubator_brpc_tpu import protocol as proto_pkg
@@ -338,9 +339,9 @@ class ServerOptions:
         # AND baidu_std (PRPC) frames cut/dispatched in C++,
         # natively-registered methods answered without the interpreter in
         # the protocol the request arrived in, other protocols handed off
-        # to the Python plane per connection. Requires libtbutil; silently
-        # falls back to the Python acceptor when the toolchain is missing
-        # or the listen endpoint is a unix socket.
+        # to the Python plane per connection. Requires libtbutil; falls
+        # back to the Python acceptor, with one warning from start(), when
+        # the toolchain is missing or the listen endpoint is a unix socket.
         self.native_plane = native_plane
         # Reactor count for the native plane: one per-core event loop,
         # each owning its own epoll fd, SO_REUSEPORT listener, telemetry
@@ -770,17 +771,24 @@ class Server:
                     make_handshake_handler(self), MethodStatus(hs, 0), hs
                 ),
             )
-        use_native = (
-            self.options.native_plane
-            and not ep.ip.startswith("unix://")
-            # the C++ reactor has no TLS stack: TLS ports stay on the
-            # Python plane
-            and self.options.ssl_context is None
-        )
+        use_native = self.options.native_plane
         if use_native:
             from incubator_brpc_tpu.transport import native_plane as np_mod
 
-            if not np_mod.NET_AVAILABLE:
+            why_not = None
+            if ep.ip.startswith("unix://"):
+                why_not = "the C++ listener takes no unix socket"
+            elif self.options.ssl_context is not None:
+                why_not = "the C++ reactor has no TLS stack"
+            elif not np_mod.NET_AVAILABLE:
+                why_not = "libtbutil.so could not be built or loaded"
+            if why_not is not None:
+                # the fall-back serves the same answers, so only this line
+                # tells which plane a measurement was taken on
+                logger.warning(
+                    "Server(native_plane=True) on %s cannot be honoured: %s; "
+                    "serving on the Python plane", ep, why_not,
+                )
                 use_native = False
         if use_native:
             # the C++ listener is AF_INET-only: fall back to the Python
@@ -796,8 +804,8 @@ class Server:
                 port = plane.listen(ep.ip, ep.port)
             except OSError as e:
                 logger.warning(
-                    "native plane cannot listen on %s (%s); "
-                    "falling back to the Python acceptor", ep, e
+                    "Server(native_plane=True) cannot listen on %s (%s); "
+                    "serving on the Python plane", ep, e
                 )
                 plane.stop()
                 use_native = False
@@ -1233,6 +1241,8 @@ class Server:
         # SendRpcResponse off the request's protocol the same way)
         cntl._wire_protocol = getattr(frame, "wire_protocol", "tbus_std")
         cntl._arrival_ts = getattr(frame, "arrival_ts", None)
+        cntl._plane_callback_ns = getattr(frame, "plane_callback_ns", None)
+        cntl._after_send = []
         cntl._mark_start()
 
         # deadline propagation (reference RpcRequestMeta.timeout_ms +
@@ -1463,6 +1473,10 @@ class Server:
             if s is not None:
                 s._fail(cntl.error_code, "rpc failed after stream_accept")
         self._send_response(sock, cntl, response)
+        if cntl._after_send:
+            sent_ns = time.monotonic_ns()
+            for hook in cntl._after_send:
+                hook(sent_ns)
         cntl._mark_end()
         if status is not None:
             self._release(status, cntl)

@@ -33,6 +33,7 @@ from __future__ import annotations
 import itertools
 import threading
 from collections import deque
+from functools import partial
 from typing import Optional, Tuple
 
 import time as _time
@@ -66,9 +67,16 @@ m_cq_wait = LatencyRecorder(name="device_transport_cq_wait_us")
 m_ready = LatencyRecorder(name="device_transport_ready_us")
 m_readback = LatencyRecorder(name="device_transport_readback_us")
 m_wake = LatencyRecorder(name="device_transport_wake_us")
-# messenger cut -> server_handler entered (the server's half of the host
-# plane in front of the device path)
+# The server's half of the host plane around the device path, for a call
+# that came through server_handler. ingress: the request's frame cut off
+# the wire (the Python messenger's stamp, or the C++ cutter's on the
+# native plane) -> server_handler entered. plane_callback, native plane
+# only: the cut -> the reactor's frame callback had the interpreter; part
+# of ingress. egress: call_bytes returned -> the response was handed to
+# the connection's write.
 m_ingress = LatencyRecorder(name="device_transport_ingress_us")
+m_plane_callback = LatencyRecorder(name="device_transport_plane_callback_us")
+m_egress = LatencyRecorder(name="device_transport_egress_us")
 # per dispatch that reached the device, fed by the completion watcher
 m_dispatches = Adder(name="device_transport_dispatches")
 m_dispatch_rows = Adder(name="device_transport_dispatch_rows")
@@ -82,9 +90,38 @@ _stage_feed = RecorderFeed(
     (recorder, 1e-3)
     for recorder in (
         m_copy, m_credit_wait, m_queue_wait, m_stack, m_launch,
-        m_cq_wait, m_ready, m_readback, m_wake, m_ingress,
+        m_cq_wait, m_ready, m_readback, m_wake,
+        m_ingress, m_plane_callback, m_egress,
     )
 )
+
+
+def _record(pending: "_PendingCall", cntl, sent_ns: Optional[int]) -> None:
+    """One row for ``_stage_feed`` from a completed call: its stages,
+    and from the server-side controller of the RPC it served, if any, the
+    host plane's times around them. A sampled rpcz span gets the same
+    timeline as annotations."""
+    ingress = plane_callback = egress = span = entered = None
+    if cntl is not None:
+        span = getattr(cntl, "_span", None)
+        arrival = getattr(cntl, "_arrival_ts", None)
+        entered = getattr(cntl, "_plane_callback_ns", None)
+        if arrival is not None:
+            cut_ns = int(arrival * 1e9)
+            ingress = pending.t_entry - cut_ns
+            if entered is not None:
+                plane_callback = entered - cut_ns
+    if sent_ns is not None:
+        egress = sent_ns - pending.t_exit
+    _stage_feed.rows.append(
+        pending.stages() + (ingress, plane_callback, egress)
+    )
+    if span is not None:
+        if entered is not None:  # before the span's own start
+            span.annotate("device plane_callback", entered)
+        pending.annotate(span)
+        if sent_ns is not None:
+            span.annotate("device sent", sent_ns)
 
 
 def flush_stage_recorders() -> None:
@@ -185,10 +222,10 @@ class _PendingCall:
             ("exit", self.t_exit),
         )
 
-    def stages(self, ingress_ns: Optional[int]) -> tuple:
-        """The stage times of a call through call_bytes, ns, in
-        ``_stage_feed``'s recorders' order; but for ingress they add up
-        to ``t_exit - t_entry``."""
+    def stages(self) -> tuple:
+        """The stage times of a call through call_bytes, ns, in the
+        order of ``_stage_feed``'s first nine recorders; they add up to
+        ``t_exit - t_entry``."""
         d = self.dispatch
         t_cq, t_ready = d.watcher
         return (
@@ -205,7 +242,6 @@ class _PendingCall:
             t_ready - t_cq,
             d.t_readback - t_ready,
             self.t_woke - d.t_readback,
-            ingress_ns,
         )
 
     def annotate(self, span) -> None:
@@ -512,15 +548,13 @@ class DeviceEndpoint:
             out = pending.response_words.tobytes()[:nbytes]
         pending.t_exit = _time.monotonic_ns()
         if pending.completed():
-            ingress_ns = span = None
-            if cntl is not None:
-                span = getattr(cntl, "_span", None)
-                arrival = getattr(cntl, "_arrival_ts", None)
-                if arrival is not None:
-                    ingress_ns = t_entry - int(arrival * 1e9)
-            _stage_feed.rows.append(pending.stages(ingress_ns))
-            if span is not None:
-                pending.annotate(span)
+            after_send = getattr(cntl, "_after_send", None)
+            if after_send is None:
+                _record(pending, cntl, None)
+            else:
+                # the server calls back once the response is written, so
+                # the way out is on the call's row too
+                after_send.append(partial(_record, pending, cntl))
         return pending.error_code, out
 
     def warm(self, payload_bytes: int, timeout: float = 300.0) -> None:

@@ -50,6 +50,7 @@ from incubator_brpc_tpu.native import (
     FRAME_FN,
     HANDOFF_FN,
     LIB,
+    LIB_HELD,
 )
 from incubator_brpc_tpu.utils.endpoint import EndPoint
 from incubator_brpc_tpu.utils.status import ErrorCode
@@ -233,6 +234,23 @@ def _resolve_num_reactors(nloops) -> int:
     except (AttributeError, OSError):
         ncpu = os.cpu_count() or 1
     return max(1, min(16, ncpu))
+
+
+# a copy longer than this gives the interpreter lock up while it runs
+_HELD_COPY_MAX = 1 << 16
+
+
+def _copy_out(iobuf_h, n: int, pos: int) -> bytes:
+    """``n`` bytes at ``pos`` of a tb_iobuf, for the reactor's frame
+    callback: the calling thread queues for the interpreter lock again
+    after every CDLL call, so short copies keep the lock (LIB_HELD) and
+    only one that outlasts a hand-over of it lets the lock go."""
+    if n <= 0:
+        return b""
+    out = ctypes.create_string_buffer(n)
+    lib = LIB_HELD if n <= _HELD_COPY_MAX else LIB
+    got = lib.tb_iobuf_copy_to(iobuf_h, out, n, pos)
+    return ctypes.string_at(out, got)
 
 
 class NativeConnSock:
@@ -1035,12 +1053,15 @@ class NativeServerPlane:
 
     # fabriclint: hotpath
     def _on_frame(self, _ctx, token, cid_lo, cid_hi, flags, error_code,
-                  meta_ptr, meta_len, body_h) -> None:
-        from incubator_brpc_tpu.iobuf import IOBuf
+                  meta_ptr, meta_len, body_h, cut_ns) -> None:
+        # this thread holds the interpreter from here; cut_ns was read in
+        # C++ before it asked for it
+        entered_ns = time.monotonic_ns()
         from incubator_brpc_tpu.protocol.tbus_std import Meta, ParsedFrame
 
         try:
-            body = IOBuf(_handle=body_h)  # take ownership
+            # the body is ours: copied out below, then freed
+            blen = LIB_HELD.tb_iobuf_size(body_h)
             meta_bytes = (
                 ctypes.string_at(meta_ptr, meta_len) if meta_len else b""
             )
@@ -1057,7 +1078,6 @@ class NativeServerPlane:
                 meta = rpc_meta_to_meta(RpcMeta.decode(meta_bytes))
             else:
                 meta = Meta.from_bytes(meta_bytes)
-            blen = len(body)
             att = meta.attachment_size
             if att > blen:
                 # consumed, unrecoverable: kill the connection (the Python
@@ -1065,8 +1085,8 @@ class NativeServerPlane:
                 # fabriclint: allow(ffi-unchecked) the conn is being killed for a fatal parse; a stale token means it is already dead — both outcomes are the goal
                 LIB.tb_conn_close(token)
                 return
-            payload = body.to_bytes(blen - att)
-            attachment = body.to_bytes(att, pos=blen - att) if att else b""
+            payload = _copy_out(body_h, blen - att, 0)
+            attachment = _copy_out(body_h, att, blen - att)
             frame = ParsedFrame(
                 meta=meta,
                 payload=payload,
@@ -1075,9 +1095,12 @@ class NativeServerPlane:
                 flags=flags & ~(_FLAG_WIRE_PRPC | _FLAG_CONN_AUTHED),
                 error_code=error_code,
             )
-            # deadline-shed baseline for the worker-pool queue ahead
+            # the cut's own time on time.monotonic()'s clock, as the
+            # Python messenger stamps it: the deadline-shed baseline
             # (Server.process_request measures mid-queue expiry from it)
-            frame.arrival_ts = time.monotonic()
+            # and where device_transport_ingress_us starts
+            frame.arrival_ts = cut_ns / 1e9
+            frame.plane_callback_ns = entered_ns
             if is_prpc:
                 frame.wire_protocol = "baidu_std"
             sock = self._sock_for(token)
@@ -1088,6 +1111,8 @@ class NativeServerPlane:
             self._dispatch(sock, frame)
         except Exception:
             logger.exception("native frame dispatch failed")
+        finally:
+            LIB_HELD.tb_iobuf_destroy(body_h)
 
     # fabriclint: hotpath
     def _dispatch(self, sock: NativeConnSock, frame) -> None:

@@ -2197,6 +2197,9 @@ FrameStatus process_frames_tbus(NetConn* c) {
       tb_iobuf_clear(scratch);
       continue;
     }
+    // the cut's time, read on this route only (the native fast path
+    // above pays no clock): the callback may wait for the interpreter
+    uint64_t cut_ns = tb_monotonic_ns();
     // the Python callee owns its body: hand it a fresh handle that
     // ref-shares the scratch's blocks (no byte copy), then reuse scratch
     tb_iobuf* body = tb_iobuf_create();
@@ -2207,7 +2210,7 @@ FrameStatus process_frames_tbus(NetConn* c) {
                     (c->authenticated.load(std::memory_order_relaxed)
                          ? kFlagConnAuthed
                          : 0),
-                hdr.error_code, cb_meta, hdr.meta_len, body);
+                hdr.error_code, cb_meta, hdr.meta_len, body, cut_ns);
   }
 }
 
@@ -2351,12 +2354,13 @@ FrameStatus process_frames_prpc(NetConn* c) {
       tb_iobuf_clear(scratch);
       continue;
     }
+    uint64_t cut_ns = tb_monotonic_ns();  // as in the tbus loop
     tb_iobuf* body = tb_iobuf_create();
     tb_iobuf_append_iobuf(body, scratch);
     tb_iobuf_clear(scratch);
     s->frame_cb(s->frame_ctx, c->token, static_cast<uint32_t>(pm.cid),
                 static_cast<uint32_t>(pm.cid >> 32), cb_flags, pm.error_code,
-                mptr, meta_len, body);
+                mptr, meta_len, body, cut_ns);
   }
 }
 

@@ -62,10 +62,14 @@ typedef struct tb_channel tb_channel;
 // `flags` bit 8 (0x100) marks a frame that arrived on a baidu_std (PRPC)
 // connection: `meta` is then raw RpcMeta proto bytes, not JSON, and the
 // callee answers with PRPC bytes via tb_conn_write.
+// `cut_ns` is tb_monotonic_ns() (CLOCK_MONOTONIC, Python's time.monotonic
+// clock) taken when the frame left the cut loop for this route: the
+// callee's arrival stamp, whatever the callback then waits for.
 typedef void (*tb_frame_fn)(void* ctx, uint64_t conn_token, uint32_t cid_lo,
                             uint32_t cid_hi, uint32_t flags,
                             uint32_t error_code, const char* meta,
-                            size_t meta_len, tb_iobuf* body);
+                            size_t meta_len, tb_iobuf* body,
+                            uint64_t cut_ns);
 
 // Protocol-sniff handoff: the first bytes of a new connection are not
 // tbus_std.  The callee takes ownership of `fd` and receives whatever was

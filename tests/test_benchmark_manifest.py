@@ -55,6 +55,8 @@ def hand_made_run(counters: dict):
 DEVICE = {f"device_transport_{s}_us": recorder(100, 100.0) for s in DEVICE_STAGES}
 DEVICE.update({
     "device_transport_ingress_us": recorder(100, 2500.0),
+    "device_transport_egress_us": recorder(100, 400.0),
+    "device_transport_plane_callback_us": recorder(100, 150.0),
     "device_transport_dispatches": 40,
     "device_transport_dispatch_rows": 100,
     "device_transport_dispatch_pad_rows": 128,
@@ -84,6 +86,8 @@ EXPECTED = {
     **{f"device_{s}_us": (DEVICE, 100.0) for s in DEVICE_STAGES},
     "device_path_unattributed_pct": (DEVICE, 10.0),
     "host_plane_ingress_us": (DEVICE, 2500.0),
+    "host_plane_egress_us": (DEVICE, 400.0),
+    "native_plane_callback_us": (DEVICE, 150.0),
     "dispatch_rows": (DEVICE, 2.5),
     "dispatch_pad_pct": (DEVICE, 100.0 * 28 / 128),
     "echo_step_hbm_pct_dispatched": (DEVICE, HBM_DISPATCHED),
@@ -134,14 +138,48 @@ def test_every_metric_is_accounted_for():
 
 
 def test_new_metrics_report_in_the_cells_the_issue_gives_them():
+    """At least these cells: a later cell of an echo configuration joins
+    the lists of the metrics its deployment feeds (PR 27's did)."""
     cells = {m["name"]: m.get("workloads") for m in BENCH["per_layer"]}
     for name in EXPECTED:
         if name.startswith("link_"):
             assert cells[name] == ["link_echo_ici_1m"], name
+        elif name == "native_plane_callback_us":
+            # only the native plane feeds it
+            assert cells[name] == ["echo_256b_c16_native"]
         elif name == "dispatch_pad_pct":
-            assert cells[name] == ["echo_256b_c16", "echo_mixed_c16"]
+            assert {"echo_256b_c16", "echo_mixed_c16"} <= set(cells[name])
+            assert "echo_4m_c2" not in cells[name]
         else:
-            assert cells[name] == ECHO_CELLS, name
+            assert set(ECHO_CELLS) <= set(cells[name]), name
+    for name, listed in cells.items():
+        # the native cell is echo_256b_c16 with the plane changed: it
+        # reports whatever that cell reports
+        if listed and "echo_256b_c16" in listed:
+            assert "echo_256b_c16_native" in listed, name
+
+
+def test_each_configuration_is_the_file_the_manifest_names():
+    expected = {
+        "echo_device": ("device_echo", {}, None),
+        "echo_device_native": (
+            "device_echo_native", {"native_plane": True}, {"native_plane": True},
+        ),
+        "link_performance_ici": ("link_echo", None, None),
+    }
+    configs = {c["name"]: c for c in BENCH["configs"]}
+    assert set(expected) <= set(configs)  # a later PR may add more
+    sources = [c["source"] for c in BENCH["configs"]]
+    assert len(sources) == len(set(sources))
+    for name, (deployment, channel_options, server_options) in expected.items():
+        config = manifest.load_json("configs", name + ".json")
+        assert configs[name]["file"] == f"benchmark/configs/{name}.json"
+        assert config["deployment"] == deployment
+        assert config["reference"] == "echo_identity"
+        assert config["reduced"] == configs[name]["reduced"] == []
+        if channel_options is not None:
+            assert config["channel_options"] == channel_options
+        assert config.get("server_options") == server_options
 
 
 @pytest.mark.parametrize("metric", sorted(EXPECTED))

@@ -329,10 +329,11 @@ class TestStageRecorders:
 
         before = _transport_counters()
         ingress = device.m_ingress.count()
-        row = (1000, 2000, 3000, 4000, 5000, 6000, 7000, 8000, 9000, None)
+        stages = (1000, 2000, 3000, 4000, 5000, 6000, 7000, 8000, 9000)
         for _ in range(20):
-            device._stage_feed.rows.append(row)
-        device._stage_feed.rows.append(row[:-1] + (500,))  # came through a server
+            device._stage_feed.rows.append(stages + (None, None, None))
+        # came through a server: ingress, no native callback, egress
+        device._stage_feed.rows.append(stages + (500, None, 700))
         assert device.m_wake.count() == before["wake"][0]  # they wait
         assert _wait_until(  # no flush of ours: the 1 Hz sampler feeds them
             lambda: device.m_wake.count() - before["wake"][0] == 21, timeout=5
@@ -383,8 +384,12 @@ class TestStageRecorders:
             for i in range(5):
                 cntl = ch.call_method("tensor", "echo", b"ingress-%d" % i)
                 assert cntl.ok(), cntl.error_text
-            flush_stage_recorders()
-            assert m_ingress.count() - before[0] == 5
+            # a call's row is appended once its response is written, which
+            # the client may see first
+            assert _wait_until(
+                lambda: flush_stage_recorders()
+                or m_ingress.count() - before[0] == 5
+            )
             # cut off the wire before the handler: a positive time, and
             # far under a second on any machine
             gained = m_ingress.latency_sum() - before[1]

@@ -569,6 +569,7 @@ class TestRegistryRules:
         "device_transport_launch_us", "device_transport_cq_wait_us",
         "device_transport_ready_us", "device_transport_readback_us",
         "device_transport_wake_us", "device_transport_ingress_us",
+        "device_transport_plane_callback_us", "device_transport_egress_us",
         "device_transport_latency", "device_transport_dispatches",
         "device_transport_dispatch_rows", "device_transport_dispatch_pad_rows",
         "device_transport_dispatch_words", "device_link_capacity_bytes",
