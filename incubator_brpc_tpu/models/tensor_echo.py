@@ -27,7 +27,11 @@ class TensorEchoService:
     _method_map of MethodProperty (reference server.cpp:1209) at device level.
 
     Handlers must be shape-preserving uint32->uint32 transforms (static
-    shapes; XLA traces each handler once per payload geometry).
+    shapes; XLA traces each handler once per payload geometry). Behind a
+    ``DeviceEndpoint`` a handler sees its payload zero-padded to a width
+    the endpoint chooses (the call's bucket, or a wider one it shares a
+    dispatch with), and the caller is given the first ``n`` words of the
+    answer, ``n`` the words it sent: those must not depend on that width.
     """
 
     def __init__(self) -> None:

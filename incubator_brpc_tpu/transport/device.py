@@ -17,7 +17,10 @@ A ``DeviceEndpoint`` is the RdmaEndpoint re-thought for XLA:
   (rdma_completion_queue delivering CQ events, here PJRT readiness);
 - frames are bucketed to power-of-two payload sizes so XLA compiles one
   program per geometry and reuses it (static shapes; the block-pool
-  fixed-block discipline applied to programs instead of buffers).
+  fixed-block discipline applied to programs instead of buffers);
+- calls queued together leave as one dispatch whatever their buckets:
+  the rows are stacked at the widest bucket among them, under
+  ``MAX_STACKED_WORDS``.
 
 ``DeviceEndpoint.call_bytes`` adapts the host byte world: payloads are
 padded into the bucket and responses trimmed to the request's length
@@ -51,6 +54,17 @@ from incubator_brpc_tpu.utils.status import ErrorCode
 
 MIN_BUCKET_WORDS = 64
 MAX_BUCKET_WORDS = 1 << 24  # 64 MiB of uint32
+# The most words (padded rows x widest bucket) a dispatch may stack once
+# it holds calls of different buckets: 1 MiB, sixteen rows of a 64 KiB
+# bucket. What it stands on (PERF_LEDGER.jsonl, PR 27): per byte a
+# dispatch costs about 0.7 ms a MiB (device_stack_us 806 +
+# device_readback_us 1,516 + the copy inside the launch, for 4 MiB,
+# echo_4m_c2), per dispatch the launch alone costs 2.2-3.5 ms
+# (device_launch_us, same lines), so under 1 MiB widening adds less than
+# a third of what one saved dispatch gives back. Calls of one bucket
+# stack as they always did, whatever their size. Move it only on chip
+# evidence, written into PERF.md.
+MAX_STACKED_WORDS = 1 << 18
 
 # credit held -> response parsed, per call: the total the stages split
 device_latency = LatencyRecorder(name="device_transport_latency")
@@ -82,6 +96,7 @@ m_dispatches = Adder(name="device_transport_dispatches")
 m_dispatch_rows = Adder(name="device_transport_dispatch_rows")
 m_dispatch_pad_rows = Adder(name="device_transport_dispatch_pad_rows")
 m_dispatch_words = Adder(name="device_transport_dispatch_words")
+m_dispatch_widened_rows = Adder(name="device_transport_dispatch_widened_rows")
 
 
 # a completed call's stage times (ns, in this order) wait here for the
@@ -139,22 +154,32 @@ def _bucket_words(n: int) -> int:
     return b
 
 
+def _pad_rows(b: int) -> int:
+    """Rows the program runs for a batch of ``b`` calls: the next power of
+    two, so jit compiles O(log max_batch) programs per bucket."""
+    return 1 << (b - 1).bit_length()
+
+
 class _Dispatch:
     """One (batch, bucket) program execution, shared by the calls stacked
-    into it. Its ``time.monotonic_ns()`` stamps are each written once, by
-    the thread that does the work: the drain or ``-tx`` thread up to
-    ``t_launched``, a completion watcher from there."""
+    into it; ``bucket`` is the widest among theirs. Its
+    ``time.monotonic_ns()`` stamps are each written once, by the thread
+    that does the work: the drain or ``-tx`` thread up to ``t_launched``,
+    a completion watcher from there."""
 
     __slots__ = (
-        "seq", "rows", "pad_rows", "bucket",
+        "seq", "rows", "pad_rows", "bucket", "widened_rows",
         "t_batched", "t_stacked", "t_launched", "watcher", "t_readback",
     )
 
-    def __init__(self, seq: int, rows: int, pad_rows: int, bucket: int):
+    def __init__(
+        self, seq: int, rows: int, pad_rows: int, bucket: int, widened_rows: int
+    ):
         self.seq = seq  # the endpoint's dispatch number
         self.rows = rows  # calls stacked
         self.pad_rows = pad_rows  # rows the program ran (next power of two)
-        self.bucket = bucket  # payload words per row
+        self.bucket = bucket  # payload words per row as the program ran it
+        self.widened_rows = widened_rows  # calls whose own bucket is narrower
         self.t_batched = _time.monotonic_ns()  # taken off the queue
         self.t_stacked = 0  # rows copied into one array
         self.t_launched = 0  # the program call, which stages the rows, returned
@@ -263,6 +288,12 @@ class _PendingCall:
 class DeviceEndpoint:
     """One device-resident service behind a credit window.
 
+    A handler sees its payload zero-padded to a width the endpoint
+    chooses: its call's bucket, or the widest bucket of the calls that
+    share its dispatch. A call gets back the first ``n`` words of the
+    answer, ``n`` the words it sent, so those must not depend on that
+    width (every elementwise handler qualifies).
+
     A dispatch launches with one call of the jitted step program on the
     stacked host rows: the call stages its numpy arguments itself, onto
     ``device`` (both programs pin ``in_shardings`` there), and nothing
@@ -281,14 +312,14 @@ class DeviceEndpoint:
         self.service = service or TensorEchoService()
         self.device = device if device is not None else jax.devices()[0]
         self.window_size = window_size
-        # Micro-batching: concurrent same-bucket calls stack into ONE
-        # [B, width] dispatch of the vmapped step (batch sizes padded to
-        # powers of two so jit compiles a handful of programs, not one
-        # per B). This is the TPU-idiomatic fix for per-dispatch fixed
-        # costs: 16 concurrent callers pay ~1-2 dispatches, not 16 — and
-        # the stacked rows feed the MXU together. Clamped to the window:
-        # at most window_size calls hold credits concurrently, so a
-        # larger batch ceiling could never form.
+        # Micro-batching: concurrent calls stack into ONE [B, width]
+        # dispatch of the vmapped step (batch sizes padded to powers of
+        # two so jit compiles a handful of programs, not one per B;
+        # width the widest bucket among them). This is the TPU-idiomatic
+        # fix for per-dispatch fixed costs: 16 concurrent callers pay
+        # ~1-2 dispatches, not 16 — and the stacked rows feed the MXU
+        # together. Clamped to the window: at most window_size calls hold
+        # credits concurrently, so a larger batch ceiling could never form.
         self.max_batch = max(1, min(max_batch, window_size))
         self._credits = Butex(window_size)
         self._cq = DeviceCompletionButex()
@@ -412,15 +443,22 @@ class DeviceEndpoint:
                 if not self._queue:
                     self._draining = False
                     return
-                # group the head run of SAME-BUCKET entries (shape =
-                # program identity); mids/cids are per-row arguments
-                bucket = self._queue[0][0]
-                batch = []
-                while (
-                    self._queue
-                    and self._queue[0][0] == bucket
-                    and len(batch) < self.max_batch
-                ):
+                # a batch is the FIFO prefix of the queue, stacked at the
+                # widest bucket in it (rows x bucket = program identity;
+                # mids/cids are per-row arguments). Calls of one bucket
+                # stack whatever their size; once buckets differ the
+                # stacked array stays under MAX_STACKED_WORDS. A prefix
+                # only: nothing is reordered, nothing can starve
+                batch = [self._queue.popleft()]
+                bucket, mixed = batch[0][0], False
+                while self._queue and len(batch) < self.max_batch:
+                    joining = self._queue[0][0]
+                    if mixed or joining != bucket:
+                        widest = max(bucket, joining)
+                        stacked = _pad_rows(len(batch) + 1) * widest
+                        if stacked > MAX_STACKED_WORDS:
+                            break
+                        bucket, mixed = widest, True
                     batch.append(self._queue.popleft())
                 more = bool(self._queue)
             if more:
@@ -440,19 +478,21 @@ class DeviceEndpoint:
                 self._dispatch_batch(bucket, batch)
 
     def _dispatch_batch(self, bucket: int, batch: list) -> None:
+        """Run ``batch`` as one program execution over rows of ``bucket``
+        words, the widest bucket among its entries."""
         b = len(batch)
-        # pad the batch to a power of two so jit compiles O(log max_batch)
-        # programs per bucket; pad rows are zero frames whose (flagged-
-        # garbage) response rows are simply ignored
-        bpad = 1
-        while bpad < b:
-            bpad <<= 1
-        dispatch = _Dispatch(next(self._dispatch_seq), b, bpad, bucket)
-        rows = np.zeros((bpad, bucket + 0), dtype=np.uint32)
+        # pad rows are zero frames whose (flagged-garbage) response rows
+        # are simply ignored
+        bpad = _pad_rows(b)
+        dispatch = _Dispatch(
+            next(self._dispatch_seq), b, bpad, bucket,
+            sum(entry[0] != bucket for entry in batch),
+        )
+        rows = np.zeros((bpad, bucket), dtype=np.uint32)
         cids = np.zeros(bpad, dtype=np.uint32)
         mids = np.zeros(bpad, dtype=np.uint32)
         for i, (_, mid, padded, cid, pending, _n) in enumerate(batch):
-            rows[i] = padded
+            rows[i, : padded.size] = padded
             cids[i] = cid
             mids[i] = mid
             pending.dispatch = dispatch
@@ -509,6 +549,7 @@ class DeviceEndpoint:
             m_dispatch_rows << dispatch.rows
             m_dispatch_pad_rows << dispatch.pad_rows
             m_dispatch_words << dispatch.pad_rows * dispatch.bucket
+            m_dispatch_widened_rows << dispatch.widened_rows
 
         self._cq.watch(
             response, on_complete=on_complete, stamps=dispatch.watcher

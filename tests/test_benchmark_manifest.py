@@ -61,6 +61,7 @@ DEVICE.update({
     "device_transport_dispatch_rows": 100,
     "device_transport_dispatch_pad_rows": 128,
     "device_transport_dispatch_words": 128 * 64,
+    "device_transport_dispatch_widened_rows": 60,
 })
 # two links: 3 is the busiest; 2 must not be read
 LINK = {
@@ -90,6 +91,7 @@ EXPECTED = {
     "native_plane_callback_us": (DEVICE, 150.0),
     "dispatch_rows": (DEVICE, 2.5),
     "dispatch_pad_pct": (DEVICE, 100.0 * 28 / 128),
+    "dispatch_widened_pct": (DEVICE, 60.0),
     "echo_step_hbm_pct_dispatched": (DEVICE, HBM_DISPATCHED),
     "link_flush_us": (LINK, 40.0),
     "link_launch_us": (LINK, 1200.0),
@@ -147,7 +149,7 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
         elif name == "native_plane_callback_us":
             # only the native plane feeds it
             assert cells[name] == ["echo_256b_c16_native"]
-        elif name == "dispatch_pad_pct":
+        elif name in ("dispatch_pad_pct", "dispatch_widened_pct"):
             assert {"echo_256b_c16", "echo_mixed_c16"} <= set(cells[name])
             assert "echo_4m_c2" not in cells[name]
         else:

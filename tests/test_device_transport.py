@@ -184,7 +184,10 @@ def _transport_counters():
     recs["latency"] = device.device_latency
     device.flush_stage_recorders()  # the sampler thread would, within the second
     out = {k: (r.count(), r.latency_sum()) for k, r in recs.items()}
-    for name in ("dispatches", "dispatch_rows", "dispatch_pad_rows", "dispatch_words"):
+    for name in (
+        "dispatches", "dispatch_rows", "dispatch_pad_rows", "dispatch_words",
+        "dispatch_widened_rows",
+    ):
         out[name] = getattr(device, "m_" + name).get_value()
     return out
 
@@ -216,10 +219,15 @@ class TestStageRecorders:
         for size in set(sizes):
             ep.warm(size)
         dispatched = []  # (calls, bucket) of every batch, from the inside
+        widened = []  # (its widest bucket, calls of a narrower one) of each
         inner = ep._dispatch_batch
 
         def recording(bucket, batch):
             dispatched.append((len(batch), bucket))
+            widened.append((
+                max(entry[0] for entry in batch),
+                sum(entry[0] < bucket for entry in batch),
+            ))
             inner(bucket, batch)
 
         ep._dispatch_batch = recording
@@ -258,6 +266,8 @@ class TestStageRecorders:
         assert gained["dispatch_words"] == sum(
             pow2(b) * bucket for b, bucket in dispatched
         )
+        assert [w for w, _ in widened] == [bucket for _, bucket in dispatched]
+        assert gained["dispatch_widened_rows"] == sum(n for _, n in widened)
 
     def test_stamps_are_monotone_along_a_call(self, endpoint):
         import time
@@ -474,6 +484,197 @@ def two_method_endpoint():
     svc = TensorEchoService()
     svc.add_method(7, lambda p: p ^ jnp.uint32(XOR))
     return DeviceEndpoint(service=svc, window_size=16, max_batch=16)
+
+
+def _queue_then_drain(ep, calls, monkeypatch):
+    """``calls``: ``(words, method id)`` each. All are queued while the
+    drain is held, as calls that arrived together, then one drain runs.
+    Returns the pending calls, in queue order, and the batches the drain
+    formed, ``(bucket, [correlation ids])`` each, in no order (a batch
+    with more behind it leaves on a thread of its own)."""
+    batches, lock = [], threading.Lock()
+    inner = ep._dispatch_batch
+
+    def recording(bucket, batch):
+        with lock:
+            batches.append((bucket, [int(entry[3]) for entry in batch]))
+        inner(bucket, batch)
+
+    monkeypatch.setattr(ep, "_dispatch_batch", recording)
+    with ep._qlock:
+        ep._draining = True
+    pendings = [
+        ep.call_words(words, method_id=mid, correlation_id=i + 1, timeout=60)
+        for i, (words, mid) in enumerate(calls)
+    ]
+    ep._drain()
+    for pending in pendings:
+        assert pending.wait(timeout=120)
+    assert ep.inflight == 0
+    return pendings, batches
+
+
+def _payloads(sizes, seed):
+    """Words of ``sizes`` lengths, no two calls alike."""
+    rng = np.random.default_rng(seed)
+    return [rng.integers(1, 1 << 32, size=n, dtype=np.uint32) for n in sizes]
+
+
+def _counters_at_rest():
+    """The counters once no watcher of an earlier test is still feeding
+    the dispatch adders (it does so after its callers are awake)."""
+    last = _transport_counters()
+    for _ in range(500):
+        time.sleep(0.02)
+        now = _transport_counters()
+        if now == last:
+            return now
+        last = now
+    raise AssertionError("the transport's counters never came to rest")
+
+
+def _dispatch_gains(before, dispatches):
+    """The dispatch adders' gains since ``before``, once ``dispatches``
+    more have completed (the watcher feeds them after its callers wake)."""
+    assert _wait_until(
+        lambda: _transport_counters()["dispatches"] - before["dispatches"]
+        == dispatches
+    )
+    after = _transport_counters()
+    return {k: after[k] - before[k] for k in after if k.startswith("dispatch")}
+
+
+class TestWidenedBatches:
+    """A batch is the FIFO prefix of the queue, whatever the buckets in
+    it; its rows are stacked at the widest, under ``MAX_STACKED_WORDS``
+    once buckets differ (PR 28)."""
+
+    @pytest.mark.parametrize("buckets", [(64, 256), (64, 16384), (4096, 1024)])
+    @pytest.mark.parametrize("b", [2, 3, 5, 16])
+    def test_buckets_queued_together_leave_as_one_dispatch(
+        self, two_method_endpoint, monkeypatch, buckets, b
+    ):
+        ep = two_method_endpoint
+        widest = max(buckets)
+        # alternate the two buckets; not every row fills its own
+        sizes = [buckets[i % 2] - (i % 3) for i in range(b)]
+        calls = [
+            (words, (0, 7)[i % 2])
+            for i, words in enumerate(_payloads(sizes, widest + b))
+        ]
+        before = _counters_at_rest()
+        with _response_frames(ep) as frames:
+            pendings, batches = _queue_then_drain(ep, calls, monkeypatch)
+        assert batches == [(widest, list(range(1, b + 1)))]
+        pad = 1 << (b - 1).bit_length()
+        (frame,) = frames
+        assert frame.shape == ((widest + 8,) if pad == 1 else (pad, widest + 8))
+        narrower = sum(_bucket_words(n) < widest for n in sizes)
+        assert 0 < narrower < b
+        for (words, mid), pending in zip(calls, pendings):
+            assert pending.error is None and pending.error_code == 0
+            want = words ^ np.uint32(XOR) if mid == 7 else words
+            np.testing.assert_array_equal(pending.response_words, want)
+            d = pending.dispatch
+            assert (d.rows, d.pad_rows, d.bucket) == (b, pad, widest)
+            assert d.widened_rows == narrower
+        assert _dispatch_gains(before, 1) == {
+            "dispatches": 1,
+            "dispatch_rows": b,
+            "dispatch_pad_rows": pad,
+            "dispatch_words": pad * widest,
+            "dispatch_widened_rows": narrower,
+        }
+
+    @pytest.mark.parametrize(
+        "queued, formed",
+        [
+            # the issue's case: a 4 MiB call shares a dispatch with nobody
+            # of another bucket, the small ones behind it with each other
+            ([1 << 20, 64, 64, 64], [(1 << 20, 1), (64, 3)]),
+            ([64, 1 << 20, 64], [(64, 1), (1 << 20, 1), (64, 1)]),
+            # 4 rows of 64 Ki words are the cap; a fifth call means 8 rows
+            ([1 << 16] + [64] * 6, [(1 << 16, 4), (64, 3)]),
+            # once buckets differ the cap holds for the widest bucket too
+            ([64] + [1 << 16] * 5, [(1 << 16, 4), (1 << 16, 2)]),
+            # 16 rows x 16 Ki words: the whole window of the widest size
+            # of the benchmark's mix, exactly at the cap
+            ([64, 1 << 14] * 8, [(1 << 14, 16)]),
+            ([1 << 15, 64] * 5, [(1 << 15, 8), (1 << 15, 2)]),
+        ],
+    )
+    def test_the_cap_ends_a_batch_and_the_order_is_kept(
+        self, two_method_endpoint, monkeypatch, queued, formed
+    ):
+        from incubator_brpc_tpu.transport.device import MAX_STACKED_WORDS
+
+        assert MAX_STACKED_WORDS == 1 << 18
+        ep = two_method_endpoint
+        calls = [(words, 0) for words in _payloads(queued, len(queued))]
+        pendings, batches = _queue_then_drain(ep, calls, monkeypatch)
+        batches.sort(key=lambda batch: batch[1][0])
+        assert [(bucket, len(cids)) for bucket, cids in batches] == formed
+        # the batches are consecutive runs of the queue: nothing overtook
+        assert [c for _, cids in batches for c in cids] == list(
+            range(1, len(queued) + 1)
+        )
+        for (words, _), pending in zip(calls, pendings):
+            assert pending.error_code == 0
+            np.testing.assert_array_equal(pending.response_words, words)
+            d = pending.dispatch
+            if d.widened_rows:
+                assert d.pad_rows * d.bucket <= MAX_STACKED_WORDS
+
+    @pytest.mark.parametrize(
+        "bucket, queued, max_batch, formed",
+        [
+            (64, 5, 4, [4, 1]),
+            (256, 16, 16, [16]),
+            # one bucket stacks whatever its size, as it always did:
+            # 8 rows of 64 Ki words are twice the cap
+            (1 << 16, 8, 8, [8]),
+        ],
+    )
+    def test_a_queue_of_one_bucket_forms_the_batches_it_always_did(
+        self, monkeypatch, bucket, queued, max_batch, formed
+    ):
+        ep = DeviceEndpoint(window_size=16, max_batch=max_batch)
+        sizes = [bucket - (i % 3) for i in range(queued)]
+        calls = [(words, 0) for words in _payloads(sizes, bucket)]
+        before = _counters_at_rest()
+        pendings, batches = _queue_then_drain(ep, calls, monkeypatch)
+        batches.sort(key=lambda batch: batch[1][0])
+        assert [(b, len(cids)) for b, cids in batches] == [
+            (bucket, n) for n in formed
+        ]
+        for (words, _), pending in zip(calls, pendings):
+            assert pending.error_code == 0
+            np.testing.assert_array_equal(pending.response_words, words)
+        gained = _dispatch_gains(before, len(formed))
+        assert gained["dispatch_rows"] == queued
+        assert gained["dispatch_widened_rows"] == 0
+
+    @pytest.mark.parametrize("flagged", [0, 2, 5])
+    def test_a_flagged_frame_in_a_widened_batch_fails_only_its_call(
+        self, two_method_endpoint, monkeypatch, flagged
+    ):
+        ep = two_method_endpoint
+        sizes = [40, 1000, 64, 4000, 200, 7]
+        calls = [
+            (words, 999 if i == flagged else (0, 7)[i % 2])
+            for i, words in enumerate(_payloads(sizes, flagged))
+        ]
+        pendings, batches = _queue_then_drain(ep, calls, monkeypatch)
+        assert batches == [(4096, [1, 2, 3, 4, 5, 6])]
+        for i, ((words, mid), pending) in enumerate(zip(calls, pendings)):
+            assert pending.error is None
+            if i == flagged:
+                assert pending.error_code == 1002  # ENOMETHOD, this row only
+                assert not pending.response_words.any()
+            else:
+                assert pending.error_code == 0, i
+                want = words ^ np.uint32(XOR) if mid == 7 else words
+                np.testing.assert_array_equal(pending.response_words, want)
 
 
 class TestLaunch:
