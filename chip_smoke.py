@@ -3,10 +3,9 @@
 
 Drives the fabric's main path through the entry points a user calls
 (Server / Channel / DeviceEndpoint / DeviceLink / fabricnet /
-``__graft_entry__``), at the sizes of upstream's payload sweep and the
-repo's own bench geometry, and checks what comes back by the repo's own
-means: bytes out == bytes in, a numpy twin, an integer model, a plain
-reference. One process touches JAX and runs every in-process phase; the
+``__graft_entry__``), at the sizes of upstream's payload sweep, and
+checks what comes back by the repo's own means: bytes out == bytes in, a
+numpy twin, an integer model, a plain reference. One process touches JAX and runs every in-process phase; the
 parent that launched it stays off JAX so that, on a four-chip host, it
 can afterwards hand one chip to each ``mc_worker`` process.
 
@@ -40,14 +39,14 @@ PHASE_CAP_S = 300.0
 RESULT_TAG = "CHIP_SMOKE_RESULT "
 
 # Sizes. REAL follows upstream's payload sweep (docs/cn/benchmark.md:94-110
-# via BASELINE.md) and bench.py's geometry; a size is cut only where the
-# time limit forces it, and each cut is listed in CUTS and printed.
+# via BASELINE.md); a size is cut only where the time limit forces it, and
+# each cut is listed in CUTS and printed.
 REAL = SimpleNamespace(
     rpc_payloads=[64, 4 << 10, 32 << 10, 1 << 20, 32 << 20],
     rpc_callers=16,
     rpc_calls=8,
     rpc_burst_payload=256,
-    echo_words=64 * 1024 * 1024,  # 256 MiB frame (bench.py headline)
+    echo_words=64 * 1024 * 1024,  # a 256 MiB frame: the fused step at a size no RPC reaches
     echo_iters=3,
     link_echo_bytes=1 << 20,
     link_slot_words=256 * 1024,
@@ -265,7 +264,7 @@ def _stream_link(link, S) -> int:
 
 def phase_link(S) -> str:
     """transport=tpu through its normal entry point, then the jitted
-    on-device swap with the bench's link geometry."""
+    on-device swap at the link geometry of ``S``."""
     import jax
 
     import __graft_entry__ as ge
@@ -336,8 +335,7 @@ def phase_quantized_twins(S) -> str:
 
 def phase_fabricnet(S) -> str:
     """Train steps on a 1-device fabric mesh; the serialized and
-    overlapped schedules must stay bit-identical (bench.py asserts the
-    same)."""
+    overlapped schedules must stay bit-identical."""
     import jax
     import jax.numpy as jnp
     import numpy as np

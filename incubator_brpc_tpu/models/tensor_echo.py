@@ -90,7 +90,7 @@ def make_echo_step(
     service: Optional[TensorEchoService] = None,
 ):
     """Returns (jitted step fn, example framed request) for a given payload
-    geometry — used by bench.py and __graft_entry__.entry()."""
+    geometry — used by chip_smoke.py and __graft_entry__.entry()."""
     service = service or TensorEchoService()
     step = jax.jit(service.step)
     payload = jnp.arange(payload_words, dtype=jnp.uint32)

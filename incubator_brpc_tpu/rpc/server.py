@@ -312,7 +312,6 @@ class ServerOptions:
         rtmp_service=None,
         ssl_context=None,
         native_plane: bool = False,
-        native_loops: Optional[int] = None,
         num_reactors: Optional[int] = None,
         native_dispatch_workers: int = 0,
         session_local_data_factory=None,
@@ -347,10 +346,7 @@ class ServerOptions:
         # each owning its own epoll fd, SO_REUSEPORT listener, telemetry
         # ring, and cut/pack buffers; connections shard round-robin at
         # accept and never migrate.  None = auto from the affinity mask.
-        # ``native_loops`` is the legacy spelling of the same knob.
-        self.num_reactors = (
-            num_reactors if num_reactors is not None else native_loops
-        )
+        self.num_reactors = num_reactors
         # Work-stealing dispatch pool threads for native user methods
         # flagged long-running (native_long_running) or arriving behind a
         # queue-pressured burst; 0 = every native method runs inline on
@@ -410,17 +406,6 @@ class ServerOptions:
         # block: a blocking handler stalls every connection hashed to the
         # same dispatcher. First N-1 of a batch still fan out to fibers.
         self.usercode_inline = usercode_inline
-
-    @property
-    def native_loops(self) -> Optional[int]:
-        """Legacy spelling of ``num_reactors`` — a live alias, so code
-        that still assigns ``opts.native_loops = N`` after construction
-        keeps steering the reactor count."""
-        return self.num_reactors
-
-    @native_loops.setter
-    def native_loops(self, value: Optional[int]) -> None:
-        self.num_reactors = value
 
 
 class Server:

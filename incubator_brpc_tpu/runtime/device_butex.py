@@ -29,7 +29,7 @@ class _WatcherPool:
     """Dedicated completion threads (NOT the worker pool: a watcher blocks in
     the PJRT event wait, which would starve RPC fibers)."""
 
-    def __init__(self, nthreads: int = 2):
+    def __init__(self, nthreads: int):
         self._jobs: List = []
         self._cond = threading.Condition()
         self._active = 0  # jobs currently executing
@@ -83,6 +83,11 @@ class _WatcherPool:
                     self._cond.notify_all()
 
 
+# Completion-watcher threads of the process (the reference's rdma_cq_num,
+# CQ poller count rdma_completion_queue.cpp:39-55): completion handlers do
+# the host readback, so this bounds how many device→host fetches overlap.
+CQ_THREADS = 8
+
 _watchers: Optional[_WatcherPool] = None
 _watchers_lock = threading.Lock()
 
@@ -92,15 +97,7 @@ def _watcher_pool() -> _WatcherPool:
     if _watchers is None:
         with _watchers_lock:
             if _watchers is None:
-                # sized by flag (the reference's rdma_cq_num, CQ poller
-                # count rdma_completion_queue.cpp:39-55): completion
-                # handlers do the host readback, so this bounds how many
-                # device→host fetches overlap
-                from incubator_brpc_tpu.utils.flags import get_flag
-
-                _watchers = _WatcherPool(
-                    max(1, int(get_flag("device_cq_threads")))
-                )
+                _watchers = _WatcherPool(CQ_THREADS)
     return _watchers
 
 

@@ -95,15 +95,13 @@ _NATIVE_COMPRESS_WIRE = {"snappy": 1, "gzip": 2, "zlib1": 3}
 _NATIVE_COMPRESS_NAMES = {v: k for k, v in _NATIVE_COMPRESS_WIRE.items()}
 
 # client fast-path instrumentation: per-call round-trip latency (Python
-# boundary included — the L5 crossing rpc_echo_us measures), transport
-# errors, and the pipelined pump's ns/request (bench.py's native_pump_ns,
-# now scrapeable from /brpc_metrics on any process that ran a pump)
+# boundary included), transport errors, and the pipelined pump's
+# ns/request, scrapeable from /brpc_metrics on any process that ran a pump
 native_client_calls = Adder(name="native_client_calls")
 native_client_errors = Adder(name="native_client_errors")
 native_client_call_us = LatencyRecorder(name="native_client_call_us")
 native_pump_ns = IntRecorder(name="native_pump_ns")
-# the same pipelined pump over the baidu_std (PRPC) wire — bench.py's
-# prpc_pump_ns row scrapes this
+# the same pipelined pump over the baidu_std (PRPC) wire
 prpc_pump_ns = IntRecorder(name="prpc_pump_ns")
 
 # process-wide compress/auth telemetry summed across every live native

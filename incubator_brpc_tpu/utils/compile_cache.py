@@ -12,8 +12,8 @@ never hits. The choice is exported to the environment so every child
 process inherits it.
 
 Only programs that own a device call :func:`configure` (``chip_smoke.py``,
-``bench.py``). Importing the package never does, so the tier-1 tests on
-the CPU leave the checkout's cache empty.
+``benchmark/run.py``). Importing the package never does, so the tier-1
+tests on the CPU leave the checkout's cache empty.
 """
 
 from __future__ import annotations

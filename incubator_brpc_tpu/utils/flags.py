@@ -124,11 +124,6 @@ define_flag(
     lambda v: v >= 0,
 )
 define_flag("socket_max_unwritten_bytes", 64 * 1024 * 1024, "write-queue backpressure threshold (EOVERCROWDED)", lambda v: v > 0)
-define_flag(
-    "device_cq_threads",
-    8,
-    "completion-watcher threads; bounds overlapped device->host readbacks (rdma_cq_num analog)",
-)
 define_flag("enable_rpcz", False, "collect rpcz spans", lambda v: True)
 define_flag(
     "enable_dir_service",

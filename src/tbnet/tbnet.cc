@@ -1078,8 +1078,8 @@ struct NetConn : PollObj {
   // bytes change every call (span ids), so the byte-keyed memo above
   // can never hit — this one compares the decoded service/method slices
   // instead, keeping a traced flood at two memcmps per frame instead of
-  // a per-request flatmap probe + name join (the prpc_traced_pump_ns
-  // gate's margin lives here)
+  // a per-request flatmap probe + name join (the traced-pump gate of
+  // tests/test_tracing.py has its margin here)
   std::string memo_svc;  // fabricscan: owner(loop)
   std::string memo_mth;  // fabricscan: owner(loop)
   long memo_name_idx = -1;  // -1 = no memo  // fabricscan: owner(loop)
@@ -3739,7 +3739,7 @@ long tb_channel_pump(tb_channel* ch, const void* meta, size_t meta_len,
   // Built ONCE like the plain template; every trace_every'th frame uses
   // it, the rest the plain one — counter-scheduled exact rate with zero
   // per-frame re-encoding, which is what keeps a traced flood within a
-  // hair of the bare pump (the prpc_traced_pump_ns bench gate).
+  // hair of the bare pump (the traced-pump gate, tests/test_tracing.py).
   std::vector<char> ttmpl;
   size_t tcid_off = 0, tspan_off = 0;
   const uint32_t trace_every = ch->proto == 1 ? ch->trace_every : 0;

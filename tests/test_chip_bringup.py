@@ -1,5 +1,5 @@
 """What the chip bring-up added, as far as a CPU can check it: the smoke
-and the benchmark refuse to run off a TPU, the compile cache is placed
+refuses to run off a TPU, the compile cache is placed
 by one rule, and the launcher — not the worker — decides a child's
 platform. Each case runs in a child process: the subjects set process
 environment and ``jax.config`` and must not leak into the suite (a
@@ -42,12 +42,6 @@ class TestRefusesOffTheChip:
         assert "chip_smoke needs a TPU" in r.stdout
         assert '"ok"' not in r.stdout and "PHASE" not in r.stdout
         assert _cache_state() == before  # refused before compiling anything
-
-    def test_bench_refuses(self):
-        r = _run(["bench.py"], {"JAX_PLATFORMS": "cpu"})
-        assert r.returncode != 0
-        assert "platform=cpu" in r.stderr
-        assert r.stdout.strip() == ""  # no metric line from a CPU
 
 
 _CONFIGURE = (

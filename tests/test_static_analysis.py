@@ -583,6 +583,28 @@ class TestRegistryRules:
         with open(registry_lint.OBSERVABILITY_MD) as fh:
             assert f"`{name}`" in fh.read()
 
+    @pytest.mark.parametrize("doc", [
+        "README.md", "docs/ANALYSIS.md", "docs/DEVICE_PLANE.md",
+        "docs/OBSERVABILITY.md", "docs/PARITY.md", "docs/ROBUSTNESS.md",
+    ])
+    def test_document_names_only_files_of_the_tree(self, doc):
+        assert os.path.join(REPO, doc) in registry_lint.doc_paths()
+        vs = registry_lint.check_doc_files([os.path.join(REPO, doc)])
+        assert not vs, _fmt(vs)
+
+    def test_document_naming_a_missing_file_flagged(self, tmp_path):
+        doc = tmp_path / "DOC.md"
+        doc.write_text(
+            "`rpc/server.py:315` and `python3 chip_smoke.py --rehearse-on-cpu`\n"
+            "and `README.md` are here; `retired_script.py:7,9-12` is not,\n"
+            "nor is `docs/server.py`; `tbnet.cc` and out.json are not asked.\n"
+        )
+        vs = registry_lint.check_doc_files([str(doc)])
+        assert [(v.rule, v.line) for v in vs] == [
+            ("doc-file-missing", 2), ("doc-file-missing", 3)], _fmt(vs)
+        assert "retired_script.py" in vs[0].message
+        assert "docs/server.py" in vs[1].message
+
 
 # ---------------------------------------------------------------------------
 # 3. sanitizer harness (slow; probe-gated like the multiprocess tiers)

@@ -233,11 +233,9 @@ class TestTracedFloodStaysNative:
     def test_traced_pump_close_to_bare_pump(self, native_server,
                                             tuned_flags):
         # same-run ratio gate with a deliberately generous bound: the
-        # bench row (prpc_traced_pump_ns, acceptance ~1.15x) carries the
-        # honest number with host calibration; HERE the tripwire is the
-        # catastrophic regression — traced frames falling back to the
-        # interpreter route is a >10x cliff, so 2x catches it through
-        # shared-container noise without flaking
+        # tripwire is the catastrophic regression — traced frames falling
+        # back to the interpreter route is a >10x cliff, so 2x catches it
+        # through shared-container noise without flaking
         tuned_flags("enable_rpcz", False)  # isolate the wire/record cost
         srv = native_server({"svc": {"echo": native_echo}})
         nch = NativeClientChannel("127.0.0.1", srv.port, protocol="baidu_std")

@@ -18,7 +18,8 @@ Python-driven native plane:
 - **registry** (registry_lint.py): every ``define_flag`` is read
   somewhere and carries help text; exposed bvar names are valid
   Prometheus identifiers and the ``native_*``/``mc_*`` families match
-  docs/OBSERVABILITY.md.
+  docs/OBSERVABILITY.md; a ``.py``/``.md`` file that README.md or docs/
+  names in backticks exists.
 - **lifetime** (lifetime.py): every C callback registered from Python
   is held in a keepalive before crossing the FFI (the classic ctypes
   GC-of-live-callback crash), checked structurally.
@@ -94,6 +95,7 @@ RULES = (
     "flag-undocumented",
     "bvar-name",
     "bvar-undocumented",
+    "doc-file-missing",
     "ffi-keepalive",
     "ffi-unchecked",
     "bad-allow",

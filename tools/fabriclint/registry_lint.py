@@ -19,6 +19,10 @@ part before the first runtime placeholder).  The device path's families,
 name: the benchmark's per-layer readers find them by name, so a
 per-link ``f"{pfx}_launch_us"`` has to be in the document as
 ``device_link_<n>_launch_us``, not only its prefix.
+
+Documents (``doc-file-missing``): a ``.py`` or ``.md`` file that
+README.md or a file of docs/ names in backticks has to be in the tree,
+so a document cannot go on speaking for a script that was deleted.
 """
 
 from __future__ import annotations
@@ -314,5 +318,73 @@ def check_bvars(paths: Optional[List[str]] = None) -> List[Violation]:
     return out
 
 
+# ---------------------------------------------------------------------------
+# documents
+# ---------------------------------------------------------------------------
+
+_BACKTICKED = re.compile(r"`([^`\n]+)`")
+_LINE_SUFFIX = re.compile(r":[\d,\-–]+$")
+# a stem before the extension: a bare ".py" or a glob "*.py" names no file
+_PY_OR_MD = re.compile(r"\w\.(?:py|md)$")
+_NOT_THE_TREE = ("__pycache__", "build", "chiprun_out", "chip_scratch")
+
+
+def doc_paths() -> List[str]:
+    """README.md and every ``.md`` file of docs/."""
+
+    docs = os.path.join(REPO_ROOT, "docs")
+    return [os.path.join(REPO_ROOT, "README.md")] + [
+        os.path.join(docs, f) for f in sorted(os.listdir(docs))
+        if f.endswith(".md")
+    ]
+
+
+def _tree_files() -> Set[str]:
+    """Root-relative paths of the checkout's files; what building and
+    running leave behind (dot and cache directories, chip outputs, the
+    parent's copy under chip_scratch/) is not the tree."""
+
+    out: Set[str] = set()
+    for dirpath, dirnames, filenames in os.walk(REPO_ROOT):
+        dirnames[:] = [
+            d for d in dirnames
+            if not d.startswith(".") and d not in _NOT_THE_TREE
+        ]
+        rel = os.path.relpath(dirpath, REPO_ROOT)
+        out.update(os.path.normpath(os.path.join(rel, f)) for f in filenames)
+    return out
+
+
+def check_doc_files(docs: Optional[List[str]] = None) -> List[Violation]:
+    """A word in backticks that ends in ``.py`` or ``.md`` (a trailing
+    ``:line`` dropped) names a file: by its path from the root, by its
+    path under ``incubator_brpc_tpu/``, or by its base name."""
+
+    files = _tree_files()
+    pkg = "incubator_brpc_tpu/"
+    known = (
+        files
+        | {f[len(pkg):] for f in files if f.startswith(pkg)}
+        | {os.path.basename(f) for f in files}
+    )
+    out: List[Violation] = []
+    for path in docs if docs is not None else doc_paths():
+        with open(path, "r") as fh:
+            for lineno, text in enumerate(fh, 1):
+                for m in _BACKTICKED.finditer(text):
+                    for word in m.group(1).split():
+                        token = _LINE_SUFFIX.sub("", word)
+                        if _PY_OR_MD.search(token) and token not in known:
+                            out.append(
+                                Violation(
+                                    "doc-file-missing", path, lineno,
+                                    f"`{token}` is not a file of this tree: "
+                                    "by its path from the root, under "
+                                    "incubator_brpc_tpu/, or by its base name",
+                                )
+                            )
+    return out
+
+
 def check(paths: Optional[List[str]] = None) -> List[Violation]:
-    return check_flags(paths) + check_bvars(paths)
+    return check_flags(paths) + check_bvars(paths) + check_doc_files()
