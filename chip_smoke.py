@@ -224,7 +224,7 @@ def phase_fused_echo(S) -> str:
 def _stream_link(link, S) -> int:
     """Window-saturated byte stream through one link; the sink hashes
     what arrives in arrival order, so a reordered or corrupted chunk
-    changes the digest. Returns the steps the link dispatched."""
+    changes the digest. Returns the slots the link dispatched."""
     import numpy as np
 
     from incubator_brpc_tpu.transport.device_link import DeviceSocket
@@ -282,20 +282,20 @@ def phase_link(S) -> str:
             f"{echo['devices']}"
         )
     dev = jax.devices()[0]
-    steps = {}
+    slots = {}
     for ack_mode in ("local", "wire"):
         link = DeviceLink(
             [dev, dev], slot_words=S.link_slot_words, window=S.link_window,
             host_loopback=False, ack_mode=ack_mode,
         )
         assert link.geometry == "device-swap", link.geometry
-        steps[ack_mode] = _stream_link(link, S)
-        assert steps[ack_mode] * S.link_slot_words * 4 >= S.link_total
+        slots[ack_mode] = _stream_link(link, S)
+        assert slots[ack_mode] * S.link_slot_words * 4 >= S.link_total
     return (
         f"{chosen}; DeviceLink([dev, dev], host_loopback=False) device-swap: "
         f"{S.link_total} B in {S.link_chunk} B sends, slot_words="
         f"{S.link_slot_words} window={S.link_window}, in order, "
-        f"steps local={steps['local']} wire={steps['wire']}"
+        f"slots local={slots['local']} wire={slots['wire']}"
     )
 
 
@@ -398,7 +398,7 @@ def phase_link_ici(S) -> str:
         assert facts["geometry"] == "ppermute", facts
         assert facts["ack_mode"] == ack_mode, facts
         assert len(set(facts["devices"])) == 2, facts
-        seen.append(f"{ack_mode}: {facts['steps']} steps")
+        seen.append(f"{ack_mode}: {facts['slots']} slots")
     return (
         f"Channel(transport='tpu') {S.link_echo_bytes} B echo, ppermute "
         f"step between {facts['devices']}; {', '.join(seen)}"

@@ -64,6 +64,8 @@ from incubator_brpc_tpu.transport.device_link import (
     HANDSHAKE_METHOD,
     DeviceLink,
     DeviceSocket,
+    link_slots,
+    link_steps,
 )
 from incubator_brpc_tpu.utils.status import ErrorCode
 
@@ -211,18 +213,21 @@ class MultiControllerLink(DeviceLink):
 
     # -- the lockstep drive loop --------------------------------------------
 
-    def _make_local_slots(self, row: np.ndarray):
+    def _warm_step(self) -> None:
+        """Nothing to warm: an exchange here is a collective, dispatched
+        only once both hosts have agreed to it, and every agreed step has
+        the one shape (one slot a side), which the first of them compiles."""
+
+    def _make_local_slots(self, train: np.ndarray):
         import jax
 
-        shard = jax.device_put(row[None, :], self.devices[self.own_side])
+        shard = jax.device_put(train[None], self.devices[self.own_side])
         return jax.make_array_from_single_device_arrays(
-            (2, self._width), self._sharding, [shard]
+            (2,) + train.shape, self._sharding, [shard]
         )
 
     def _drive(self) -> None:
         import time as _time
-
-        from incubator_brpc_tpu.transport.device_link import link_steps
 
         stall_since: Optional[float] = None
         while True:
@@ -250,7 +255,10 @@ class MultiControllerLink(DeviceLink):
                         need = self._cq.load() + 1
                     else:
                         need = None
-                        row = self._fill_slot_locked(self.own_side)
+                        # one slot a step, whatever the backlog: both
+                        # hosts must dispatch the same shape, and the
+                        # agreed budget (target) counts steps
+                        row = self._fill_train_locked(self.own_side, 1)
                         # the step's timeline feeds the per-link
                         # recorders exactly like the base _drive: popped
                         # at in-order delivery
@@ -292,6 +300,7 @@ class MultiControllerLink(DeviceLink):
                 return
             step.t_launched = _time.monotonic_ns()
             link_steps << 1
+            link_slots << 1
             self._cq.watch(
                 out,
                 on_complete=lambda arrays, error, _seq=seq: self._on_step_done(

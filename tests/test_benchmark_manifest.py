@@ -78,6 +78,8 @@ LINK = {
     "device_link_3_inflight_at_dispatch": recorder(680, 2.5),
     "device_link_bytes": 40 * (1 << 20),
     "device_link_capacity_bytes": 2 * 680 * 65536,
+    "device_link_steps": 680,
+    "device_link_slots": 4 * 680,
 }
 HBM_DISPATCHED = (
     100.0 * 4 * (2 * 128 * 64 + roofline.FRAME_HEADER_WORDS * 128)
@@ -102,6 +104,7 @@ EXPECTED = {
     "link_dispatch_interval_us": (LINK, 2000.0),
     "link_window_used": (LINK, 2.5),
     "link_slot_fill_pct": (LINK, 100.0 * 40 * (1 << 20) / (2 * 680 * 65536)),
+    "link_slots_per_step": (LINK, 4.0),
 }
 # what the benchmark had before PR 25 reads no recorder this PR added
 OLDER = {

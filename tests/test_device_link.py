@@ -831,10 +831,12 @@ class TestStepStageRecorders:
         self._stream(link)
         m = _link_recorders(link)
         steps = m["rtt"].count()
-        assert steps >= 12  # 48 KiB through 4 KiB slots
+        # 48 KiB through 4 KiB slots: 12 slots, a step each on the host
+        # swap, trains of up to the window's 4 where a program is dispatched
+        assert steps >= (12 if geometry == "host-swap" else 3)
         for name in ("launch", "ready", "reorder_wait", "readback", "pump", "inflight"):
             assert m[name].count() == steps, name
-        assert m["flush"].count() == 2 * steps  # both sides, every step
+        assert m["flush"].count() == 2 * steps  # both sides' trains, every step
         parts = sum(
             m[name].latency_sum()
             for name in ("launch", "ready", "reorder_wait", "readback", "pump")
@@ -843,7 +845,7 @@ class TestStepStageRecorders:
         # one send, one drive: every step but the first has an interval
         assert m["dispatch_interval"].count() == steps - 1
         assert m["dispatch_interval"].latency_sum() > 0
-        # in flight at each dispatch, the new step included: 1..window
+        # slots in flight at each dispatch, the new train's included: 1..window
         assert steps <= m["inflight"].latency_sum() <= 4 * steps
         assert m["inflight"].max_latency() <= 4
 
@@ -901,3 +903,256 @@ class TestStepStageRecorders:
         }
         link.fail("retire")
         assert not list(expose_registry.snapshot(pfx))
+
+
+def _trains(slots, window):
+    """The train lengths one ``send()`` of ``slots`` slots' worth of bytes
+    leaves behind when the other side is idle: each the largest power of
+    two within the backlog and the free credit, and a train's credit comes
+    back whole, at its delivery."""
+    out, free = [], window
+    while slots:
+        if free == 0:
+            free = window  # the drive waited: everything out was delivered
+        k = 1 << (min(slots, free).bit_length() - 1)
+        out.append(k)
+        slots -= k
+        free -= k
+    return out
+
+
+class _FrameSink(_CountingSink):
+    """Counts like the sink above and re-cuts the byte stream into the
+    length-prefixed frames ``_framed_stream`` wrote."""
+
+    def frames(self):
+        data, out = b"".join(self.chunks), []
+        while data:
+            n = int.from_bytes(data[:4], "little")
+            out.append(data[4 : 4 + n])
+            data = data[4 + n :]
+        return out
+
+
+def _framed_stream(seed, nbytes):
+    """Seeded frames of uneven sizes, length-prefixed, ``nbytes`` in all."""
+    import numpy as np
+
+    rng = np.random.default_rng(seed)
+    frames, left = [], nbytes
+    while left:
+        n = min(left - 4, int(rng.integers(1, 1500))) if left > 4 else 0
+        if left - 4 - n < 4:  # no room for another prefix: take the rest
+            n = left - 4
+        frames.append(rng.bytes(n))
+        left -= 4 + n
+    stream = b"".join(len(f).to_bytes(4, "little") + f for f in frames)
+    assert len(stream) == nbytes
+    return frames, stream
+
+
+class TestSlotTrains:
+    """One exchange program carries a train of slots a side: as many as
+    the backlog fills and the credit admits, a power of two."""
+
+    SLOT_WORDS = 256  # 1 KiB slots
+
+    def _make_link(self, geometry="ppermute", **kw):
+        import jax
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        devs = jax.devices()
+        if geometry == "ppermute":
+            if len(devs) < 2:
+                pytest.skip("needs two devices")
+            link = dl.DeviceLink(devs[:2], slot_words=self.SLOT_WORDS, **kw)
+        else:
+            link = dl.DeviceLink(
+                [devs[0], devs[0]], slot_words=self.SLOT_WORDS,
+                host_loopback=False, **kw,
+            )
+        assert link.geometry == geometry
+        sinks = (_FrameSink(), _FrameSink())
+        socks = [
+            dl.DeviceSocket(link, side=i, messenger=sinks[i]) for i in (0, 1)
+        ]
+        return link, socks, sinks
+
+    @staticmethod
+    def _queue_then_drive(link, queue):
+        """Run ``queue()`` (sends, closes) with the drive held off, then
+        start it: the backlog it meets is everything queued."""
+        with link._lock:
+            assert not link._driving
+            link._driving = True
+        queue()
+        with link._lock:
+            link._driving = False
+        link._kick()
+
+    @pytest.mark.parametrize(
+        "slots,window",
+        [(1, 8), (2, 8), (3, 8), (4, 8), (8, 8), (17, 8), (22, 8), (8, 4), (5, 1)],
+    )
+    def test_backlog_and_credit_set_the_train(self, slots, window):
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link(window=window)
+        dl._quiesce_links(timeout=5.0)  # earlier tests' links are idle
+        before = (dl.link_steps.get_value(), dl.link_slots.get_value())
+        # the last slot part full: frames cross slot and train boundaries
+        frames, stream = _framed_stream(slots, slots * 1024 - 100)
+        assert link.send(0, stream, timeout=60) == 0
+        assert _wait(lambda: sinks[1].nbytes == len(stream), timeout=60)
+        assert _wait(lambda: link.inflight_steps == 0)
+        assert sinks[1].frames() == frames
+        expect = _trains(slots, window)
+        assert dl.link_steps.get_value() - before[0] == len(expect)
+        assert dl.link_slots.get_value() - before[1] == slots == link._seq
+        m = _link_recorders(link)
+        assert m["rtt"].count() == len(expect)
+        # slots in flight at each dispatch, the new train's included
+        assert sum(expect) <= m["inflight"].latency_sum() <= window * len(expect)
+        assert m["inflight"].max_latency() <= window
+
+    def test_one_mebibyte_echo_takes_a_fraction_of_the_steps(self, echo_server):
+        # 1 MiB + header is 17 slots of the default 64 KiB each way: 34
+        # steps a call at one slot a step, trains of 8, 8, 1 now
+        ch = _tpu_channel(echo_server)
+        assert ch.call_method("EchoService", "Echo", b"warm").ok()
+        link = ch._device_sock.link
+        assert (link.slot_words, link.window) == (16384, 8)
+        assert _wait(lambda: link.inflight_steps == 0)
+        steps, slots = _link_recorders(link)["rtt"].count(), link._seq
+        big = bytes(range(256)) * 4096
+        cntl = ch.call_method("EchoService", "Echo", b"", attachment=big)
+        assert cntl.ok(), cntl.error_text
+        assert cntl.response_attachment == big
+        assert _wait(lambda: link.inflight_steps == 0)
+        assert link._seq - slots == 34
+        assert _link_recorders(link)["rtt"].count() - steps <= 12
+
+    @pytest.mark.parametrize("ack_mode", ["local", "wire"])
+    def test_window_bounds_slots_in_flight_both_ways(self, ack_mode):
+        link, socks, sinks = self._make_link(window=4, ack_mode=ack_mode)
+        peak = []
+        take = link._take_seq_locked
+
+        def spy(k=1):
+            out = take(k)
+            peak.append((k, link._inflight, link._seq - link._peer_ack))
+            return out
+
+        link._take_seq_locked = spy
+        a, b = _framed_stream(1, 40_000), _framed_stream(2, 30_000)
+        self._queue_then_drive(
+            link,
+            lambda: (link.send(0, a[1], timeout=60), link.send(1, b[1], timeout=60)),
+        )
+        assert _wait(lambda: sinks[1].nbytes == 40_000, timeout=60)
+        assert _wait(lambda: sinks[0].nbytes == 30_000, timeout=60)
+        assert sinks[1].frames() == a[0] and sinks[0].frames() == b[0]
+        assert max(k for k, _, _ in peak) == 4  # trains ran at the window
+        if ack_mode == "local":
+            assert max(inflight for _, inflight, _ in peak) <= 4
+        else:
+            # one over for the catch-up step that carries the acks
+            assert max(ahead for _, _, ahead in peak) <= 4 + 1
+            assert link._seq - link._peer_ack <= 4 + 1
+
+    def test_close_rides_the_slot_that_ends_its_stream(self):
+        from incubator_brpc_tpu.transport.sock import CONNECTED
+
+        link, socks, sinks = self._make_link(window=8)
+        a, b = _framed_stream(3, 1500), _framed_stream(4, 4 * 1024)
+
+        def queue():
+            assert link.send(0, a[1]) == 0  # two slots, then the close
+            link.close(0)
+            assert link.send(1, b[1]) == 0  # five slots: a train of four
+        self._queue_then_drive(link, queue)
+        assert _wait(lambda: socks[1].state != CONNECTED, timeout=30)
+        assert socks[1].error_code == ErrorCode.ECLOSE
+        assert sinks[1].frames() == a[0]  # every byte before the close
+        assert _wait(lambda: sinks[0].nbytes == len(b[1]), timeout=30)
+        assert sinks[0].frames() == b[0]
+        # the close came back: both ends down, nothing in flight or driving
+        assert _wait(lambda: socks[0].state != CONNECTED, timeout=30)
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        assert socks[0].write(b"late") == ErrorCode.EFAILEDSOCKET
+
+    def test_dispatch_failure_mid_train_leaves_no_sender_parked(self):
+        link, socks, sinks = self._make_link(window=8)
+        step, calls = link._step, []
+
+        def failing(slots):
+            calls.append(slots.shape[1])
+            if len(calls) == 2:
+                raise RuntimeError("injected device fault")
+            return step(slots)
+
+        link._step = failing
+        rcs = []
+
+        def sender():
+            # 40 slots' worth in sends of 10: past the window's byte budget
+            # after the first, so later ones park until credit or failure
+            for _ in range(4):
+                rcs.append(link.send(0, b"z" * (10 * 1024), timeout=30))
+
+        t = threading.Thread(target=sender)
+        t.start()
+        t.join(timeout=20)
+        assert not t.is_alive()
+        assert _wait(lambda: link._closed, timeout=10)
+        assert calls[0] == 8 and len(calls) == 2  # the second train failed
+        assert ErrorCode.EFAILEDSOCKET in rcs
+        # CONNECTED == 0: fail() took both sockets down with the link
+        assert _wait(lambda: all(s.state != 0 for s in socks))
+        assert _wait(lambda: not link._driving)
+
+    @pytest.mark.parametrize("geometry", ["ppermute", "device-swap"])
+    def test_no_train_length_compiles_after_the_handshake(self, geometry):
+        import jax
+
+        compiles = []
+
+        def listener(name, *_a, **_k):
+            if name == "/jax/core/compile/backend_compile_duration":
+                compiles.append(name)
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        try:
+            link, socks, sinks = self._make_link(geometry, window=8)
+            built = len(compiles)
+            assert built >= 4  # one program a train length: 1, 2, 4, 8
+            seen = set()
+            take = link._take_seq_locked
+            link._take_seq_locked = lambda k=1: (seen.add(k), take(k))[1]
+            total = 0
+            for slots in (1, 2, 4, 8, 15):
+                total += slots * 1024
+                assert link.send(0, b"c" * (slots * 1024), timeout=60) == 0
+                assert _wait(lambda: sinks[1].nbytes == total, timeout=60)
+            assert seen == {1, 2, 4, 8}
+            assert len(compiles) == built
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listener)
+
+    def test_host_swap_keeps_one_slot_a_step(self):
+        import jax
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        dev = jax.devices()[0]
+        link = dl.DeviceLink([dev, dev], slot_words=self.SLOT_WORDS, window=8)
+        assert link.geometry == "host-swap"
+        sink = _FrameSink()
+        dl.DeviceSocket(link, side=0, messenger=_FrameSink())
+        dl.DeviceSocket(link, side=1, messenger=sink)
+        frames, stream = _framed_stream(7, 8 * 1024)
+        assert link.send(0, stream) == 0
+        assert _wait(lambda: sink.nbytes == len(stream))
+        assert sink.frames() == frames
+        assert _link_recorders(link)["rtt"].count() == link._seq == 8
