@@ -53,6 +53,11 @@ REAL = SimpleNamespace(
     link_window=8,
     link_chunk=1 << 20,
     link_total=64 << 20,
+    # the streaming deployment's transfer (benchmark cell link_stream_ici)
+    stream_total=32 << 20,
+    stream_message=1 << 20,
+    stream_window=2 << 20,
+    stream_slot_words=16384,
     quant_floats=(1 << 20) // 4,  # one MAX_WIDTH session row
     fabricnet=dict(
         d_model=2048, d_ff=8192, d_expert=2048, experts_per_rank=2,
@@ -85,6 +90,10 @@ REHEARSAL = SimpleNamespace(
     link_window=4,
     link_chunk=4 << 10,
     link_total=256 << 10,
+    stream_total=64 << 10,
+    stream_message=4 << 10,
+    stream_window=8 << 10,
+    stream_slot_words=1024,
     quant_floats=1024,
     fabricnet=dict(
         d_model=32, d_ff=64, d_expert=32, experts_per_rank=2,
@@ -405,6 +414,29 @@ def phase_link_ici(S) -> str:
     )
 
 
+def phase_stream_ici(S) -> str:
+    """StreamingRPC over the four-chip link: the streaming deployment's
+    transfer (benchmark cell link_stream_ici) through stream_create and
+    stream_accept, bytes, boundaries and the window compared in the leg."""
+    import __graft_entry__ as ge
+    import numpy as np
+
+    data = np.random.default_rng(7).bytes(S.stream_total)
+    facts = ge.stream_leg(
+        data, S.stream_message, max_buf_size=S.stream_window,
+        link_slot_words=S.stream_slot_words,
+    )
+    assert facts["geometry"] == "ppermute", facts
+    assert len(set(facts["devices"])) == 2, facts
+    return (
+        f"{S.stream_total} B in {facts['messages']} messages of "
+        f"{S.stream_message} B under a {S.stream_window} B window over "
+        f"ppermute {facts['devices']}: bytes, boundaries and order kept, at "
+        f"most {facts['ahead']} B ahead, {facts['slots']} slots, "
+        f"{S.stream_total / facts['seconds'] / 1e9:.4f} GB/s"
+    )
+
+
 def phase_combo(S) -> str:
     import __graft_entry__ as ge
 
@@ -581,6 +613,7 @@ ONE_CHIP = [
 ]
 FOUR_CHIPS = [
     ("link_ici", phase_link_ici),
+    ("stream_ici", phase_stream_ici),
     ("combo_collective", phase_combo),
     ("fabricnet_4dev", phase_fabricnet4),
     ("ring_attention", phase_ring_attention),

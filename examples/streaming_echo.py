@@ -1,10 +1,22 @@
 #!/usr/bin/env python
 """streaming_echo — bidirectional stream with credit-window flow control
 (reference example/streaming_echo_c++): the client opens a stream on an
-RPC, pushes messages, the server echoes them back on its half.
-Run: python examples/streaming_echo.py
+RPC, pushes messages, the server echoes them back on its half. Then the
+sink form (upstream's server.cpp consumes and writes nothing back): one
+transfer of --total_bytes in --message_bytes messages to a sink that
+acknowledges the whole with one receipt, GB/s printed, as
+link_performance.py does for its configuration. This is the shape of the
+benchmark's link_stream_sink_ici deployment.
+
+Run (self-contained: starts its own servers):
+    python examples/streaming_echo.py                     # host sockets
+    python examples/streaming_echo.py --transport tpu     # device link
+    python examples/streaming_echo.py --transport tpu \
+        --message_bytes 1048576 --total_bytes 33554432    # the cell's sizes
 """
 
+import argparse
+import os
 import sys
 import threading
 import time
@@ -21,7 +33,32 @@ from incubator_brpc_tpu.rpc import (  # noqa: E402
 )
 
 
-def main() -> None:
+def sink_transfer(args) -> None:
+    import __graft_entry__ as ge
+
+    facts = ge.stream_leg(
+        os.urandom(args.total_bytes), args.message_bytes,
+        transport=args.transport,
+    )
+    over = f" over {facts['geometry']} {facts['devices']}" if "geometry" in facts else ""
+    print(
+        f"[sink] transport={args.transport}{over}: {args.total_bytes} B in "
+        f"{facts['messages']} messages of {args.message_bytes} B, bytes and "
+        f"boundaries kept, at most {facts['ahead']} B ahead of the sink, "
+        f"{args.total_bytes / facts['seconds'] / 1e9:.4f} GB/s"
+    )
+
+
+def main(argv=None) -> None:
+    p = argparse.ArgumentParser(description=__doc__)
+    p.add_argument("--transport", choices=("tpu", "tcp"), default="tcp",
+                   help="the sink transfer's transport: device link or host sockets")
+    p.add_argument("--message_bytes", type=int, default=64 << 10,
+                   help="bytes a Stream.write of the sink transfer")
+    p.add_argument("--total_bytes", type=int, default=4 << 20,
+                   help="bytes the sink transfer moves")
+    args = p.parse_args(argv)
+
     server = Server()
     server_streams = {}
 
@@ -63,6 +100,7 @@ def main() -> None:
     print("[client] received:", got)
     s.close()
     server.stop()
+    sink_transfer(args)
 
 
 if __name__ == "__main__":

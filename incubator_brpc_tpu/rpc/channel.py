@@ -1324,6 +1324,11 @@ class Channel:
             ):
                 # handshake complete: the server's stream id arrived
                 cntl._request_stream._connect(sock, frame.meta.stream_id)
+                if cntl._span is not None:
+                    cntl._span.annotate(
+                        f"stream {cntl._request_stream.id} connected to "
+                        f"remote stream {frame.meta.stream_id}"
+                    )
         self._end_rpc(cntl)
 
     def _end_rpc(self, cntl: Controller) -> None:

@@ -18,6 +18,11 @@ Kept design points (and where they live in the reference):
   ``on_closed``, and the registry entry dies (versioned ids are not needed:
   ids are never reused).
 
+Always-on recorders (docs/OBSERVABILITY.md, "Streams"): a stream counts
+under ``device_link_stream_*`` once it rides a ``DeviceSocket`` and under
+``stream_*`` on a host socket. The hot paths stamp and append one row;
+bvar's 1 Hz sampler feeds the recorders (``RecorderFeed``).
+
 Deviation: the reference routes writes through a fake Socket so the
 wait-free write queue is shared (STREAM_FAKE_FD, socket.h:193); here stream
 frames are packed directly onto the real Socket's MPSC write queue — same
@@ -29,9 +34,12 @@ from __future__ import annotations
 import itertools
 import logging
 import threading
+import time
+from collections import deque
 from typing import Callable, Dict, List, Optional
 
 from incubator_brpc_tpu import protocol as proto_pkg
+from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
 from incubator_brpc_tpu.protocol.tbus_std import (
     FLAG_STREAM,
     Meta,
@@ -56,6 +64,43 @@ IDLE = 0
 CONNECTING = 1
 CONNECTED = 2
 CLOSED = 3
+
+
+class _StreamVars:
+    """One namespace of stream recorders and adders. Rows wait in the
+    feeds for the sampler thread; ``flush`` feeds them now (tests)."""
+
+    def __init__(self, prefix: str):
+        def recorder(what: str) -> LatencyRecorder:
+            return LatencyRecorder(name=f"{prefix}_{what}")
+
+        def adder(what: str) -> Adder:
+            return Adder(name=f"{prefix}_{what}")
+
+        # writer: an admitted write's time parked on the window (0 when
+        # admitted at once) and the bytes it found unconsumed ahead of it
+        self.writes = RecorderFeed((
+            (recorder("write_wait_us"), 1e-3), (recorder("unconsumed_at_write"), 1),
+        ))
+        # a write admitted -> the feedback frame covering its last byte applied
+        self.feedbacks = RecorderFeed(((recorder("feedback_lag_us"), 1e-3),))
+        # reader: _on_frame -> the handler entered for that message
+        self.delivers = RecorderFeed(((recorder("deliver_us"), 1e-3),))
+        # time inside on_received_messages, a batch
+        self.consumes = RecorderFeed(((recorder("consume_us"), 1e-3),))
+        self.messages = adder("messages")  # handed to a handler
+        self.batches = adder("batches")  # on_received_messages calls
+        self.bytes = adder("bytes")  # of those messages
+        self.feedback_frames = adder("feedback_frames")  # sent
+        self.write_retries = adder("write_retries")  # EAGAIN/EOVERCROWDED returned
+
+    def flush(self) -> None:
+        for feed in (self.writes, self.feedbacks, self.delivers, self.consumes):
+            feed.flush()
+
+
+HOST_VARS = _StreamVars("stream")
+LINK_VARS = _StreamVars("device_link_stream")
 
 
 class StreamOptions:
@@ -111,6 +156,10 @@ class Stream:
         self._produced = 0  # bytes written to the wire
         self._remote_consumed = 0  # last feedback
         self._wbutex = Butex(0)
+        # admitted writes no feedback covers yet: [end offset, admitted ns];
+        # bounded, so a peer that never feeds back costs samples, not memory
+        self._unacked: deque = deque(maxlen=1024)
+        self._vars = HOST_VARS  # LINK_VARS once connected over a device link
         # reader side
         self._consumed = 0  # bytes this side has handled
         self._last_feedback = 0  # _consumed value last told to the peer
@@ -129,6 +178,8 @@ class Stream:
             self._sock = sock
             self.remote_id = remote_id
             self.state = CONNECTED
+            if hasattr(sock, "link"):  # a DeviceSocket
+                self._vars = LINK_VARS
         sock.on_failed.append(self._on_socket_failed)
         self._connected_event.set()
 
@@ -144,11 +195,11 @@ class Stream:
         ``timeout`` expired (timeout=0 → immediate EAGAIN, None → block
         forever); EOVERCROWDED if the socket backlog refused the frame
         (transient — retry); EINVAL once closed/failed."""
-        import time as _time
-
         n = len(data)
         limit = self.options.max_buf_size
-        deadline = None if timeout is None else _time.monotonic() + timeout
+        deadline = None if timeout is None else time.monotonic() + timeout
+        t_enter = time.monotonic_ns()
+        parked = False
         while True:
             with self._lock:
                 if self.state != CONNECTED:
@@ -157,16 +208,22 @@ class Stream:
                 # in-flight message may overshoot the window, so a message
                 # larger than max_buf_size still goes out on an idle stream
                 # (AppendIfNotFull stream.cpp:263 checks the same way).
-                if not limit or (self._produced - self._remote_consumed) < limit:
+                ahead = self._produced - self._remote_consumed
+                if not limit or ahead < limit:
                     self._produced += n
                     sock, rid = self._sock, self.remote_id
+                    admitted = [self._produced, time.monotonic_ns()]
+                    self._unacked.append(admitted)
                     break
+            parked = True
             if timeout == 0:
+                self._vars.write_retries << 1
                 return ErrorCode.EAGAIN
             remaining = None
             if deadline is not None:
-                remaining = deadline - _time.monotonic()
+                remaining = deadline - time.monotonic()
                 if remaining <= 0:
+                    self._vars.write_retries << 1
                     return ErrorCode.EAGAIN
             seq = self._wbutex.load()
             with self._lock:
@@ -176,7 +233,11 @@ class Stream:
                     and (self._produced - self._remote_consumed) >= limit
                 )
             if blocked and self._wbutex.wait(seq, timeout=remaining) == ETIMEDOUT:
+                self._vars.write_retries << 1
                 return ErrorCode.EAGAIN
+        self._vars.writes.rows.append(
+            (admitted[1] - t_enter if parked else 0, ahead)
+        )
         meta = Meta(stream_id=rid, extra={"ft": FT_DATA, "from": self.id})
         # IOBuf pack: no body/frame concat copies on the data hot path.
         # drain_inline: this thread is blocking-capable (it just passed the
@@ -187,7 +248,7 @@ class Stream:
         # back to the KeepWrite fiber — the frame is still sent.
         drain_budget = None
         if deadline is not None:
-            drain_budget = max(0.0, deadline - _time.monotonic())
+            drain_budget = max(0.0, deadline - time.monotonic())
         rc = sock.write(
             pack_frame_iobuf(meta, data, 0, flags=FLAG_STREAM),
             timeout=drain_budget,
@@ -199,8 +260,10 @@ class Stream:
             # writer parked on it must be woken (no feedback will do it)
             with self._lock:
                 self._produced -= n
+                self._forget_write_locked(admitted, n)
             self._wbutex.add(1)
             self._wbutex.wake_all()
+            self._vars.write_retries << 1
             return rc
         if rc != 0:
             self._fail(rc, "stream data write failed")
@@ -214,8 +277,25 @@ class Stream:
             if consumed <= self._remote_consumed:
                 return
             self._remote_consumed = consumed
+            now = time.monotonic_ns()
+            unacked, lags = self._unacked, self._vars.feedbacks.rows
+            while unacked and unacked[0][0] <= consumed:
+                lags.append((now - unacked.popleft()[1],))
         self._wbutex.add(1)
         self._wbutex.wake_all()
+
+    def _forget_write_locked(self, admitted: list, n: int) -> None:
+        """A rolled-back write leaves the feedback-lag ledger: later
+        writes' end offsets fall by its ``n`` bytes, as ``_produced`` did."""
+        kept, after = [], False
+        for entry in self._unacked:
+            if entry is admitted:
+                after = True
+                continue
+            if after:
+                entry[0] -= n
+            kept.append(entry)
+        self._unacked = deque(kept, maxlen=self._unacked.maxlen)
 
     # -- reader side --------------------------------------------------------
 
@@ -227,16 +307,19 @@ class Stream:
         # the native parse path leaves stream payloads as zero-copy IOBuf
         # cuts; the consumer materializes only when the handler wants bytes
         data = frame.payload_iobuf
-        self._rq.execute((ft, frame.payload if data is None else data))
+        self._rq.execute(
+            (ft, frame.payload if data is None else data, time.monotonic_ns())
+        )
 
     def _consume(self, it: TaskIterator) -> None:
         """Ordered consumer fiber (stream.cpp:86): batch data messages to the
         handler, then feed consumption back to the writer."""
         handler = self.options.handler
         batch: List[bytes] = []
+        arrived: List[int] = []  # _on_frame's stamp of each message
         closed = False
         raw = self.options.raw_messages
-        for ft, payload in it:
+        for ft, payload, t_frame in it:
             if ft == FT_DATA:
                 if not raw and not isinstance(payload, (bytes, bytearray)):
                     payload = payload.to_bytes()  # IOBuf -> bytes contract
@@ -250,15 +333,24 @@ class Stream:
                     wrapped.append(bytes(payload))
                     payload = wrapped
                 batch.append(payload)
+                arrived.append(t_frame)
             elif ft in (FT_CLOSE, FT_RST):
                 closed = True
         if batch:
-            self._consumed += sum(len(m) for m in batch)
+            nbytes = sum(len(m) for m in batch)
+            self._consumed += nbytes
+            v = self._vars
+            t_in = time.monotonic_ns()
             if handler is not None:
                 try:
                     handler.on_received_messages(self, batch)
                 except Exception:
                     logger.exception("stream %d handler raised", self.id)
+            v.consumes.rows.append((time.monotonic_ns() - t_in,))
+            v.delivers.rows.extend((t_in - t,) for t in arrived)
+            v.messages << len(batch)
+            v.batches << 1
+            v.bytes << nbytes
             self._send_feedback()
         if closed or it.is_queue_stopped():
             self._finish_close(notify=closed)
@@ -271,6 +363,7 @@ class Stream:
             sock, rid, consumed = self._sock, self.remote_id, self._consumed
         meta = Meta(stream_id=rid, extra={"ft": FT_FEEDBACK, "consumed": consumed})
         sock.write(pack_frame(meta, b"", 0, flags=FLAG_STREAM))
+        self._vars.feedback_frames << 1
 
     # -- close / failure ----------------------------------------------------
 
@@ -428,6 +521,9 @@ def stream_accept(cntl, options: Optional[StreamOptions] = None) -> Optional[Str
         _streams[s.id] = s
     s._connect(sock, remote_id)
     cntl._accepted_stream_id = s.id  # echoed in the response meta
+    span = getattr(cntl, "_span", None)
+    if span is not None:  # /rpcz: the call that carried the handshake
+        span.annotate(f"stream {s.id} accepted for remote stream {remote_id}")
     return s
 
 

@@ -132,12 +132,19 @@ class _Step:
     ``time.monotonic_ns()`` stamps each written once by the thread that
     does the work."""
 
-    __slots__ = ("t_dispatch", "interval_ns", "inflight", "t_launched", "watcher")
+    __slots__ = (
+        "t_dispatch", "interval_ns", "inflight", "seen", "t_launched", "watcher",
+    )
 
-    def __init__(self, t_dispatch: int, interval_ns: int, inflight: int):
+    def __init__(
+        self, t_dispatch: int, interval_ns: int, inflight: int,
+        seen: tuple = (None, None),
+    ):
         self.t_dispatch = t_dispatch  # slots filled, seq taken
         self.interval_ns = interval_ns  # since the drive's previous dispatch
         self.inflight = inflight  # undrained slots, this train's included
+        # (backlog slots, free credit) the train's length was taken from
+        self.seen = seen
         self.t_launched = 0  # _make_slots and the step call returned
         # DeviceCompletionButex.watch fills these: a watcher thread took
         # the job, block_until_ready returned
@@ -235,6 +242,9 @@ class DeviceLink:
             name=f"{pfx}_dispatch_interval_us"
         )
         self._m_inflight = LatencyRecorder(name=f"{pfx}_inflight_at_dispatch")
+        self._m_backlog = LatencyRecorder(name=f"{pfx}_backlog_slots_at_dispatch")
+        self._m_credit = LatencyRecorder(name=f"{pfx}_credit_at_dispatch")
+        self._m_send_wait = LatencyRecorder(name=f"{pfx}_send_wait_us")
         self._m_out_rate = PerSecond(self._m_out_bytes, name=f"{pfx}_out_bytes_second")
         self._m_in_rate = PerSecond(self._m_in_bytes, name=f"{pfx}_in_bytes_second")
         # a delivered step's numbers (ns, but for the in-flight count) wait
@@ -245,7 +255,10 @@ class DeviceLink:
             (self._m_reorder_wait, 1e-3), (self._m_readback, 1e-3),
             (self._m_pump, 1e-3), (self._m_rtt, 1e-3),
             (self._m_dispatch_interval, 1e-3), (self._m_inflight, 1),
+            (self._m_backlog, 1), (self._m_credit, 1),
         ))
+        # one row a send(): ns parked over the backlog budget
+        self._send_feed = RecorderFeed(((self._m_send_wait, 1e-3),))
         self._metrics_retired = False
         self._steps: Dict[int, _Step] = {}  # first seq -> timeline, until delivered
         self._last_dispatch_ns = 0  # this drive's previous dispatch; 0 = none
@@ -260,10 +273,12 @@ class DeviceLink:
             return
         self._metrics_retired = True
         self._step_feed.flush()  # profile() still reads the recorders
+        self._send_feed.flush()
         for v in (
             self._m_rtt, self._m_flush, self._m_launch, self._m_ready,
             self._m_reorder_wait, self._m_readback, self._m_pump,
             self._m_dispatch_interval, self._m_inflight,
+            self._m_backlog, self._m_credit, self._m_send_wait,
             self._m_out_rate, self._m_in_rate,
         ):
             try:
@@ -397,6 +412,7 @@ class DeviceLink:
             return 0
         budget = self.window * self._slot_bytes
         deadline = None
+        t_parked = 0  # monotonic_ns of the first park; 0 = admitted at once
         while True:
             with self._lock:
                 if self._closed or self._send_blocked:
@@ -410,15 +426,18 @@ class DeviceLink:
                     break
                 seq = self._wbutex.load()
             # window stall: park until a step drains (credit released)
-            import time as _time
-
             if deadline is None:
-                deadline = _time.monotonic() + (timeout if timeout else 10.0)
-            remaining = deadline - _time.monotonic()
+                t_parked = time.monotonic_ns()
+                deadline = time.monotonic() + (timeout if timeout else 10.0)
+            remaining = deadline - time.monotonic()
             if remaining <= 0:
                 link_overcrowded << 1
+                self._send_feed.rows.append((time.monotonic_ns() - t_parked,))
                 return ErrorCode.EOVERCROWDED
             self._wbutex.wait(seq, timeout=remaining)
+        self._send_feed.rows.append(
+            (time.monotonic_ns() - t_parked if t_parked else 0,)
+        )
         self._kick()
         return 0
 
@@ -457,29 +476,34 @@ class DeviceLink:
             return self.window - (self._seq - self._peer_ack)
         return self.window - self._inflight
 
-    def _train_len_locked(self) -> int:
+    def _train_len_locked(self) -> tuple:
         """Slots a side the next step carries, from what the link observes
         under its lock: the largest power of two (one compiled program a
         length, ``_warm_step``) within both the slots the fuller side's
         backlog fills and the free credit. One where a step goes out with
         no data or no credit (close-only, wire-mode catch-up), and on the
-        host swap, which dispatches no program a train could save."""
-        if self._step is None:
-            return 1
+        host swap, which dispatches no program a train could save. Returns
+        the length and the ``(backlog slots, free credit)`` it was taken
+        from, for the train's timeline."""
         backlog = -(-max(self._out_nbytes) // self._slot_bytes)
-        k = max(1, min(backlog, self._credit_locked()))
-        return 1 << (k.bit_length() - 1)
+        credit = self._credit_locked()
+        if self._step is None:
+            return 1, (backlog, credit)
+        k = max(1, min(backlog, credit))
+        return 1 << (k.bit_length() - 1), (backlog, credit)
 
-    def _take_seq_locked(self, k: int = 1) -> tuple:
+    def _take_seq_locked(self, k: int = 1, seen: tuple = (None, None)) -> tuple:
         """Under the link lock, a train of ``k`` slots a side filled: take
-        its seqs, count its slots in flight and start its timeline."""
+        its seqs, count its slots in flight and start its timeline. ``seen``
+        is what ``_train_len_locked`` took ``k`` from (a link that never
+        asks it, ``MultiControllerLink``, records neither)."""
         seq = self._seq
         self._seq += k
         self._inflight += k
         now = time.monotonic_ns()
         last, self._last_dispatch_ns = self._last_dispatch_ns, now
         step = self._steps[seq] = _Step(
-            now, now - last if last else 0, self._inflight
+            now, now - last if last else 0, self._inflight, seen
         )
         return seq, step
 
@@ -521,9 +545,9 @@ class DeviceLink:
                         # window has no later completion to wake for
                         need = self._wbutex.load()
                 if need is None:
-                    k = self._train_len_locked()
+                    k, seen = self._train_len_locked()
                     rows = [self._fill_train_locked(s, k) for s in (0, 1)]
-                    seq, step = self._take_seq_locked(k)
+                    seq, step = self._take_seq_locked(k, seen)
             if need is not None:
                 self._wbutex.wait(need, timeout=1.0)
                 continue
@@ -693,6 +717,7 @@ class DeviceLink:
             t_end - step.t_dispatch,
             step.interval_ns or None,
             step.inflight,
+            *step.seen,
         ))
 
     def _rows_to_host(self, arrays) -> List[Optional[np.ndarray]]:

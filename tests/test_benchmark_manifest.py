@@ -34,19 +34,29 @@ def recorder(count, mean_us):
     return {"count": count, "sum": count * mean_us}
 
 
+# the one configuration that has a stream and link geometry to read
+STREAM_CONFIG = {
+    "stream": {"max_buf_size": 2097152},
+    "channel_options": {"link_slot_words": 16384},
+}
+
+
 def hand_made_run(counters: dict):
     """What ``run.py`` hands a reader, by hand: 100 handler spans of 1 ms,
-    one device with 50 step executions of 2 us inside the window."""
+    one device with 50 step executions of 2 us inside the window and no
+    operation of its own."""
     t_in = T_OPEN + np.arange(100, dtype=np.int64) * 10_000_000
     start = T_OPEN + np.arange(50, dtype=np.int64) * 1_000_000
     steps = xplane.Events(["jit_step"] * 50, start, start + 2_000)
     return types.SimpleNamespace(
         counters=counters,
         handler=np.stack([t_in, t_in + 1_000_000], axis=1),
-        devices={"/device:TPU:0": {"steps": steps}},
+        devices={"/device:TPU:0": {
+            "steps": steps, "ops": xplane.Events([], [], [])}},
         t_open=T_OPEN, t_close=T_CLOSE, window_s=20.0,
-        peaks={"hbm_bytes_per_s": 819e9},
+        peaks={"hbm_bytes_per_s": 819e9, "ici_bits_per_s_per_chip": 1600e9},
         traffic={"sizes": [256]}, done=np.zeros((100, 6)),
+        cell=types.SimpleNamespace(config=STREAM_CONFIG),
     )
 
 
@@ -76,10 +86,25 @@ LINK = {
     "device_link_3_pump_us": recorder(680, 500.0),
     "device_link_3_dispatch_interval_us": recorder(640, 2000.0),
     "device_link_3_inflight_at_dispatch": recorder(680, 2.5),
+    "device_link_2_send_wait_us": recorder(5, 9e9),
+    "device_link_3_send_wait_us": recorder(900, 350.0),
+    "device_link_3_backlog_slots_at_dispatch": recorder(680, 10.5),
     "device_link_bytes": 40 * (1 << 20),
     "device_link_capacity_bytes": 2 * 680 * 65536,
     "device_link_steps": 680,
     "device_link_slots": 4 * 680,
+}
+# a stream's counters over a window: 640 one-message batches
+STREAM = {
+    "device_link_stream_write_wait_us": recorder(640, 7000.0),
+    "device_link_stream_unconsumed_at_write": recorder(640, 1048576.0),
+    "device_link_stream_feedback_lag_us": recorder(640, 21000.0),
+    "device_link_stream_deliver_us": recorder(640, 450.0),
+    "device_link_stream_messages": 640,
+    "device_link_stream_batches": 320,
+    # a host stream's numbers must not be read for the link's
+    "stream_write_wait_us": recorder(7, 9e9),
+    "stream_messages": 9_000_000,
 }
 HBM_DISPATCHED = (
     100.0 * 4 * (2 * 128 * 64 + roofline.FRAME_HEADER_WORDS * 128)
@@ -105,7 +130,16 @@ EXPECTED = {
     "link_window_used": (LINK, 2.5),
     "link_slot_fill_pct": (LINK, 100.0 * 40 * (1 << 20) / (2 * 680 * 65536)),
     "link_slots_per_step": (LINK, 4.0),
+    "link_send_wait_us": (LINK, 350.0),
+    "link_backlog_slots": (LINK, 10.5),
+    "stream_write_wait_us": (STREAM, 7000.0),
+    "stream_feedback_lag_us": (STREAM, 21000.0),
+    "stream_deliver_us": (STREAM, 450.0),
+    "stream_window_used_pct": (STREAM, 50.0),
+    "stream_messages_per_batch": (STREAM, 2.0),
 }
+# PR 31's device_trace reader: not a counter's mean, so outside EXPECTED
+TRACE_READERS = {"link_step_ici_pct"}
 # what the benchmark had before PR 25 reads no recorder this PR added
 OLDER = {
     "host_plane_us", "device_path_us", "calls_per_dispatch",
@@ -132,7 +166,7 @@ def test_cell_resolves_its_files_and_readers(name):
 def test_every_metric_is_accounted_for():
     names = [m["name"] for m in BENCH["per_layer"]]
     assert len(names) == len(set(names))
-    assert OLDER | set(EXPECTED) <= set(names)  # a later PR may add more
+    assert OLDER | set(EXPECTED) | TRACE_READERS <= set(names)  # a later PR may add more
     with open(os.path.join(ROOT, "PERF.md"), encoding="utf-8") as f:
         perf = f.read()
     for m in BENCH["per_layer"]:
@@ -146,9 +180,13 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
     """At least these cells: a later cell of an echo configuration joins
     the lists of the metrics its deployment feeds (PR 27's did)."""
     cells = {m["name"]: m.get("workloads") for m in BENCH["per_layer"]}
-    for name in EXPECTED:
+    for name in sorted(set(EXPECTED) | TRACE_READERS):
         if name.startswith("link_"):
-            assert cells[name] == ["link_echo_ici_1m"], name
+            # every link cell drives the link: PR 31's joined them
+            assert {"link_echo_ici_1m", "link_stream_ici"} <= set(cells[name]), name
+        elif name.startswith("stream_"):
+            # only the streaming deployment opens a stream
+            assert cells[name] == ["link_stream_ici"], name
         elif name == "native_plane_callback_us":
             # only the native plane feeds it
             assert cells[name] == ["echo_256b_c16_native"]
@@ -165,22 +203,28 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
 
 
 def test_each_configuration_is_the_file_the_manifest_names():
+    link_options = manifest.load_json(
+        "configs", "link_performance_ici.json")["channel_options"]
     expected = {
-        "echo_device": ("device_echo", {}, None),
+        "echo_device": ("device_echo", "echo_identity", {}, None),
         "echo_device_native": (
-            "device_echo_native", {"native_plane": True}, {"native_plane": True},
+            "device_echo_native", "echo_identity",
+            {"native_plane": True}, {"native_plane": True},
         ),
-        "link_performance_ici": ("link_echo", None, None),
+        "link_performance_ici": ("link_echo", "echo_identity", None, None),
+        # the stream rides the link link_performance_ici states
+        "link_stream_sink_ici": ("link_stream", "stream_sink", link_options, None),
     }
     configs = {c["name"]: c for c in BENCH["configs"]}
     assert set(expected) <= set(configs)  # a later PR may add more
     sources = [c["source"] for c in BENCH["configs"]]
     assert len(sources) == len(set(sources))
-    for name, (deployment, channel_options, server_options) in expected.items():
+    for name, (deployment, reference, channel_options, server_options) in (
+            expected.items()):
         config = manifest.load_json("configs", name + ".json")
         assert configs[name]["file"] == f"benchmark/configs/{name}.json"
         assert config["deployment"] == deployment
-        assert config["reference"] == "echo_identity"
+        assert config["reference"] == reference
         assert config["reduced"] == configs[name]["reduced"] == []
         if channel_options is not None:
             assert config["channel_options"] == channel_options
@@ -231,4 +275,66 @@ def test_roofline_share_needs_device_time_and_peaks():
     assert read(run) is None
     run = hand_made_run(dict(DEVICE))
     run.peaks = None  # a rehearsal on the CPU has no peaks
+    assert read(run) is None
+
+
+def permute_run(counters: dict):
+    """Two chips, each with 100 exchange programs in the window: a
+    ``collective-permute-start`` of 1 us and a ``-done`` of 9 us, a copy
+    that is not the step's, and one permute outside the window."""
+    run = hand_made_run(counters)
+    start = T_OPEN + np.arange(100, dtype=np.int64) * 1_000_000
+    names = (
+        ["%collective-permute-start = (u32[1,8,16392]{2,1,0}, u32[1,8,16392]"
+         "{2,1,0}, u32[], u32[]) collective-permute-start(u32[1,8,16392] %p)"] * 100
+        + ["%collective-permute-done = u32[1,8,16392]{2,1,0} "
+           "collective-permute-done(%collective-permute-start)"] * 101
+        + ["%copy.3 = u32[1,8,16392]{2,1,0} copy(%collective-permute-done)"] * 100
+    )
+    starts = np.concatenate(
+        (start, start + 1_000, [T_CLOSE + 5_000], start + 10_000))
+    ends = np.concatenate(
+        (start + 1_000, start + 10_000, [T_CLOSE + 9_000], start + 50_000))
+    ops = xplane.Events(names, starts, ends)
+    run.devices = {
+        plane: {"ops": ops, "steps": xplane.Events([], [], [])}
+        for plane in ("/device:TPU:0", "/device:TPU:1")
+    }
+    return run
+
+
+def test_link_step_ici_share_from_a_fabricated_trace():
+    read = manifest.load_module("layers", "link_step_ici_pct.py").read
+    run = permute_run({"device_link_slots": 800})
+    # 800 slots of 64 KiB a side in 100 x 10 us of permute on each chip,
+    # against the chip's 1,600 Gbit/s
+    share = 100.0 * (800 * 16384 * 4 / 1e-3) / (1600e9 / 8)
+    assert read(run) == pytest.approx(share)
+    assert 0 < share < 100
+    for spoil in ("slots", "trace", "peaks", "ici", "words"):
+        run = permute_run({"device_link_slots": 800})
+        if spoil == "slots":
+            run.counters = {}  # a program from before PR 30
+        elif spoil == "trace":
+            run.devices = {}  # a CPU rehearsal: no device plane
+        elif spoil == "peaks":
+            run.peaks = None
+        elif spoil == "ici":
+            run.peaks = {"hbm_bytes_per_s": 819e9}
+        else:
+            run.cell = types.SimpleNamespace(config={"channel_options": {}})
+        assert read(run) is None, spoil
+    # a chip that ran no permute in the window does not count in the mean
+    run = permute_run({"device_link_slots": 800})
+    run.devices["/device:TPU:2"] = {
+        "ops": xplane.Events(["%copy.9 = u32[8]{0} copy(%p)"], [T_OPEN], [T_OPEN + 9]),
+        "steps": xplane.Events([], [], []),
+    }
+    assert read(run) == pytest.approx(share)
+
+
+def test_window_share_needs_the_configuration_to_state_a_window():
+    read = manifest.load_module("layers", "stream_window_used_pct.py").read
+    run = hand_made_run(dict(STREAM))
+    run.cell = types.SimpleNamespace(config={"channel_options": {}})
     assert read(run) is None

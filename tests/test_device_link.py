@@ -899,6 +899,7 @@ class TestStepStageRecorders:
             "step_rtt_us", "flush_us", "launch_us", "ready_us",
             "reorder_wait_us", "readback_us", "pump_us",
             "dispatch_interval_us", "inflight_at_dispatch",
+            "backlog_slots_at_dispatch", "credit_at_dispatch", "send_wait_us",
             "out_bytes_second", "in_bytes_second",
         }
         link.fail("retire")
@@ -1039,8 +1040,8 @@ class TestSlotTrains:
         peak = []
         take = link._take_seq_locked
 
-        def spy(k=1):
-            out = take(k)
+        def spy(k=1, *saw):
+            out = take(k, *saw)
             peak.append((k, link._inflight, link._seq - link._peer_ack))
             return out
 
@@ -1129,7 +1130,7 @@ class TestSlotTrains:
             assert built >= 4  # one program a train length: 1, 2, 4, 8
             seen = set()
             take = link._take_seq_locked
-            link._take_seq_locked = lambda k=1: (seen.add(k), take(k))[1]
+            link._take_seq_locked = lambda k=1, *saw: (seen.add(k), take(k, *saw))[1]
             total = 0
             for slots in (1, 2, 4, 8, 15):
                 total += slots * 1024

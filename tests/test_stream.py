@@ -358,7 +358,10 @@ class TestRawMessages:
 
         s = Stream(999001, StreamOptions(handler=RawSink(), raw_messages=True),
                    is_client=False)
-        s._rq.execute((FT_DATA, b"plain-bytes-payload"))
+        # what _on_frame queues: kind, payload, its arrival stamp
+        s._rq.execute(
+            (FT_DATA, b"plain-bytes-payload", __import__("time").monotonic_ns())
+        )
         deadline = __import__("time").monotonic() + 5
         while not got and __import__("time").monotonic() < deadline:
             __import__("time").sleep(0.01)
