@@ -21,7 +21,9 @@ re-thought for XLA devices:
   uint32 slots per direction (negotiated ``slot_words`` each): as many
   as the fuller side's backlog fills and the credit window has free, a
   power of two (the RDMA endpoint posting the work requests its window
-  admits, not one and wait). The link is a BYTE STREAM: queued host
+  admits, not one and wait). A backlog that would fill a longer train
+  than the free credit admits waits for the slots in flight to land
+  rather than going as a short train beside them. The link is a BYTE STREAM: queued host
   frames (tbus_std bytes — the same frames TCP carries, as RDMA carries
   baidu_std bytes) are packed head-to-tail into slots and re-cut by the
   receiver's normal InputMessenger loop. XLA's functional model replaces
@@ -78,6 +80,9 @@ link_steps = Adder(name="device_link_steps")  # exchange programs dispatched
 # slots a side those programs carried: over device_link_steps it is how
 # long the trains run
 link_slots = Adder(name="device_link_slots")
+# of those programs, the ones whose dispatch waited for the credit the
+# train its backlog wanted needs
+link_held = Adder(name="device_link_held_steps")
 link_bytes = Adder(name="device_link_bytes")
 # payload capacity of every slot side filled: set against device_link_bytes
 # it says how full the slots travel
@@ -133,18 +138,22 @@ class _Step:
     does the work."""
 
     __slots__ = (
-        "t_dispatch", "interval_ns", "inflight", "seen", "t_launched", "watcher",
+        "t_dispatch", "interval_ns", "inflight", "seen", "hold_ns",
+        "t_launched", "watcher",
     )
 
     def __init__(
         self, t_dispatch: int, interval_ns: int, inflight: int,
-        seen: tuple = (None, None),
+        seen: tuple = (None, None), hold_ns: int = 0,
     ):
         self.t_dispatch = t_dispatch  # slots filled, seq taken
         self.interval_ns = interval_ns  # since the drive's previous dispatch
         self.inflight = inflight  # undrained slots, this train's included
         # (backlog slots, free credit) the train's length was taken from
         self.seen = seen
+        # the drive's first look that found less credit than the train its
+        # backlog wanted needs -> this dispatch; 0 = never held
+        self.hold_ns = hold_ns
         self.t_launched = 0  # _make_slots and the step call returned
         # DeviceCompletionButex.watch fills these: a watcher thread took
         # the job, block_until_ready returned
@@ -224,9 +233,11 @@ class DeviceLink:
         # messenger). flush = the staging gather into one side's train;
         # dispatch_interval = the host time between one drive's consecutive
         # dispatches; inflight_at_dispatch = how many slots of the window
-        # are in use (a count, not a time); plus bytes-per-second windows
-        # each way. Retired (hidden from the registry) when the link dies
-        # so churning links don't accumulate.
+        # are in use (a count, not a time); hold = how long the drive kept
+        # a train back for the credit of a longer one (before its dispatch,
+        # so outside step_rtt); plus bytes-per-second windows each way.
+        # Retired (hidden from the registry) when the link dies so churning
+        # links don't accumulate.
         self.link_id = next(_link_ids)
         pfx = f"device_link_{self.link_id}"
         self._m_out_bytes = Adder()
@@ -245,23 +256,25 @@ class DeviceLink:
         self._m_backlog = LatencyRecorder(name=f"{pfx}_backlog_slots_at_dispatch")
         self._m_credit = LatencyRecorder(name=f"{pfx}_credit_at_dispatch")
         self._m_send_wait = LatencyRecorder(name=f"{pfx}_send_wait_us")
+        self._m_hold = LatencyRecorder(name=f"{pfx}_hold_us")
         self._m_out_rate = PerSecond(self._m_out_bytes, name=f"{pfx}_out_bytes_second")
         self._m_in_rate = PerSecond(self._m_in_bytes, name=f"{pfx}_in_bytes_second")
         # a delivered step's numbers (ns, but for the in-flight count) wait
-        # here for the sampler thread: nine feeds a step on the delivering
+        # here for the sampler thread: eleven feeds a step on the delivering
         # thread would sit between one step and the next
         self._step_feed = RecorderFeed((
             (self._m_launch, 1e-3), (self._m_ready, 1e-3),
             (self._m_reorder_wait, 1e-3), (self._m_readback, 1e-3),
             (self._m_pump, 1e-3), (self._m_rtt, 1e-3),
             (self._m_dispatch_interval, 1e-3), (self._m_inflight, 1),
-            (self._m_backlog, 1), (self._m_credit, 1),
+            (self._m_backlog, 1), (self._m_credit, 1), (self._m_hold, 1e-3),
         ))
         # one row a send(): ns parked over the backlog budget
         self._send_feed = RecorderFeed(((self._m_send_wait, 1e-3),))
         self._metrics_retired = False
         self._steps: Dict[int, _Step] = {}  # first seq -> timeline, until delivered
         self._last_dispatch_ns = 0  # this drive's previous dispatch; 0 = none
+        self._held_since_ns = 0  # the drive is holding a train back; 0 = not
         self._build_step()
         with _links_lock:
             _all_links.add(self)
@@ -278,7 +291,7 @@ class DeviceLink:
             self._m_rtt, self._m_flush, self._m_launch, self._m_ready,
             self._m_reorder_wait, self._m_readback, self._m_pump,
             self._m_dispatch_interval, self._m_inflight,
-            self._m_backlog, self._m_credit, self._m_send_wait,
+            self._m_backlog, self._m_credit, self._m_send_wait, self._m_hold,
             self._m_out_rate, self._m_in_rate,
         ):
             try:
@@ -477,33 +490,44 @@ class DeviceLink:
         return self.window - self._inflight
 
     def _train_len_locked(self) -> tuple:
-        """Slots a side the next step carries, from what the link observes
-        under its lock: the largest power of two (one compiled program a
-        length, ``_warm_step``) within both the slots the fuller side's
-        backlog fills and the free credit. One where a step goes out with
-        no data or no credit (close-only, wire-mode catch-up), and on the
-        host swap, which dispatches no program a train could save. Returns
-        the length and the ``(backlog slots, free credit)`` it was taken
-        from, for the train's timeline."""
+        """Slots a side the next step may carry and the slots its backlog
+        wants, from what the link observes under its lock. Each is the
+        largest power of two (one compiled program a length,
+        ``_warm_step``) within the slots the fuller side's backlog fills:
+        the first within the free credit too, the second within the whole
+        window. Where the first is the shorter, a delivery still to come
+        would bring the credit of a longer train (``_drive`` holds for it).
+        Both are one where a step goes out with no data or no credit
+        (close-only, wire-mode catch-up), and on the host swap, which
+        dispatches no program a train could save. Returns the two lengths
+        and the ``(backlog slots, free credit)`` they were taken from, for
+        the train's timeline."""
         backlog = -(-max(self._out_nbytes) // self._slot_bytes)
         credit = self._credit_locked()
         if self._step is None:
-            return 1, (backlog, credit)
-        k = max(1, min(backlog, credit))
-        return 1 << (k.bit_length() - 1), (backlog, credit)
+            return 1, 1, (backlog, credit)
+        admitted = max(1, min(backlog, credit))
+        wanted = max(1, min(backlog, self.window))
+        return (
+            1 << (admitted.bit_length() - 1),
+            1 << (wanted.bit_length() - 1),
+            (backlog, credit),
+        )
 
     def _take_seq_locked(self, k: int = 1, seen: tuple = (None, None)) -> tuple:
         """Under the link lock, a train of ``k`` slots a side filled: take
         its seqs, count its slots in flight and start its timeline. ``seen``
         is what ``_train_len_locked`` took ``k`` from (a link that never
-        asks it, ``MultiControllerLink``, records neither)."""
+        asks it, ``MultiControllerLink``, records neither and never holds)."""
         seq = self._seq
         self._seq += k
         self._inflight += k
         now = time.monotonic_ns()
         last, self._last_dispatch_ns = self._last_dispatch_ns, now
+        held, self._held_since_ns = self._held_since_ns, 0
         step = self._steps[seq] = _Step(
-            now, now - last if last else 0, self._inflight, seen
+            now, now - last if last else 0, self._inflight, seen,
+            now - held if held else 0,
         )
         return seq, step
 
@@ -545,9 +569,21 @@ class DeviceLink:
                         # window has no later completion to wake for
                         need = self._wbutex.load()
                 if need is None:
-                    k, seen = self._train_len_locked()
-                    rows = [self._fill_train_locked(s, k) for s in (0, 1)]
-                    seq, step = self._take_seq_locked(k, seen)
+                    k, wanted, seen = self._train_len_locked()
+                    if k < wanted and self._inflight and not ack_only:
+                        # the backlog would fill a longer train than the
+                        # free credit admits, and slots are still out: a
+                        # short train now would keep the link at two or
+                        # three short trains in flight, whose threads meet
+                        # at the interpreter lock (PERF.md, PR 30 and 32).
+                        # Hold for the delivery that brings the credit;
+                        # it is already in flight, so this cannot wedge
+                        if not self._held_since_ns:
+                            self._held_since_ns = time.monotonic_ns()
+                        need = self._wbutex.load()
+                    else:
+                        rows = [self._fill_train_locked(s, k) for s in (0, 1)]
+                        seq, step = self._take_seq_locked(k, seen)
             if need is not None:
                 self._wbutex.wait(need, timeout=1.0)
                 continue
@@ -586,6 +622,8 @@ class DeviceLink:
             step.t_launched = time.monotonic_ns()
             link_steps << 1
             link_slots << k
+            if step.hold_ns:
+                link_held << 1
             self._cq.watch(
                 out,
                 on_complete=lambda arrays, error, _seq=seq, _k=k: (
@@ -718,6 +756,7 @@ class DeviceLink:
             step.interval_ns or None,
             step.inflight,
             *step.seen,
+            step.hold_ns,
         ))
 
     def _rows_to_host(self, arrays) -> List[Optional[np.ndarray]]:

@@ -89,6 +89,9 @@ LINK = {
     "device_link_2_send_wait_us": recorder(5, 9e9),
     "device_link_3_send_wait_us": recorder(900, 350.0),
     "device_link_3_backlog_slots_at_dispatch": recorder(680, 10.5),
+    "device_link_2_hold_us": recorder(5, 9e9),
+    "device_link_3_hold_us": recorder(680, 750.0),
+    "device_link_held_steps": 170,
     "device_link_bytes": 40 * (1 << 20),
     "device_link_capacity_bytes": 2 * 680 * 65536,
     "device_link_steps": 680,
@@ -132,6 +135,8 @@ EXPECTED = {
     "link_slots_per_step": (LINK, 4.0),
     "link_send_wait_us": (LINK, 350.0),
     "link_backlog_slots": (LINK, 10.5),
+    "link_hold_us": (LINK, 750.0),
+    "link_held_pct": (LINK, 25.0),
     "stream_write_wait_us": (STREAM, 7000.0),
     "stream_feedback_lag_us": (STREAM, 21000.0),
     "stream_deliver_us": (STREAM, 450.0),

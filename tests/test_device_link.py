@@ -791,9 +791,17 @@ def _link_recorders(link):
         name: getattr(link, "_m_" + name)
         for name in (
             "rtt", "flush", "launch", "ready", "reorder_wait", "readback",
-            "pump", "dispatch_interval", "inflight",
+            "pump", "dispatch_interval", "inflight", "hold",
         )
     }
+
+
+def _stages_sum(m):
+    """The five stages that add up to ``step_rtt``, summed."""
+    return sum(
+        m[name].latency_sum()
+        for name in ("launch", "ready", "reorder_wait", "readback", "pump")
+    )
 
 
 class TestStepStageRecorders:
@@ -834,14 +842,15 @@ class TestStepStageRecorders:
         # 48 KiB through 4 KiB slots: 12 slots, a step each on the host
         # swap, trains of up to the window's 4 where a program is dispatched
         assert steps >= (12 if geometry == "host-swap" else 3)
-        for name in ("launch", "ready", "reorder_wait", "readback", "pump", "inflight"):
+        for name in (
+            "launch", "ready", "reorder_wait", "readback", "pump", "inflight", "hold",
+        ):
             assert m[name].count() == steps, name
+        # one send over an idle link: no train was held (TestSlotTrains has
+        # one that was: the hold lies before the dispatch, outside step_rtt)
+        assert m["hold"].max_latency() == 0
         assert m["flush"].count() == 2 * steps  # both sides' trains, every step
-        parts = sum(
-            m[name].latency_sum()
-            for name in ("launch", "ready", "reorder_wait", "readback", "pump")
-        )
-        assert parts == pytest.approx(m["rtt"].latency_sum(), rel=1e-6)
+        assert _stages_sum(m) == pytest.approx(m["rtt"].latency_sum(), rel=1e-6)
         # one send, one drive: every step but the first has an interval
         assert m["dispatch_interval"].count() == steps - 1
         assert m["dispatch_interval"].latency_sum() > 0
@@ -900,7 +909,7 @@ class TestStepStageRecorders:
             "reorder_wait_us", "readback_us", "pump_us",
             "dispatch_interval_us", "inflight_at_dispatch",
             "backlog_slots_at_dispatch", "credit_at_dispatch", "send_wait_us",
-            "out_bytes_second", "in_bytes_second",
+            "hold_us", "out_bytes_second", "in_bytes_second",
         }
         link.fail("retire")
         assert not list(expose_registry.snapshot(pfx))
@@ -910,7 +919,10 @@ def _trains(slots, window):
     """The train lengths one ``send()`` of ``slots`` slots' worth of bytes
     leaves behind when the other side is idle: each the largest power of
     two within the backlog and the free credit, and a train's credit comes
-    back whole, at its delivery."""
+    back whole, at its delivery. One send over an idle link never meets the
+    hold (PR 32): with a window that is a power of two, whenever slots are
+    out here the free credit is either none, which waits as it always did,
+    or covers the train the rest of the backlog wants."""
     out, free = [], window
     while slots:
         if free == 0:
@@ -1157,3 +1169,191 @@ class TestSlotTrains:
         assert _wait(lambda: sink.nbytes == len(stream))
         assert sink.frames() == frames
         assert _link_recorders(link)["rtt"].count() == link._seq == 8
+
+    # -- PR 32: a dispatch waits for the credit the train it wants needs ----
+
+    @staticmethod
+    def _gate_deliveries(link):
+        """Completions wait on the returned event before ``_on_step_done``
+        runs: trains stay in flight until the test lets them land."""
+        gate, inner = threading.Event(), link._on_step_done
+
+        def gated(*a, **k):
+            assert gate.wait(timeout=30)
+            inner(*a, **k)
+
+        link._on_step_done = gated
+        return gate
+
+    @staticmethod
+    def _spy_trains(link):
+        """Every train cut: (k, the train its backlog wanted, slots in
+        flight before it)."""
+        cuts, take = [], link._take_seq_locked
+
+        def spy(k=1, seen=(None, None)):
+            backlog = max(1, min(seen[0], link.window))
+            cuts.append((k, 1 << (backlog.bit_length() - 1), link._inflight))
+            return take(k, seen)
+
+        link._take_seq_locked = spy
+        return cuts
+
+    @pytest.mark.parametrize(
+        "inflight,backlog,window,held,then",
+        [
+            (4, 17, 8, True, [8, 8, 1]),  # half the window is out: wait for it
+            (4, 9, 8, True, [8, 1]),
+            (2, 8, 4, True, [4, 4]),
+            (4, 8, 8, True, [8]),
+            (4, 1, 8, False, [1]),  # the credit covers what is queued: go
+            (4, 2, 8, False, [2]),
+            (4, 3, 8, False, [2, 1]),
+            (4, 6, 8, False, [4, 2]),
+            (1, 5, 8, False, [4, 1]),  # credit 7 admits the 4 it wants
+            (2, 1, 4, False, [1]),
+        ],
+    )
+    def test_a_dispatch_waits_for_the_credit_of_the_train_it_wants(
+        self, inflight, backlog, window, held, then
+    ):
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link(window=window)
+        gate, cuts = self._gate_deliveries(link), self._spy_trains(link)
+        held_before = dl.link_held.get_value()
+        first_frames, first = _framed_stream(99, inflight * 1024)
+        assert link.send(0, first) == 0
+        assert _wait(lambda: link._seq == inflight and not link._driving)
+        frames, stream = _framed_stream(backlog, backlog * 1024 - 100)
+        assert link.send(0, stream) == 0
+        if held:
+            # nothing is cut while the first train is out, however long
+            time.sleep(0.3)
+            assert link._seq == inflight and link._driving
+        else:
+            # cut at once, the first train still undelivered
+            assert _wait(lambda: link._seq >= inflight + then[0])
+        assert sinks[1].nbytes == 0
+        gate.set()
+        assert _wait(lambda: sinks[1].nbytes == len(first) + len(stream), timeout=30)
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        assert sinks[1].frames() == first_frames + frames
+        assert [k for k, _, _ in cuts] == [inflight] + then
+        m = _link_recorders(link)
+        assert dl.link_held.get_value() - held_before == (1 if held else 0)
+        assert m["hold"].count() == len(cuts)
+        if held:
+            # the one held train waited out the gate; the hold lies before
+            # its dispatch, so the five stages still add up to step_rtt
+            assert m["hold"].latency_sum() == m["hold"].max_latency() >= 250_000
+            assert _stages_sum(m) == pytest.approx(m["rtt"].latency_sum(), rel=1e-6)
+        else:
+            assert m["hold"].max_latency() == 0
+
+    @pytest.mark.parametrize("geometry", ["ppermute", "device-swap"])
+    def test_every_train_is_the_wanted_one_or_alone_in_flight(self, geometry):
+        link, socks, sinks = self._make_link(geometry, window=8)
+        cuts = self._spy_trains(link)
+        frames, stream = _framed_stream(11, 300 * 1024 - 77)
+        sends = [stream[i : i + 3000] for i in range(0, len(stream), 3000)]
+
+        def sender():
+            for chunk in sends:
+                assert link.send(0, chunk, timeout=60) == 0
+
+        t = threading.Thread(target=sender)
+        t.start()
+        t.join(timeout=120)
+        assert not t.is_alive()
+        assert _wait(lambda: sinks[1].nbytes == len(stream), timeout=60)
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        assert sinks[1].frames() == frames
+        assert sum(k for k, _, _ in cuts) == link._seq >= 300
+        for k, wanted, out in cuts:
+            assert k == wanted or out == 0, (k, wanted, out)
+        assert max(k for k, _, _ in cuts) == 8  # a standing backlog rode the window
+
+    @pytest.mark.parametrize("window", [1, 2, 4, 8])
+    @pytest.mark.parametrize("ack_mode", ["local", "wire"])
+    def test_two_way_residues_drain_without_a_timeout(self, ack_mode, window):
+        from incubator_brpc_tpu.runtime.butex import ETIMEDOUT, Butex
+
+        link, socks, sinks = self._make_link(window=window, ack_mode=ack_mode)
+        waits = []
+
+        class SpyButex(Butex):
+            def wait(self, *a, **k):
+                waits.append(super().wait(*a, **k))
+                return waits[-1]
+
+        link._wbutex = SpyButex(0)  # nothing waits on a link just made
+        a, b = _framed_stream(5, 37 * 1024 + 13), _framed_stream(6, 21 * 1024 - 5)
+
+        def sender(side, stream):
+            # sends of 2.5 slots: every one leaves an odd residue behind
+            for i in range(0, len(stream), 2560):
+                assert link.send(side, stream[i : i + 2560], timeout=60) == 0
+
+        threads = [
+            threading.Thread(target=sender, args=(0, a[1])),
+            threading.Thread(target=sender, args=(1, b[1])),
+        ]
+        t0 = time.monotonic()
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=120)
+            assert not t.is_alive()
+        assert _wait(lambda: sinks[1].nbytes == len(a[1]), timeout=60)
+        assert _wait(lambda: sinks[0].nbytes == len(b[1]), timeout=60)
+        assert sinks[1].frames() == a[0] and sinks[0].frames() == b[0]
+        # what _quiesce_links waits for, of this link alone: the drive left
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        assert ETIMEDOUT not in waits, (len(waits), time.monotonic() - t0)
+
+    def test_fail_while_the_drive_holds_frees_drive_and_senders(self):
+        link, socks, sinks = self._make_link(window=8)
+        gate = self._gate_deliveries(link)
+        try:
+            assert link.send(0, b"f" * 4096) == 0
+            assert _wait(lambda: link._seq == 4 and not link._driving)
+            assert link.send(0, b"b" * (17 * 1024)) == 0  # over the send budget
+            rcs = []
+            t = threading.Thread(
+                target=lambda: rcs.append(link.send(0, b"p" * 1024, timeout=30))
+            )
+            t.start()
+            assert _wait(lambda: link._wbutex.has_waiters() and link._held_since_ns)
+            assert link._seq == 4 and link._driving and t.is_alive()
+            link.fail("injected while held")
+            t.join(timeout=5)
+            assert not t.is_alive() and rcs == [ErrorCode.EFAILEDSOCKET]
+            assert _wait(lambda: not link._driving, timeout=5)
+            assert link._seq == 4  # nothing was cut after the failure
+        finally:
+            gate.set()
+        assert _wait(lambda: all(s.state != 0 for s in socks))
+
+    def test_host_swap_never_holds(self):
+        import jax
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        dev = jax.devices()[0]
+        link = dl.DeviceLink([dev, dev], slot_words=self.SLOT_WORDS, window=8)
+        assert link.geometry == "host-swap"
+        sink = _FrameSink()
+        dl.DeviceSocket(link, side=0, messenger=_FrameSink())
+        dl.DeviceSocket(link, side=1, messenger=sink)
+        held_before = dl.link_held.get_value()
+        frames, stream = _framed_stream(9, 100 * 1024 + 3)
+        for i in range(0, len(stream), 2560):
+            assert link.send(0, stream[i : i + 2560], timeout=30) == 0
+        assert _wait(lambda: sink.nbytes == len(stream))
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        assert sink.frames() == frames
+        m = _link_recorders(link)
+        assert m["hold"].count() == m["rtt"].count() == link._seq
+        assert m["hold"].max_latency() == 0
+        assert dl.link_held.get_value() == held_before

@@ -139,6 +139,47 @@ def test_a_transfer_against_the_reference():
         deployment.close()
 
 
+@limited(180)
+def test_the_cells_own_sizes_ride_trains_of_the_window():
+    """The configuration and the traffic as their files state them (64 KiB
+    slots, a window of 8, 32 MiB in 1 MiB messages under a 2 MiB window):
+    the flow control the chip will see, none of its speed. PR 31's run of
+    this read 4.07 slots a step; with the drive holding for the credit of
+    the train its backlog wants (PR 32) my runs read 5.82-5.92, and 5.67 is
+    a message gone as 8, 8 and its 100-byte tail alone."""
+    from incubator_brpc_tpu.rpc import Controller
+    from incubator_brpc_tpu.transport import device_link as dl
+
+    traffic = manifest.load_json("traffic", "stream_32m_in_1m_c1.json")
+    _, deployment = deploy(config=copy.deepcopy(CONFIG), traffic=traffic)
+    try:
+        assert deployment.message_bytes == 1 << 20
+        data = payload(32, traffic["sizes"][0])
+        before = {a: getattr(dl, a).get_value()
+                  for a in ("link_steps", "link_slots", "link_held")}
+        result = deployment.channel().call_method(
+            "StreamService", "Open", b"ping", attachment=data,
+            cntl=Controller(timeout_ms=120000))
+        assert not result.failed(), result.error_text
+        assert result.response_attachment == data
+        link = deployment.link
+        assert (link.slot_words, link.window) == (16384, 8)
+        gained = {a: getattr(dl, a).get_value() - v for a, v in before.items()}
+        # 512 full slots, the call, feedback, the receipt, and a tail for
+        # each message the next one was not queued behind in time
+        assert 512 < gained["link_slots"] <= 32 * 17 + 40
+        assert gained["link_slots"] / gained["link_steps"] > 5.0
+        assert gained["link_held"] > 0
+        checks = {name: (value, ok) for name, value, _limit, ok in deployment.holds()}
+        assert all(ok for _value, ok in checks.values()), checks
+        for name in ("stream_messages_with_other_boundaries",
+                     "stream_window_overrun_bytes",
+                     "stream_receipts_with_other_counts"):
+            assert checks[name][0] == 0, name
+    finally:
+        deployment.close()
+
+
 @limited(60)
 def test_the_reference_cuts_where_a_writer_cuts():
     reference = manifest.load_module("references", "stream_sink.py")
@@ -310,8 +351,11 @@ def test_every_stream_recorder_gains_under_its_sockets_prefix(transport, monkeyp
         deadline = time.monotonic() + 10
         while time.monotonic() < deadline:
             gained = gains(before[mine], counts(mine))
-            if gained[mine + "messages"] == 17 and (
-                    gained[mine + "consume_us"] == gained[mine + "batches"]):
+            # (the sink's write of it once that write has returned, the
+            # feedback for it once the client's half has sent it)
+            if gained[mine + "messages"] == gained[mine + "write_wait_us"] == 17 and (
+                    gained[mine + "consume_us"] == gained[mine + "batches"]
+                    == gained[mine + "feedback_frames"]):
                 break
             time.sleep(0.01)
         for name in STREAM_NAMES:
