@@ -13,14 +13,36 @@ import sys
 
 sys.path.insert(0, ".")
 
+from incubator_brpc_tpu.bvar import expose_registry  # noqa: E402
 from incubator_brpc_tpu.rpc import (  # noqa: E402
+    CallMapper,
     Channel,
     ChannelOptions,
     ParallelChannel,
     Server,
     ServerOptions,
+    SubCall,
     device_method,
 )
+
+ROW = 2  # bytes of the request a replica answers, in the device half
+
+
+class RowMapper(CallMapper):
+    """combo_channel.md's UseFieldAsSubRequest over bytes: sub-request i is
+    row i of the request (the last may be shorter or empty)."""
+
+    def map(self, channel_index, nchannels, service, method, request):
+        return SubCall(request=request[channel_index * ROW:(channel_index + 1) * ROW])
+
+
+def lowerings() -> dict:
+    """How many combo calls took each lowering (docs/OBSERVABILITY.md)."""
+    return {
+        name.rsplit("_combo_", 1)[1]: var.get_value()
+        for name, var in expose_registry.snapshot("device_link_combo_")
+        if name.endswith(("_fused", "_mc_lowered", "_host_fanout"))
+    }
 
 
 def main() -> None:
@@ -70,13 +92,15 @@ def main() -> None:
             f"127.0.0.1:{s.port}",
             options=ChannelOptions(transport="tpu", timeout_ms=60000),
         )
-        fused.add_channel(ch)
-    cntl = fused.call_method("dsvc", "inc", b"\x01\x02\x03")
+        fused.add_channel(ch, call_mapper=RowMapper())
+    before = lowerings()
+    cntl = fused.call_method("dsvc", "inc", b"\x01\x02\x03\x04\x05")
     assert cntl.ok(), cntl.error_text
+    assert cntl.response_payload == b"\x02\x03\x04\x05\x06"
+    took = [how for how, n in lowerings().items() if n > before[how]]
     print(
-        f"fused={getattr(cntl, 'collective_fused', False)} "
-        f"merged={cntl.response_payload!r}  "
-        "(one shard_map all-gather dispatch, not 3 RPCs)"
+        f"lowering={took} merged={cntl.response_payload!r}  "
+        "(fused: one shard_map all-gather dispatch, not 3 RPCs)"
     )
     for s in dservers:
         s.stop()

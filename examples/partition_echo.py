@@ -10,11 +10,33 @@ import sys
 
 sys.path.insert(0, ".")
 
+from incubator_brpc_tpu.bvar import expose_registry  # noqa: E402
 from incubator_brpc_tpu.rpc import (  # noqa: E402
+    CallMapper,
     DynamicPartitionChannel,
     PartitionChannel,
     Server,
+    SubCall,
 )
+
+ROW = 8  # bytes of the request a shard answers, in the device half
+
+
+class RowMapper(CallMapper):
+    """combo_channel.md's UseFieldAsSubRequest over bytes: sub-request i is
+    row i of the request (the last may be shorter or empty)."""
+
+    def map(self, channel_index, nchannels, service, method, request):
+        return SubCall(request=request[channel_index * ROW:(channel_index + 1) * ROW])
+
+
+def lowerings() -> dict:
+    """How many combo calls took each lowering (docs/OBSERVABILITY.md)."""
+    return {
+        name.rsplit("_combo_", 1)[1]: var.get_value()
+        for name, var in expose_registry.snapshot("device_link_combo_")
+        if name.endswith(("_fused", "_mc_lowered", "_host_fanout"))
+    }
 
 
 def shard_server(i: int) -> Server:
@@ -63,13 +85,16 @@ def main() -> None:
     if len(jax.devices()) < 4:
         print("(single device: the device-fabric half needs a 4+ mesh)")
         return
-    from incubator_brpc_tpu.rpc import ChannelOptions, ServerOptions
+    from incubator_brpc_tpu.rpc import ChannelOptions, ServerOptions, device_method
+
+    def echo_kernel(data, n):  # the device kernel every shard serves
+        return data, n
 
     dshards = []
     for i in range(3):
         s = Server(ServerOptions(device_index=i + 1, usercode_inline=True))
         s.add_service(
-            "EchoService", {"Echo": (lambda c, req, _i=i: b"[dev%d]%s" % (_i, req))}
+            "PartitionEcho", {"Echo": device_method(echo_kernel, width=256)}
         )
         assert s.start(0)
         dshards.append(s)
@@ -81,19 +106,24 @@ def main() -> None:
         durl,
         partition_count=3,
         options=ChannelOptions(transport="tpu", timeout_ms=60000),
+        call_mapper=RowMapper(),
     )
     from incubator_brpc_tpu.rpc import Controller
 
     # sub-calls inherit the PARENT controller's budget: give the first
     # call room for 3 link handshakes + the first jitted step's compile
+    before = lowerings()
     cntl = dpc2.call_method(
-        "EchoService", "Echo", b"over-ici", cntl=Controller(timeout_ms=60000)
+        "PartitionEcho", "Echo", b"row-zero" b"row--one" b"two",
+        cntl=Controller(timeout_ms=60000),
     )
     assert cntl.ok(), cntl.error_text
+    assert cntl.response_payload == b"row-zerorow--onetwo"
+    took = [how for how, n in lowerings().items() if n > before[how]]
     peers = sorted(
         str(sub[0]._device_sock.link.devices[1]) for sub in dpc2._subs
     )
-    print(f"device-fabric response: {cntl.response_payload!r}")
+    print(f"device-fabric response: {cntl.response_payload!r} (lowering: {took})")
     print(f"star fabric peers: {peers}")
     dpc2.stop()
     for s in dshards:

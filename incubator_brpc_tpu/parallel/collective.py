@@ -7,11 +7,21 @@ XLA schedules them on ICI; no Python control flow depends on data.
 
 from __future__ import annotations
 
+import threading
 from typing import Callable
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+# Threads of one process that launch multi-device programs with a
+# collective in them (the parties of a session co-hosted in one process,
+# parallel/mc_dispatch.py; concurrent callers of a fused combo channel,
+# rpc/combo.py) hold this across the (async) enqueue: two devices that see
+# those launches in different orders each wait in a collective the other
+# has not reached. Held, every device queue gets the same order. Only the
+# enqueue is ordered, never the host work before it or the read-back after.
+launch_order = threading.Lock()
 
 
 def fanout(x: jnp.ndarray, axis: str) -> jnp.ndarray:

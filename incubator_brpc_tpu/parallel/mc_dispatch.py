@@ -54,6 +54,7 @@ from typing import Callable, Dict, List, Optional, Tuple
 import numpy as np
 
 from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, PassiveStatus
+from incubator_brpc_tpu.parallel.collective import launch_order as _launch_order
 from incubator_brpc_tpu.utils.flags import define_flag, get_flag
 
 logger = logging.getLogger(__name__)
@@ -755,13 +756,11 @@ _ck_quant_cache: Dict[tuple, object] = {}
 _step_cache_lock = threading.Lock()  # guards ALL three caches (never nested)
 # One process may host several parties of a session (single-controller
 # runs, the in-process tests): their threads dispatch the same
-# multi-device collective program concurrently, and two devices that see
-# those launches in different orders each wait in a collective the other
-# has not reached.  Holding this across the (async) enqueue gives every
-# device queue the same order.  A process that addresses ONE device of
+# multi-device collective program concurrently, and take the process's one
+# launch order (parallel/collective.py, shared with the fused combo
+# dispatch) across the enqueue.  A process that addresses ONE device of
 # the party set (the one-party-per-process deployment) has no co-hosted
 # launch to order and never takes it.
-_launch_order = threading.Lock()
 _no_launch_order = contextlib.nullcontext()
 
 
@@ -2864,8 +2863,6 @@ def propose_with_recovery(
 
 # -- the ParallelChannel lowering ----------------------------------------------
 
-mc_lowered_dispatches = Adder(name="parallel_channel_mc_lowered")
-
 
 def lower_parallel_call(
     channels,
@@ -2903,5 +2900,4 @@ def lower_parallel_call(
         timeout_ms=timeout_ms,
         max_reproposals=0,
     )
-    mc_lowered_dispatches << 1
     return out["results"]

@@ -26,9 +26,10 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 from typing import Callable, List, Optional, Tuple
 
-from incubator_brpc_tpu.bvar import Adder
+from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
 from incubator_brpc_tpu.rpc.channel import Channel, ChannelOptions
 from incubator_brpc_tpu.rpc.controller import RETRIABLE, Controller
 from incubator_brpc_tpu.utils.endpoint import EndPoint
@@ -36,10 +37,86 @@ from incubator_brpc_tpu.utils.status import ErrorCode, berror
 
 logger = logging.getLogger(__name__)
 
-# /vars observability for the collective lowering: how many combo calls
-# fused into one shard_map dispatch vs ran the host fan-out
-fused_dispatches = Adder(name="parallel_channel_fused")
-host_fanouts = Adder(name="parallel_channel_host_fanout")
+# the stages of a fused call, in order: their means add up to the call's
+# but for the CallMapper's own time. One row a fused call waits in the
+# feed for bvar's 1 Hz sampler, as rpc/stream.py's do
+FUSED_STAGES = (
+    "resolve", "pack", "put", "launch_wait", "launch", "gather", "merge",
+)
+
+
+class _ComboVars:
+    """The collective lowering's recorders and adders, under the device
+    link's prefix (docs/OBSERVABILITY.md). A call counts in exactly one of
+    ``fused`` (one shard_map dispatch), ``mc_lowered`` (a session of the
+    collective method plane) and ``host_fanout``."""
+
+    def __init__(self, prefix: str = "device_link_combo"):
+        self.calls = RecorderFeed(tuple(
+            (LatencyRecorder(name=f"{prefix}_{what}_us"), 1e-3)
+            for what in ("call",) + FUSED_STAGES
+        ))
+        self.fused = Adder(name=f"{prefix}_fused")
+        self.host_fanout = Adder(name=f"{prefix}_host_fanout")
+        self.mc_lowered = Adder(name=f"{prefix}_mc_lowered")
+        self.rows = Adder(name=f"{prefix}_rows")  # sub-requests a fused program ran
+        self.bytes = Adder(name=f"{prefix}_bytes")  # request bytes scattered in them
+
+
+COMBO_VARS = _ComboVars()
+
+
+class _FusedCall:
+    """What one lowered call leaves behind: the lowering taken, its
+    partitions and the stamps (ns) its stages are cut at."""
+
+    __slots__ = ("service", "method", "lowering", "devices", "nbytes", "stamps")
+
+    def __init__(self, service: str, method: str, t_call: int):
+        self.service, self.method = service, method
+        self.lowering = ""
+        self.devices: list = []
+        self.nbytes = 0
+        self.stamps = [t_call]
+
+    def stamp(self) -> None:
+        self.stamps.append(time.monotonic_ns())
+
+    def record(self) -> None:
+        """The call is over: the adders, one row for the sampler and, under
+        rpcz, the call's one span."""
+        from incubator_brpc_tpu.builtin.rpcz import (
+            SPAN_TYPE_COLLECTIVE,
+            end_custom_span,
+            start_custom_span,
+        )
+
+        end = time.monotonic_ns()
+        stages = None
+        if self.lowering == "fused":
+            t = self.stamps
+            stages = tuple(b - a for a, b in zip(t[1:], t[2:]))
+            COMBO_VARS.calls.rows.append((end - t[0],) + stages)
+            COMBO_VARS.fused << 1
+            COMBO_VARS.rows << len(self.devices)
+            COMBO_VARS.bytes << self.nbytes
+        else:
+            COMBO_VARS.mc_lowered << 1
+        span = start_custom_span(SPAN_TYPE_COLLECTIVE, self.service, self.method)
+        if span is None:
+            return
+        span.start_real_us -= (end - self.stamps[0]) // 1000
+        note = (
+            f"lowering={self.lowering} partitions={len(self.devices)} "
+            f"devices={[getattr(d, 'id', None) for d in self.devices]} "
+            f"request_bytes={self.nbytes}"
+        )
+        if stages is not None:
+            note += " " + " ".join(
+                f"{what}_us={ns / 1e3:.0f}" for what, ns in zip(FUSED_STAGES, stages)
+            )
+        span.annotate(note)
+        end_custom_span(span)
 
 
 # -- ParallelChannel ---------------------------------------------------------
@@ -136,12 +213,26 @@ class ParallelChannel:
         request: bytes,
         cntl: Optional[Controller] = None,
         done: Optional[Callable[[Controller], None]] = None,
+        attachment: bytes = b"",
+        request_stream=None,
     ) -> Controller:
+        """What ``Channel.call_method`` takes. The attachment goes to every
+        non-skipped sub-call as it is (parallel_channel.cpp appends the
+        parent's to each sub-controller's) and the sub-calls' response
+        attachments come back joined in channel order; a call that carries
+        one does not fuse, for a device kernel sees request bytes only. A
+        stream rides one connection, which a combo channel has not."""
+        t_call = time.monotonic_ns()
         if cntl is None:
             cntl = Controller()
         nchan = len(self._subs)
-        if nchan == 0:
-            cntl.set_failed(ErrorCode.EINVAL, "ParallelChannel has no sub channels")
+        refused = (
+            "ParallelChannel has no sub channels" if nchan == 0
+            else "a combo channel carries no stream" if request_stream is not None
+            else None
+        )
+        if refused:
+            cntl.set_failed(ErrorCode.EINVAL, refused)
             if done:
                 done(cntl)
             return cntl
@@ -156,10 +247,11 @@ class ParallelChannel:
             if done:
                 done(cntl)
             return cntl
-        if self.fuse_device_calls and ndone >= 2:
+        if self.fuse_device_calls and ndone >= 2 and not attachment:
+            call = _FusedCall(service, method, t_call)
             try:
                 fused = self._maybe_fused_device_call(
-                    service, method, request, plan, cntl
+                    service, method, request, plan, cntl, call
                 )
             except Exception as e:
                 logger.exception("fused collective dispatch failed")
@@ -171,13 +263,13 @@ class ParallelChannel:
                     done(cntl)
                 return cntl
             if fused is not None:
-                fused_dispatches << 1
                 cntl.response_payload = fused
                 cntl.collective_fused = True
+                call.record()
                 if done is not None:
                     done(cntl)
                 return cntl
-        host_fanouts << 1
+        COMBO_VARS.host_fanout << 1
 
         # 1 <= fail_limit <= ndone (parallel_channel.cpp:625-637)
         fail_limit = self.fail_limit
@@ -204,14 +296,16 @@ class ParallelChannel:
                     f"(fail_limit={fail_limit}): {text}",
                 )
             else:
-                merged = b""
+                merged, attached = b"", []
                 for i, p in enumerate(plan):
                     if p is None:
                         continue
                     sc = sub_cntls[i]
                     if sc is not None and sc.ok():
                         merged = p[1].merge(merged, sc.response_payload)
+                        attached.append(sc.response_attachment)
                 cntl.response_payload = merged
+                cntl.response_attachment = b"".join(attached)
             all_done.set()
             if done is not None:
                 done(cntl)
@@ -252,6 +346,7 @@ class ParallelChannel:
                 request if sub.request is None else sub.request,
                 cntl=sc,
                 done=(lambda c, _i=i: sub_done(_i, c)),
+                attachment=attachment,
             )
         if done is None:
             all_done.wait()
@@ -262,7 +357,7 @@ class ParallelChannel:
     # -- the ICI collective lowering (SURVEY §2.5; BASELINE #3/#4) -----------
 
     def _maybe_fused_device_call(
-        self, service, method, request, plan, cntl
+        self, service, method, request, plan, cntl, call: "_FusedCall"
     ) -> Optional[bytes]:
         """One shard_map dispatch over the sub-channels' server devices, or
         None when the preconditions don't hold (host fan-out runs instead).
@@ -272,11 +367,11 @@ class ParallelChannel:
         non-skipped sub-channel uses transport='tpu' and resolves a live
         device link; the links' server devices are pairwise distinct (they
         form the mesh axis); every sub-request fits the kernel row width.
+        ``call`` takes the lowering chosen and the stamps of its stages.
         """
-        import time as _time
-
         from incubator_brpc_tpu.rpc.device_method import lookup_device_method
 
+        call.stamp()  # resolve: LB picks and fingerprint checks
         dm = lookup_device_method(service, method)
         if dm is None:
             return None
@@ -337,12 +432,14 @@ class ParallelChannel:
         # devices) — they lower through the collective method plane
         # instead: one 1-step N-party session of the SAME kernel over the
         # same axis, scheduled over the host plane (parallel/mc_dispatch)
+        call.devices, call.nbytes = devices, sum(len(r) for r in requests)
         mc = [getattr(lk, "own_side", None) is not None for lk in links]
         if any(mc):
             if not all(mc):
                 _settle_probes()
                 return None  # mixed planes cannot form one party axis
-            t0 = _time.perf_counter()
+            call.lowering = "mc_lowered"
+            t0 = time.perf_counter()
             try:
                 from incubator_brpc_tpu.parallel import mc_dispatch
 
@@ -357,7 +454,7 @@ class ParallelChannel:
             except Exception:
                 _settle_probes()
                 raise
-            latency_us = (_time.perf_counter() - t0) * 1e6
+            latency_us = (time.perf_counter() - t0) * 1e6
             for pch, pds in probed:
                 if pch._lb is not None:
                     pch._lb.feedback(pds, latency_us, 0)
@@ -365,15 +462,16 @@ class ParallelChannel:
             for pos, (_i, (ch, merger, _sub)) in enumerate(subs):
                 merged = merger.merge(merged, outs[pos])
             return merged
-        t0 = _time.perf_counter()
+        call.lowering = "fused"
+        call.stamp()  # pack
         try:
-            rows_out, ns_out = self._fused_dispatch(dm, devices, requests)
+            rows_out, ns_out = self._fused_dispatch(dm, devices, requests, call)
         except Exception:
             _settle_probes()
             raise
         # the servers DID serve this dispatch: settle each LB pick with the
         # real fused latency (the host path's per-sub feedback analog)
-        latency_us = (_time.perf_counter() - t0) * 1e6
+        latency_us = (call.stamps[-1] - call.stamps[2]) / 1e3
         for pch, pds in probed:
             if pch._lb is not None:
                 pch._lb.feedback(pds, latency_us, 0)
@@ -382,9 +480,14 @@ class ParallelChannel:
         merged = b""
         for pos, (_i, (ch, merger, _sub)) in enumerate(subs):
             merged = merger.merge(merged, dm.unpack(rows_out[pos], ns_out[pos]))
+        call.stamp()  # the merge is over
         return merged
 
-    def _fused_dispatch(self, dm, devices, requests: List[bytes]):
+    def _fused_dispatch(self, dm, devices, requests: List[bytes], call: "_FusedCall"):
+        """Pack, put, launch and gather; one stamp of ``call`` closes each
+        stage. Only the enqueue of the program is ordered across threads
+        (``collective.launch_order``): the host packing before it and the
+        read-back after it run beside other callers'."""
         import jax
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -403,7 +506,8 @@ class ParallelChannel:
             mesh = Mesh(np.asarray(devices), ("par",))
             data_sh = NamedSharding(mesh, P("par"))
 
-            def body(data, ns):
+            # named for the device trace: the program is jit_combo_fused
+            def combo_fused(data, ns):
                 # per-partition service execution on this shard's device...
                 out, m = dm.kernel(data[0], ns[0])
                 # ...then ONE all-gather returns every response everywhere
@@ -414,7 +518,7 @@ class ParallelChannel:
             # the all_gather makes outputs replicated, which the static
             # replication check cannot always infer — turn it off
             wrapped = jax.shard_map(
-                body, mesh=mesh, in_specs=(P("par"), P("par")),
+                combo_fused, mesh=mesh, in_specs=(P("par"), P("par")),
                 out_specs=(P(), P()), check_vma=False,
             )
             fused = jax.jit(wrapped)
@@ -423,6 +527,7 @@ class ParallelChannel:
         fused, data_sh, mesh, _ = cached
         rows = np.stack([dm.pack(r)[0] for r in requests])
         ns = np.asarray([len(r) for r in requests], dtype=np.int32)
+        call.stamp()  # put: device_puts and array assembly
         data = jax.make_array_from_single_device_arrays(
             (n, dm.width),
             data_sh,
@@ -433,8 +538,14 @@ class ParallelChannel:
             data_sh,
             [jax.device_put(ns[i : i + 1], devices[i]) for i in range(n)],
         )
-        g, gm = fused(data, ns_sharded)
-        return np.asarray(g), np.asarray(gm)
+        call.stamp()  # launch_wait
+        with collective.launch_order:
+            call.stamp()  # launch: the program call until it returned
+            g, gm = fused(data, ns_sharded)
+        call.stamp()  # gather: the gathered rows on the host
+        out = np.asarray(g), np.asarray(gm)
+        call.stamp()  # merge: LB feedback, unpack and the mergers
+        return out
 
 
 # -- SelectiveChannel --------------------------------------------------------
@@ -597,6 +708,7 @@ class SelectiveChannel:
         request: bytes,
         cntl: Optional[Controller] = None,
         done: Optional[Callable[[Controller], None]] = None,
+        attachment: bytes = b"",
     ) -> Controller:
         if cntl is None:
             cntl = Controller(max_retry=self.max_retry)
@@ -611,10 +723,11 @@ class SelectiveChannel:
             from incubator_brpc_tpu.runtime.worker_pool import global_worker_pool
 
             global_worker_pool().spawn(
-                self._call_blocking, service, method, request, cntl, done
+                self._call_blocking, service, method, request, cntl, done,
+                attachment,
             )
             return cntl
-        return self._call_blocking(service, method, request, cntl, None)
+        return self._call_blocking(service, method, request, cntl, None, attachment)
 
     def _call_blocking(
         self,
@@ -623,6 +736,7 @@ class SelectiveChannel:
         request: bytes,
         cntl: Controller,
         done: Optional[Callable[[Controller], None]],
+        attachment: bytes = b"",
     ) -> Controller:
         import time as _time
 
@@ -659,7 +773,7 @@ class SelectiveChannel:
             )
             sc.compress_type = cntl.compress_type
             sc.log_id = cntl.log_id
-            sub.call_method(service, method, request, cntl=sc)
+            sub.call_method(service, method, request, cntl=sc, attachment=attachment)
             last = sc
             # only a LATER attempt can be budget-starved: the first one
             # had the whole deadline, so its timeout indicts the replica
@@ -916,6 +1030,8 @@ class DynamicPartitionChannel:
         request: bytes,
         cntl: Optional[Controller] = None,
         done: Optional[Callable[[Controller], None]] = None,
+        attachment: bytes = b"",
+        request_stream=None,
     ) -> Controller:
         pc = self._pick_scheme()
         if pc is None:
@@ -925,6 +1041,9 @@ class DynamicPartitionChannel:
             if done:
                 done(cntl)
             return cntl
-        return pc.call_method(service, method, request, cntl=cntl, done=done)
+        return pc.call_method(
+            service, method, request, cntl=cntl, done=done,
+            attachment=attachment, request_stream=request_stream,
+        )
 
     call = call_method

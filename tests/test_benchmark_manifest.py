@@ -109,6 +109,22 @@ STREAM = {
     "stream_write_wait_us": recorder(7, 9e9),
     "stream_messages": 9_000_000,
 }
+# a fused combo channel's counters over a window (PR 33): 200 calls of
+# 1,000 us whose seven stages cover 900, three rows of 1 MiB each
+COMBO_STAGES = {
+    "resolve": 50.0, "pack": 150.0, "put": 200.0, "launch_wait": 25.0,
+    "launch": 125.0, "gather": 250.0, "merge": 100.0,
+}
+COMBO = {
+    "device_link_combo_call_us": recorder(200, 1000.0),
+    **{f"device_link_combo_{s}_us": recorder(200, us)
+       for s, us in COMBO_STAGES.items()},
+    "device_link_combo_fused": 200,
+    "device_link_combo_host_fanout": 40,
+    "device_link_combo_mc_lowered": 10,
+    "device_link_combo_rows": 600,
+    "device_link_combo_bytes": 600 << 20,
+}
 HBM_DISPATCHED = (
     100.0 * 4 * (2 * 128 * 64 + roofline.FRAME_HEADER_WORDS * 128)
     / 819e9 / (50 * 2_000 / 1e9)
@@ -142,9 +158,14 @@ EXPECTED = {
     "stream_deliver_us": (STREAM, 450.0),
     "stream_window_used_pct": (STREAM, 50.0),
     "stream_messages_per_batch": (STREAM, 2.0),
+    "combo_call_us": (COMBO, 1000.0),
+    **{f"combo_{s}_us": (COMBO, us) for s, us in COMBO_STAGES.items()},
+    "combo_unattributed_pct": (COMBO, 10.0),
+    "combo_fused_pct": (COMBO, 80.0),
 }
-# PR 31's device_trace reader: not a counter's mean, so outside EXPECTED
-TRACE_READERS = {"link_step_ici_pct"}
+# PR 31's and PR 33's device_trace readers: not a counter's mean, so
+# outside EXPECTED
+TRACE_READERS = {"link_step_ici_pct", "combo_step_kernel_us", "combo_gather_ici_pct"}
 # what the benchmark had before PR 25 reads no recorder this PR added
 OLDER = {
     "host_plane_us", "device_path_us", "calls_per_dispatch",
@@ -189,6 +210,9 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
         if name.startswith("link_"):
             # every link cell drives the link: PR 31's joined them
             assert {"link_echo_ici_1m", "link_stream_ici"} <= set(cells[name]), name
+        elif name.startswith("combo_"):
+            # only the partitioned deployment builds a combo channel
+            assert cells[name] == ["partition_star_4"], name
         elif name.startswith("stream_"):
             # only the streaming deployment opens a stream
             assert cells[name] == ["link_stream_ici"], name
@@ -219,6 +243,9 @@ def test_each_configuration_is_the_file_the_manifest_names():
         "link_performance_ici": ("link_echo", "echo_identity", None, None),
         # the stream rides the link link_performance_ici states
         "link_stream_sink_ici": ("link_stream", "stream_sink", link_options, None),
+        # the star is three of that link
+        "partition_echo_ici": (
+            "partition_echo", "partition_concat", link_options, None),
     }
     configs = {c["name"]: c for c in BENCH["configs"]}
     assert set(expected) <= set(configs)  # a later PR may add more
@@ -336,6 +363,75 @@ def test_link_step_ici_share_from_a_fabricated_trace():
         "steps": xplane.Events([], [], []),
     }
     assert read(run) == pytest.approx(share)
+
+
+def gather_run(counters: dict):
+    """Three shard chips, each with 100 executions of the fused program in
+    the window: an ``all-gather`` of 20 us and the copy after it inside a
+    program execution of 50 us, a link's exchange program that is not the
+    combo's, and one all-gather outside the window."""
+    run = hand_made_run(counters)
+    start = T_OPEN + np.arange(100, dtype=np.int64) * 1_000_000
+    names = (
+        ["%all-gather.5 = u8[3,1,1048576]{2,1,0} all-gather(u8[1,1048576] "
+         "%bitcast.3), channel_id=1, replica_groups={{0,1,2}}"] * 101
+        + ["%copy_bitcast_fusion = u8[3,1048576]{1,0} fusion(%all-gather.5)"] * 100
+    )
+    starts = np.concatenate((start, [T_CLOSE + 5_000], start + 20_000))
+    ends = np.concatenate((start + 20_000, [T_CLOSE + 9_000], start + 50_000))
+    ops = xplane.Events(names, starts, ends)
+    steps = xplane.Events(
+        ["jit_combo_fused(123)"] * 100 + ["jit_exchange(9)"] * 5,
+        np.concatenate((start, start[:5] + 500_000)),
+        np.concatenate((start + 50_000, start[:5] + 900_000)),
+    )
+    run.devices = {
+        f"/device:TPU:{i}": {"ops": ops, "steps": steps} for i in (1, 2, 3)
+    }
+    run.cell = types.SimpleNamespace(
+        config={"partitions": 3, "row_bytes": 1048576})
+    return run
+
+
+def test_combo_device_readers_from_a_fabricated_trace():
+    kernel = manifest.load_module("layers", "combo_step_kernel_us.py").read
+    share = manifest.load_module("layers", "combo_gather_ici_pct.py").read
+    run = gather_run(dict(COMBO))
+    assert kernel(run) == pytest.approx(50.0)  # the exchange program is not read
+    # 200 calls, the other two 1 MiB rows each, in 100 x 20 us of all-gather
+    # on each chip, against the chip's 1,600 Gbit/s
+    want = 100.0 * (200 * 2 * 1048576 / 2e-3) / (1600e9 / 8)
+    assert share(run) == pytest.approx(want)
+    assert 0 < want < 105
+    for spoil in ("calls", "trace", "peaks", "ici", "rows"):
+        run = gather_run(dict(COMBO))
+        if spoil == "calls":
+            run.counters = {}  # a program from before PR 33
+        elif spoil == "trace":
+            run.devices = {}  # a CPU rehearsal: no device plane
+        elif spoil == "peaks":
+            run.peaks = None
+        elif spoil == "ici":
+            run.peaks = {"hbm_bytes_per_s": 819e9}
+        else:
+            run.cell = types.SimpleNamespace(config=STREAM_CONFIG)
+        assert share(run) is None, spoil
+        if spoil == "trace":
+            assert kernel(run) is None
+    # another cell's trace holds no fused program and no all-gather
+    assert kernel(permute_run(dict(COMBO))) is None
+    assert share(permute_run(dict(COMBO))) is None
+
+
+def test_combo_shares_need_every_recorder_and_a_call():
+    unattributed = manifest.load_module("layers", "combo_unattributed_pct.py").read
+    fused = manifest.load_module("layers", "combo_fused_pct.py").read
+    short = dict(COMBO)
+    del short["device_link_combo_merge_us"]
+    assert unattributed(hand_made_run(short)) is None
+    idle = dict(COMBO, device_link_combo_fused=0, device_link_combo_host_fanout=0,
+                device_link_combo_mc_lowered=0)
+    assert fused(hand_made_run(idle)) is None
 
 
 def test_window_share_needs_the_configuration_to_state_a_window():

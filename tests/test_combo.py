@@ -231,7 +231,8 @@ class _ScriptedSub:
         self.healthy = True
         self.calls = 0
 
-    def call_method(self, service, method, request, cntl=None, done=None):
+    def call_method(self, service, method, request, cntl=None, done=None,
+                    attachment=b""):
         self.calls += 1
         if self.healthy:
             cntl.response_payload = b"ok:" + request

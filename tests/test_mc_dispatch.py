@@ -575,7 +575,8 @@ class TestMcLoweringRouting:
         def _pick_socket(self, cntl):
             return self._ds
 
-        def call_method(self, service, method, request, cntl=None, done=None):
+        def call_method(self, service, method, request, cntl=None, done=None,
+                        attachment=b""):
             self.host_calls += 1
             cntl.response_payload = b"host:" + request
             if done:
@@ -675,7 +676,7 @@ class TestMcLoweringRouting:
         from incubator_brpc_tpu.rpc import Controller
         from incubator_brpc_tpu.rpc.combo import ParallelChannel
 
-        def refuse(self, dm, devices, requests):
+        def refuse(self, dm, devices, requests, call):
             raise RuntimeError("XLA refused to compile the fused step")
 
         monkeypatch.setattr(ParallelChannel, "_fused_dispatch", refuse)

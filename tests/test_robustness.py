@@ -326,6 +326,13 @@ class TestServerAutoLimiter:
             for _ in range(20):
                 c = ch.call_method("cap", "work", b"")
                 assert c.ok(), c.error_text
+            # the server counts a response after it has written it, so the
+            # 20th sample (the one that settles the window) can land just
+            # after the client has its answer
+            settle_by = time.monotonic() + 2.0
+            while (srv._server_limiter.describe()["min_latency_us"] <= 0
+                   and time.monotonic() < settle_by):
+                time.sleep(0.01)
             assert srv._server_limiter.describe()["min_latency_us"] > 0, (
                 "baseline window never settled", srv._server_limiter.describe(),
             )
