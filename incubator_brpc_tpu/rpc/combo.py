@@ -485,9 +485,14 @@ class ParallelChannel:
 
     def _fused_dispatch(self, dm, devices, requests: List[bytes], call: "_FusedCall"):
         """Pack, put, launch and gather; one stamp of ``call`` closes each
-        stage. Only the enqueue of the program is ordered across threads
-        (``collective.launch_order``): the host packing before it and the
-        read-back after it run beside other callers'."""
+        stage. The operands are staged once: ``pack`` writes every request
+        into its row of one ``(n, width)`` host buffer made for the call
+        (``DeviceMethod.pack_into``: no zero-fill but a short row's tail),
+        ``put`` hands the rows and the ``n``s to the runtime in one
+        ``device_put`` under the mesh's sharding. Only the enqueue of the
+        program is ordered across threads (``collective.launch_order``):
+        the host packing before it and the read-back after it run beside
+        other callers'."""
         import jax
         import numpy as np
         from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -525,19 +530,14 @@ class ParallelChannel:
             cached = (fused, data_sh, mesh, dm)
             self._fused_cache[key] = cached
         fused, data_sh, mesh, _ = cached
-        rows = np.stack([dm.pack(r)[0] for r in requests])
-        ns = np.asarray([len(r) for r in requests], dtype=np.int32)
-        call.stamp()  # put: device_puts and array assembly
-        data = jax.make_array_from_single_device_arrays(
-            (n, dm.width),
-            data_sh,
-            [jax.device_put(rows[i : i + 1], devices[i]) for i in range(n)],
-        )
-        ns_sharded = jax.make_array_from_single_device_arrays(
-            (n,),
-            data_sh,
-            [jax.device_put(ns[i : i + 1], devices[i]) for i in range(n)],
-        )
+        # one host buffer a call, each request byte written once; the arrays
+        # made from it keep it alive while the runtime still reads it
+        rows = np.empty((n, dm.width), dtype=np.uint8)
+        ns = np.empty(n, dtype=np.int32)
+        for i, r in enumerate(requests):
+            ns[i] = dm.pack_into(rows[i], r)
+        call.stamp()  # put: one hand-over, the runtime cuts the rows itself
+        data, ns_sharded = jax.device_put((rows, ns), data_sh)
         call.stamp()  # launch_wait
         with collective.launch_order:
             call.stamp()  # launch: the program call until it returned

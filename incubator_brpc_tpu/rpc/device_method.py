@@ -37,6 +37,12 @@ DEFAULT_WIDTH = 4096
 class DeviceMethod:
     """A jittable bytes-in/bytes-out kernel with fixed row geometry.
 
+    A request becomes the kernel's operand as a zero-padded row of
+    ``width`` uint8 and its length: ``pack`` makes the row (the host
+    handler, ``mc_dispatch``), ``pack_into`` writes it in place into a row
+    of the caller's buffer (the fused combo call, which stages all its
+    rows in one). The width check and the padding rule are ``pack_into``'s.
+
     ``chunkable=True`` declares the kernel CHUNK-SAFE: applying it to any
     contiguous slice of the row produces the same bytes as slicing the
     full-width result (elementwise along the width, collectives included
@@ -148,14 +154,25 @@ class DeviceMethod:
             return self._jitted
 
     def pack(self, request: bytes) -> Tuple[np.ndarray, np.int32]:
-        if len(request) > self.width:
+        row = np.empty(self.width, dtype=np.uint8)
+        return row, np.int32(self.pack_into(row, request))
+
+    def pack_into(self, row: np.ndarray, request: bytes) -> int:
+        """``pack`` in place: ``request`` written once at the front of
+        ``row`` (``width`` contiguous uint8 of a buffer that need not be
+        zeroed) and only the tail beyond it zeroed, so a kernel that reads
+        its whole row sees what ``pack`` would hand it. Returns ``n``."""
+        n = len(request)
+        if n > self.width:
             raise ValueError(
-                f"request of {len(request)}B exceeds device-method width "
-                f"{self.width}"
+                f"request of {n}B exceeds device-method width {self.width}"
             )
-        row = np.zeros(self.width, dtype=np.uint8)
-        row[: len(request)] = np.frombuffer(request, dtype=np.uint8)
-        return row, np.int32(len(request))
+        # numpy's assignment drops the interpreter lock over a large copy and
+        # keeps it over a small one: callers' 1 MiB rows are copied side by side
+        row[:n] = np.frombuffer(request, dtype=np.uint8)
+        if n < self.width:
+            row[n:] = 0
+        return n
 
     def unpack(self, row, n) -> bytes:
         n = int(n)

@@ -13,6 +13,7 @@ import os
 import subprocess
 import sys
 import threading
+import time
 
 import numpy as np
 import pytest
@@ -38,8 +39,9 @@ def payload(seed: int, size: int) -> bytes:
     return np.random.default_rng(seed).bytes(size)
 
 
-def deploy(control=None):
-    """The configuration as its file states it, but for rows of 512 B."""
+def deploy(control=None, kernel=None):
+    """The configuration as its file states it, but for rows of 512 B (and,
+    where a test gives one, for the kernel its shards serve)."""
     import jax
 
     if len(jax.devices()) < 4:
@@ -47,6 +49,8 @@ def deploy(control=None):
     config = copy.deepcopy(CONFIG)
     config["row_bytes"] = ROW
     module = manifest.load_module("deployments", "partition_echo.py")
+    if kernel is not None:
+        module.echo_kernel = kernel  # a fresh module every load: this one's only
     deployment = module.Deployment(config, control, None)
     deployment.warm(TRAFFIC)
     return module, deployment
@@ -108,6 +112,97 @@ def test_the_reference_refuses_what_does_not_fit_its_rows():
     assert REFERENCE.slices(b"ab", 2, 3) == [b"ab", b"", b""]
     with pytest.raises(ValueError):
         REFERENCE.slices(b"abcdefg", 2, 3)
+
+
+def reversed_kernel(data, n):
+    """Reads its whole row: the answer's first ``n`` bytes are the row's
+    last, so a tail that is not zero shows in every short row's answer."""
+    return data[::-1], n
+
+
+def reversed_answer(row: bytes) -> bytes:
+    """What a shard serving ``reversed_kernel`` answers ``row``, plainly."""
+    return (bytes(ROW - len(row)) + row[::-1])[:len(row)]
+
+
+def broadcast_channel(deployment):
+    """The deployment's shards behind a channel with the default
+    ``CallMapper``: every partition gets the whole request."""
+    from incubator_brpc_tpu.rpc import ChannelOptions, PartitionChannel
+
+    n = deployment.partitions
+    url = "list://" + ",".join(
+        f"127.0.0.1:{s.port} {i}/{n}" for i, s in enumerate(deployment.servers))
+    channel = PartitionChannel(fail_limit=1)
+    assert channel.init(url, partition_count=n, lb_name=CONFIG["lb"],
+                        options=ChannelOptions(**CONFIG["channel_options"]))
+    return channel
+
+
+@pytest.mark.parametrize("n", [0, 1, ROW - 1, ROW])
+def test_pack_into_a_dirty_row_gives_the_row_pack_makes(n):
+    from incubator_brpc_tpu.rpc.device_method import DeviceMethod
+
+    dm = DeviceMethod(reversed_kernel, width=ROW)
+    request = payload(n, n)
+    row, length = dm.pack(request)
+    assert row.dtype == np.uint8 and row.shape == (ROW,) and length.dtype == np.int32
+    assert bytes(row) == request + bytes(ROW - n) and int(length) == n
+    buffer = np.full((3, ROW), 0xAB, dtype=np.uint8)
+    assert dm.pack_into(buffer[1], request) == n
+    assert bytes(buffer[1]) == bytes(row)
+    assert set(bytes(buffer[0]) + bytes(buffer[2])) == {0xAB}  # its own row only
+    for packer in (dm.pack, lambda r: dm.pack_into(buffer[0], r)):
+        with pytest.raises(ValueError, match="exceeds device-method width"):
+            packer(bytes(ROW + 1))
+
+
+# the sizes of the requests one thread sends in turn through one channel; the
+# rows a request is cut into are REFERENCE.slices', so ROW + 1 is (ROW, 1, 0)
+STAGING_CASES = {
+    "rows_of_0": (False, [0]),
+    "a_row_of_1": (False, [1]),
+    "a_row_of_width_less_1": (False, [ROW - 1]),
+    "a_row_of_width": (False, [ROW]),
+    "every_row_of_width": (False, [3 * ROW]),
+    "unequal_rows_width_1_0": (False, [ROW + 1]),
+    "unequal_rows_width_width_less_1": (False, [3 * ROW - 1]),
+    "broadcast_short": (True, [ROW - 9]),
+    "broadcast_of_width": (True, [ROW]),
+    "short_after_full_width": (False, [3 * ROW, 5, 3 * ROW, ROW + 7]),
+    "broadcast_short_after_full_width": (True, [ROW, 3, ROW, 0]),
+}
+
+
+@pytest.mark.parametrize("case", list(STAGING_CASES))
+@limited(120)
+def test_a_kernel_that_reads_its_whole_row_answers_the_same_fused_and_fanned_out(case):
+    """The fused call stages its rows in a buffer that is not zero-filled
+    (``DeviceMethod.pack_into``): a tail left unzeroed, or bytes of an
+    earlier call's buffer, would come back through ``reversed_kernel`` where
+    the host fan-out (``DeviceMethod.pack``) and the plain reference answer
+    zeros."""
+    broadcast, sizes = STAGING_CASES[case]
+    _, deployment = deploy(kernel=reversed_kernel)
+    channel = None
+    try:
+        channel = broadcast_channel(deployment) if broadcast else deployment.channel()
+        for at, size in enumerate(sizes):
+            request = payload(1000 + at, size).replace(b"\x00", b"\x01")
+            rows = [request] * 3 if broadcast else REFERENCE.slices(request, ROW, 3)
+            want = b"".join(reversed_answer(row) for row in rows)
+            channel.fuse_device_calls = True
+            fused = call(channel, request)
+            assert fused.collective_fused is True
+            channel.fuse_device_calls = False
+            host = call(channel, request)
+            assert getattr(host, "collective_fused", False) is False
+            assert fused.response_payload == want, (case, at, "fused")
+            assert host.response_payload == want, (case, at, "host fan-out")
+    finally:
+        if broadcast and channel is not None:
+            channel.stop()
+        deployment.close()
 
 
 @limited(120)
@@ -178,31 +273,39 @@ def test_the_host_fan_out_forwards_the_attachment_and_joins_the_answers():
             server.join(timeout=5)
 
 
+def run_callers(callers: int, calls: int, answered_rightly, seconds: float) -> None:
+    """``callers`` threads make ``calls`` calls each, ``answered_rightly(c,
+    i)`` one of them; none may hang, raise or be answered wrongly."""
+    wrong, errors = [], []
+
+    def caller(c):
+        try:
+            wrong.extend((c, i) for i in range(calls) if not answered_rightly(c, i))
+        except BaseException as e:  # noqa: BLE001 — reported below
+            errors.append(repr(e))
+
+    threads = [threading.Thread(target=caller, args=(c,)) for c in range(callers)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(seconds)
+    assert not any(t.is_alive() for t in threads), "callers hung"
+    assert not errors and not wrong, (errors, wrong)
+
+
 @limited(120)
 def test_four_callers_through_one_fused_channel_each_get_their_own_bytes():
     _, deployment = deploy()
     try:
         channel = deployment.channel()
-        wrong, errors = [], []
 
-        def caller(c):
-            try:
-                for i in range(25):
-                    request = payload(1000 * c + i, 3 * ROW - (i % 5))
-                    cntl = call(channel, request)
-                    if not cntl.collective_fused or cntl.response_payload != request:
-                        wrong.append((c, i))
-            except BaseException as e:  # noqa: BLE001 — reported below
-                errors.append(repr(e))
+        def echoed(c, i):
+            request = payload(1000 * c + i, 3 * ROW - (i % 5))
+            cntl = call(channel, request)
+            return cntl.collective_fused and cntl.response_payload == request
 
         before = combo_vars()
-        threads = [threading.Thread(target=caller, args=(c,)) for c in range(4)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join(90)
-        assert not any(t.is_alive() for t in threads), "callers hung"
-        assert not errors and not wrong, (errors, wrong)
+        run_callers(4, 25, echoed, 90)
         after = combo_vars()
         assert gained(before, after, "fused") == 100
         assert gained(before, after, "host_fanout") == 0
@@ -210,6 +313,82 @@ def test_four_callers_through_one_fused_channel_each_get_their_own_bytes():
         held = {name: (value, ok) for name, value, _l, ok in deployment.holds()}
         assert held["partition_distinct_devices"] == (3, True)
         assert held["partition_geometry"] == ("ppermute", True)
+    finally:
+        deployment.close()
+
+
+@limited(240)
+def test_eight_callers_of_mixed_lengths_each_get_the_answer_to_their_own_request():
+    """The staging buffer's life under threads: 8 callers x 100 calls of
+    seeded, distinct payloads of every length from nothing to three rows
+    (every tenth three full rows) through one channel, the shards serving
+    ``reversed_kernel``, so another call's bytes in a row or in its tail
+    come back as a mismatch."""
+    _, deployment = deploy(kernel=reversed_kernel)
+    try:
+        channel = deployment.channel()
+        sizes = np.random.default_rng(34).integers(0, 3 * ROW + 1, (8, 100))
+        sizes[:, ::10] = 3 * ROW
+
+        def answered(c, i):
+            request = payload(1000 * c + i, int(sizes[c, i])).replace(b"\x00", b"\x01")
+            cntl = call(channel, request)
+            want = b"".join(reversed_answer(r) for r in REFERENCE.slices(request, ROW, 3))
+            return cntl.collective_fused and cntl.response_payload == want
+
+        before = combo_vars()
+        run_callers(8, 100, answered, 200)
+        after = combo_vars()
+        assert gained(before, after, "fused") == 800
+        assert gained(before, after, "host_fanout") == 0
+    finally:
+        deployment.close()
+
+
+@pytest.mark.parametrize("size", [3 * ROW, ROW + 7], ids=["full_width", "short_rows"])
+@limited(120)
+def test_a_fused_call_stages_its_operands_once(size, monkeypatch):
+    """Between the ``pack`` stamp and the ``launch`` stamp of a fused call
+    (PR 34): no ``np.stack``, no zero-filled row, no array assembled from
+    single-device parts, and one ``jax.device_put`` at the most."""
+    import jax
+
+    from incubator_brpc_tpu.rpc import combo
+
+    _, deployment = deploy()
+    try:
+        channel = deployment.channel()
+        call(channel, payload(1, size))  # this size's rows have been through once
+        noted, stamps = [], []
+
+        def noting(module, name):
+            fn = getattr(module, name)
+
+            def noted_call(*args, **kwargs):
+                noted.append((name, threading.get_ident(), time.monotonic_ns()))
+                return fn(*args, **kwargs)
+
+            monkeypatch.setattr(module, name, noted_call)
+
+        for module, name in ((np, "stack"), (np, "zeros"), (jax, "device_put"),
+                             (jax, "make_array_from_single_device_arrays")):
+            noting(module, name)
+        record = combo._FusedCall.record
+
+        def recording(self):
+            stamps.append((threading.get_ident(), list(self.stamps)))
+            return record(self)
+
+        monkeypatch.setattr(combo._FusedCall, "record", recording)
+        request = payload(2, size)
+        cntl = call(channel, request)
+        assert cntl.collective_fused is True and cntl.response_payload == request
+        ((thread, t),) = stamps
+        # t[0] the call, then the stamp each stage starts at, then the end
+        assert len(t) == 2 + len(STAGES)
+        pack, launch = t[1 + STAGES.index("pack")], t[1 + STAGES.index("launch")]
+        staged = [name for name, who, at in noted if who == thread and pack <= at <= launch]
+        assert staged in ([], ["device_put"]), staged
     finally:
         deployment.close()
 
