@@ -1,0 +1,10 @@
+"""Device: the share of the busiest chip's idle time with at least one worker
+span open: a thread of the program is inside a stage it executes. From the
+program's own timelines (``benchmark/timeline.py``); the three
+``idle_*_pct`` add up to 100. ``None`` on a program that keeps no rows."""
+from benchmark import timeline
+
+
+def read(run):
+    shares = timeline.idle_shares(run)
+    return None if shares is None else shares["worker_open"]

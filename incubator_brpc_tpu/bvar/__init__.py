@@ -16,8 +16,10 @@ from incubator_brpc_tpu.bvar.recorder import (
     IntRecorder,
     LatencyRecorder,
     RecorderFeed,
+    feeds,
 )
-from incubator_brpc_tpu.bvar.window import Window, PerSecond
+from incubator_brpc_tpu.bvar.ring import CPU_CLOCK_EVERY, Ring, clocks
+from incubator_brpc_tpu.bvar.window import Window, PerSecond, sampler_passes
 from incubator_brpc_tpu.bvar.percentile import Percentile
 
 __all__ = [
@@ -31,6 +33,11 @@ __all__ = [
     "IntRecorder",
     "LatencyRecorder",
     "RecorderFeed",
+    "feeds",
+    "Ring",
+    "CPU_CLOCK_EVERY",
+    "clocks",
+    "sampler_passes",
     "Window",
     "PerSecond",
     "Percentile",

@@ -8,11 +8,15 @@ window), max latency (Maxer window), qps (PerSecond of a count Adder).
 from __future__ import annotations
 
 import threading
+import weakref
 from collections import deque
 from typing import Optional
 
+import numpy as np
+
 from incubator_brpc_tpu.bvar.variable import Variable
 from incubator_brpc_tpu.bvar.reducer import Adder, Maxer
+from incubator_brpc_tpu.bvar.ring import Ring
 from incubator_brpc_tpu.bvar.window import PerSecond, Window, sample_every_second
 from incubator_brpc_tpu.bvar.percentile import Percentile
 
@@ -130,39 +134,122 @@ class LatencyRecorder(Variable):
 class RecorderFeed:
     """Rows of numbers on their way to a row of LatencyRecorders: the
     write path is one ``rows.append(tuple)``, and the 1 Hz sampler thread
-    feeds each recorder its column through ``record_batch`` — count, sum
-    and max exact, the percentile reservoir given one row of 16, the
-    recorders up to a second behind. For timelines stamped on a hot path:
-    ten ``<<`` a call, on the caller's thread, cost 5% of the calls/s of a
-    256-byte device echo (PERF.md, PR 25).
+    does the arithmetic and feeds each recorder through ``record_batch``
+    — count, sum and max exact, the percentile reservoir given one row of
+    16, the recorders up to a second behind. For timelines stamped on a
+    hot path: ten ``<<`` a call, on the caller's thread, cost 5% of the
+    calls/s of a 256-byte device echo (PERF.md, PR 25).
 
-    ``columns``: ``(recorder, scale)`` per position of a row; a value is
+    A row holds whole numbers: absolute stamps (ns) and counts, by the
+    positions ``stamps`` names. ``columns`` is the table of what is fed:
+    ``(recorder, scale, span)`` each. ``span`` is one position, whose value
+    is fed as it stands (a count; left out, the column's own index), a
+    ``(begin, end)`` pair of positions, fed as their difference, or a
+    tuple of such pairs, fed as the differences' sum. A value is
     multiplied by ``scale`` on its way in (1e-3 for ns into a us
-    recorder), and a ``None`` is skipped."""
+    recorder). A number under 0 (``MISSING``) is a stamp never taken or a
+    count never seen: the row feeds that column nothing. 0 is a reading
+    like any other (a new thread's CPU clock).
 
-    def __init__(self, columns):
-        self.columns = tuple(columns)
+    With ``ring_rows`` the rows the sampler has fed stay, the last
+    ``ring_rows`` of them, for ``timeline()``; ``name`` lists the feed in
+    ``feeds()``. ``worker`` and ``call`` declare which ``(begin, end)``
+    pairs a reader of the timeline may take as spans: a thread of the
+    program inside a stage it executes, both stamps its own; a call, step
+    or write from entry to exit (docs/OBSERVABILITY.md has the tables)."""
+
+    MISSING = -1
+
+    def __init__(
+        self, columns, stamps=None, name=None, ring_rows=0, worker=(), call=()
+    ):
+        columns = tuple(columns)
+        self.stamps = tuple(
+            stamps if stamps is not None else range(len(columns))
+        )
+        at = {stamp: i for i, stamp in enumerate(self.stamps)}
+        self.columns = []  # (recorder, scale, begin positions, end positions)
+        for i, (recorder, scale, *span) in enumerate(columns):
+            span = span[0] if span else self.stamps[i]
+            if not isinstance(span, tuple):
+                begins, ends = [at[span]], None  # a value as it stands
+            else:
+                pairs = span if isinstance(span[0], tuple) else (span,)
+                begins = [at[b] for b, _ in pairs]
+                ends = [at[e] for _, e in pairs]
+            self.columns.append((recorder, scale, begins, ends))
+        self.name, self.worker, self.call = name, tuple(worker), tuple(call)
         # bounded, so a starved sampler drops the oldest rows, not memory
         self.rows: deque = deque(maxlen=1 << 16)
+        self.ring = Ring(self.stamps, ring_rows) if ring_rows else None
+        self._feeding = threading.Lock()  # one flush at a time: never the writer
+        if name is not None:
+            _feeds[name] = self
         sample_every_second(self)
+
+    def _values(self, table: np.ndarray):
+        """``(recorder, scale, values, fed)`` a column: what each row of
+        ``table`` gives it, and which rows give it anything."""
+        for recorder, scale, begins, ends in self.columns:
+            if ends is None:
+                values = table[:, begins[0]]
+                fed = values >= 0
+            else:
+                begin, end = table[:, begins], table[:, ends]
+                values = (end - begin).sum(axis=1)
+                fed = ((begin >= 0) & (end >= 0)).all(axis=1)
+            yield recorder, scale, values, fed
+
+    def read(self, row) -> list:
+        """What one row gives each column, unscaled, in the table's order;
+        ``None`` where it gives nothing."""
+        table = np.array([row], dtype=np.int64)
+        return [
+            int(values[0]) if fed[0] else None
+            for _recorder, _scale, values, fed in self._values(table)
+        ]
 
     def flush(self) -> None:
         """Feed every row that waits now (tests; a reader that wants the
-        last rows counted)."""
-        rows = []
-        try:
-            while True:
-                rows.append(self.rows.popleft())
-        except IndexError:
-            pass
-        if not rows:
-            return
-        for (recorder, scale), column in zip(self.columns, zip(*rows)):
-            column = [v for v in column if v is not None]
-            if column:
-                recorder.record_batch(
-                    len(column), sum(column) * scale, max(column) * scale,
-                    [v * scale for v in column[::16]],
-                )
+        last rows counted), then keep them in the ring."""
+        with self._feeding:
+            rows = []
+            try:
+                while True:
+                    rows.append(self.rows.popleft())
+            except IndexError:
+                pass
+            if not rows:
+                return
+            table = np.array(rows, dtype=np.int64)
+            for recorder, scale, values, fed in self._values(table):
+                if not fed.all():
+                    values = values[fed]
+                if len(values):
+                    recorder.record_batch(
+                        len(values), int(values.sum()) * scale,
+                        int(values.max()) * scale,
+                        (values[::16] * scale).tolist(),
+                    )
+            if self.ring is not None:
+                self.ring.extend(table)
 
     _take_sample = flush  # what the sampler thread calls
+
+    def timeline(self):
+        """``(stamps, rows)``: what waits is fed, then the ring's rows as
+        an int64 array, oldest first, a column a position of ``stamps``.
+        ``None`` for a feed that keeps no rows."""
+        if self.ring is None:
+            return None
+        self.flush()
+        return self.ring.read()
+
+
+# feeds that keep their rows, by name, while their owner lives
+_feeds: "weakref.WeakValueDictionary" = weakref.WeakValueDictionary()
+
+
+def feeds() -> dict:
+    """``{name: RecorderFeed}`` of the live feeds that were given a name."""
+    return dict(_feeds)

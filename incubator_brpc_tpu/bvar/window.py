@@ -14,6 +14,9 @@ import weakref
 from collections import deque
 from typing import Deque, Optional, Tuple
 
+import numpy as np
+
+from incubator_brpc_tpu.bvar.ring import Ring, clocks
 from incubator_brpc_tpu.bvar.variable import Variable
 
 _MAX_WINDOW = 3600
@@ -29,6 +32,10 @@ class _SamplerThread:
         self._samplers: list = []
         self._lock = threading.Lock()
         self._started = False
+        # each pass's own begin and end on both clocks: the pass holds the
+        # interpreter while it feeds, so a reader can rule it in or out as
+        # the holder of a silence (four minutes of passes)
+        self.passes = Ring(("begin", "end", "begin_cpu", "end_cpu"), 256)
 
     def register(self, sampler: "Window") -> None:
         with self._lock:
@@ -41,6 +48,7 @@ class _SamplerThread:
     def _run(self) -> None:
         while True:
             start = time.monotonic()
+            begin, begin_cpu = clocks()
             with self._lock:
                 refs = list(self._samplers)
             dead = False
@@ -56,11 +64,21 @@ class _SamplerThread:
             if dead:
                 with self._lock:
                     self._samplers = [r for r in self._samplers if r() is not None]
+            end, end_cpu = clocks()
+            self.passes.extend(
+                np.array([[begin, end, begin_cpu, end_cpu]], dtype=np.int64)
+            )
             elapsed = time.monotonic() - start
             time.sleep(max(0.0, 1.0 - elapsed))
 
 
 _sampler_thread = _SamplerThread()
+
+
+def sampler_passes() -> tuple:
+    """``(names, rows)``: begin and end of the sampler thread's last
+    passes, ``time.monotonic_ns()`` and its ``time.thread_time_ns()``."""
+    return _sampler_thread.passes.read()
 
 
 def sample_every_second(sampler) -> None:

@@ -24,12 +24,19 @@ Kept semantics:
 
 from __future__ import annotations
 
+import itertools
 import logging
 import threading
 import time
 from typing import Callable, List, Optional, Tuple
 
-from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
+from incubator_brpc_tpu.bvar import (
+    CPU_CLOCK_EVERY,
+    Adder,
+    LatencyRecorder,
+    RecorderFeed,
+    clocks,
+)
 from incubator_brpc_tpu.rpc.channel import Channel, ChannelOptions
 from incubator_brpc_tpu.rpc.controller import RETRIABLE, Controller
 from incubator_brpc_tpu.utils.endpoint import EndPoint
@@ -43,6 +50,21 @@ logger = logging.getLogger(__name__)
 FUSED_STAGES = (
     "resolve", "pack", "put", "launch_wait", "launch", "gather", "merge",
 )
+# the stages that run on the caller's processor (launch_wait parks for the
+# launch order, resolve is not timed twice): <stage>_cpu_us beside <stage>_us.
+# One fused call in bvar.CPU_CLOCK_EVERY carries the CPU stamps, the others -1
+FUSED_CPU_STAGES = ("pack", "put", "launch", "gather", "merge")
+_fused_calls = itertools.count()
+# A fused call's row: call_method entered, the stamp that opens each stage
+# (time.monotonic_ns()), the merge's end and the call's, then the caller's
+# CPU clock (time.thread_time_ns()) at the same stamps from resolve to
+# merged: every stamp is the caller's own thread's.
+_FUSED_WALL = ("call",) + FUSED_STAGES + ("merged", "end")
+FUSED_STAMPS = _FUSED_WALL + tuple(
+    what + "_cpu" for what in FUSED_STAGES + ("merged",)
+)
+# a stage lies between the stamp of its name and the next one
+_FUSED_SPAN = dict(zip(FUSED_STAGES, zip(FUSED_STAGES, _FUSED_WALL[2:])))
 
 
 class _ComboVars:
@@ -52,10 +74,24 @@ class _ComboVars:
     collective method plane) and ``host_fanout``."""
 
     def __init__(self, prefix: str = "device_link_combo"):
-        self.calls = RecorderFeed(tuple(
-            (LatencyRecorder(name=f"{prefix}_{what}_us"), 1e-3)
-            for what in ("call",) + FUSED_STAGES
-        ))
+        def recorder(what: str) -> LatencyRecorder:
+            return LatencyRecorder(name=f"{prefix}_{what}_us")
+
+        # the last 16 Ki rows stay (30 s of the partitioned cell, twice over)
+        self.calls = RecorderFeed(
+            [(recorder("call"), 1e-3, ("call", "end"))]
+            + [(recorder(s), 1e-3, _FUSED_SPAN[s]) for s in FUSED_STAGES]
+            + [
+                (recorder(s + "_cpu"), 1e-3,
+                 tuple(at + "_cpu" for at in _FUSED_SPAN[s]))
+                for s in FUSED_CPU_STAGES
+            ],
+            stamps=FUSED_STAMPS,
+            name=f"{prefix}_calls",
+            ring_rows=1 << 14,
+            worker=tuple(_FUSED_SPAN[s] for s in FUSED_STAGES if s != "launch_wait"),
+            call=(("call", "end"),),
+        )
         self.fused = Adder(name=f"{prefix}_fused")
         self.host_fanout = Adder(name=f"{prefix}_host_fanout")
         self.mc_lowered = Adder(name=f"{prefix}_mc_lowered")
@@ -68,9 +104,15 @@ COMBO_VARS = _ComboVars()
 
 class _FusedCall:
     """What one lowered call leaves behind: the lowering taken, its
-    partitions and the stamps (ns) its stages are cut at."""
+    partitions and the stamps its stages are cut at, on both clocks
+    (``stamps`` wall from ``call_method``'s entry, ``cpu`` the caller's
+    CPU clock from the first ``stamp()``, -1 in a call that is not
+    ``timed``)."""
 
-    __slots__ = ("service", "method", "lowering", "devices", "nbytes", "stamps")
+    __slots__ = (
+        "service", "method", "lowering", "devices", "nbytes", "stamps", "cpu",
+        "timed",
+    )
 
     def __init__(self, service: str, method: str, t_call: int):
         self.service, self.method = service, method
@@ -78,9 +120,13 @@ class _FusedCall:
         self.devices: list = []
         self.nbytes = 0
         self.stamps = [t_call]
+        self.cpu: list = []
+        self.timed = next(_fused_calls) % CPU_CLOCK_EVERY == 0
 
     def stamp(self) -> None:
-        self.stamps.append(time.monotonic_ns())
+        wall, cpu = clocks(self.timed)
+        self.stamps.append(wall)
+        self.cpu.append(cpu)
 
     def record(self) -> None:
         """The call is over: the adders, one row for the sampler and, under
@@ -92,11 +138,10 @@ class _FusedCall:
         )
 
         end = time.monotonic_ns()
-        stages = None
+        row = None
         if self.lowering == "fused":
-            t = self.stamps
-            stages = tuple(b - a for a, b in zip(t[1:], t[2:]))
-            COMBO_VARS.calls.rows.append((end - t[0],) + stages)
+            row = (*self.stamps, end, *self.cpu)
+            COMBO_VARS.calls.rows.append(row)
             COMBO_VARS.fused << 1
             COMBO_VARS.rows << len(self.devices)
             COMBO_VARS.bytes << self.nbytes
@@ -111,7 +156,8 @@ class _FusedCall:
             f"devices={[getattr(d, 'id', None) for d in self.devices]} "
             f"request_bytes={self.nbytes}"
         )
-        if stages is not None:
+        if row is not None:
+            stages = COMBO_VARS.calls.read(row)[1 : 1 + len(FUSED_STAGES)]
             note += " " + " ".join(
                 f"{what}_us={ns / 1e3:.0f}" for what, ns in zip(FUSED_STAGES, stages)
             )

@@ -66,6 +66,11 @@ CONNECTED = 2
 CLOSED = 3
 
 
+# rows of each feed that stay for a timeline's reader: 30 s of a stream of
+# 1 MiB messages many times over
+_RING_ROWS = 1 << 13
+
+
 class _StreamVars:
     """One namespace of stream recorders and adders. Rows wait in the
     feeds for the sampler thread; ``flush`` feeds them now (tests)."""
@@ -77,17 +82,40 @@ class _StreamVars:
         def adder(what: str) -> Adder:
             return Adder(name=f"{prefix}_{what}")
 
-        # writer: an admitted write's time parked on the window (0 when
-        # admitted at once) and the bytes it found unconsumed ahead of it
-        self.writes = RecorderFeed((
-            (recorder("write_wait_us"), 1e-3), (recorder("unconsumed_at_write"), 1),
-        ))
+        # Rows hold stamps (time.monotonic_ns()); the sampler cuts the
+        # times. writer: an admitted write entered and was admitted (the
+        # same stamp twice when it never parked on the window: 0), and the
+        # bytes it found unconsumed ahead of it
+        self.writes = RecorderFeed(
+            (
+                (recorder("write_wait_us"), 1e-3, ("enter", "admitted")),
+                (recorder("unconsumed_at_write"), 1, "ahead"),
+            ),
+            stamps=("enter", "admitted", "ahead"),
+            name=f"{prefix}_writes", ring_rows=_RING_ROWS,
+            call=(("enter", "admitted"),),
+        )
         # a write admitted -> the feedback frame covering its last byte applied
-        self.feedbacks = RecorderFeed(((recorder("feedback_lag_us"), 1e-3),))
+        self.feedbacks = RecorderFeed(
+            ((recorder("feedback_lag_us"), 1e-3, ("admitted", "applied")),),
+            stamps=("admitted", "applied"),
+            name=f"{prefix}_feedbacks", ring_rows=_RING_ROWS,
+            call=(("admitted", "applied"),),
+        )
         # reader: _on_frame -> the handler entered for that message
-        self.delivers = RecorderFeed(((recorder("deliver_us"), 1e-3),))
-        # time inside on_received_messages, a batch
-        self.consumes = RecorderFeed(((recorder("consume_us"), 1e-3),))
+        self.delivers = RecorderFeed(
+            ((recorder("deliver_us"), 1e-3, ("frame", "handler")),),
+            stamps=("frame", "handler"),
+            name=f"{prefix}_delivers", ring_rows=_RING_ROWS,
+            call=(("frame", "handler"),),
+        )
+        # time inside on_received_messages, a batch, on the consumer fiber
+        self.consumes = RecorderFeed(
+            ((recorder("consume_us"), 1e-3, ("handler", "returned")),),
+            stamps=("handler", "returned"),
+            name=f"{prefix}_consumes", ring_rows=_RING_ROWS,
+            worker=(("handler", "returned"),),
+        )
         self.messages = adder("messages")  # handed to a handler
         self.batches = adder("batches")  # on_received_messages calls
         self.bytes = adder("bytes")  # of those messages
@@ -236,7 +264,7 @@ class Stream:
                 self._vars.write_retries << 1
                 return ErrorCode.EAGAIN
         self._vars.writes.rows.append(
-            (admitted[1] - t_enter if parked else 0, ahead)
+            (t_enter, admitted[1] if parked else t_enter, ahead)
         )
         meta = Meta(stream_id=rid, extra={"ft": FT_DATA, "from": self.id})
         # IOBuf pack: no body/frame concat copies on the data hot path.
@@ -280,7 +308,7 @@ class Stream:
             now = time.monotonic_ns()
             unacked, lags = self._unacked, self._vars.feedbacks.rows
             while unacked and unacked[0][0] <= consumed:
-                lags.append((now - unacked.popleft()[1],))
+                lags.append((unacked.popleft()[1], now))
         self._wbutex.add(1)
         self._wbutex.wake_all()
 
@@ -346,8 +374,8 @@ class Stream:
                     handler.on_received_messages(self, batch)
                 except Exception:
                     logger.exception("stream %d handler raised", self.id)
-            v.consumes.rows.append((time.monotonic_ns() - t_in,))
-            v.delivers.rows.extend((t_in - t,) for t in arrived)
+            v.consumes.rows.append((t_in, time.monotonic_ns()))
+            v.delivers.rows.extend((t, t_in) for t in arrived)
             v.messages << len(batch)
             v.batches << 1
             v.bytes << nbytes

@@ -22,7 +22,17 @@ import threading
 import time
 from typing import Any, Callable, List, Optional
 
+from incubator_brpc_tpu.bvar import PassiveStatus
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
+
+# CPU time of the whole process, us: every thread of it, the runtime's own
+# included. Read only when a snapshot asks; its gain over a window, over
+# the window's length, is the processors the process kept busy. Here
+# because every device path of the process (endpoint, link, combo) comes
+# through this module.
+process_cpu_us = PassiveStatus(
+    lambda: time.process_time_ns() / 1e3, name="device_transport_process_cpu_us"
+)
 
 
 class _WatcherPool:
@@ -127,10 +137,13 @@ class DeviceCompletionButex(Butex):
         watcher thread (guarded — a raising callback cannot strand waiters,
         because the bump/wake already happened).
 
-        ``stamps``: a two-slot list the watcher fills with
-        ``time.monotonic_ns()`` before on_complete runs — [0] when a
-        watcher thread took the job (how long it queued behind the pool),
-        [1] when ``block_until_ready`` returned."""
+        ``stamps``: a list the watcher fills with ``time.monotonic_ns()``
+        before on_complete runs — [0] when a watcher thread took the job
+        (how long it queued behind the pool), [1] when
+        ``block_until_ready`` returned — and, where it has a third slot,
+        [2] with the watcher's own ``time.thread_time_ns()`` at that
+        return: on_complete runs on this thread, so a stage it ends has
+        both clocks to begin from."""
         import jax
 
         with self._cb_lock:
@@ -146,6 +159,8 @@ class DeviceCompletionButex(Butex):
                 error = e
             if stamps is not None:
                 stamps[1] = time.monotonic_ns()
+                if len(stamps) > 2:
+                    stamps[2] = time.thread_time_ns()
             with self._cb_lock:
                 self._inflight -= 1
                 if error is not None:

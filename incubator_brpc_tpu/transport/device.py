@@ -46,7 +46,13 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
-from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
+from incubator_brpc_tpu.bvar import (
+    CPU_CLOCK_EVERY,
+    Adder,
+    LatencyRecorder,
+    RecorderFeed,
+    clocks,
+)
 from incubator_brpc_tpu.ops import framing
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.device_butex import DeviceCompletionButex
@@ -68,29 +74,6 @@ MAX_STACKED_WORDS = 1 << 18
 
 # credit held -> response parsed, per call: the total the stages split
 device_latency = LatencyRecorder(name="device_transport_latency")
-# One recorder per stage of a call through call_bytes (us, one sample per
-# completed call), so that their means add up to the time inside
-# call_bytes. docs/OBSERVABILITY.md has the table. Fed within the second
-# through _stage_feed, not by the caller.
-m_copy = LatencyRecorder(name="device_transport_copy_us")
-m_credit_wait = LatencyRecorder(name="device_transport_credit_wait_us")
-m_queue_wait = LatencyRecorder(name="device_transport_queue_wait_us")
-m_stack = LatencyRecorder(name="device_transport_stack_us")
-m_launch = LatencyRecorder(name="device_transport_launch_us")
-m_cq_wait = LatencyRecorder(name="device_transport_cq_wait_us")
-m_ready = LatencyRecorder(name="device_transport_ready_us")
-m_readback = LatencyRecorder(name="device_transport_readback_us")
-m_wake = LatencyRecorder(name="device_transport_wake_us")
-# The server's half of the host plane around the device path, for a call
-# that came through server_handler. ingress: the request's frame cut off
-# the wire (the Python messenger's stamp, or the C++ cutter's on the
-# native plane) -> server_handler entered. plane_callback, native plane
-# only: the cut -> the reactor's frame callback had the interpreter; part
-# of ingress. egress: call_bytes returned -> the response was handed to
-# the connection's write.
-m_ingress = LatencyRecorder(name="device_transport_ingress_us")
-m_plane_callback = LatencyRecorder(name="device_transport_plane_callback_us")
-m_egress = LatencyRecorder(name="device_transport_egress_us")
 # per dispatch that reached the device, fed by the completion watcher
 m_dispatches = Adder(name="device_transport_dispatches")
 m_dispatch_rows = Adder(name="device_transport_dispatch_rows")
@@ -98,41 +81,119 @@ m_dispatch_pad_rows = Adder(name="device_transport_dispatch_pad_rows")
 m_dispatch_words = Adder(name="device_transport_dispatch_words")
 m_dispatch_widened_rows = Adder(name="device_transport_dispatch_widened_rows")
 
+# A completed call's row, as _PendingCall.row writes it: the dispatch it
+# rode, its stamps in the order written (time.monotonic_ns()), the host
+# plane's three around them (-1: never taken), then the CPU clock
+# (time.thread_time_ns()) of the thread that wrote the stamp, for the
+# stages of the dispatch that one thread begins and ends, on one dispatch
+# in bvar.CPU_CLOCK_EVERY (-1 on the others). The caller's own stamps carry
+# none: a read is a system call, and seven a call cost 6.8% of the calls/s
+# at 256 B on the chip's host (PERF.md, PR 35).
+_WALL = (
+    "entry", "words", "credit_held", "enqueued", "batched", "stacked",
+    "launched", "cq_taken", "ready", "readback", "woke", "exit",
+)
+STAMPS = ("seq",) + _WALL + (
+    "cut", "plane_callback", "sent",
+    # the drain or -tx thread of the dispatch, then its completion watcher
+    "batched_cpu", "stacked_cpu", "launched_cpu", "ready_cpu", "readback_cpu",
+)
+# One recorder per stage of a call through call_bytes (us, one sample per
+# completed call), each the difference of the stamps beside it, so that
+# the first nine means add up to the time inside call_bytes; the host
+# plane's three lie around them. ingress: the request's frame cut off the
+# wire (the Python messenger's stamp, or the C++ cutter's on the native
+# plane) -> server_handler entered. plane_callback, native plane only: the
+# cut -> the reactor's frame callback had the interpreter; part of
+# ingress. egress: call_bytes returned -> the response was handed to the
+# connection's write. docs/OBSERVABILITY.md has the table. The stage is
+# defined here and nowhere else: the sampler does the subtraction.
+STAGES = {
+    # the adapter's host copies: bytes to words, words into the zeroed
+    # bucket, response words back to bytes
+    "copy": (("entry", "words"), ("credit_held", "enqueued"), ("woke", "exit")),
+    "credit_wait": ("words", "credit_held"),
+    "queue_wait": ("enqueued", "batched"),
+    "stack": ("batched", "stacked"),
+    "launch": ("stacked", "launched"),
+    "cq_wait": ("launched", "cq_taken"),
+    "ready": ("cq_taken", "ready"),
+    "readback": ("ready", "readback"),
+    "wake": ("readback", "woke"),
+    "ingress": ("cut", "entry"),
+    "plane_callback": ("cut", "plane_callback"),
+    "egress": ("exit", "sent"),
+}
+# the stages of a dispatch, each begun and ended by one thread: these
+# carry its CPU clock too, <stage>_cpu_us beside <stage>_us, once a timed
+# dispatch and credited to each of its calls as the wall stage is (wall
+# less CPU = that thread was off the processor)
+CPU_STAGES = ("stack", "launch", "readback")
 
-# a completed call's stage times (ns, in this order) wait here for the
-# sampler thread: the write path is one append
+
+# by stage; the names are written out so that a search for one finds it
+_recorders = {
+    "copy": LatencyRecorder(name="device_transport_copy_us"),
+    "credit_wait": LatencyRecorder(name="device_transport_credit_wait_us"),
+    "queue_wait": LatencyRecorder(name="device_transport_queue_wait_us"),
+    "stack": LatencyRecorder(name="device_transport_stack_us"),
+    "launch": LatencyRecorder(name="device_transport_launch_us"),
+    "cq_wait": LatencyRecorder(name="device_transport_cq_wait_us"),
+    "ready": LatencyRecorder(name="device_transport_ready_us"),
+    "readback": LatencyRecorder(name="device_transport_readback_us"),
+    "wake": LatencyRecorder(name="device_transport_wake_us"),
+    "ingress": LatencyRecorder(name="device_transport_ingress_us"),
+    "plane_callback": LatencyRecorder(name="device_transport_plane_callback_us"),
+    "egress": LatencyRecorder(name="device_transport_egress_us"),
+    "stack_cpu": LatencyRecorder(name="device_transport_stack_cpu_us"),
+    "launch_cpu": LatencyRecorder(name="device_transport_launch_cpu_us"),
+    "readback_cpu": LatencyRecorder(name="device_transport_readback_cpu_us"),
+}
+m_copy, m_credit_wait, m_queue_wait, m_stack, m_launch = (
+    _recorders[s] for s in ("copy", "credit_wait", "queue_wait", "stack", "launch")
+)
+m_cq_wait, m_ready, m_readback, m_wake = (
+    _recorders[s] for s in ("cq_wait", "ready", "readback", "wake")
+)
+m_ingress, m_plane_callback, m_egress = (
+    _recorders[s] for s in ("ingress", "plane_callback", "egress")
+)
+
+# completed calls' rows wait here for the sampler thread: the write path
+# is one append. The last 32 Ki rows stay (30 s of the fastest cell)
 _stage_feed = RecorderFeed(
-    (recorder, 1e-3)
-    for recorder in (
-        m_copy, m_credit_wait, m_queue_wait, m_stack, m_launch,
-        m_cq_wait, m_ready, m_readback, m_wake,
-        m_ingress, m_plane_callback, m_egress,
-    )
+    [(_recorders[stage], 1e-3, span) for stage, span in STAGES.items()]
+    + [
+        (_recorders[stage + "_cpu"], 1e-3,
+         tuple(stamp + "_cpu" for stamp in STAGES[stage]))
+        for stage in CPU_STAGES
+    ],
+    stamps=STAMPS,
+    name="device_transport",
+    ring_rows=1 << 15,
+    worker=(STAGES["stack"], STAGES["launch"], STAGES["ready"], STAGES["readback"]),
+    call=(("entry", "exit"),),
 )
 
 
 def _record(pending: "_PendingCall", cntl, sent_ns: Optional[int]) -> None:
-    """One row for ``_stage_feed`` from a completed call: its stages,
-    and from the server-side controller of the RPC it served, if any, the
-    host plane's times around them. A sampled rpcz span gets the same
-    timeline as annotations."""
-    ingress = plane_callback = egress = span = entered = None
+    """One row for ``_stage_feed`` from a completed call: its stamps, and
+    from the server-side controller of the RPC it served, if any, the
+    host plane's around them. A sampled rpcz span gets the same timeline
+    as annotations."""
+    cut_ns = entered = -1
+    span = None
     if cntl is not None:
         span = getattr(cntl, "_span", None)
         arrival = getattr(cntl, "_arrival_ts", None)
-        entered = getattr(cntl, "_plane_callback_ns", None)
         if arrival is not None:
             cut_ns = int(arrival * 1e9)
-            ingress = pending.t_entry - cut_ns
-            if entered is not None:
-                plane_callback = entered - cut_ns
-    if sent_ns is not None:
-        egress = sent_ns - pending.t_exit
+            entered = getattr(cntl, "_plane_callback_ns", None) or -1
     _stage_feed.rows.append(
-        pending.stages() + (ingress, plane_callback, egress)
+        pending.row(cut_ns, entered, -1 if sent_ns is None else sent_ns)
     )
     if span is not None:
-        if entered is not None:  # before the span's own start
+        if entered > 0:  # before the span's own start
             span.annotate("device plane_callback", entered)
         pending.annotate(span)
         if sent_ns is not None:
@@ -162,14 +223,16 @@ def _pad_rows(b: int) -> int:
 
 class _Dispatch:
     """One (batch, bucket) program execution, shared by the calls stacked
-    into it; ``bucket`` is the widest among theirs. Its
-    ``time.monotonic_ns()`` stamps are each written once, by the thread
-    that does the work: the drain or ``-tx`` thread up to ``t_launched``,
+    into it; ``bucket`` is the widest among theirs. Its stamps are each
+    written once, by the thread that does the work, on both clocks
+    (``bvar.clocks``: ``t_*`` wall, ``c_*`` that thread's CPU, -1 unless the
+    dispatch is ``timed``): the drain or ``-tx`` thread up to ``launched``,
     a completion watcher from there."""
 
     __slots__ = (
-        "seq", "rows", "pad_rows", "bucket", "widened_rows",
+        "seq", "rows", "pad_rows", "bucket", "widened_rows", "timed",
         "t_batched", "t_stacked", "t_launched", "watcher", "t_readback",
+        "c_batched", "c_stacked", "c_launched", "c_readback",
     )
 
     def __init__(
@@ -180,13 +243,17 @@ class _Dispatch:
         self.pad_rows = pad_rows  # rows the program ran (next power of two)
         self.bucket = bucket  # payload words per row as the program ran it
         self.widened_rows = widened_rows  # calls whose own bucket is narrower
-        self.t_batched = _time.monotonic_ns()  # taken off the queue
-        self.t_stacked = 0  # rows copied into one array
-        self.t_launched = 0  # the program call, which stages the rows, returned
+        self.timed = seq % CPU_CLOCK_EVERY == 0  # its stamps carry the CPU clock
+        self.t_batched, self.c_batched = clocks(self.timed)  # taken off the queue
+        self.t_stacked, self.c_stacked = 0, -1  # rows copied into one array
+        # the program call, which stages the rows, returned
+        self.t_launched, self.c_launched = 0, -1
         # DeviceCompletionButex.watch fills these: a watcher thread took
-        # the job, block_until_ready returned
-        self.watcher = [0, 0]
-        self.t_readback = 0  # device_get returned; 0 = never completed
+        # the job, block_until_ready returned, and (the third slot, which
+        # only a timed dispatch gives it) the watcher's CPU clock at that
+        # return
+        self.watcher = [0, 0, -1] if self.timed else [0, 0]
+        self.t_readback, self.c_readback = 0, -1  # device_get returned; 0 = never completed
 
 
 class _PendingCall:
@@ -229,45 +296,33 @@ class _PendingCall:
         (whatever the response said). Only such calls are recorded."""
         return self.dispatch is not None and self.dispatch.t_readback != 0
 
-    def timeline(self):
-        """``(name, monotonic_ns)`` of every stamp, in the order written."""
+    def row(self, cut: int = -1, plane_callback: int = -1, sent: int = -1) -> tuple:
+        """The call's row for ``_stage_feed``, a number a position of
+        ``STAMPS``; -1 where the host plane took no stamp, and for the exit
+        of a raw ``call_words``, which has none."""
         d = self.dispatch
+        t_cq, t_ready = d.watcher[:2]
         return (
-            ("entry", self.t_entry),
-            ("words", self.t_words),
-            ("credit_held", self.t_credit),
-            ("enqueued", self.t_enqueued),
-            ("batched", d.t_batched),
-            ("stacked", d.t_stacked),
-            ("launched", d.t_launched),
-            ("cq_taken", d.watcher[0]),
-            ("ready", d.watcher[1]),
-            ("readback", d.t_readback),
-            ("woke", self.t_woke),
-            ("exit", self.t_exit),
+            d.seq,
+            self.t_entry, self.t_words, self.t_credit, self.t_enqueued,
+            d.t_batched, d.t_stacked, d.t_launched, t_cq, t_ready,
+            d.t_readback, self.t_woke, self.t_exit or -1,
+            cut, plane_callback, sent,
+            d.c_batched, d.c_stacked, d.c_launched, d.watcher[-1] if d.timed else -1,
+            d.c_readback,
         )
 
-    def stages(self) -> tuple:
-        """The stage times of a call through call_bytes, ns, in the
-        order of ``_stage_feed``'s first nine recorders; they add up to
-        ``t_exit - t_entry``."""
-        d = self.dispatch
-        t_cq, t_ready = d.watcher
-        return (
-            # the adapter's host copies: bytes to words, words into the
-            # zeroed bucket, response words back to bytes
-            (self.t_words - self.t_entry)
-            + (self.t_enqueued - self.t_credit)
-            + (self.t_exit - self.t_woke),
-            self.t_credit - self.t_words,
-            d.t_batched - self.t_enqueued,
-            d.t_stacked - d.t_batched,
-            d.t_launched - d.t_stacked,
-            t_cq - d.t_launched,
-            t_ready - t_cq,
-            d.t_readback - t_ready,
-            self.t_woke - d.t_readback,
-        )
+    def timeline(self):
+        """``(name, monotonic_ns)`` of every stamp, in the order written."""
+        return tuple(zip(_WALL, self.row()[1 : 1 + len(_WALL)]))
+
+    def stages(self) -> dict:
+        """``{stage: ns}`` as ``_stage_feed``'s table cuts them from the
+        call's stamps (``<stage>_cpu`` on the CPU clock); the nine of a
+        call through call_bytes add up to ``t_exit - t_entry``."""
+        values = _stage_feed.read(self.row())
+        names = list(STAGES) + [stage + "_cpu" for stage in CPU_STAGES]
+        return {n: v for n, v in zip(names, values) if v is not None}
 
     def annotate(self, span) -> None:
         """A sampled server span gets the call's stamps as annotations,
@@ -275,7 +330,7 @@ class _PendingCall:
         share its number."""
         d = self.dispatch
         for name, at in self.timeline():
-            if not at:
+            if at <= 0:
                 continue
             if name == "batched":
                 name = (
@@ -496,7 +551,7 @@ class DeviceEndpoint:
             cids[i] = cid
             mids[i] = mid
             pending.dispatch = dispatch
-        dispatch.t_stacked = _time.monotonic_ns()
+        dispatch.t_stacked, dispatch.c_stacked = clocks(dispatch.timed)
         # the launch is the program call alone: it stages the host arrays
         # itself. rows, cids and mids are this dispatch's own and are not
         # written again (the runtime may still be reading them)
@@ -512,7 +567,7 @@ class DeviceEndpoint:
                 pending.error_code = ErrorCode.EINTERNAL
                 pending.settle()
             return
-        dispatch.t_launched = _time.monotonic_ns()
+        dispatch.t_launched, dispatch.c_launched = clocks(dispatch.timed)
 
         def on_complete(arrays, error, _batch=batch, _single=(bpad == 1)):
             try:
@@ -521,7 +576,7 @@ class DeviceEndpoint:
                     host = np.asarray(jax.device_get(arrays))
             except Exception as e:  # noqa: BLE001 — fetch failed
                 error, host = e, None
-            dispatch.t_readback = _time.monotonic_ns()
+            dispatch.t_readback, dispatch.c_readback = clocks(dispatch.timed)
             for i, (_, _mid, _padded, _cid, pending, n) in enumerate(_batch):
                 try:
                     if error is not None:

@@ -56,7 +56,14 @@ from typing import Dict, List, Optional
 
 import numpy as np
 
-from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, PerSecond, RecorderFeed
+from incubator_brpc_tpu.bvar import (
+    CPU_CLOCK_EVERY,
+    Adder,
+    LatencyRecorder,
+    PerSecond,
+    RecorderFeed,
+    clocks,
+)
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.device_butex import DeviceCompletionButex
 from incubator_brpc_tpu.runtime.worker_pool import global_worker_pool
@@ -132,32 +139,79 @@ import atexit
 atexit.register(_quiesce_links)
 
 
+# A delivered step's row, as _record_step writes it: the train's first
+# seq, its stamps in the order written (time.monotonic_ns()), three counts,
+# then the CPU clock (time.thread_time_ns()) of the thread that wrote the
+# stamp, where a stage begins and ends on it. A stage is the difference of
+# the stamps beside it (us), a count is fed as it stands; launch, ready,
+# reorder_wait, readback and pump add up to step_rtt. One step in
+# bvar.CPU_CLOCK_EVERY carries the CPU stamps, the others -1.
+STEP_STAMPS = (
+    "seq",
+    "previous_dispatch",  # the drive's dispatch before this one; -1 = none
+    "held_since",  # the drive began to hold the train back, else = dispatch
+    "dispatch", "launched", "ready", "deliver", "host", "delivered",
+    "inflight", "backlog_slots", "credit",
+    "dispatch_cpu", "launched_cpu",  # the drive's thread
+    "deliver_cpu", "host_cpu", "delivered_cpu",  # the in-order deliverer's
+)
+# (the link's recorder _m_<this>, scale, what of the row it is fed)
+STEP_COLUMNS = (
+    ("rtt", 1e-3, ("dispatch", "delivered")),
+    ("launch", 1e-3, ("dispatch", "launched")),
+    ("ready", 1e-3, ("launched", "ready")),
+    ("reorder_wait", 1e-3, ("ready", "deliver")),
+    ("readback", 1e-3, ("deliver", "host")),
+    ("pump", 1e-3, ("host", "delivered")),
+    ("dispatch_interval", 1e-3, ("previous_dispatch", "dispatch")),
+    ("hold", 1e-3, ("held_since", "dispatch")),
+    ("inflight", 1, "inflight"),
+    ("backlog", 1, "backlog_slots"),
+    ("credit", 1, "credit"),
+    # one thread begins and ends these: its CPU clock beside the wall clock
+    ("launch_cpu", 1e-3, ("dispatch_cpu", "launched_cpu")),
+    ("readback_cpu", 1e-3, ("deliver_cpu", "host_cpu")),
+    ("pump_cpu", 1e-3, ("host_cpu", "delivered_cpu")),
+)
+
+
 class _Step:
-    """One exchange step's timeline (a train of slots a side),
-    ``time.monotonic_ns()`` stamps each written once by the thread that
-    does the work."""
+    """One exchange step's timeline (a train of slots a side): stamps each
+    written once by the thread that does the work, on both clocks
+    (``bvar.clocks``: ``t_*`` wall, ``c_*`` that thread's CPU)."""
 
     __slots__ = (
-        "t_dispatch", "interval_ns", "inflight", "seen", "hold_ns",
-        "t_launched", "watcher",
+        "t_dispatch", "c_dispatch", "t_previous", "inflight", "seen", "t_held",
+        "t_launched", "c_launched", "watcher",
     )
 
     def __init__(
-        self, t_dispatch: int, interval_ns: int, inflight: int,
-        seen: tuple = (None, None), hold_ns: int = 0,
+        self, t_dispatch: int, c_dispatch: int, t_previous: int, inflight: int,
+        seen: tuple, t_held: int,
     ):
         self.t_dispatch = t_dispatch  # slots filled, seq taken
-        self.interval_ns = interval_ns  # since the drive's previous dispatch
+        self.c_dispatch = c_dispatch  # -1: this step is not timed on the CPU clock
+        # the drive's previous dispatch; none: never taken
+        self.t_previous = t_previous or RecorderFeed.MISSING
         self.inflight = inflight  # undrained slots, this train's included
         # (backlog slots, free credit) the train's length was taken from
         self.seen = seen
         # the drive's first look that found less credit than the train its
-        # backlog wanted needs -> this dispatch; 0 = never held
-        self.hold_ns = hold_ns
+        # backlog wanted needs; a train never held: its dispatch
+        self.t_held = t_held or t_dispatch
         self.t_launched = 0  # _make_slots and the step call returned
+        self.c_launched = RecorderFeed.MISSING
         # DeviceCompletionButex.watch fills these: a watcher thread took
         # the job, block_until_ready returned
         self.watcher = [0, 0]
+
+    @property
+    def timed(self) -> bool:
+        """This step's stamps carry the CPU clock."""
+        return self.c_dispatch >= 0
+
+    def launched(self) -> None:
+        self.t_launched, self.c_launched = clocks(self.timed)
 
 
 class DeviceLink:
@@ -242,8 +296,9 @@ class DeviceLink:
         pfx = f"device_link_{self.link_id}"
         self._m_out_bytes = Adder()
         self._m_in_bytes = Adder()
-        self._m_rtt = LatencyRecorder(name=f"{pfx}_step_rtt_us")
         self._m_flush = LatencyRecorder(name=f"{pfx}_flush_us")
+        self._m_send_wait = LatencyRecorder(name=f"{pfx}_send_wait_us")
+        self._m_rtt = LatencyRecorder(name=f"{pfx}_step_rtt_us")
         self._m_launch = LatencyRecorder(name=f"{pfx}_launch_us")
         self._m_ready = LatencyRecorder(name=f"{pfx}_ready_us")
         self._m_reorder_wait = LatencyRecorder(name=f"{pfx}_reorder_wait_us")
@@ -255,24 +310,39 @@ class DeviceLink:
         self._m_inflight = LatencyRecorder(name=f"{pfx}_inflight_at_dispatch")
         self._m_backlog = LatencyRecorder(name=f"{pfx}_backlog_slots_at_dispatch")
         self._m_credit = LatencyRecorder(name=f"{pfx}_credit_at_dispatch")
-        self._m_send_wait = LatencyRecorder(name=f"{pfx}_send_wait_us")
         self._m_hold = LatencyRecorder(name=f"{pfx}_hold_us")
+        self._m_launch_cpu = LatencyRecorder(name=f"{pfx}_launch_cpu_us")
+        self._m_readback_cpu = LatencyRecorder(name=f"{pfx}_readback_cpu_us")
+        self._m_pump_cpu = LatencyRecorder(name=f"{pfx}_pump_cpu_us")
         self._m_out_rate = PerSecond(self._m_out_bytes, name=f"{pfx}_out_bytes_second")
         self._m_in_rate = PerSecond(self._m_in_bytes, name=f"{pfx}_in_bytes_second")
-        # a delivered step's numbers (ns, but for the in-flight count) wait
-        # here for the sampler thread: eleven feeds a step on the delivering
-        # thread would sit between one step and the next
-        self._step_feed = RecorderFeed((
-            (self._m_launch, 1e-3), (self._m_ready, 1e-3),
-            (self._m_reorder_wait, 1e-3), (self._m_readback, 1e-3),
-            (self._m_pump, 1e-3), (self._m_rtt, 1e-3),
-            (self._m_dispatch_interval, 1e-3), (self._m_inflight, 1),
-            (self._m_backlog, 1), (self._m_credit, 1), (self._m_hold, 1e-3),
-        ))
-        # one row a send(): ns parked over the backlog budget
-        self._send_feed = RecorderFeed(((self._m_send_wait, 1e-3),))
+        # a delivered step's row waits here for the sampler thread: fourteen
+        # feeds a step on the delivering thread would sit between one step
+        # and the next. The last 16 Ki rows stay (30 s of a busy link)
+        self._step_feed = RecorderFeed(
+            [
+                (getattr(self, "_m_" + attr), scale, span)
+                for attr, scale, span in STEP_COLUMNS
+            ],
+            stamps=STEP_STAMPS,
+            name=f"{pfx}_steps",
+            ring_rows=1 << 14,
+            worker=(("dispatch", "launched"), ("deliver", "host"), ("host", "delivered")),
+            call=(("dispatch", "delivered"),),
+        )
+        # one row a send(): when it first parked over the backlog budget
+        # (admitted at once: when it was admitted) and when it was admitted
+        # or gave up
+        self._send_feed = RecorderFeed(
+            ((self._m_send_wait, 1e-3, ("parked", "admitted")),),
+            stamps=("parked", "admitted"),
+            name=f"{pfx}_sends",
+            ring_rows=1 << 14,
+            call=(("parked", "admitted"),),
+        )
         self._metrics_retired = False
         self._steps: Dict[int, _Step] = {}  # first seq -> timeline, until delivered
+        self._steps_taken = 0  # trains dispatched: which carry the CPU clock
         self._last_dispatch_ns = 0  # this drive's previous dispatch; 0 = none
         self._held_since_ns = 0  # the drive is holding a train back; 0 = not
         self._build_step()
@@ -288,11 +358,8 @@ class DeviceLink:
         self._step_feed.flush()  # profile() still reads the recorders
         self._send_feed.flush()
         for v in (
-            self._m_rtt, self._m_flush, self._m_launch, self._m_ready,
-            self._m_reorder_wait, self._m_readback, self._m_pump,
-            self._m_dispatch_interval, self._m_inflight,
-            self._m_backlog, self._m_credit, self._m_send_wait, self._m_hold,
-            self._m_out_rate, self._m_in_rate,
+            *(getattr(self, "_m_" + attr) for attr, *_rest in STEP_COLUMNS),
+            self._m_flush, self._m_send_wait, self._m_out_rate, self._m_in_rate,
         ):
             try:
                 v.hide()
@@ -445,12 +512,11 @@ class DeviceLink:
             remaining = deadline - time.monotonic()
             if remaining <= 0:
                 link_overcrowded << 1
-                self._send_feed.rows.append((time.monotonic_ns() - t_parked,))
+                self._send_feed.rows.append((t_parked, time.monotonic_ns()))
                 return ErrorCode.EOVERCROWDED
             self._wbutex.wait(seq, timeout=remaining)
-        self._send_feed.rows.append(
-            (time.monotonic_ns() - t_parked if t_parked else 0,)
-        )
+        now = time.monotonic_ns()
+        self._send_feed.rows.append((t_parked or now, now))
         self._kick()
         return 0
 
@@ -514,7 +580,10 @@ class DeviceLink:
             (backlog, credit),
         )
 
-    def _take_seq_locked(self, k: int = 1, seen: tuple = (None, None)) -> tuple:
+    def _take_seq_locked(
+        self, k: int = 1,
+        seen: tuple = (RecorderFeed.MISSING, RecorderFeed.MISSING),
+    ) -> tuple:
         """Under the link lock, a train of ``k`` slots a side filled: take
         its seqs, count its slots in flight and start its timeline. ``seen``
         is what ``_train_len_locked`` took ``k`` from (a link that never
@@ -522,12 +591,12 @@ class DeviceLink:
         seq = self._seq
         self._seq += k
         self._inflight += k
-        now = time.monotonic_ns()
+        now, now_cpu = clocks(self._steps_taken % CPU_CLOCK_EVERY == 0)
+        self._steps_taken += 1
         last, self._last_dispatch_ns = self._last_dispatch_ns, now
         held, self._held_since_ns = self._held_since_ns, 0
         step = self._steps[seq] = _Step(
-            now, now - last if last else 0, self._inflight, seen,
-            now - held if held else 0,
+            now, now_cpu, last, self._inflight, seen, held
         )
         return seq, step
 
@@ -597,9 +666,8 @@ class DeviceLink:
                 # _driving=True with the queue wedged.
                 link_steps << 1
                 link_slots << k
-                step.t_launched = step.watcher[0] = step.watcher[1] = (
-                    time.monotonic_ns()
-                )
+                step.launched()
+                step.watcher[0] = step.watcher[1] = step.t_launched
                 try:
                     self._on_step_done(
                         seq, ("host", [rows[1], rows[0]]), None, k
@@ -619,10 +687,10 @@ class DeviceLink:
                 with self._lock:
                     self._driving = False
                 return
-            step.t_launched = time.monotonic_ns()
+            step.launched()
             link_steps << 1
             link_slots << k
-            if step.hold_ns:
+            if step.t_held != step.t_dispatch:
                 link_held << 1
             self._cq.watch(
                 out,
@@ -723,40 +791,38 @@ class DeviceLink:
                     if done is None:
                         return
                     arrays, k = done
-                    step = self._steps.pop(self._next_deliver, None)
+                    seq = self._next_deliver
+                    step = self._steps.pop(seq, None)
                     self._next_deliver += k
                 self._deliver_tid = threading.get_ident()
-                t_begin = t_host = time.monotonic_ns()
+                timed = step is not None and step.timed
+                begin = host = clocks(timed)
                 try:
                     rows = self._rows_to_host(arrays)
-                    t_host = time.monotonic_ns()
+                    host = clocks(timed)
                     self._deliver(rows)
                 finally:
                     self._deliver_tid = None
-                    self._record_step(step, t_begin, t_host, time.monotonic_ns())
+                    self._record_step(seq, step, begin, host, clocks(timed))
             with self._lock:
                 self._inflight -= k
             self._wbutex.add(1)
             self._wbutex.wake_all()
 
-    def _record_step(self, step, t_begin: int, t_host: int, t_end: int) -> None:
-        """Hand a delivered step's timeline to the recorders' feed: launch,
-        ready, reorder_wait, readback and pump add up to step_rtt. ``step``
-        is None when fail() dropped the timelines under a late completion."""
+    def _record_step(self, seq: int, step, begin, host, end) -> None:
+        """Hand a delivered step's timeline to the recorders' feed, a number
+        a position of ``STEP_STAMPS``. ``begin``, ``host`` and ``end`` are
+        the deliverer's ``clocks()`` around the readback and the pump.
+        ``step`` is None when fail() dropped the timelines under a late
+        completion."""
         if step is None:
             return
-        t_ready = step.watcher[1]
         self._step_feed.rows.append((
-            step.t_launched - step.t_dispatch,
-            t_ready - step.t_launched,
-            t_begin - t_ready,
-            t_host - t_begin,
-            t_end - t_host,
-            t_end - step.t_dispatch,
-            step.interval_ns or None,
-            step.inflight,
-            *step.seen,
-            step.hold_ns,
+            seq, step.t_previous, step.t_held,
+            step.t_dispatch, step.t_launched, step.watcher[1],
+            begin[0], host[0], end[0],
+            step.inflight, *step.seen,
+            step.c_dispatch, step.c_launched, begin[1], host[1], end[1],
         ))
 
     def _rows_to_host(self, arrays) -> List[Optional[np.ndarray]]:

@@ -298,7 +298,7 @@ class MultiControllerLink(DeviceLink):
                 with self._lock:
                     self._driving = False
                 return
-            step.t_launched = _time.monotonic_ns()
+            step.launched()
             link_steps << 1
             link_slots << 1
             self._cq.watch(

@@ -73,6 +73,11 @@ DEVICE.update({
     "device_transport_dispatch_words": 128 * 64,
     "device_transport_dispatch_widened_rows": 60,
 })
+# PR 35: the CPU clock of the stages one thread begins and ends
+DEVICE_CPU = {"stack": 60.0, "launch": 45.0, "readback": 30.0}
+DEVICE.update({
+    f"device_transport_{s}_cpu_us": recorder(100, us) for s, us in DEVICE_CPU.items()
+})
 # two links: 3 is the busiest; 2 must not be read
 LINK = {
     "device_link_2_step_rtt_us": recorder(5, 9e9),
@@ -91,6 +96,10 @@ LINK = {
     "device_link_3_backlog_slots_at_dispatch": recorder(680, 10.5),
     "device_link_2_hold_us": recorder(5, 9e9),
     "device_link_3_hold_us": recorder(680, 750.0),
+    "device_link_2_launch_cpu_us": recorder(5, 9e9),
+    "device_link_3_launch_cpu_us": recorder(680, 700.0),
+    "device_link_3_readback_cpu_us": recorder(680, 600.0),
+    "device_link_3_pump_cpu_us": recorder(680, 450.0),
     "device_link_held_steps": 170,
     "device_link_bytes": 40 * (1 << 20),
     "device_link_capacity_bytes": 2 * 680 * 65536,
@@ -119,6 +128,8 @@ COMBO = {
     "device_link_combo_call_us": recorder(200, 1000.0),
     **{f"device_link_combo_{s}_us": recorder(200, us)
        for s, us in COMBO_STAGES.items()},
+    **{f"device_link_combo_{s}_cpu_us": recorder(200, COMBO_STAGES[s] / 4)
+       for s in ("pack", "put", "launch", "gather", "merge")},
     "device_link_combo_fused": 200,
     "device_link_combo_host_fanout": 40,
     "device_link_combo_mc_lowered": 10,
@@ -162,10 +173,22 @@ EXPECTED = {
     **{f"combo_{s}_us": (COMBO, us) for s, us in COMBO_STAGES.items()},
     "combo_unattributed_pct": (COMBO, 10.0),
     "combo_fused_pct": (COMBO, 80.0),
+    # PR 35: the second clock
+    **{f"device_{s}_cpu_us": (DEVICE, us) for s, us in DEVICE_CPU.items()},
+    "link_launch_cpu_us": (LINK, 700.0),
+    "link_readback_cpu_us": (LINK, 600.0),
+    "link_pump_cpu_us": (LINK, 450.0),
+    **{f"combo_{s}_cpu_us": (COMBO, COMBO_STAGES[s] / 4)
+       for s in ("pack", "put", "launch", "gather", "merge")},
+    # 30 s of CPU time in a window of 20: a processor and a half kept busy
+    "host_cpu_cores": ({"device_transport_process_cpu_us": 30e6}, 1.5),
 }
 # PR 31's and PR 33's device_trace readers: not a counter's mean, so
 # outside EXPECTED
 TRACE_READERS = {"link_step_ici_pct", "combo_step_kernel_us", "combo_gather_ici_pct"}
+# PR 35's readers of the program's kept rows (benchmark/timeline.py): their
+# numbers are checked in tests/test_stage_timeline.py
+SPAN_READERS = {"idle_worker_open_pct", "idle_waiting_only_pct", "idle_outside_pct"}
 # what the benchmark had before PR 25 reads no recorder this PR added
 OLDER = {
     "host_plane_us", "device_path_us", "calls_per_dispatch",
@@ -192,7 +215,8 @@ def test_cell_resolves_its_files_and_readers(name):
 def test_every_metric_is_accounted_for():
     names = [m["name"] for m in BENCH["per_layer"]]
     assert len(names) == len(set(names))
-    assert OLDER | set(EXPECTED) | TRACE_READERS <= set(names)  # a later PR may add more
+    # a later PR may add more
+    assert OLDER | set(EXPECTED) | TRACE_READERS | SPAN_READERS <= set(names)
     with open(os.path.join(ROOT, "PERF.md"), encoding="utf-8") as f:
         perf = f.read()
     for m in BENCH["per_layer"]:
@@ -288,6 +312,26 @@ def test_reader_gives_none_where_the_program_lacks_the_recorder(metric):
     }
     assert read(hand_made_run(before_pr25)) is None
     assert read(hand_made_run({})) is None
+
+
+@pytest.mark.parametrize("metric", sorted(SPAN_READERS))
+def test_idle_share_reader_is_a_program_span_in_every_cell_and_none_without_rows(metric):
+    (entry,) = [m for m in BENCH["per_layer"] if m["name"] == metric]
+    assert entry["source"] == "program_span" and entry["unit"] == "%"
+    assert set(entry["workloads"]) == set(CELLS)
+    read = manifest.load_module("layers", metric + ".py").read
+    # no feed of the program holds a row inside this hand-made window, and a
+    # parent from before PR 35 has no feeds at all: nothing to read is None
+    assert read(hand_made_run(dict(DEVICE))) is None
+    assert read(hand_made_run({})) is None
+
+
+def test_the_new_entries_only_follow_the_old():
+    """``per_layer`` only grows: PR 34's 57 entries lead, in their order."""
+    names = [m["name"] for m in BENCH["per_layer"]]
+    assert names[56] == "combo_gather_ici_pct" and names[57] == "device_stack_cpu_us"
+    assert len(names) == 57 + 15
+    assert all(m["moves"] == "latency_p50_us" for m in BENCH["per_layer"][57:])
 
 
 def test_unattributed_share_needs_every_stage_and_a_handler_span():

@@ -115,10 +115,10 @@ class TestRecorderFeed:
         a, b, c = LatencyRecorder(), LatencyRecorder(), LatencyRecorder()
         feed = RecorderFeed(((a, 1e-3), (b, 1e-3), (c, 1)))
         for i in range(40):
-            feed.rows.append((1000 * (i + 1), None if i % 2 else 5000, 3))
+            feed.rows.append((1000 * (i + 1), feed.MISSING if i % 2 else 5000, 3))
         assert a.count() == 0  # nothing until a flush
         feed.flush()
-        assert (a.count(), b.count(), c.count()) == (40, 20, 40)  # None skipped
+        assert (a.count(), b.count(), c.count()) == (40, 20, 40)  # MISSING skipped
         assert a.latency_sum() == pytest.approx(sum(range(1, 41)))  # ns -> us
         assert a.max_latency() == pytest.approx(40.0)
         assert b.latency() == pytest.approx(5.0)

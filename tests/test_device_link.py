@@ -910,6 +910,8 @@ class TestStepStageRecorders:
             "dispatch_interval_us", "inflight_at_dispatch",
             "backlog_slots_at_dispatch", "credit_at_dispatch", "send_wait_us",
             "hold_us", "out_bytes_second", "in_bytes_second",
+            # PR 35: the CPU clock of the stages one thread begins and ends
+            "launch_cpu_us", "readback_cpu_us", "pump_cpu_us",
         }
         link.fail("retire")
         assert not list(expose_registry.snapshot(pfx))

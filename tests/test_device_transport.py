@@ -339,11 +339,24 @@ class TestStageRecorders:
 
         before = _transport_counters()
         ingress = device.m_ingress.count()
-        stages = (1000, 2000, 3000, 4000, 5000, 6000, 7000, 8000, 9000)
+        # a row holds stamps, not times: stage i is cut (i + 1) us long
+        # (the copy: its three pieces 200, 300 and 500 ns), from t = 1 ms
+        stamp = dict.fromkeys(device.STAMPS, -1)  # -1: never taken
+        stamp.update(seq=7, entry=1_000_000, words=1_000_200)
+        stamp["credit_held"] = stamp["words"] + 2000
+        stamp["enqueued"] = stamp["credit_held"] + 300
+        at = stamp["enqueued"]
+        for i, name in enumerate(
+            ("batched", "stacked", "launched", "cq_taken", "ready", "readback", "woke"),
+            start=3,
+        ):
+            at = stamp[name] = at + 1000 * i
+        stamp["exit"] = stamp["woke"] + 500
         for _ in range(20):
-            device._stage_feed.rows.append(stages + (None, None, None))
+            device._stage_feed.rows.append(tuple(stamp[n] for n in device.STAMPS))
         # came through a server: ingress, no native callback, egress
-        device._stage_feed.rows.append(stages + (500, None, 700))
+        stamp.update(cut=stamp["entry"] - 500, sent=stamp["exit"] + 700)
+        device._stage_feed.rows.append(tuple(stamp[n] for n in device.STAMPS))
         assert device.m_wake.count() == before["wake"][0]  # they wait
         assert _wait_until(  # no flush of ours: the 1 Hz sampler feeds them
             lambda: device.m_wake.count() - before["wake"][0] == 21, timeout=5
