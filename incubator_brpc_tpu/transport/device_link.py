@@ -90,6 +90,8 @@ link_slots = Adder(name="device_link_slots")
 # of those programs, the ones whose dispatch waited for the credit the
 # train its backlog wanted needs
 link_held = Adder(name="device_link_held_steps")
+# of those programs, the ones whose host copies were asked for at dispatch
+link_prefetched = Adder(name="device_link_prefetched_steps")
 link_bytes = Adder(name="device_link_bytes")
 # payload capacity of every slot side filled: set against device_link_bytes
 # it says how full the slots travel
@@ -426,13 +428,15 @@ class DeviceLink:
     def _warm_step(self) -> None:
         """Run the exchange once on empty rows at every train length the
         window admits (1, 2, 4, ...): one program a length, compiled here
-        in the handshake, so that no dispatch of live traffic compiles."""
-        import jax
-
+        in the handshake, so that no dispatch of live traffic compiles. Each
+        is asked for and read back as a live train is, so that the first of
+        those does not pay the transfer path's first use either."""
         k = 1
         while k <= self.window:
             empty = np.zeros((k, self._width), dtype=np.uint32)
-            jax.block_until_ready(self._step(self._make_slots([empty, empty])))
+            out = self._step(self._make_slots([empty, empty]))
+            self._request_host(out)
+            self._rows_to_host(out)
             k *= 2
 
     @property
@@ -463,6 +467,16 @@ class DeviceLink:
         return jax.make_array_from_single_device_arrays(
             (2,) + rows[0].shape, self._sharding, shards
         )
+
+    @staticmethod
+    def _request_host(out) -> None:
+        """Ask the runtime for the host copy of a step's output, every
+        addressable shard of it, as soon as the step is dispatched: the
+        transfers queue behind the exchange on each device and run beside
+        each other and beside the watcher's hand-over, and ``_rows_to_host``
+        finds them landed or landing instead of asking for one after the
+        other (PERF.md, PR 36)."""
+        out.copy_to_host_async()
 
     # -- send side -----------------------------------------------------------
 
@@ -681,6 +695,7 @@ class DeviceLink:
                 continue
             try:
                 out = self._step(self._make_slots(rows))
+                self._request_host(out)
             except Exception:
                 logger.exception("device link step dispatch failed")
                 self.fail("link step dispatch failed")
@@ -690,6 +705,7 @@ class DeviceLink:
             step.launched()
             link_steps << 1
             link_slots << k
+            link_prefetched << 1
             if step.t_held != step.t_dispatch:
                 link_held << 1
             self._cq.watch(

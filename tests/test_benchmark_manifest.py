@@ -101,6 +101,7 @@ LINK = {
     "device_link_3_readback_cpu_us": recorder(680, 600.0),
     "device_link_3_pump_cpu_us": recorder(680, 450.0),
     "device_link_held_steps": 170,
+    "device_link_prefetched_steps": 680,
     "device_link_bytes": 40 * (1 << 20),
     "device_link_capacity_bytes": 2 * 680 * 65536,
     "device_link_steps": 680,
@@ -164,6 +165,7 @@ EXPECTED = {
     "link_backlog_slots": (LINK, 10.5),
     "link_hold_us": (LINK, 750.0),
     "link_held_pct": (LINK, 25.0),
+    "link_prefetched_pct": (LINK, 100.0),
     "stream_write_wait_us": (STREAM, 7000.0),
     "stream_feedback_lag_us": (STREAM, 21000.0),
     "stream_deliver_us": (STREAM, 450.0),
@@ -330,8 +332,33 @@ def test_the_new_entries_only_follow_the_old():
     """``per_layer`` only grows: PR 34's 57 entries lead, in their order."""
     names = [m["name"] for m in BENCH["per_layer"]]
     assert names[56] == "combo_gather_ici_pct" and names[57] == "device_stack_cpu_us"
-    assert len(names) == 57 + 15
-    assert all(m["moves"] == "latency_p50_us" for m in BENCH["per_layer"][57:])
+    assert all(m["moves"] == "latency_p50_us" for m in BENCH["per_layer"][57:72])
+    # PR 36's one entry follows PR 35's fifteen
+    assert names[72:] == ["link_prefetched_pct"]
+    assert BENCH["per_layer"][72] == {
+        "name": "link_prefetched_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "link", "moves": "goodput",
+        "workloads": ["link_echo_ici_1m", "link_stream_ici"],
+    }
+
+
+def test_every_per_layer_entry_has_its_reader_file():
+    for m in BENCH["per_layer"]:
+        # a missing file raises here, under the entry's name
+        assert callable(manifest.load_module("layers", m["name"] + ".py").read)
+
+
+def test_prefetched_share_counts_the_trains_asked_for_at_dispatch():
+    read = manifest.load_module("layers", "link_prefetched_pct.py").read
+    # a window that opened over a link from before the request: 510 of 680
+    part = {**LINK, "device_link_prefetched_steps": 510}
+    assert read(hand_made_run(part)) == pytest.approx(75.0)
+    # the host swap dispatches no program and asks for nothing
+    assert read(hand_made_run({**LINK, "device_link_prefetched_steps": 0})) == 0.0
+    # a program without the adder (the parent), or a window with no step
+    without = {k: v for k, v in LINK.items() if k != "device_link_prefetched_steps"}
+    assert read(hand_made_run(without)) is None
+    assert read(hand_made_run({"device_link_prefetched_steps": 0})) is None
 
 
 def test_unattributed_share_needs_every_stage_and_a_handler_span():

@@ -1359,3 +1359,213 @@ class TestSlotTrains:
         assert m["hold"].count() == m["rtt"].count() == link._seq
         assert m["hold"].max_latency() == 0
         assert dl.link_held.get_value() == held_before
+
+
+class TestHostCopyRequests:
+    """PR 36: a train's host copies are asked for when the train is
+    dispatched, not when the deliverer needs them."""
+
+    _make_link = TestSlotTrains._make_link
+    _gate_deliveries = staticmethod(TestSlotTrains._gate_deliveries)
+    SLOT_WORDS = TestSlotTrains.SLOT_WORDS
+
+    @staticmethod
+    def _spy_requests(link):
+        """Every ``_request_host`` of the link: the step output it was given
+        and the single-device arrays the runtime was asked to copy under it."""
+        from jax._src.array import ArrayImpl
+
+        asked, inner = [], ArrayImpl._copy_single_device_array_to_host_async
+        requests, request = [], link._request_host
+
+        def copy_spy(arr):
+            asked.append(id(arr))
+            return inner(arr)
+
+        def request_spy(out):
+            asked.clear()
+            ArrayImpl._copy_single_device_array_to_host_async = copy_spy
+            try:
+                request(out)
+            finally:
+                ArrayImpl._copy_single_device_array_to_host_async = inner
+            requests.append((out, list(asked)))
+
+        link._request_host = request_spy
+        return requests
+
+    @pytest.mark.parametrize("geometry", ["ppermute", "device-swap"])
+    def test_every_shard_is_asked_for_once_before_the_watcher_returns(self, geometry):
+        link, socks, sinks = self._make_link(geometry, window=8)
+        done, inner = [], link._on_step_done
+        link._on_step_done = lambda seq, arrays, *a, **k: (
+            done.append(arrays), inner(seq, arrays, *a, **k)
+        )[1]
+        gate, requests = self._gate_deliveries(link), self._spy_requests(link)
+        frames, stream = _framed_stream(36, 8 * 1024)
+        assert link.send(0, stream) == 0
+        # the train is out and its watcher parked before _on_step_done: the
+        # request was made on the drive's thread, at the dispatch
+        assert _wait(lambda: link._seq == 8 and not link._driving)
+        assert len(requests) == 1 and not done
+        gate.set()
+        assert _wait(lambda: sinks[1].nbytes == len(stream), timeout=30)
+        assert sinks[1].frames() == frames
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        (out, asked), = requests
+        assert done == [out]  # the array the deliverer reads is the one asked for
+        if geometry == "ppermute":
+            shards = out.addressable_shards
+            assert len(shards) == 2
+            assert sorted(asked) == sorted(id(s.data) for s in shards)
+        else:
+            assert asked == [id(out)]
+
+    @pytest.mark.parametrize("geometry", ["ppermute", "device-swap", "host-swap"])
+    def test_prefetched_steps_follow_steps_both_ways(self, geometry):
+        import jax
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        if geometry == "host-swap":
+            dev = jax.devices()[0]
+            link = dl.DeviceLink([dev, dev], slot_words=self.SLOT_WORDS, window=8)
+            sinks = (_FrameSink(), _FrameSink())
+            for i in (0, 1):
+                dl.DeviceSocket(link, side=i, messenger=sinks[i])
+        else:
+            link, socks, sinks = self._make_link(geometry, window=8)
+        assert link.geometry == geometry
+        dl._quiesce_links(timeout=5.0)  # earlier tests' links are idle
+        before = (dl.link_steps.get_value(), dl.link_prefetched.get_value())
+        a, b = _framed_stream(7, 45 * 1024 + 5), _framed_stream(8, 19 * 1024 - 3)
+        threads = [
+            threading.Thread(target=lambda s=s, d=d: link.send(s, d[1], timeout=60))
+            for s, d in ((0, a), (1, b))
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert _wait(lambda: sinks[1].nbytes == len(a[1]), timeout=60)
+        assert _wait(lambda: sinks[0].nbytes == len(b[1]), timeout=60)
+        assert sinks[1].frames() == a[0] and sinks[0].frames() == b[0]
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        steps = dl.link_steps.get_value() - before[0]
+        assert steps >= 6
+        # a program dispatched is a request made; the host swap dispatches none
+        assert dl.link_prefetched.get_value() - before[1] == (
+            0 if geometry == "host-swap" else steps
+        )
+
+    def test_one_mebibyte_echo_at_the_defaults_prefetches_every_train(self, echo_server):
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        ch = _tpu_channel(echo_server)
+        assert ch.call_method("EchoService", "Echo", b"warm").ok()
+        link = ch._device_sock.link
+        assert (link.slot_words, link.window, link.geometry) == (16384, 8, "ppermute")
+        dl._quiesce_links(timeout=5.0)
+        before = (dl.link_steps.get_value(), dl.link_prefetched.get_value())
+        big = bytes(range(256)) * 4096
+        cntl = ch.call_method("EchoService", "Echo", b"", attachment=big)
+        assert cntl.ok(), cntl.error_text
+        assert cntl.response_attachment == big
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+        steps = dl.link_steps.get_value() - before[0]
+        assert 6 <= steps <= 12
+        assert dl.link_prefetched.get_value() - before[1] == steps
+
+    @pytest.mark.parametrize(
+        "slots,window",
+        [(1, 8), (2, 8), (3, 8), (4, 8), (8, 8), (17, 8), (22, 8), (8, 4), (5, 1)],
+    )
+    def test_the_byte_stream_is_the_one_a_link_without_the_request_carries(
+        self, slots, window
+    ):
+        frames, stream = _framed_stream(slots, slots * 1024 - 100)
+        got = []
+        for request in (True, False):
+            link, socks, sinks = self._make_link(window=window)
+            if not request:
+                link._request_host = lambda out: None  # the parent's link
+            cuts = TestSlotTrains._spy_trains(link)
+            assert link.send(0, stream, timeout=60) == 0
+            assert _wait(lambda: sinks[1].nbytes == len(stream), timeout=60)
+            assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+            got.append(([k for k, _, _ in cuts], b"".join(sinks[1].chunks)))
+        assert got[0] == got[1] == (_trains(slots, window), stream)
+        assert sinks[1].frames() == frames
+
+    def test_a_request_that_raises_is_a_dispatch_that_failed(self):
+        link, socks, sinks = self._make_link(window=8)
+        request, calls = link._request_host, []
+
+        def failing(out):
+            calls.append(out.shape[1])
+            if len(calls) == 2:
+                raise RuntimeError("injected transfer fault")
+            request(out)
+
+        link._request_host = failing
+        rcs = []
+
+        def sender():
+            for _ in range(4):
+                rcs.append(link.send(0, b"z" * (10 * 1024), timeout=30))
+
+        t = threading.Thread(target=sender)
+        t.start()
+        t.join(timeout=20)
+        assert not t.is_alive()  # no sender left parked
+        assert _wait(lambda: link._closed, timeout=10)
+        assert calls[0] == 8 and len(calls) == 2  # the second train's request
+        assert ErrorCode.EFAILEDSOCKET in rcs
+        assert _wait(lambda: all(s.state != 0 for s in socks))
+        assert _wait(lambda: not link._driving)
+
+    @pytest.mark.parametrize("geometry", ["ppermute", "device-swap"])
+    def test_the_handshake_warms_request_and_readback_at_every_length(
+        self, geometry, monkeypatch
+    ):
+        import jax
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        warmed = []
+        request, rows_to_host = dl.DeviceLink._request_host, dl.DeviceLink._rows_to_host
+        monkeypatch.setattr(
+            dl.DeviceLink, "_request_host",
+            staticmethod(lambda out: (warmed.append(("request", out.shape[1])), request(out))[1]),
+        )
+        monkeypatch.setattr(
+            dl.DeviceLink, "_rows_to_host",
+            lambda self, arrays: (
+                warmed.append(("read", arrays.shape[1])), rows_to_host(self, arrays)
+            )[1],
+        )
+        compiles = []
+
+        def listener(name, *_a, **_k):
+            if name == "/jax/core/compile/backend_compile_duration":
+                compiles.append(name)
+
+        jax.monitoring.register_event_duration_secs_listener(listener)
+        try:
+            link, socks, sinks = self._make_link(geometry, window=8)
+            assert warmed == [
+                (what, k) for k in (1, 2, 4, 8) for what in ("request", "read")
+            ]
+            built = len(compiles)
+            frames, stream = _framed_stream(3, 15 * 1024)
+            assert link.send(0, stream, timeout=60) == 0
+            assert _wait(lambda: sinks[1].nbytes == len(stream), timeout=60)
+            assert sinks[1].frames() == frames
+            # 8, 4, 2, 1: each asked for once and read once, none compiled
+            live = warmed[8:]
+            assert sorted(live) == sorted(
+                (what, k) for k in (8, 4, 2, 1) for what in ("request", "read")
+            )
+            assert len(compiles) == built
+        finally:
+            jax.monitoring.unregister_event_duration_listener(listener)
