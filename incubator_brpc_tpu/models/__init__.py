@@ -6,11 +6,16 @@ workloads:
 
 - ``tensor_echo``: the echo_c++ analog — a fully jitted echo RPC step whose
   payload lives in HBM (framing + checksum + handler + response framing).
+- ``record_table``: a keyed record store whose table lives in HBM between
+  calls, behind the same ``DeviceEndpoint`` (YCSB's ``usertable``; a
+  parameter or embedding shard): reads gather, updates change the state the
+  next dispatch reads.
 - ``fabricnet``: the flagship multi-chip workload — a sharded MoE/pipeline
   network whose forward/backward exercises every combo-channel lowering
   (dp fan-out, tp partition, pp pipeline stream, sp ring, ep all_to_all).
 """
 
+from incubator_brpc_tpu.models.record_table import RecordTableService
 from incubator_brpc_tpu.models.tensor_echo import TensorEchoService, make_echo_step
 from incubator_brpc_tpu.models.fabricnet import (
     FabricNetConfig,
@@ -20,6 +25,7 @@ from incubator_brpc_tpu.models.fabricnet import (
 )
 
 __all__ = [
+    "RecordTableService",
     "TensorEchoService",
     "make_echo_step",
     "FabricNetConfig",

@@ -26,12 +26,17 @@ class TensorEchoService:
     """Registry of method_id -> jittable handler, mirroring Server's
     _method_map of MethodProperty (reference server.cpp:1209) at device level.
 
-    Handlers must be shape-preserving uint32->uint32 transforms (static
+    Handlers are shape-preserving uint32->uint32 transforms (static
     shapes; XLA traces each handler once per payload geometry). Behind a
-    ``DeviceEndpoint`` a handler sees its payload zero-padded to a width
-    the endpoint chooses (the call's bucket, or a wider one it shares a
-    dispatch with), and the caller is given the first ``n`` words of the
-    answer, ``n`` the words it sent: those must not depend on that width.
+    ``DeviceEndpoint`` this is the service that keeps no state
+    (``init_state`` gives ``None``), whose step is row-wise (a dispatch's
+    rows know nothing of each other: ``dispatch_step`` vmaps ``step``)
+    and whose answer is as long as its request (``answer_bytes``). A
+    handler sees its payload zero-padded to a width the endpoint chooses
+    (the call's bucket, or a wider one it shares a dispatch with), and
+    the caller is given the answer cut at the request's length: that part
+    must not depend on the width. ``models/record_table`` is the service
+    that keeps state, sees its batch whole and answers 1,000 B to 8.
     """
 
     def __init__(self) -> None:
@@ -83,6 +88,33 @@ class TensorEchoService:
             flags=framing.FLAG_RESPONSE,
             error_code=err,
         )
+
+    # -- what a DeviceEndpoint asks of its service --------------------------
+
+    def init_state(self, device) -> None:
+        """Nothing lives on the device between calls."""
+        return None
+
+    def answer_bytes(self, method_id: int, request_bytes: int) -> int:
+        return request_bytes
+
+    def dispatch_step(self, state, rows, cids, mids):
+        """One dispatch: every row framed and stepped on its own. A row
+        alone is stepped as the 1-D program it always was: vmapped over a
+        batch of one, XLA lays the frame out as ``[1, n]`` and assembles
+        it by update-slices (compiled for a v5e at 1 Mi words, PR 37)."""
+
+        def row(padded, cid_lo, mid):
+            return self.step(
+                framing.frame(padded, (cid_lo, jnp.uint32(0)), method_id=mid)
+            )
+
+        if rows.shape[0] == 1:
+            return state, row(rows[0], cids[0], mids[0])[None]
+        return state, jax.vmap(row)(rows, cids, mids)
+
+    def account(self, mids, frames) -> None:
+        """No counter of its own."""
 
 
 def make_echo_step(

@@ -22,9 +22,16 @@ A ``DeviceEndpoint`` is the RdmaEndpoint re-thought for XLA:
   the rows are stacked at the widest bucket among them, under
   ``MAX_STACKED_WORDS``.
 
+- a service may keep state in HBM between calls (models/record_table):
+  the endpoint owns it, hands it to every dispatch, donated, and takes
+  the next one back; dispatches launch in the order they took it, and a
+  dispatch sees its batch whole (docs/DEVICE_PLANE.md has the contract).
+
 ``DeviceEndpoint.call_bytes`` adapts the host byte world: payloads are
-padded into the bucket and responses trimmed to the request's length
-(handlers are shape-preserving word transforms). ``server_handler`` plugs
+padded into the bucket and responses cut at the length the service says
+its method answers that request with (an echo: the request's own; a
+record read: 1,000 B to 8), the bucket being the larger of the request
+and the answer. ``server_handler`` plugs
 an endpoint into an ordinary Server method map, giving the full
 host-RPC → HBM → fused-step → response path — the reference's
 "flip transport=tpu and rerun the same example pair" moment (SURVEY §7
@@ -36,13 +43,13 @@ from __future__ import annotations
 import itertools
 import threading
 from collections import deque
+from contextlib import nullcontext
 from functools import partial
 from typing import Optional, Tuple
 
 import time as _time
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
@@ -97,6 +104,8 @@ STAMPS = ("seq",) + _WALL + (
     "cut", "plane_callback", "sent",
     # the drain or -tx thread of the dispatch, then its completion watcher
     "batched_cpu", "stacked_cpu", "launched_cpu", "ready_cpu", "readback_cpu",
+    # the service's state in the dispatch's hand, inside stacked -> launched
+    "state",
 )
 # One recorder per stage of a call through call_bytes (us, one sample per
 # completed call), each the difference of the stamps beside it, so that
@@ -148,6 +157,10 @@ _recorders = {
     "stack_cpu": LatencyRecorder(name="device_transport_stack_cpu_us"),
     "launch_cpu": LatencyRecorder(name="device_transport_launch_cpu_us"),
     "readback_cpu": LatencyRecorder(name="device_transport_readback_cpu_us"),
+    # a part of the launch, not a tenth stage: rows stacked -> the service's
+    # state in the dispatch's hand, i.e. the wait for the dispatch before it
+    # to hand the state on (a service that keeps none waits for nothing)
+    "state_wait": LatencyRecorder(name="device_transport_state_wait_us"),
 }
 m_copy, m_credit_wait, m_queue_wait, m_stack, m_launch = (
     _recorders[s] for s in ("copy", "credit_wait", "queue_wait", "stack", "launch")
@@ -167,7 +180,8 @@ _stage_feed = RecorderFeed(
         (_recorders[stage + "_cpu"], 1e-3,
          tuple(stamp + "_cpu" for stamp in STAGES[stage]))
         for stage in CPU_STAGES
-    ],
+    ]
+    + [(_recorders["state_wait"], 1e-3, ("stacked", "state"))],
     stamps=STAMPS,
     name="device_transport",
     ring_rows=1 << 15,
@@ -232,7 +246,7 @@ class _Dispatch:
     __slots__ = (
         "seq", "rows", "pad_rows", "bucket", "widened_rows", "timed",
         "t_batched", "t_stacked", "t_launched", "watcher", "t_readback",
-        "c_batched", "c_stacked", "c_launched", "c_readback",
+        "c_batched", "c_stacked", "c_launched", "c_readback", "t_state",
     )
 
     def __init__(
@@ -246,6 +260,7 @@ class _Dispatch:
         self.timed = seq % CPU_CLOCK_EVERY == 0  # its stamps carry the CPU clock
         self.t_batched, self.c_batched = clocks(self.timed)  # taken off the queue
         self.t_stacked, self.c_stacked = 0, -1  # rows copied into one array
+        self.t_state = -1  # the service's state in hand, its turn come
         # the program call, which stages the rows, returned
         self.t_launched, self.c_launched = 0, -1
         # DeviceCompletionButex.watch fills these: a watcher thread took
@@ -309,7 +324,7 @@ class _PendingCall:
             d.t_readback, self.t_woke, self.t_exit or -1,
             cut, plane_callback, sent,
             d.c_batched, d.c_stacked, d.c_launched, d.watcher[-1] if d.timed else -1,
-            d.c_readback,
+            d.c_readback, d.t_state,
         )
 
     def timeline(self):
@@ -318,10 +333,12 @@ class _PendingCall:
 
     def stages(self) -> dict:
         """``{stage: ns}`` as ``_stage_feed``'s table cuts them from the
-        call's stamps (``<stage>_cpu`` on the CPU clock); the nine of a
-        call through call_bytes add up to ``t_exit - t_entry``."""
+        call's stamps (``<stage>_cpu`` on the CPU clock, ``state_wait`` a
+        part of ``launch``); the nine of a call through call_bytes add up
+        to ``t_exit - t_entry``."""
         values = _stage_feed.read(self.row())
         names = list(STAGES) + [stage + "_cpu" for stage in CPU_STAGES]
+        names.append("state_wait")
         return {n: v for n, v in zip(names, values) if v is not None}
 
     def annotate(self, span) -> None:
@@ -340,14 +357,69 @@ class _PendingCall:
             span.annotate("device " + name, at)
 
 
+# what an endpoint holds in place of a state it can no longer vouch for: a
+# program call raised with the state donated to it
+_LOST = object()
+
+
+class _StepProgram:
+    """The service's jitted step as the endpoint runs it: ``program(rows,
+    cids, mids) -> response frames``, the service's state taken from the
+    endpoint in turn, donated to the step, and the next one put back
+    before the turn passes on. ``dispatch`` gets the moment the state was
+    in hand."""
+
+    __slots__ = ("_endpoint", "_jitted")
+
+    def __init__(self, endpoint: "DeviceEndpoint", jitted):
+        self._endpoint, self._jitted = endpoint, jitted
+
+    def __call__(self, rows, cids, mids, dispatch: Optional["_Dispatch"] = None):
+        ep = self._endpoint
+        with ep._state_turn:
+            if dispatch is not None:
+                dispatch.t_state = _time.monotonic_ns()
+            state = ep._state
+            if state is _LOST:
+                raise RuntimeError(
+                    "the endpoint lost its service's state: an earlier "
+                    "dispatch raised with the state donated to it"
+                )
+            ep._state = ep._state_lost  # until the step hands the next one back
+            ep._state, frames = self._jitted(state, rows, cids, mids)
+        return frames
+
+    def _cache_size(self) -> int:
+        """Compiled geometries and fast-path entries (tests)."""
+        return self._jitted._cache_size()
+
+
 class DeviceEndpoint:
     """One device-resident service behind a credit window.
 
-    A handler sees its payload zero-padded to a width the endpoint
-    chooses: its call's bucket, or the widest bucket of the calls that
-    share its dispatch. A call gets back the first ``n`` words of the
-    answer, ``n`` the words it sent, so those must not depend on that
-    width (every elementwise handler qualifies).
+    The service (``models/tensor_echo``, ``models/record_table``) gives
+    ``init_state(device)``: what it keeps in HBM between calls, or
+    ``None``; ``dispatch_step(state, rows, cids, mids) -> (state',
+    response frames)``, jittable, over a whole batch of zero-padded
+    payload rows; ``answer_bytes(method_id, request_bytes)``: how long
+    that method's answer to such a request is; and ``account(mids,
+    frames)``, called on the host with a completed dispatch's method ids
+    and response frames (its counters). A call's bucket is the larger of
+    its request and its answer, its row is zero-padded to its bucket or
+    the widest bucket of the calls that share its dispatch, and it gets
+    back the answer's first ``answer_bytes`` bytes, so those must not
+    depend on that width.
+
+    The state is the endpoint's: it lies on ``device``, every dispatch
+    takes it, donates it to the program and puts the next one back, so a
+    table is updated where it lies. One dispatch holds it at a time
+    (``_state_turn``): dispatches launch in the order they took it, the
+    wait is ``device_transport_state_wait_us``, and the calls of one
+    dispatch are in flight together, so the service fixes their order
+    among themselves. A program call that raises with the state in its
+    hand fails the endpoint: the state may be gone, every later dispatch
+    fails, none is answered from a copy. A service that keeps nothing has
+    no turn to wait for and nothing to lose.
 
     A dispatch launches with one call of the jitted step program on the
     stacked host rows: the call stages its numpy arguments itself, onto
@@ -378,37 +450,48 @@ class DeviceEndpoint:
         self.max_batch = max(1, min(max_batch, window_size))
         self._credits = Butex(window_size)
         self._cq = DeviceCompletionButex()
-        self._queue = deque()  # (bucket, mid_u32, row, cid_u32, pending, n)
+        # (bucket, mid_u32, row, cid_u32, pending, words of the answer)
+        self._queue = deque()
         self._qlock = threading.Lock()
         self._draining = False
         self._dispatch_seq = itertools.count(1)
-        # frame-building fused INTO the jitted program; the batched form
-        # vmaps the same fused step over stacked rows (jit's per-shape
-        # cache gives one compiled program per (batch, bucket) geometry —
-        # the fixed-block discipline). in_shardings: a dispatch hands
-        # these the host arrays, which must land on self.device and not
-        # the default one, and must run the executable a caller warmed
-        # with arrays already committed there (without it each route
-        # compiles its own)
+        # what the service keeps on the device between dispatches (None:
+        # nothing), whose turn it is to hold it, and what is left once a
+        # program call raised with it: a service without state takes no
+        # turn (its dispatches launch side by side) and loses nothing
+        self._state = self.service.init_state(self.device)
+        stateless = self._state is None
+        self._state_turn = nullcontext() if stateless else threading.Lock()
+        self._state_lost = None if stateless else _LOST
+        # the service's step over (state, stacked rows, cids, mids), the
+        # state donated; jit's per-shape cache gives one compiled program
+        # per (batch, bucket) geometry — the fixed-block discipline. One
+        # row alone runs as a batch of one and answers one frame. The two
+        # are named for the trace: jit_step_row, jit_step_batch.
+        # in_shardings: a dispatch hands these the host arrays, which
+        # must land on self.device and not the default one, and must run
+        # the executable a caller warmed with arrays already committed
+        # there (without it each route compiles its own)
         on_device = SingleDeviceSharding(self.device)
-        self._program = jax.jit(
-            lambda padded, cid_lo, mid: self.service.step(
-                framing.frame(
-                    padded, (cid_lo, jnp.uint32(0)), method_id=mid
-                )
-            ),
-            in_shardings=on_device,
-        )
-        self._batch_program = jax.jit(
-            jax.vmap(
-                lambda padded, cid_lo, mid: self.service.step(
-                    framing.frame(
-                        padded, (cid_lo, jnp.uint32(0)), method_id=mid
-                    )
-                )
-            ),
-            in_shardings=on_device,
-        )
+        service = self.service
+
+        def step_batch(state, rows, cids, mids):
+            return service.dispatch_step(state, rows, cids, mids)
+
+        def step_row(state, padded, cid_lo, mid):
+            state, frames = step_batch(state, padded[None], cid_lo[None], mid[None])
+            return state, frames[0]
+
+        self._program = _StepProgram(
+            self, jax.jit(step_row, in_shardings=on_device, donate_argnums=0))
+        self._batch_program = _StepProgram(
+            self, jax.jit(step_batch, in_shardings=on_device, donate_argnums=0))
+
+    def _lose_state(self) -> None:
+        """A dispatch failed once its program held the state: what the
+        endpoint holds now was computed from it."""
+        with self._state_turn:
+            self._state = self._state_lost
 
     # -- credit window (rdma_endpoint.h:176-195) ----------------------------
 
@@ -454,9 +537,12 @@ class DeviceEndpoint:
             pending.settle()
             return pending
         pending.t_credit = _time.monotonic_ns()
-        n = payload_words.shape[0]
+        # the words of the answer the caller is owed, and the bucket that
+        # holds both the request and it
+        sent = payload_words.shape[0]
+        n = -(-self.service.answer_bytes(method_id, 4 * sent) // 4)
         try:
-            bucket = _bucket_words(max(1, n))
+            bucket = _bucket_words(max(1, n, sent))
         except ValueError:
             # oversized payload: the credit MUST come back (a leak here
             # shrinks the window forever) and the caller gets the settled-
@@ -466,7 +552,7 @@ class DeviceEndpoint:
             pending.settle()
             return pending
         padded = np.zeros(bucket, dtype=np.uint32)
-        padded[:n] = payload_words
+        padded[:sent] = payload_words
         pending.t_enqueued = _time.monotonic_ns()
         with self._qlock:
             self._queue.append(
@@ -556,10 +642,10 @@ class DeviceEndpoint:
         # itself. rows, cids and mids are this dispatch's own and are not
         # written again (the runtime may still be reading them)
         try:
-            if bpad == 1:  # single call: no vmap overhead
-                response = self._program(rows[0], cids[0], mids[0])
+            if bpad == 1:  # a call alone: the one-row program, one frame back
+                response = self._program(rows[0], cids[0], mids[0], dispatch)
             else:
-                response = self._batch_program(rows, cids, mids)
+                response = self._batch_program(rows, cids, mids, dispatch)
         except Exception as e:  # dispatch failed: settle the whole batch
             for _, _mid, _padded, _cid, pending, _n in batch:
                 self._release_credit()
@@ -574,6 +660,8 @@ class DeviceEndpoint:
                 host = None
                 if error is None:
                     host = np.asarray(jax.device_get(arrays))
+                    if _single:
+                        host = host[None]
             except Exception as e:  # noqa: BLE001 — fetch failed
                 error, host = e, None
             dispatch.t_readback, dispatch.c_readback = clocks(dispatch.timed)
@@ -583,8 +671,7 @@ class DeviceEndpoint:
                         pending.error = error
                         pending.error_code = ErrorCode.EINTERNAL
                     else:
-                        row = host if _single else host[i]
-                        _, words, err = _parse_response(row)
+                        _, words, err = _parse_response(host[i])
                         pending.error_code = int(err)
                         pending.response_words = words[:n]
                     device_latency << (
@@ -597,6 +684,8 @@ class DeviceEndpoint:
                 finally:
                     self._release_credit()
                     pending.settle()
+            if error is not None:
+                self._lose_state()  # what the next state was computed from
             # after the callers are awake: this thread is a pooled
             # watcher, so the adders keep one agent each (the drain and
             # -tx threads are born per dispatch)
@@ -605,6 +694,8 @@ class DeviceEndpoint:
             m_dispatch_pad_rows << dispatch.pad_rows
             m_dispatch_words << dispatch.pad_rows * dispatch.bucket
             m_dispatch_widened_rows << dispatch.widened_rows
+            if host is not None:
+                self.service.account(mids[:b], host[:b])
 
         self._cq.watch(
             response, on_complete=on_complete, stamps=dispatch.watcher
@@ -618,8 +709,9 @@ class DeviceEndpoint:
         timeout: Optional[float] = 10.0,
         cntl=None,
     ) -> Tuple[int, bytes]:
-        """Sync byte adapter: pad to words, run, trim the response to the
-        request's byte length (handlers are shape-preserving). ``cntl``:
+        """Sync byte adapter: pad to words, run, cut the response at the
+        byte length the service says this method answers such a request
+        with (``answer_bytes``; an echo's is the request's own). ``cntl``:
         the server-side controller of the RPC this call serves, if any —
         its arrival stamp gives the ingress time, and its rpcz span, if
         sampled, gets the call's timeline as annotations."""
@@ -641,7 +733,9 @@ class DeviceEndpoint:
             return ErrorCode.ERPCTIMEDOUT, b""
         out = b""
         if not pending.error_code:
-            out = pending.response_words.tobytes()[:nbytes]
+            out = pending.response_words.tobytes()[
+                : self.service.answer_bytes(method_id, nbytes)
+            ]
         pending.t_exit = _time.monotonic_ns()
         if pending.completed():
             after_send = getattr(cntl, "_after_send", None)
@@ -653,13 +747,21 @@ class DeviceEndpoint:
                 after_send.append(partial(_record, pending, cntl))
         return pending.error_code, out
 
-    def warm(self, payload_bytes: int, timeout: float = 300.0) -> None:
-        """Compile every (batch, bucket) geometry this payload size can hit
-        — single + each power-of-two batch up to max_batch — so a timed or
-        latency-sensitive workload never pays XLA compilation mid-flight.
-        Batch formation depends on arrival timing, so a concurrency burst
-        does NOT reliably warm the larger geometries; this does."""
-        n_words = max(1, (payload_bytes + 3) // 4)
+    def warm(
+        self, payload_bytes: int, timeout: float = 300.0, method_id: int = 0
+    ) -> None:
+        """Compile every (batch, bucket) geometry a ``method_id`` request
+        of this payload size can hit — single + each power-of-two batch up
+        to max_batch — so a timed or latency-sensitive workload never pays
+        XLA compilation mid-flight. Batch formation
+        depends on arrival timing, so a concurrency burst does NOT
+        reliably warm the larger geometries; this does. The rows it runs
+        are a dispatch's pad rows (zero payload, method 0), so a service
+        with state must answer those without touching it."""
+        n_words = max(
+            1, (payload_bytes + 3) // 4,
+            (self.service.answer_bytes(method_id, payload_bytes) + 3) // 4,
+        )
         bucket = _bucket_words(n_words)
         # host-typed arguments, as _dispatch_batch hands them
         row = np.zeros(bucket, dtype=np.uint32)

@@ -17,7 +17,7 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import manifest, roofline, xplane  # noqa: E402
+from benchmark import manifest, roofline, roofline_table, xplane  # noqa: E402
 
 with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
     BENCH = json.load(_f)
@@ -184,10 +184,24 @@ EXPECTED = {
        for s in ("pack", "put", "launch", "gather", "merge")},
     # 30 s of CPU time in a window of 20: a processor and a half kept busy
     "host_cpu_cores": ({"device_transport_process_cpu_us": 30e6}, 1.5),
+    # PR 37: the wait for the table, a part of the launch
+    "table_state_wait_us": (
+        {"device_transport_state_wait_us": recorder(100, 640.0)}, 640.0),
+}
+# PR 37's record table over a window: 40 dispatches that ran 128 rows, 90 of
+# them reads and 10 updates, two of which a later row of their dispatch replaced
+TABLE = {
+    **DEVICE,
+    "device_transport_table_reads": 90,
+    "device_transport_table_updates": 10,
+    "device_transport_table_overwritten_rows": 2,
+    "device_transport_state_wait_us": recorder(100, 640.0),
 }
 # PR 31's and PR 33's device_trace readers: not a counter's mean, so
 # outside EXPECTED
 TRACE_READERS = {"link_step_ici_pct", "combo_step_kernel_us", "combo_gather_ici_pct"}
+# PR 37's: the table step's device time and its share of the HBM roofline
+TABLE_TRACE_READERS = {"table_step_kernel_us", "table_step_hbm_pct"}
 # PR 35's readers of the program's kept rows (benchmark/timeline.py): their
 # numbers are checked in tests/test_stage_timeline.py
 SPAN_READERS = {"idle_worker_open_pct", "idle_waiting_only_pct", "idle_outside_pct"}
@@ -218,7 +232,8 @@ def test_every_metric_is_accounted_for():
     names = [m["name"] for m in BENCH["per_layer"]]
     assert len(names) == len(set(names))
     # a later PR may add more
-    assert OLDER | set(EXPECTED) | TRACE_READERS | SPAN_READERS <= set(names)
+    assert (OLDER | set(EXPECTED) | TRACE_READERS | TABLE_TRACE_READERS
+            | SPAN_READERS <= set(names))
     with open(os.path.join(ROOT, "PERF.md"), encoding="utf-8") as f:
         perf = f.read()
     for m in BENCH["per_layer"]:
@@ -245,6 +260,9 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
         elif name == "native_plane_callback_us":
             # only the native plane feeds it
             assert cells[name] == ["echo_256b_c16_native"]
+        elif name == "table_state_wait_us":
+            # only the record table keeps a state to wait for
+            assert cells[name] == ["ycsb_b_zipf_c16"]
         elif name in ("dispatch_pad_pct", "dispatch_widened_pct"):
             assert {"echo_256b_c16", "echo_mixed_c16"} <= set(cells[name])
             assert "echo_4m_c2" not in cells[name]
@@ -334,12 +352,31 @@ def test_the_new_entries_only_follow_the_old():
     assert names[56] == "combo_gather_ici_pct" and names[57] == "device_stack_cpu_us"
     assert all(m["moves"] == "latency_p50_us" for m in BENCH["per_layer"][57:72])
     # PR 36's one entry follows PR 35's fifteen
-    assert names[72:] == ["link_prefetched_pct"]
+    assert names[72] == "link_prefetched_pct"
     assert BENCH["per_layer"][72] == {
         "name": "link_prefetched_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "link", "moves": "goodput",
         "workloads": ["link_echo_ici_1m", "link_stream_ici"],
     }
+    # PR 37's three follow it, and its configuration and cell the old ones
+    assert names[73:] == [
+        "table_step_kernel_us", "table_step_hbm_pct", "table_state_wait_us"]
+    for entry, source, layer, moves in zip(
+            BENCH["per_layer"][73:],
+            ("device_trace", "device_trace", "program_counter"),
+            ("device program", "device program",
+             "host to HBM crossing and completion"),
+            ("latency_p50_us", "call_rate", "latency_p50_us")):
+        assert (entry["source"], entry["layer"], entry["moves"]) == (
+            source, layer, moves)
+        assert entry["workloads"] == ["ycsb_b_zipf_c16"]
+    assert [c["name"] for c in BENCH["configs"]][5:] == ["ycsb_b_device_table"]
+    assert CELLS[7:] == ["ycsb_b_zipf_c16"]
+    assert BENCH["workloads"][7]["chips"] == 1
+    for m in BENCH["end_to_end"] + BENCH["per_layer"][:73]:
+        # an older metric gained the cell's name at the end of its list or not at all
+        if "ycsb_b_zipf_c16" in m.get("workloads", ()):
+            assert m["workloads"][-1] == "ycsb_b_zipf_c16", m["name"]
 
 
 def test_every_per_layer_entry_has_its_reader_file():
@@ -510,3 +547,120 @@ def test_window_share_needs_the_configuration_to_state_a_window():
     run = hand_made_run(dict(STREAM))
     run.cell = types.SimpleNamespace(config={"channel_options": {}})
     assert read(run) is None
+
+
+# -- PR 37: the record table's configuration, cell and readers ----------------
+
+YCSB_JOINS = (
+    [f"device_{s}_us" for s in DEVICE_STAGES]
+    + ["device_path_unattributed_pct", "device_path_us", "host_plane_ingress_us",
+       "host_plane_egress_us", "dispatch_rows", "dispatch_pad_pct",
+       "dispatch_widened_pct", "device_stack_cpu_us", "device_launch_cpu_us",
+       "device_readback_cpu_us", "host_cpu_cores", "idle_worker_open_pct",
+       "idle_waiting_only_pct", "idle_outside_pct", "device_idle_pct"])
+YCSB_STAYS_OUT = (
+    "echo_step_kernel_us", "echo_step_hbm_pct", "echo_step_hbm_pct_dispatched",
+    "calls_per_dispatch", "host_plane_us", "native_plane_callback_us")
+
+
+@pytest.mark.parametrize("metric", YCSB_JOINS)
+def test_the_table_cell_joins_the_metric_its_deployment_feeds(metric):
+    cell = manifest.Cell(BENCH, "ycsb_b_zipf_c16")
+    assert metric in [m["name"] for m in cell.per_layer]
+
+
+@pytest.mark.parametrize("metric", YCSB_STAYS_OUT)
+def test_the_table_cell_stays_out_of_what_reads_an_echo_or_another_process(metric):
+    cell = manifest.Cell(BENCH, "ycsb_b_zipf_c16")
+    assert metric not in [m["name"] for m in cell.per_layer]
+
+
+def test_the_table_cell_reports_rate_and_tails():
+    cell = manifest.Cell(BENCH, "ycsb_b_zipf_c16")
+    assert {m["name"] for m in cell.end_to_end} == {
+        "call_rate", "latency_p50_us", "latency_p99_us", "setup_s"}
+    assert cell.traffic == {
+        "what": cell.traffic["what"], "arrival": "closed", "callers": 16,
+        "sizes": [128], "carrier": "payload", "service": "ycsb",
+        "method": "operation", "pool_per_size": 2048,
+        "warm_calls_per_caller": 8, "warm_seconds": 3.0,
+    }
+
+
+def test_the_table_configuration_keeps_the_sources_shapes():
+    config = manifest.load_json("configs", "ycsb_b_device_table.json")
+    (entry,) = [c for c in BENCH["configs"] if c["name"] == "ycsb_b_device_table"]
+    assert entry["file"] == "benchmark/configs/ycsb_b_device_table.json"
+    assert entry["source"] == config["source"] and len(entry["source"]) <= 200
+    assert config["reduced"] == entry["reduced"] == ["recordcount"]
+    assert (config["deployment"], config["reference"], config["generator"]) == (
+        "record_table", "ycsb_record_store", "in_process")
+    # workloads/workloadb and CoreWorkload.java's defaults, as they are
+    assert (config["fieldcount"], config["fieldlength"]) == (10, 100)
+    assert (config["readproportion"], config["updateproportion"]) == (0.95, 0.05)
+    assert config["scanproportion"] == config["insertproportion"] == 0
+    assert config["readallfields"] is True and config["writeallfields"] is False
+    assert (config["requestdistribution"], config["zipfian_constant"]) == (
+        "zipfian", 0.99)
+    # cut to one chip: 8 GiB of rows of 256 words, half of its memory
+    assert config["recordcount"] * config["row_words"] * 4 == 8 << 30
+    assert config["rehearsal_records"] == 4096
+    assert config["endpoint"] == {"window_size": 16, "max_batch": 16}
+    assert len(config["guarantees"]) >= 5
+    for key in ("key", "table_seed", "threadcount", "operationcount", "generator",
+                "allocator"):
+        assert key in config["assumed"], key
+    reference = manifest.load_module("references", "ycsb_record_store.py")
+    assert (reference.FIELDCOUNT, reference.FIELDLENGTH) == (10, 100)
+    assert (reference.READPROPORTION, reference.ZIPFIAN_CONSTANT) == (0.95, 0.99)
+    assert reference.RECORDCOUNT == config["recordcount"]
+
+
+def test_the_table_deployment_has_the_two_controls_every_configuration_has():
+    deployment = manifest.load_module("deployments", "record_table.py")
+    assert deployment.CONTROLS == ("flip_bit", "stale")
+    for name in ("port", "warm", "holds", "close", "channel"):
+        assert name == "port" or callable(getattr(deployment.Deployment, name))
+
+
+def test_roofline_table_counts_three_reads_and_an_update_by_hand():
+    # four rows ran: a request row each at the narrowest bucket, 64 words;
+    # a read reads its 1,024 B row and writes a frame of 32 + 1,000 B;
+    # the update writes its 100 B field
+    by_hand = 4 * 256 + 3 * (1024 + 32 + 1000) + 1 * 100
+    assert by_hand == 7292
+    assert roofline_table.table_step_bytes(reads=3, updates=1, rows_run=4) == by_hand
+    # pad rows are rows the step had to read to know they ask nothing
+    assert roofline_table.table_step_bytes(3, 1, 8) == by_hand + 4 * 256
+    assert roofline_table.table_step_bytes(0, 0, 0) == 0
+    assert roofline_table.REQUEST_ROW_BYTES == 4 * roofline.bucket_words(8)
+    assert roofline_table.FRAME_HEADER_BYTES == 4 * roofline.FRAME_HEADER_WORDS
+
+
+def test_table_step_readers_from_fabricated_counters_and_a_fabricated_trace():
+    kernel = manifest.load_module("layers", "table_step_kernel_us.py").read
+    share = manifest.load_module("layers", "table_step_hbm_pct.py").read
+    wait = manifest.load_module("layers", "table_state_wait_us.py").read
+    run = hand_made_run(dict(TABLE))
+    # 50 executions of 2 us in the window
+    assert kernel(run) == pytest.approx(2.0)
+    # 128 rows run, 90 reads, 10 updates, in 100 us of steps, against 819 GB/s
+    least = 128 * 256 + 90 * (1024 + 32 + 1000) + 10 * 100
+    assert least == 218_808
+    want = 100.0 * least / 819e9 / 100e-6
+    assert share(run) == pytest.approx(want) and 0 < want < 105
+    assert wait(run) == pytest.approx(640.0)
+    # a program without the table's counters (the parent, an echo cell)
+    for reader in (kernel, share, wait):
+        assert reader(hand_made_run(dict(DEVICE))) is None
+        assert reader(hand_made_run({})) is None
+    # a CPU rehearsal: no device plane, no peaks
+    run = hand_made_run(dict(TABLE))
+    run.devices = {}
+    assert kernel(run) is None and share(run) is None
+    run = hand_made_run(dict(TABLE))
+    run.peaks = None
+    assert share(run) is None and kernel(run) == pytest.approx(2.0)
+    # a window that served nothing ran no row
+    idle = dict(TABLE, device_transport_dispatch_pad_rows=0)
+    assert share(hand_made_run(idle)) is None

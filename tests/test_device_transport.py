@@ -749,10 +749,11 @@ class TestLaunch:
         monkeypatch.setattr(
             device, "jax", _Counting(device.jax, ("device_put",), calls)
         )
-        monkeypatch.setattr(
-            device, "jnp",
-            _Counting(device.jnp, ("asarray", "array", "uint32", "zeros"), calls),
-        )
+        # the proxy does see a call (the frame's own jnp calls are the
+        # service's since PR 37, traced inside the program call)
+        device.jax.device_put(np.zeros(1, np.uint32))
+        assert [name for name, _ in calls] == ["device_put"]
+        calls.clear()
         launched = []
         for b in (1, 2, 5, 16):
             batch, _ = _stacked_batch(ep, 64, b)
@@ -762,15 +763,12 @@ class TestLaunch:
             d = batch[0][4].dispatch
             launched.append((d.t_stacked, d.t_launched))
             assert 0 < d.t_stacked <= d.t_launched
-        # the proxies do see a call: the cold geometry traces the lambda
-        # (jnp.uint32(0), the id's high word) inside its program call
         assert calls == []
+        # a cold geometry compiles inside its program call and stages nothing
         batch, _ = _stacked_batch(ep, 128, 1)
         ep._dispatch_batch(128, batch)
         assert batch[0][4].wait(timeout=60)
-        assert [name for name, _ in calls] and {n for n, _ in calls} == {"uint32"}
-        for t0, t1 in launched:
-            assert not [c for c in calls if t0 <= c[1] <= t1]
+        assert calls == []
 
 
 def _compiles(caplog):
@@ -779,7 +777,7 @@ def _compiles(caplog):
     return [
         r.getMessage() for r in caplog.records
         if r.name == "jax._src.interpreters.pxla"
-        and r.getMessage().startswith("Compiling jit(<lambda>)")
+        and r.getMessage().startswith("Compiling jit(step_")
     ]
 
 

@@ -158,11 +158,14 @@ def test_the_programs_own_feeds_are_built_from_those_tables():
     from incubator_brpc_tpu.transport import device
 
     feed = device._stage_feed
-    assert feed.stamps == device.STAMPS and len(feed.stamps) == 21
+    # PR 37: the state's stamp follows PR 35's 21, its recorder their columns
+    assert feed.stamps == device.STAMPS and len(feed.stamps) == 22
+    assert feed.stamps[-1] == "state"
     names = [r._exposed_name for r, *_rest in feed.columns]
     assert names == (
         [f"device_transport_{s}_us" for s in device.STAGES]
-        + [f"device_transport_{s}_cpu_us" for s in device.CPU_STAGES])
+        + [f"device_transport_{s}_cpu_us" for s in device.CPU_STAGES]
+        + ["device_transport_state_wait_us"])
     assert len(combo.COMBO_VARS.calls.stamps) == 18
     for namespace in (stream.HOST_VARS, stream.LINK_VARS):
         for one in (namespace.writes, namespace.feedbacks, namespace.delivers,
