@@ -102,6 +102,7 @@ LINK = {
     "device_link_3_pump_cpu_us": recorder(680, 450.0),
     "device_link_held_steps": 170,
     "device_link_prefetched_steps": 680,
+    "device_link_staged_steps": 680,
     "device_link_bytes": 40 * (1 << 20),
     "device_link_capacity_bytes": 2 * 680 * 65536,
     "device_link_steps": 680,
@@ -166,6 +167,7 @@ EXPECTED = {
     "link_hold_us": (LINK, 750.0),
     "link_held_pct": (LINK, 25.0),
     "link_prefetched_pct": (LINK, 100.0),
+    "link_staged_pct": (LINK, 100.0),
     "stream_write_wait_us": (STREAM, 7000.0),
     "stream_feedback_lag_us": (STREAM, 21000.0),
     "stream_deliver_us": (STREAM, 450.0),
@@ -359,10 +361,16 @@ def test_the_new_entries_only_follow_the_old():
         "workloads": ["link_echo_ici_1m", "link_stream_ici"],
     }
     # PR 37's three follow it, and its configuration and cell the old ones
-    assert names[73:] == [
+    assert names[73:76] == [
         "table_step_kernel_us", "table_step_hbm_pct", "table_state_wait_us"]
+    # PR 38's one entry follows them
+    assert BENCH["per_layer"][76:] == [{
+        "name": "link_staged_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "link", "moves": "goodput",
+        "workloads": ["link_echo_ici_1m", "link_stream_ici"],
+    }]
     for entry, source, layer, moves in zip(
-            BENCH["per_layer"][73:],
+            BENCH["per_layer"][73:76],
             ("device_trace", "device_trace", "program_counter"),
             ("device program", "device program",
              "host to HBM crossing and completion"),
@@ -396,6 +404,27 @@ def test_prefetched_share_counts_the_trains_asked_for_at_dispatch():
     without = {k: v for k, v in LINK.items() if k != "device_link_prefetched_steps"}
     assert read(hand_made_run(without)) is None
     assert read(hand_made_run({"device_link_prefetched_steps": 0})) is None
+
+
+@pytest.mark.parametrize(
+    "counters,share",
+    [
+        # every train of the window launched from one host buffer
+        (LINK, 100.0),
+        # a window that opened over a link from before the staging: 170 of 680
+        ({**LINK, "device_link_staged_steps": 170}, 25.0),
+        # the host swap dispatches no program and stages nothing
+        ({**LINK, "device_link_staged_steps": 0}, 0.0),
+        # a program without the adder (the parent), or a window with no step
+        ({k: v for k, v in LINK.items() if k != "device_link_staged_steps"}, None),
+        ({"device_link_staged_steps": 0}, None),
+    ],
+    ids=["every-train", "a-quarter", "host-swap", "no-adder", "no-step"],
+)
+def test_staged_share_counts_the_trains_launched_from_one_host_buffer(counters, share):
+    read = manifest.load_module("layers", "link_staged_pct.py").read
+    value = read(hand_made_run(dict(counters)))
+    assert value is None if share is None else value == pytest.approx(share)
 
 
 def test_unattributed_share_needs_every_stage_and_a_handler_span():

@@ -2,6 +2,7 @@
 test/brpc_rdma_unittest.cpp — handshake, data path, flow control, teardown
 — run loopback on the virtual device mesh, SURVEY §4's prescription)."""
 
+import contextlib
 import threading
 import time
 
@@ -1569,3 +1570,230 @@ class TestHostCopyRequests:
             assert len(compiles) == built
         finally:
             jax.monitoring.unregister_event_duration_listener(listener)
+
+
+@contextlib.contextmanager
+def _backend_compiles():
+    """The backend compilations of the process while the block runs."""
+    import jax
+
+    compiles = []
+
+    def listener(name, *_a, **_k):
+        if name == "/jax/core/compile/backend_compile_duration":
+            compiles.append(name)
+
+    jax.monitoring.register_event_duration_secs_listener(listener)
+    try:
+        yield compiles
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listener)
+
+
+class _DirtyNumpy:
+    """numpy as the link sees it, except that ``empty`` hands back memory
+    full of a pattern: a word the fill leaves unwritten arrives as it."""
+
+    PATTERN = 0xA5A5A5A5
+
+    def __getattr__(self, name):
+        import numpy as np
+
+        return getattr(np, name)
+
+    def empty(self, shape, dtype):
+        import numpy as np
+
+        return np.full(shape, self.PATTERN, dtype=dtype)
+
+
+class TestTrainStagedOnce:
+    """PR 38: a train is staged once. Both sides' slots are filled into one
+    ``(2, k, width)`` host buffer and that buffer is what the exchange
+    program is called with; on the host swap its halves are what the
+    deliverer is handed."""
+
+    SLOT_WORDS = TestSlotTrains.SLOT_WORDS
+    GEOMETRIES = ["host-swap", "device-swap", "ppermute"]
+    _queue_then_drive = staticmethod(TestSlotTrains._queue_then_drive)
+
+    def _make_link(self, geometry, **kw):
+        import jax
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        if geometry != "host-swap":
+            return TestSlotTrains._make_link(self, geometry, **kw)
+        dev = jax.devices()[0]
+        link = dl.DeviceLink([dev, dev], slot_words=self.SLOT_WORDS, **kw)
+        assert link.geometry == geometry
+        sinks = (_FrameSink(), _FrameSink())
+        socks = [dl.DeviceSocket(link, side=i, messenger=sinks[i]) for i in (0, 1)]
+        return link, socks, sinks
+
+    @staticmethod
+    def _uneven(k):
+        """Side 0 fills ``k`` slots but for 100 bytes, side 1 half a slot
+        less than half as many: both sides loaded, neither evenly."""
+        a = _framed_stream(40 + k, k * 1024 - 100)
+        b = _framed_stream(50 + k, max(1, k // 2) * 1024 - 512)
+        return a, b
+
+    def _exchange(self, link, sinks, a, b):
+        had = (sinks[0].nbytes, sinks[1].nbytes)
+        self._queue_then_drive(
+            link, lambda: (link.send(0, a[1]), link.send(1, b[1]))
+        )
+        assert _wait(lambda: sinks[1].nbytes == had[1] + len(a[1]), timeout=60)
+        assert _wait(lambda: sinks[0].nbytes == had[0] + len(b[1]), timeout=60)
+        assert _wait(lambda: link.inflight_steps == 0 and not link._driving)
+
+    @pytest.mark.parametrize("k", [1, 2, 4, 8])
+    @pytest.mark.parametrize("geometry", GEOMETRIES)
+    def test_both_sides_arrive_in_order_with_unwritten_words_zeroed(
+        self, geometry, k, monkeypatch
+    ):
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link(geometry, window=8)
+        monkeypatch.setattr(dl, "np", _DirtyNumpy())
+        cuts = TestSlotTrains._spy_trains(link)
+        arrived, deliver = [], link._deliver
+        link._deliver = lambda rows: (
+            arrived.append([np.array(r) for r in rows]), deliver(rows)
+        )[1]
+        a, b = self._uneven(k)
+        self._exchange(link, sinks, a, b)
+        # bytes out equal bytes in, in order, both ways
+        assert b"".join(sinks[1].chunks) == a[1] and sinks[1].frames() == a[0]
+        assert b"".join(sinks[0].chunks) == b[1] and sinks[0].frames() == b[0]
+        # one train of k slots a side; the host swap keeps one slot a step
+        assert [c[0] for c in cuts] == ([1] * k if geometry == "host-swap" else [k])
+        base = dl.LINK_HEADER_WORDS * 4
+        slots = [row for rows in arrived for side in rows for row in side]
+        assert len(slots) == 2 * k
+        short = 0
+        for row in slots:
+            assert int(row[0]) == dl.LINK_MAGIC
+            assert not row[6 : dl.LINK_HEADER_WORDS].any()  # reserved words
+            used = int(row[1])
+            short += used < self.SLOT_WORDS * 4
+            if geometry != "host-swap":
+                # the whole row crossed the wire: nothing of the heap with it
+                assert not row.view(np.uint8)[base + used :].any()
+        # side 0's last slot, side 1's last and every empty slot behind it
+        assert short == 1 + (k - max(1, k // 2) + 1)
+
+    @pytest.mark.parametrize("k", [1, 2, 4, 8])
+    @pytest.mark.parametrize("geometry", GEOMETRIES)
+    def test_a_train_is_one_host_buffer_and_one_call_into_the_runtime(
+        self, geometry, k, monkeypatch
+    ):
+        import jax
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link(geometry, window=8)
+        driving = threading.local()
+        placed, drive = [], link._drive
+
+        def spied_drive():
+            driving.on = True
+            try:
+                drive()
+            finally:
+                driving.on = False
+
+        link._drive = spied_drive
+        for name in ("device_put", "make_array_from_single_device_arrays"):
+            inner = getattr(jax, name)
+            monkeypatch.setattr(
+                jax, name,
+                lambda *a, _inner=inner, _name=name, **kw: (
+                    placed.append(_name) if getattr(driving, "on", False) else None,
+                    _inner(*a, **kw),
+                )[1],
+            )
+        handed = []
+        if geometry == "host-swap":
+            done = link._on_step_done
+            link._on_step_done = lambda seq, arrays, *r: (
+                handed.append(arrays[1]), done(seq, arrays, *r)
+            )[1]
+        else:
+            step = link._step
+            link._step = lambda both: (handed.append(both), step(both))[1]
+        dl._quiesce_links(timeout=5.0)  # earlier tests' links are idle
+        before = (dl.link_steps.get_value(), dl.link_staged.get_value())
+        a, b = self._uneven(k)
+        self._exchange(link, sinks, a, b)
+        assert sinks[1].frames() == a[0] and sinks[0].frames() == b[0]
+        steps = dl.link_steps.get_value() - before[0]
+        # no staging call from the drive: the program call places the buffer
+        assert placed == []
+        if geometry == "host-swap":
+            # no program, nothing staged: the deliverer is handed the two
+            # halves of the one buffer, the peer's first
+            assert steps == k and dl.link_staged.get_value() == before[1]
+            for rows in handed:
+                assert rows[0].base is rows[1].base
+                assert rows[0].base.shape == (2, 1, link._width)
+                assert np.shares_memory(rows[0], rows[0].base[1])
+                assert np.shares_memory(rows[1], rows[1].base[0])
+            return
+        assert steps == 1 and dl.link_staged.get_value() - before[1] == 1
+        (both,) = handed
+        assert type(both) is np.ndarray and both.dtype == np.uint32
+        assert both.shape == (2, k, link._width)
+
+    @pytest.mark.parametrize("window", [8, 4])
+    @pytest.mark.parametrize("geometry", ["device-swap", "ppermute"])
+    def test_the_handshake_warms_the_host_buffer_call_at_every_length(
+        self, geometry, window
+    ):
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        warmed = []
+        with _backend_compiles() as compiles:
+            link, socks, sinks = self._make_link(geometry, window=window)
+            built = len(compiles)
+            lengths = [k for k in (1, 2, 4, 8) if k <= window]
+            assert built >= len(lengths)  # one program a train length
+            # _warm_step calls what _drive calls, with what _drive hands it
+            step = link._step
+            link._step = lambda both: (warmed.append(both), step(both))[1]
+            link._warm_step()
+            assert [(type(w), w.shape) for w in warmed] == [
+                (np.ndarray, (2, k, link._width)) for k in lengths
+            ]
+            assert len(compiles) == built
+            cuts = TestSlotTrains._spy_trains(link)
+            before = dl.link_staged.get_value()
+            for k in lengths:
+                self._exchange(link, sinks, *self._uneven(k))
+            assert [c[0] for c in cuts] == lengths
+            assert dl.link_staged.get_value() - before >= len(lengths)
+            assert len(compiles) == built  # no train of live traffic compiled
+
+    def test_the_step_takes_a_committed_array_of_its_sharding_as_it_is(self):
+        """What ``MultiControllerLink`` hands the inherited step: a global
+        array already laid out over the link's two devices."""
+        import jax
+        import numpy as np
+
+        link, socks, sinks = self._make_link("ppermute", window=8)
+        rows = np.arange(2 * 4 * link._width, dtype=np.uint32).reshape(2, 4, -1)
+        shards = [jax.device_put(rows[i][None], link.devices[i]) for i in (0, 1)]
+        placed = jax.make_array_from_single_device_arrays(
+            rows.shape, link._sharding, shards
+        )
+        out = link._step(placed)
+        assert out.sharding == link._sharding
+        assert np.array_equal(np.asarray(out), rows[::-1])
+        # and the same rows as a host buffer give the same exchange
+        assert np.array_equal(np.asarray(link._step(rows)), rows[::-1])
