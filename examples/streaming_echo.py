@@ -6,7 +6,13 @@ sink form (upstream's server.cpp consumes and writes nothing back): one
 transfer of --total_bytes in --message_bytes messages to a sink that
 acknowledges the whole with one receipt, GB/s printed, as
 link_performance.py does for its configuration. This is the shape of the
-benchmark's link_stream_sink_ici deployment.
+benchmark's link_stream_sink_ici deployment. Last, the same write loop with
+the messages device arrays (``Stream.write`` of a ``jax.Array``): over a
+device link between two devices the sink's handler is handed a
+``jax.Array`` on its own device and no block touches host memory; over
+host sockets, or a link on one shared device, it is handed the array's
+bytes. Nothing chooses but what the socket under the stream is (the shape
+of the benchmark's kv_block_stream_ici deployment).
 
 Run (self-contained: starts its own servers):
     python examples/streaming_echo.py                     # host sockets
@@ -46,6 +52,71 @@ def sink_transfer(args) -> None:
         f"{facts['messages']} messages of {args.message_bytes} B, bytes and "
         f"boundaries kept, at most {facts['ahead']} B ahead of the sink, "
         f"{args.total_bytes / facts['seconds'] / 1e9:.4f} GB/s"
+    )
+
+
+def device_transfer(args) -> None:
+    """``--total_bytes`` as device arrays of ``--message_bytes``, made on
+    this side's device before the clock starts, to a sink that checks each
+    against its source and says what it was handed."""
+    import jax
+    import numpy as np
+
+    from incubator_brpc_tpu.rpc import ChannelOptions
+    from incubator_brpc_tpu.utils.status import ErrorCode
+
+    words = max(1, args.message_bytes // 4)
+    count = max(1, args.total_bytes // (4 * words))
+    handed, done = [], threading.Event()
+
+    class Sink(StreamHandler):
+        def on_received_messages(self, stream, messages):
+            handed.extend(messages)  # jax.Arrays or bytes, in order
+            if len(handed) >= count:
+                done.set()
+
+    def open_stream(cntl, request):
+        stream_accept(cntl, StreamOptions(handler=Sink()))
+        return b""
+
+    server = Server()
+    server.add_service("StreamService", {"Open": open_stream})
+    assert server.start(0)
+    ch = Channel()
+    options = ChannelOptions(timeout_ms=60000)
+    if args.transport == "tpu":
+        options = ChannelOptions(transport="tpu", timeout_ms=60000)
+    assert ch.init(f"127.0.0.1:{server.port}", options=options)
+    s = stream_create(StreamOptions(max_buf_size=max(1 << 20, 16 * words)))
+    cntl = ch.call_method("StreamService", "Open", b"", request_stream=s)
+    assert cntl.ok(), cntl.error_text
+    assert s.wait_connected(60)
+    source = [np.full(words, i, np.uint32) for i in range(count)]
+    blocks = jax.block_until_ready(
+        [jax.device_put(block, jax.devices()[0]) for block in source])
+    # a deployment warms the lane for its shapes (DeviceLink.warm_lane);
+    # here the first message compiles its program, inside the clock
+    t0 = time.monotonic()
+    for block in blocks:
+        while (rc := s.write(block, timeout=10)) != 0:
+            assert rc in (ErrorCode.EAGAIN, ErrorCode.EOVERCROWDED), rc
+    assert done.wait(120), "the sink did not get every message"
+    seconds = time.monotonic() - t0
+    s.close()
+    server.stop()
+    arrays = [m for m in handed if isinstance(m, jax.Array)]
+    for got, want in zip(handed, source):
+        words_got = np.asarray(got) if isinstance(got, jax.Array) else (
+            np.frombuffer(got, np.uint32))
+        assert np.array_equal(words_got, want), "a block changed on the way"
+    where = (
+        f"{len(arrays)} jax.Arrays on {sorted(arrays[0].devices())[0]}" if arrays
+        else f"{len(handed)} bytes messages (no second device under this socket)"
+    )
+    print(
+        f"[device] transport={args.transport}: {count} device arrays of "
+        f"{4 * words} B written from {jax.devices()[0]}, the sink was handed "
+        f"{where}, {count * 4 * words / seconds / 1e9:.4f} GB/s"
     )
 
 
@@ -101,6 +172,7 @@ def main(argv=None) -> None:
     s.close()
     server.stop()
     sink_transfer(args)
+    device_transfer(args)
 
 
 if __name__ == "__main__":

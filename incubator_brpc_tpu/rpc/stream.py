@@ -17,6 +17,14 @@ Kept design points (and where they live in the reference):
 - Close is a frame like any other; the consumer sees it in order, fires
   ``on_closed``, and the registry entry dies (versioned ids are not needed:
   ids are never reused).
+- A message may be a device array. Over a ``DeviceSocket`` whose link
+  runs between two devices, ``write`` of a ``jax.Array`` sends a data
+  frame with an empty body that names shape, dtype and the lane's
+  sequence number, and hands the array to the link's lane
+  (``transport/device_link.py``): the body crosses chip to chip and the
+  handler is handed a ``jax.Array`` on its own device, in the stream's
+  order among its other messages. The window counts its ``nbytes``. Over
+  any other socket the array's bytes go as a bytes message.
 
 Always-on recorders (docs/OBSERVABILITY.md, "Streams"): a stream counts
 under ``device_link_stream_*`` once it rides a ``DeviceSocket`` and under
@@ -36,10 +44,11 @@ import logging
 import threading
 import time
 from collections import deque
-from typing import Callable, Dict, List, Optional
+from typing import TYPE_CHECKING, Callable, Dict, List, Optional, Union
 
 from incubator_brpc_tpu import protocol as proto_pkg
 from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
+from incubator_brpc_tpu.iobuf import IOBuf
 from incubator_brpc_tpu.protocol.tbus_std import (
     FLAG_STREAM,
     Meta,
@@ -51,7 +60,16 @@ from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.execution_queue import ExecutionQueue, TaskIterator
 from incubator_brpc_tpu.utils.status import ErrorCode
 
+if TYPE_CHECKING:
+    import jax
+
 logger = logging.getLogger(__name__)
+
+# what a message is on the host; anything else ``write`` is given has to
+# be a device array
+_HOST_MESSAGE = (bytes, bytearray, memoryview, IOBuf)
+# what a handler is handed, and what ``write`` takes
+Message = Union[bytes, IOBuf, "jax.Array"]
 
 # frame kinds inside meta.extra["ft"] (reference StreamFrameMeta.frame_type:
 # FRAME_TYPE_DATA / FEEDBACK / CLOSE / RST, streaming_rpc_meta.proto)
@@ -59,6 +77,10 @@ FT_DATA = "data"
 FT_FEEDBACK = "fb"
 FT_CLOSE = "close"
 FT_RST = "rst"
+# the consumer queue's own kind, never on the wire: a data frame whose
+# body came over the link's lane and is a device array
+_FT_DEVICE = "device"
+_WAITING = object()  # in place of a body the lane has not delivered yet
 
 IDLE = 0
 CONNECTING = 1
@@ -75,7 +97,7 @@ class _StreamVars:
     """One namespace of stream recorders and adders. Rows wait in the
     feeds for the sampler thread; ``flush`` feeds them now (tests)."""
 
-    def __init__(self, prefix: str):
+    def __init__(self, prefix: str, lane: bool = False):
         def recorder(what: str) -> LatencyRecorder:
             return LatencyRecorder(name=f"{prefix}_{what}")
 
@@ -118,7 +140,11 @@ class _StreamVars:
         )
         self.messages = adder("messages")  # handed to a handler
         self.batches = adder("batches")  # on_received_messages calls
-        self.bytes = adder("bytes")  # of those messages
+        self.bytes = adder("bytes")  # of those messages that were host bytes
+        # of those messages, the device arrays, and their nbytes: only a
+        # DeviceSocket's lane delivers one
+        self.device_messages = adder("device_messages") if lane else None
+        self.device_bytes = adder("device_bytes") if lane else None
         self.feedback_frames = adder("feedback_frames")  # sent
         self.write_retries = adder("write_retries")  # EAGAIN/EOVERCROWDED returned
 
@@ -128,7 +154,7 @@ class _StreamVars:
 
 
 HOST_VARS = _StreamVars("stream")
-LINK_VARS = _StreamVars("device_link_stream")
+LINK_VARS = _StreamVars("device_link_stream", lane=True)
 
 
 class StreamOptions:
@@ -155,7 +181,14 @@ class StreamHandler:
     """User callbacks (reference StreamInputHandler, stream.h:29-38).
     Subclass and override; all run on the stream's ordered consumer fiber."""
 
-    def on_received_messages(self, stream: "Stream", messages: List[bytes]) -> None:
+    def on_received_messages(self, stream: "Stream", messages: List[Message]) -> None:
+        """A batch of messages in the order they were written. Each is
+        ``bytes`` (an ``IOBuf`` under ``raw_messages``) or, where the
+        writer wrote a device array and the link under the stream has a
+        lane, a ``jax.Array`` of the written shape and dtype on this
+        side's device. A stream may mix the two; order and boundaries hold
+        across both. The writer's window reopens by the batch's bytes
+        (an array's ``nbytes``) when this returns."""
         pass
 
     def on_closed(self, stream: "Stream") -> None:
@@ -196,6 +229,12 @@ class Stream:
         )
         self._close_sent = False
         self._connected_event = threading.Event()
+        # frames held back behind a device message whose body the lane has
+        # not delivered yet, in the order cut: [kind, payload, stamp,
+        # shape, dtype]. None until the stream's first device message, so
+        # a stream of bytes never looks at it twice
+        self._await: Optional[deque] = None
+        self._await_lock = threading.Lock()
 
     # -- connection plumbing (module-level handshake hooks call these) ------
 
@@ -218,12 +257,38 @@ class Stream:
 
     # -- writer side --------------------------------------------------------
 
-    def write(self, data: bytes, timeout: Optional[float] = None) -> int:
+    def write(self, data: Message, timeout: Optional[float] = None) -> int:
         """Send one message. 0 on success; EAGAIN if the window is full and
         ``timeout`` expired (timeout=0 → immediate EAGAIN, None → block
         forever); EOVERCROWDED if the socket backlog refused the frame
-        (transient — retry); EINVAL once closed/failed."""
-        n = len(data)
+        (transient — retry); EINVAL once closed/failed.
+
+        ``data`` is ``bytes``, an ``IOBuf`` or a ``jax.Array``. What
+        happens to an array is chosen from what the socket under the
+        stream is, and nothing configures it:
+
+        - a ``DeviceSocket`` whose link runs between two devices: the
+          array has to lie whole on the device this side of the link
+          drives, with at least one dimension and one element (else
+          EINVAL). It is admitted against ``max_buf_size`` by its
+          ``nbytes``; its header goes over the byte stream, the array to
+          the link's lane, and the far handler is handed a ``jax.Array``
+          of that shape and dtype on its own device. The stream keeps the
+          array until the lane's program has it; **the writer may not
+          write into, donate or delete it until the message was consumed**
+          (``unconsumed_bytes`` has fallen past it): the program reads it
+          where it lies.
+        - a multi-controller link (``transport/mc_link.py``) has no lane
+          yet: EINVAL.
+        - a host socket, or a link on one shared device (the host swap):
+          there is no second device to land on; the array's bytes are
+          sent as a bytes message and the far handler is handed bytes."""
+        array = None
+        if not isinstance(data, _HOST_MESSAGE):
+            array, data = self._device_message(data)
+            if data is None:
+                return ErrorCode.EINVAL
+        n = len(data) if array is None else array.nbytes
         limit = self.options.max_buf_size
         deadline = None if timeout is None else time.monotonic() + timeout
         t_enter = time.monotonic_ns()
@@ -277,11 +342,14 @@ class Stream:
         drain_budget = None
         if deadline is not None:
             drain_budget = max(0.0, deadline - time.monotonic())
-        rc = sock.write(
-            pack_frame_iobuf(meta, data, 0, flags=FLAG_STREAM),
-            timeout=drain_budget,
-            drain_inline=True,
-        )
+        if array is None:
+            rc = sock.write(
+                pack_frame_iobuf(meta, data, 0, flags=FLAG_STREAM),
+                timeout=drain_budget,
+                drain_inline=True,
+            )
+        else:
+            rc = self._send_array(sock, meta, array, drain_budget)
         if rc == ErrorCode.EOVERCROWDED:
             # transient socket backpressure (socket.cpp:1537): surface it,
             # don't kill the stream; the rollback reopens the window so any
@@ -297,6 +365,51 @@ class Stream:
             self._fail(rc, "stream data write failed")
             return rc
         return 0
+
+    def _device_message(self, array) -> tuple:
+        """What ``write`` sends for a message that is no host bytes:
+        ``(array, b"")`` for the lane, ``(None, its bytes)`` where the
+        socket has no second device, ``(None, None)`` where it is refused."""
+        import jax
+
+        if not isinstance(array, jax.Array):
+            raise TypeError(
+                f"a stream message is bytes, an IOBuf or a jax.Array, "
+                f"not {type(array).__name__}"
+            )
+        sock = self._sock
+        if sock is None:
+            return None, None
+        lane = getattr(sock, "lane", None)
+        if lane is None:
+            import numpy as np
+
+            return None, np.asarray(array).tobytes()
+        if not lane.lane_accepts(sock.side, array):
+            return None, None
+        return array, b""
+
+    def _send_array(self, sock, meta: Meta, array, drain_budget) -> int:
+        """An admitted device message: its header (an empty body; shape,
+        dtype and the lane's sequence number in the meta) over the byte
+        stream, then the array to the lane, which pairs them again at the
+        far socket. The header goes first: it has the longer way."""
+        lane = sock.lane
+        step = lane.lane_reserve(sock.side, array.nbytes)
+        if step is None:
+            return ErrorCode.EFAILEDSOCKET
+        meta.extra.update(
+            lane=step.seq, shape=list(array.shape), dtype=array.dtype.name
+        )
+        rc = sock.write(
+            pack_frame(meta, b"", 0, flags=FLAG_STREAM),
+            timeout=drain_budget,
+            drain_inline=True,
+        )
+        if rc != 0:
+            lane.lane_abandon(step)  # no header names it: nothing to pair
+            return rc
+        return lane.lane_send(sock.side, step, array)
 
     def _set_remote_consumed(self, consumed: int) -> None:
         """Feedback arrived (SetRemoteConsumed stream.cpp:287): lift the
@@ -328,25 +441,80 @@ class Stream:
     # -- reader side --------------------------------------------------------
 
     def _on_frame(self, frame: ParsedFrame) -> None:
-        ft = frame.meta.extra.get("ft", FT_DATA)
+        extra = frame.meta.extra
+        ft = extra.get("ft", FT_DATA)
         if ft == FT_FEEDBACK:
-            self._set_remote_consumed(int(frame.meta.extra.get("consumed", 0)))
+            self._set_remote_consumed(int(extra.get("consumed", 0)))
+            return
+        if "lane" in extra:
+            self._on_lane_header(extra)
             return
         # the native parse path leaves stream payloads as zero-copy IOBuf
         # cuts; the consumer materializes only when the handler wants bytes
         data = frame.payload_iobuf
-        self._rq.execute(
-            (ft, frame.payload if data is None else data, time.monotonic_ns())
-        )
+        task = (ft, frame.payload if data is None else data, time.monotonic_ns())
+        if self._await is None:
+            self._rq.execute(task)
+        else:
+            self._queue_in_order(task)
+
+    def _queue_in_order(self, task: tuple) -> None:
+        """A frame cut after the stream's first device message: behind
+        whatever still waits for its body, else straight to the consumer."""
+        with self._await_lock:
+            if self._await:
+                self._await.append(list(task))
+            else:
+                self._rq.execute(task)
+
+    def _on_lane_header(self, extra: dict) -> None:
+        """A data frame with no body of its own: the lane has it, or will.
+        It keeps the place in the stream's order its header was cut at."""
+        sock = self._sock
+        lane = getattr(sock, "lane", None)
+        entry = [
+            _FT_DEVICE, _WAITING, time.monotonic_ns(),
+            tuple(extra.get("shape", ())), extra.get("dtype"),
+        ]
+        with self._await_lock:
+            if self._await is None:
+                self._await = deque()
+            self._await.append(entry)
+        if lane is None or not lane.lane_claim(
+            sock.side, int(extra["lane"]),
+            lambda body: self._on_lane_body(entry, body),
+        ):
+            self._fail(
+                ErrorCode.EREQUEST, "a device message the link's lane does not know"
+            )
+
+    def _on_lane_body(self, entry: list, body) -> None:
+        """The lane delivered a waiting message's array (on the reader's
+        thread if it was there before its header, else on a completion
+        watcher): everything at the head of the order that is whole goes to
+        the consumer, under the lock that keeps the order."""
+        if (tuple(body.shape), body.dtype.name) != (entry[3], entry[4]):
+            self._fail(
+                ErrorCode.EREQUEST,
+                f"lane delivered {body.dtype.name}{tuple(body.shape)} for a "
+                f"header that names {entry[4]}{entry[3]}",
+            )
+            return
+        with self._await_lock:
+            entry[1] = body
+            waiting = self._await
+            while waiting and waiting[0][1] is not _WAITING:
+                self._rq.execute(tuple(waiting.popleft()[:3]))
 
     def _consume(self, it: TaskIterator) -> None:
         """Ordered consumer fiber (stream.cpp:86): batch data messages to the
         handler, then feed consumption back to the writer."""
         handler = self.options.handler
-        batch: List[bytes] = []
+        batch: List[Message] = []
         arrived: List[int] = []  # _on_frame's stamp of each message
         closed = False
         raw = self.options.raw_messages
+        nbytes = device_messages = device_bytes = 0
         for ft, payload, t_frame in it:
             if ft == FT_DATA:
                 if not raw and not isinstance(payload, (bytes, bytearray)):
@@ -355,18 +523,21 @@ class Stream:
                     # parse paths that materialized bytes (pure-python
                     # fallback, native-plane dispatch) still honor the raw
                     # IOBuf contract: wrap, don't surprise the handler
-                    from incubator_brpc_tpu.iobuf import IOBuf
-
                     wrapped = IOBuf()
                     wrapped.append(bytes(payload))
                     payload = wrapped
+                nbytes += len(payload)
                 batch.append(payload)
+                arrived.append(t_frame)
+            elif ft == _FT_DEVICE:
+                device_messages += 1
+                device_bytes += payload.nbytes
+                batch.append(payload)  # the array, as the lane landed it
                 arrived.append(t_frame)
             elif ft in (FT_CLOSE, FT_RST):
                 closed = True
         if batch:
-            nbytes = sum(len(m) for m in batch)
-            self._consumed += nbytes
+            self._consumed += nbytes + device_bytes
             v = self._vars
             t_in = time.monotonic_ns()
             if handler is not None:
@@ -379,6 +550,9 @@ class Stream:
             v.messages << len(batch)
             v.batches << 1
             v.bytes << nbytes
+            if device_messages:
+                v.device_messages << device_messages
+                v.device_bytes << device_bytes
             self._send_feedback()
         if closed or it.is_queue_stopped():
             self._finish_close(notify=closed)
@@ -471,6 +645,9 @@ class Stream:
             self.state = CLOSED
             self.error_code = code
             self.error_text = reason
+        with self._await_lock:
+            if self._await:
+                self._await.clear()  # no body will come for these now
         self._connected_event.set()
         self._wbutex.add(1)
         self._wbutex.wake_all()

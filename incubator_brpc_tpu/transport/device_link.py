@@ -42,6 +42,14 @@ re-thought for XLA devices:
   passes the window's byte budget (EOVERCROWDED past a hard cap); slot
   headers carry cumulative seq/ack words like the RDMA endpoint's
   piggybacked imm-data acks (rdma_endpoint.h:176-195).
+- **The lane carries tensors.** Beside the byte stream a ``ppermute``
+  link has a second program: a committed device array on the sender's
+  device lands on the receiver's device by one one-way ``ppermute`` over
+  the link's own mesh, and is handed to the receiver **as a device
+  array** (``lane_send`` / ``lane_claim``): no host copy on either side.
+  Its header crosses the byte stream as any frame does and names the
+  lane's sequence number; body and header pair at the receiver,
+  whichever comes first (docs/DEVICE_PLANE.md, "The lane").
 """
 
 from __future__ import annotations
@@ -99,6 +107,12 @@ link_bytes = Adder(name="device_link_bytes")
 # payload capacity of every slot side filled: set against device_link_bytes
 # it says how full the slots travel
 link_capacity = Adder(name="device_link_capacity_bytes")
+# the lane: programs dispatched, the messages they carried (one a program
+# today; over lane_steps it is what a program that carries several would
+# save) and those messages' bytes, which never enter device_link_bytes
+lane_steps = Adder(name="device_link_lane_steps")
+lane_messages = Adder(name="device_link_lane_messages")
+lane_bytes = Adder(name="device_link_lane_bytes")
 link_acks = Adder(name="device_link_ack_steps")  # wire-mode catch-up steps
 link_errors = Adder(name="device_link_errors")  # fail() calls, all links
 # send() attempts refused with EOVERCROWDED after a full window-stall wait
@@ -128,7 +142,11 @@ def _quiesce_links(timeout: float = 10.0) -> None:
     for link in links:
         while _time.monotonic() < deadline:
             with link._lock:
-                idle = not link._driving and link._inflight == 0
+                idle = (
+                    not link._driving
+                    and link._inflight == 0
+                    and link._lane_inflight == 0
+                )
             if idle:
                 break
             _time.sleep(0.01)
@@ -219,8 +237,55 @@ class _Step:
         self.t_launched, self.c_launched = clocks(self.timed)
 
 
+# A paired lane program's row, as _lane_pair writes it: stamps
+# (time.monotonic_ns()) in the order taken, the message's bytes, then the
+# sender's CPU clock around the launch (one program in
+# bvar.CPU_CLOCK_EVERY carries it, the others -1). ``first`` and ``paired``
+# are the earlier and the later of the body seen ready and its header
+# claiming it: the wait of whichever came first.
+LANE_STAMPS = (
+    "seq", "taken", "launched", "ready", "first", "paired", "queued",
+    "nbytes", "taken_cpu", "launched_cpu",
+)
+# (the link's recorder device_link_<n>_lane_<this>, scale, what it is fed)
+LANE_COLUMNS = (
+    ("step_us", 1e-3, (
+        ("taken", "launched"), ("launched", "ready"), ("first", "paired"),
+        ("paired", "queued"),
+    )),
+    ("launch_us", 1e-3, ("taken", "launched")),
+    ("ready_us", 1e-3, ("launched", "ready")),
+    ("pair_wait_us", 1e-3, ("first", "paired")),
+    ("deliver_us", 1e-3, ("paired", "queued")),
+    ("launch_cpu_us", 1e-3, ("taken_cpu", "launched_cpu")),
+)
+
+
+class _LaneStep:
+    """One lane program's timeline and its place in the pairing: made when
+    a writer reserves the message's sequence number, kept in the link's
+    table until body and header have met (or the link failed)."""
+
+    __slots__ = (
+        "seq", "to", "nbytes", "t_taken", "c_taken", "t_launched",
+        "c_launched", "watcher", "t_header", "body", "on_body",
+    )
+
+    def __init__(self, seq: int, to: int, nbytes: int):
+        self.seq, self.to, self.nbytes = seq, to, nbytes
+        self.t_taken = self.t_launched = self.t_header = 0
+        self.c_taken = self.c_launched = RecorderFeed.MISSING
+        self.watcher = [0, 0]  # DeviceCompletionButex.watch fills these
+        self.body = None  # the array on the receiver's device, once ready
+        self.on_body = None  # the header's claim, once it came
+
+
 class DeviceLink:
     """One established two-party link: the QP pair + CQ + window."""
+
+    # a link of this class has the lane wherever its exchange is a
+    # ppermute; MultiControllerLink has none yet and refuses device arrays
+    carries_arrays = True
 
     def __init__(
         self,
@@ -351,6 +416,16 @@ class DeviceLink:
         self._steps_taken = 0  # trains dispatched: which carry the CPU clock
         self._last_dispatch_ns = 0  # this drive's previous dispatch; 0 = none
         self._held_since_ns = 0  # the drive is holding a train back; 0 = not
+        # -- the lane (ppermute geometry only; _build_step makes its feed)
+        self._lane_lock = threading.Lock()
+        self._lane_seq = itertools.count(1)
+        # seq -> _LaneStep, from the reservation until body met header
+        self._lane_pending: Dict[int, _LaneStep] = {}
+        # (side, shape, dtype) -> (program, placeholder, receiver's shard)
+        self._lane_programs: Dict[tuple, tuple] = {}
+        self._lane_inflight = 0  # programs dispatched, body not yet seen ready
+        self._lane_taken = 0  # programs launched: which carry the CPU clock
+        self._lane_feed: Optional[RecorderFeed] = None
         self._build_step()
         with _links_lock:
             _all_links.add(self)
@@ -363,9 +438,14 @@ class DeviceLink:
         self._metrics_retired = True
         self._step_feed.flush()  # profile() still reads the recorders
         self._send_feed.flush()
+        lane = ()
+        if self._lane_feed is not None:
+            self._lane_feed.flush()
+            lane = (recorder for recorder, *_rest in self._lane_feed.columns)
         for v in (
             *(getattr(self, "_m_" + attr) for attr, *_rest in STEP_COLUMNS),
             self._m_flush, self._m_send_wait, self._m_out_rate, self._m_in_rate,
+            *lane,
         ):
             try:
                 v.hide()
@@ -438,6 +518,28 @@ class DeviceLink:
             exchange, in_shardings=self._sharding, out_shardings=self._sharding
         )
         self._warm_step()
+        if not self.carries_arrays:
+            return
+        # the lane exists where the exchange is a ppermute between two
+        # devices: a row a paired program, fed by the sampler as the
+        # steps' rows are
+        lane = f"device_link_{self.link_id}_lane"
+        recorders = {
+            "step_us": LatencyRecorder(name=f"{lane}_step_us"),
+            "launch_us": LatencyRecorder(name=f"{lane}_launch_us"),
+            "ready_us": LatencyRecorder(name=f"{lane}_ready_us"),
+            "pair_wait_us": LatencyRecorder(name=f"{lane}_pair_wait_us"),
+            "deliver_us": LatencyRecorder(name=f"{lane}_deliver_us"),
+            "launch_cpu_us": LatencyRecorder(name=f"{lane}_launch_cpu_us"),
+        }
+        self._lane_feed = RecorderFeed(
+            [(recorders[what], scale, span) for what, scale, span in LANE_COLUMNS],
+            stamps=LANE_STAMPS,
+            name=f"{lane}_steps",
+            ring_rows=1 << 14,
+            worker=(("taken", "launched"), ("paired", "queued")),
+            call=(("taken", "queued"),),
+        )
 
     def _warm_step(self) -> None:
         """Run the exchange once on empty rows at every train length the
@@ -473,6 +575,204 @@ class DeviceLink:
         finds them landed or landing instead of asking for one after the
         other (PERF.md, PR 36)."""
         out.copy_to_host_async()
+
+
+    # -- the lane: device arrays, HBM to HBM ---------------------------------
+
+    @property
+    def has_lane(self) -> bool:
+        """This link can carry a device array as a device array: its
+        exchange is the ``ppermute`` between two devices. A link on one
+        shared device (host swap, device swap) has no lane; a stream over
+        it sends an array's bytes."""
+        return self._lane_feed is not None
+
+    def lane_accepts(self, side: int, array) -> bool:
+        """Whether ``lane_send`` can take ``array`` from ``side``: a
+        ``jax.Array`` of at least one dimension and one element that lies
+        whole on the device this side of the link drives."""
+        import jax
+
+        return (
+            self.has_lane
+            and isinstance(array, jax.Array)
+            and array.ndim >= 1
+            and array.size > 0
+            and array.devices() == {self.devices[side]}
+        )
+
+    def _lane_program(self, side: int, shape: tuple, dtype) -> tuple:
+        """The lane's program for messages of one shape and dtype from
+        ``side``: ``(program, placeholder, shard)``. The operand is one
+        global array cut in two along its first dimension, the sender's
+        half the message as it lies, the receiver's half a placeholder made
+        here once and kept; the output's shard ``shard`` is the message on
+        the receiver's device. Compiled and run once at the first use of a
+        shape (``warm_lane``), so that no message of live traffic
+        compiles."""
+        import jax
+        from jax.sharding import PartitionSpec as P
+
+        key = (side, tuple(shape), np.dtype(dtype).name)
+        found = self._lane_programs.get(key)
+        if found is not None:
+            return found
+        mesh = self._mesh
+
+        def device_link_lane(halves):
+            return jax.shard_map(
+                lambda x: jax.lax.ppermute(x, "link", [(side, 1 - side)]),
+                mesh=mesh, in_specs=P("link"), out_specs=P("link"),
+            )(halves)
+
+        program = jax.jit(
+            device_link_lane,
+            in_shardings=self._sharding, out_shardings=self._sharding,
+        )
+        placeholder = jax.device_put(
+            np.zeros(shape, dtype=dtype), self.devices[1 - side]
+        )
+        warm = jax.device_put(np.zeros(shape, dtype=dtype), self.devices[side])
+        out = program(self._lane_operand(side, warm, placeholder))
+        shard = [s.device for s in out.addressable_shards].index(
+            self.devices[1 - side]
+        )
+        jax.block_until_ready(out.addressable_data(shard))
+        with self._lane_lock:
+            found = self._lane_programs.setdefault(
+                key, (program, placeholder, shard)
+            )
+        return found
+
+    def _lane_operand(self, side: int, array, placeholder):
+        """Both halves as one global array, neither copied."""
+        import jax
+
+        halves = [array, placeholder] if side == 0 else [placeholder, array]
+        return jax.make_array_from_single_device_arrays(
+            (2 * array.shape[0],) + tuple(array.shape[1:]), self._sharding, halves
+        )
+
+    def warm_lane(self, side: int, shape: tuple, dtype) -> None:
+        """Compile the lane's program for messages of ``shape`` and
+        ``dtype`` sent from ``side`` and run it once, placeholder and all.
+        A deployment calls this for the shapes it will send before it
+        opens a measured window; a shape never warmed compiles at its
+        first message."""
+        if not self.has_lane:
+            raise ValueError("this link has no lane (one shared device)")
+        self._lane_program(side, shape, dtype)
+
+    def lane_reserve(self, side: int, nbytes: int) -> Optional[_LaneStep]:
+        """Take the lane's next sequence number for a message ``side`` is
+        about to send: its header names ``.seq`` and goes over the byte
+        stream first, then ``lane_send`` takes the array. ``None`` on a
+        dead link. A reservation whose header was never sent goes back
+        with ``lane_abandon``."""
+        step = _LaneStep(next(self._lane_seq), 1 - side, nbytes)
+        with self._lane_lock:
+            if self._closed:
+                return None
+            self._lane_pending[step.seq] = step
+        return step
+
+    def lane_abandon(self, step: _LaneStep) -> None:
+        with self._lane_lock:
+            self._lane_pending.pop(step.seq, None)
+
+    def lane_send(self, side: int, step: _LaneStep, array) -> int:
+        """Dispatch the lane's program on ``array`` (``lane_accepts`` said
+        yes; ``step`` is its reservation) on the caller's thread: one call
+        into the runtime, no host copy. 0, or ``EFAILEDSOCKET`` where the
+        dispatch raised, which fails the link. The array may be dropped by
+        the caller once this returns (the program holds it) but not
+        written or donated until the message was consumed."""
+        if self._closed:
+            return ErrorCode.EFAILEDSOCKET
+        timed = self._lane_taken % CPU_CLOCK_EVERY == 0
+        self._lane_taken += 1
+        step.t_taken, step.c_taken = clocks(timed)
+        with self._lane_lock:
+            self._lane_inflight += 1
+        try:
+            program, placeholder, shard = self._lane_program(
+                side, array.shape, array.dtype
+            )
+            out = program(self._lane_operand(side, array, placeholder))
+            body = out.addressable_data(shard)
+        except Exception:
+            logger.exception("device link lane dispatch failed")
+            with self._lane_lock:
+                self._lane_inflight -= 1  # never dispatched: nothing to land
+            self.fail("lane dispatch failed")
+            return ErrorCode.EFAILEDSOCKET
+        step.t_launched, step.c_launched = clocks(timed)
+        lane_steps << 1
+        lane_messages << 1
+        lane_bytes << step.nbytes
+        self._cq.watch(
+            body,
+            on_complete=lambda arrays, error, _step=step: (
+                self._lane_landed(_step, arrays, error)
+            ),
+            stamps=step.watcher,
+        )
+        return 0
+
+    def _lane_landed(self, step: _LaneStep, body, error) -> None:
+        """Completion watcher: a lane program's output is ready on the
+        receiver's device (or failed). Hand it to its header's claim if
+        that came first, else keep it for the claim."""
+        with self._lane_lock:
+            self._lane_inflight -= 1
+            if error is None and self._lane_pending.get(step.seq) is step:
+                step.body = body
+                claimed = step.on_body is not None
+                if claimed:
+                    del self._lane_pending[step.seq]
+            else:
+                claimed = False  # failed, or fail() dropped the table
+        if error is not None:
+            logger.error("device link lane program failed: %s", error)
+            self.fail(f"lane program failed: {error}")
+        elif claimed:
+            self._lane_pair(step)
+
+    def lane_claim(self, side: int, seq: int, on_body) -> bool:
+        """The receiver's half of the pairing: the header of lane message
+        ``seq`` was cut off ``side``'s byte stream. ``on_body(array)`` runs
+        once with the message on this side's device: here and now if its
+        program was seen ready already, else on the completion watcher
+        when it is. False where the lane knows no such message (the link
+        failed, or the header names a number never reserved); a link that
+        fails later drops the claim, and the socket's failure is what its
+        owner hears."""
+        with self._lane_lock:
+            step = self._lane_pending.get(seq)
+            if step is None or step.to != side or step.on_body is not None:
+                return False
+            step.on_body, step.t_header = on_body, time.monotonic_ns()
+            ready = step.body is not None
+            if ready:
+                del self._lane_pending[seq]
+        if ready:
+            self._lane_pair(step)
+        return True
+
+    def _lane_pair(self, step: _LaneStep) -> None:
+        """Body and header have met, on the thread of whichever came
+        second: hand the array over and write the program's row."""
+        t_ready = step.watcher[1]
+        paired = time.monotonic_ns()
+        try:
+            step.on_body(step.body)
+        except Exception:
+            logger.exception("device link lane delivery raised")
+        self._lane_feed.rows.append((
+            step.seq, step.t_taken, step.t_launched, t_ready,
+            min(t_ready, step.t_header), paired, time.monotonic_ns(),
+            step.nbytes, step.c_taken, step.c_launched,
+        ))
 
     # -- send side -----------------------------------------------------------
 
@@ -702,9 +1002,7 @@ class DeviceLink:
                 self._request_host(out)
             except Exception:
                 logger.exception("device link step dispatch failed")
-                self.fail("link step dispatch failed")
-                with self._lock:
-                    self._driving = False
+                self._dispatch_failed(seq, k)
                 return
             step.launched()
             link_steps << 1
@@ -720,6 +1018,17 @@ class DeviceLink:
                 ),
                 stamps=step.watcher,
             )
+
+    def _dispatch_failed(self, seq: int, k: int) -> None:
+        """The drive's dispatch of the train at ``seq`` raised: nothing of
+        it will ever be delivered, so its ``k`` slots come back off the
+        credit here (the idle check would wait out its timeout on them),
+        the link fails and the drive ends."""
+        self.fail("link step dispatch failed")
+        with self._lock:
+            self._inflight -= k
+            self._steps.pop(seq, None)
+            self._driving = False
 
     def _fill_train_locked(
         self, side: int, k: int, train: Optional[np.ndarray] = None
@@ -924,6 +1233,11 @@ class DeviceLink:
                 self._out[side].clear()
                 self._out_nbytes[side] = 0
             self._steps.clear()
+        with self._lane_lock:
+            # bodies kept for a header and claims kept for a body: neither
+            # will meet now; the sockets' failure below is what the
+            # streams on both ends hear
+            self._lane_pending.clear()
         link_errors << 1
         self._retire_metrics()
         # party-death feedback for the collective fault plane: a session
@@ -1036,6 +1350,15 @@ class DeviceSocket:
         # arbitrate the same failure twice (a queued id error delivered
         # at unlock), burning a retry attempt.
         return self.link.send(self.side, data, timeout=timeout)
+
+    @property
+    def lane(self) -> Optional[DeviceLink]:
+        """The link, where its exchange runs between two devices: a writer
+        of device arrays asks it (``lane_accepts``) and hands them over, or
+        is refused. ``None`` on one shared device, where there is nothing
+        to cross and a writer of arrays sends their bytes. A host
+        ``Socket`` has no such attribute."""
+        return self.link if self.link.geometry == "ppermute" else None
 
     # -- read path (driven by link completions) ------------------------------
 

@@ -86,7 +86,14 @@ class MultiControllerLink(DeviceLink):
     (the streaming-RPC control plane). ``devices`` are the two GLOBAL
     devices in link order [client, server]; exactly ``devices[own_side]``
     must be addressable from this process.
+
+    No lane yet (``carries_arrays``): the two halves of a lane program
+    would have to be dispatched in lockstep by two processes, which the
+    step budget below does not count. ``Stream.write`` of a device array
+    over this link is refused (``EINVAL``); ROADMAP.md D10.
     """
+
+    carries_arrays = False
 
     def __init__(
         self,
@@ -294,9 +301,7 @@ class MultiControllerLink(DeviceLink):
                 out = self._step(self._make_local_slots(row))
             except Exception:
                 logger.exception("mc link step dispatch failed")
-                self.fail("link step dispatch failed")
-                with self._lock:
-                    self._driving = False
+                self._dispatch_failed(seq, 1)
                 return
             step.launched()
             link_steps << 1

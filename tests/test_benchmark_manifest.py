@@ -17,11 +17,14 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
 
-from benchmark import manifest, roofline, roofline_table, xplane  # noqa: E402
+from benchmark import (  # noqa: E402
+    manifest, roofline, roofline_lane, roofline_table, xplane,
+)
 
 with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
     BENCH = json.load(_f)
 CELLS = [w["name"] for w in BENCH["workloads"]]
+KV_CELL = "kv_blocks_ici_32m_c1"  # PR 39's: device blocks over the lane
 ECHO_CELLS = ["echo_256b_c16", "echo_4m_c2", "echo_mixed_c16"]
 DEVICE_STAGES = (
     "copy", "credit_wait", "queue_wait", "stack", "launch",
@@ -138,6 +141,27 @@ COMBO = {
     "device_link_combo_rows": 600,
     "device_link_combo_bytes": 600 << 20,
 }
+# PR 39's lane over a window, on the busiest link: 640 paired programs
+# of one 2 MiB block each, and the stream that handed them over beside
+# 40 bytes messages of 64 B
+LANE_STAGES = {
+    "launch": 300.0, "ready": 450.0, "pair_wait": 1200.0, "deliver": 50.0,
+}
+LANE = {
+    **LINK,
+    "device_link_2_lane_step_us": recorder(5, 9e9),
+    "device_link_3_lane_step_us": recorder(640, 2000.0),
+    **{f"device_link_3_lane_{s}_us": recorder(640, us)
+       for s, us in LANE_STAGES.items()},
+    "device_link_3_lane_launch_cpu_us": recorder(160, 250.0),
+    "device_link_lane_steps": 640,
+    "device_link_lane_messages": 640,
+    "device_link_lane_bytes": 640 * 2097152,
+    "device_link_stream_device_messages": 640,
+    "device_link_stream_device_bytes": 640 * 2097152,
+    "device_link_stream_bytes": 40 * 64,
+    "device_transport_kv_pages_written": 640,
+}
 HBM_DISPATCHED = (
     100.0 * 4 * (2 * 128 * 64 + roofline.FRAME_HEADER_WORDS * 128)
     / 819e9 / (50 * 2_000 / 1e9)
@@ -189,6 +213,13 @@ EXPECTED = {
     # PR 37: the wait for the table, a part of the launch
     "table_state_wait_us": (
         {"device_transport_state_wait_us": recorder(100, 640.0)}, 640.0),
+    # PR 39: the lane's stages, a row a paired program
+    "lane_step_us": (LANE, 2000.0),
+    **{f"lane_{s}_us": (LANE, us) for s, us in LANE_STAGES.items()},
+    "lane_launch_cpu_us": (LANE, 250.0),
+    "lane_messages_per_step": (LANE, 1.0),
+    "stream_device_bytes_pct": (
+        LANE, 100.0 * 640 * 2097152 / (640 * 2097152 + 40 * 64)),
 }
 # PR 37's record table over a window: 40 dispatches that ran 128 rows, 90 of
 # them reads and 10 updates, two of which a later row of their dispatch replaced
@@ -204,6 +235,9 @@ TABLE = {
 TRACE_READERS = {"link_step_ici_pct", "combo_step_kernel_us", "combo_gather_ici_pct"}
 # PR 37's: the table step's device time and its share of the HBM roofline
 TABLE_TRACE_READERS = {"table_step_kernel_us", "table_step_hbm_pct"}
+# PR 39's: the lane program's share of the interconnect, the pool's write
+LANE_TRACE_READERS = {
+    "lane_step_ici_pct", "kv_page_write_kernel_us", "kv_page_write_hbm_pct"}
 # PR 35's readers of the program's kept rows (benchmark/timeline.py): their
 # numbers are checked in tests/test_stage_timeline.py
 SPAN_READERS = {"idle_worker_open_pct", "idle_waiting_only_pct", "idle_outside_pct"}
@@ -235,7 +269,7 @@ def test_every_metric_is_accounted_for():
     assert len(names) == len(set(names))
     # a later PR may add more
     assert (OLDER | set(EXPECTED) | TRACE_READERS | TABLE_TRACE_READERS
-            | SPAN_READERS <= set(names))
+            | LANE_TRACE_READERS | SPAN_READERS <= set(names))
     with open(os.path.join(ROOT, "PERF.md"), encoding="utf-8") as f:
         perf = f.read()
     for m in BENCH["per_layer"]:
@@ -249,16 +283,23 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
     """At least these cells: a later cell of an echo configuration joins
     the lists of the metrics its deployment feeds (PR 27's did)."""
     cells = {m["name"]: m.get("workloads") for m in BENCH["per_layer"]}
-    for name in sorted(set(EXPECTED) | TRACE_READERS):
-        if name.startswith("link_"):
-            # every link cell drives the link: PR 31's joined them
-            assert {"link_echo_ici_1m", "link_stream_ici"} <= set(cells[name]), name
+    for name in sorted(set(EXPECTED) | TRACE_READERS | LANE_TRACE_READERS):
+        if name == "link_step_ici_pct":
+            # sums every collective-permute: it would count the lane's
+            # programs as the trains', so PR 39's cell stays off its list
+            assert cells[name] == ["link_echo_ici_1m", "link_stream_ici"]
+        elif name.startswith("link_"):
+            # every link cell drives the link: PR 31's and PR 39's joined them
+            assert cells[name] == ["link_echo_ici_1m", "link_stream_ici", KV_CELL], name
+        elif name.startswith(("lane_", "kv_")) or name == "stream_device_bytes_pct":
+            # only the KV block stream sends a device array
+            assert cells[name] == [KV_CELL], name
         elif name.startswith("combo_"):
             # only the partitioned deployment builds a combo channel
             assert cells[name] == ["partition_star_4"], name
         elif name.startswith("stream_"):
-            # only the streaming deployment opens a stream
-            assert cells[name] == ["link_stream_ici"], name
+            # only the streaming deployments open a stream
+            assert cells[name] == ["link_stream_ici", KV_CELL], name
         elif name == "native_plane_callback_us":
             # only the native plane feeds it
             assert cells[name] == ["echo_256b_c16_native"]
@@ -292,6 +333,9 @@ def test_each_configuration_is_the_file_the_manifest_names():
         # the star is three of that link
         "partition_echo_ici": (
             "partition_echo", "partition_concat", link_options, None),
+        # the KV block stream rides it too, letter for letter
+        "kv_block_stream_ici": (
+            "kv_block_stream", "kv_block_pool", link_options, None),
     }
     configs = {c["name"]: c for c in BENCH["configs"]}
     assert set(expected) <= set(configs)  # a later PR may add more
@@ -358,17 +402,26 @@ def test_the_new_entries_only_follow_the_old():
     assert BENCH["per_layer"][72] == {
         "name": "link_prefetched_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "link", "moves": "goodput",
-        "workloads": ["link_echo_ici_1m", "link_stream_ici"],
+        "workloads": ["link_echo_ici_1m", "link_stream_ici", KV_CELL],
     }
     # PR 37's three follow it, and its configuration and cell the old ones
     assert names[73:76] == [
         "table_step_kernel_us", "table_step_hbm_pct", "table_state_wait_us"]
     # PR 38's one entry follows them
-    assert BENCH["per_layer"][76:] == [{
+    assert BENCH["per_layer"][76] == {
         "name": "link_staged_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "link", "moves": "goodput",
-        "workloads": ["link_echo_ici_1m", "link_stream_ici"],
-    }]
+        "workloads": ["link_echo_ici_1m", "link_stream_ici", KV_CELL],
+    }
+    # PR 39's eleven follow it, each in its one cell
+    assert names[77:] == [
+        "lane_step_us", "lane_launch_us", "lane_ready_us", "lane_pair_wait_us",
+        "lane_deliver_us", "lane_launch_cpu_us", "lane_messages_per_step",
+        "lane_step_ici_pct", "stream_device_bytes_pct",
+        "kv_page_write_kernel_us", "kv_page_write_hbm_pct"]
+    assert all(m["workloads"] == [KV_CELL] for m in BENCH["per_layer"][77:])
+    assert [m["layer"] for m in BENCH["per_layer"][77:]] == (
+        ["link"] * 8 + ["stream"] + ["device program"] * 2)
     for entry, source, layer, moves in zip(
             BENCH["per_layer"][73:76],
             ("device_trace", "device_trace", "program_counter"),
@@ -378,13 +431,18 @@ def test_the_new_entries_only_follow_the_old():
         assert (entry["source"], entry["layer"], entry["moves"]) == (
             source, layer, moves)
         assert entry["workloads"] == ["ycsb_b_zipf_c16"]
-    assert [c["name"] for c in BENCH["configs"]][5:] == ["ycsb_b_device_table"]
-    assert CELLS[7:] == ["ycsb_b_zipf_c16"]
-    assert BENCH["workloads"][7]["chips"] == 1
-    for m in BENCH["end_to_end"] + BENCH["per_layer"][:73]:
-        # an older metric gained the cell's name at the end of its list or not at all
-        if "ycsb_b_zipf_c16" in m.get("workloads", ()):
-            assert m["workloads"][-1] == "ycsb_b_zipf_c16", m["name"]
+    assert [c["name"] for c in BENCH["configs"]][5:] == [
+        "ycsb_b_device_table", "kv_block_stream_ici"]
+    assert CELLS[7:] == ["ycsb_b_zipf_c16", KV_CELL]
+    assert [w["chips"] for w in BENCH["workloads"][7:]] == [1, 4]
+    for m in BENCH["end_to_end"] + BENCH["per_layer"][:77]:
+        # an older metric gained a cell's name at the end of its list or not at all
+        listed = [w for w in m.get("workloads", ()) if w != KV_CELL]
+        if KV_CELL in m.get("workloads", ()):
+            assert m["workloads"][-1] == KV_CELL, m["name"]
+        if "ycsb_b_zipf_c16" in listed and m["name"] not in (
+                "table_step_kernel_us", "table_step_hbm_pct", "table_state_wait_us"):
+            assert listed[-1] == "ycsb_b_zipf_c16", m["name"]
 
 
 def test_every_per_layer_entry_has_its_reader_file():
@@ -693,3 +751,68 @@ def test_table_step_readers_from_fabricated_counters_and_a_fabricated_trace():
     # a window that served nothing ran no row
     idle = dict(TABLE, device_transport_dispatch_pad_rows=0)
     assert share(hand_made_run(idle)) is None
+
+
+def lane_run(counters: dict):
+    """The prefill chip and the decode chip over a window: 640 executions
+    of the lane's program of 25 us on each, 160 of the pool's write of 40
+    us on the decode chip, an exchange program that is neither, and one
+    lane program outside the window."""
+    run = hand_made_run(counters)
+    start = T_OPEN + np.arange(640, dtype=np.int64) * 1_000_000
+    lane = "jit_device_link_lane(1234567890)"
+    both = xplane.Events(
+        [lane] * 641 + ["jit_exchange(99)"] * 640,
+        np.concatenate((start, [T_CLOSE + 5_000], start + 100_000)),
+        np.concatenate((start + 25_000, [T_CLOSE + 30_000], start + 130_000)),
+    )
+    write = xplane.Events(
+        ["jit_kv_page_write(42)"] * 160, start[:160] + 200_000, start[:160] + 240_000)
+    decode = xplane.Events(
+        both.names + write.names,
+        np.concatenate((both.start, write.start)),
+        np.concatenate((both.end, write.end)),
+    )
+    none = xplane.Events([], [], [])
+    run.devices = {
+        "/device:TPU:0": {"steps": both, "ops": none},
+        "/device:TPU:1": {"steps": decode, "ops": none},
+    }
+    run.cell = types.SimpleNamespace(
+        config={"prefill_device": 0, "block_bytes": 2097152})
+    return run
+
+
+def test_lane_and_page_write_shares_from_a_fabricated_trace():
+    ici = manifest.load_module("layers", "lane_step_ici_pct.py").read
+    kernel = manifest.load_module("layers", "kv_page_write_kernel_us.py").read
+    hbm = manifest.load_module("layers", "kv_page_write_hbm_pct.py").read
+    # 640 blocks of 2 MiB in 640 x 25 us of the lane's program on the
+    # sending chip alone, against the chip's 1,600 Gbit/s
+    share = 100.0 * (640 * 2097152 / 16e-3) / (1600e9 / 8)
+    assert ici(lane_run(dict(LANE))) == pytest.approx(share)
+    assert 0 < share < 100
+    assert kernel(lane_run(dict(LANE))) == pytest.approx(40.0)
+    # 640 pages, each a block read and a page written, in 160 x 40 us
+    written = 100.0 * (2 * 640 * 2097152 / 819e9) / 6.4e-3
+    assert hbm(lane_run(dict(LANE))) == pytest.approx(written)
+    assert 0 < written < 100
+    assert roofline_lane.program_time(
+        lane_run({}).devices, T_OPEN, T_CLOSE, "device_link_lane") == (1280, 32_000_000)
+    for read in (ici, kernel, hbm):
+        # a program without the lane or the pool (the parent), a CPU
+        # rehearsal with no device plane, a machine with no peaks on record
+        assert read(lane_run({})) is None
+        run = lane_run(dict(LANE))
+        run.devices = {}
+        assert read(run) is None
+    for read in (ici, hbm):
+        run = lane_run(dict(LANE))
+        run.peaks = None
+        assert read(run) is None
+    # the exchange's programs are not the lane's: a trace without the
+    # lane's own name reads nothing
+    run = lane_run(dict(LANE))
+    for lines in run.devices.values():
+        lines["steps"] = xplane.Events(["jit_exchange(99)"], [T_OPEN], [T_OPEN + 9])
+    assert ici(run) is None and kernel(run) is None and hbm(run) is None

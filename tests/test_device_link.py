@@ -1797,3 +1797,130 @@ class TestTrainStagedOnce:
         assert np.array_equal(np.asarray(out), rows[::-1])
         # and the same rows as a host buffer give the same exchange
         assert np.array_equal(np.asarray(link._step(rows)), rows[::-1])
+
+
+class TestLane:
+    """PR 39: the link's second program. A committed array on the sender's
+    device lands on the receiver's by one one-way ``ppermute`` and is
+    handed over as a device array; the byte stream beside it is as it was."""
+
+    SLOT_WORDS = TestSlotTrains.SLOT_WORDS
+    _make_link = TestSlotTrains._make_link
+
+    LANE = {
+        "lane_step_us", "lane_launch_us", "lane_ready_us", "lane_pair_wait_us",
+        "lane_deliver_us", "lane_launch_cpu_us",
+    }
+
+    @staticmethod
+    def _names(link):
+        from incubator_brpc_tpu.bvar import expose_registry
+
+        pfx = f"device_link_{link.link_id}_"
+        return {name[len(pfx):] for name, _ in expose_registry.snapshot(pfx)}
+
+    @pytest.mark.parametrize("geometry", ["ppermute", "device-swap"])
+    def test_only_a_link_between_two_devices_has_the_lane_and_its_names(self, geometry):
+        import numpy as np
+
+        from incubator_brpc_tpu import bvar
+
+        link, socks, sinks = self._make_link(geometry)
+        feed = f"device_link_{link.link_id}_lane_steps"
+        if geometry == "ppermute":
+            assert link.has_lane and socks[0].lane is link
+            assert self.LANE <= self._names(link) and feed in bvar.feeds()
+            assert bvar.feeds()[feed].worker == (
+                ("taken", "launched"), ("paired", "queued"))
+        else:
+            assert not link.has_lane and socks[0].lane is None
+            assert not self.LANE & self._names(link) and feed not in bvar.feeds()
+            with pytest.raises(ValueError):
+                link.warm_lane(0, (8,), np.uint32)
+        link.fail("retire")
+        assert not self._names(link)  # the lane's recorders retire with the rest
+
+    @pytest.mark.parametrize("side", [0, 1])
+    def test_an_array_crosses_either_way_beside_the_byte_stream(self, side):
+        import jax
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link("ppermute", window=8)
+        data = np.random.default_rng(side).integers(
+            0, 2**32, size=(3, 500), dtype=np.uint32)
+        block = jax.device_put(data, link.devices[side])
+        assert link.lane_accepts(side, block)
+        assert not link.lane_accepts(1 - side, block)  # not that side's device
+        assert not link.lane_accepts(side, data)  # host memory
+        before = {a: getattr(dl, a).get_value()
+                  for a in ("link_bytes", "lane_bytes", "lane_steps")}
+        got = []
+        frames, stream = _framed_stream(39, 20 * 1024)
+        assert link.send(side, stream) == 0
+        for _ in range(3):
+            step = link.lane_reserve(side, block.nbytes)
+            assert link.lane_claim(1 - side, step.seq, got.append)
+            assert link.lane_send(side, step, block) == 0
+        assert _wait(lambda: len(got) == 3, timeout=30)
+        for landed in got:
+            assert isinstance(landed, jax.Array)
+            assert landed.devices() == {link.devices[1 - side]}
+            assert (landed.shape, landed.dtype) == (data.shape, data.dtype)
+            assert np.array_equal(np.asarray(landed), data)
+        assert _wait(lambda: sinks[1 - side].nbytes == len(stream), timeout=30)
+        assert sinks[1 - side].frames() == frames
+        gained = {a: getattr(dl, a).get_value() - v for a, v in before.items()}
+        assert gained == {"link_bytes": len(stream), "lane_bytes": 3 * data.nbytes,
+                          "lane_steps": 3}
+        assert _wait(lambda: link._lane_inflight == 0)
+
+    def test_a_lane_dispatch_that_raises_fails_the_link_and_leaves_nothing_in_flight(self):
+        import jax
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link("ppermute")
+        block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
+        link.warm_lane(0, block.shape, block.dtype)
+        key = (0, (64,), "uint32")
+        _program, placeholder, shard = link._lane_programs[key]
+
+        def raising(_operand):
+            raise RuntimeError("injected lane fault")
+
+        link._lane_programs[key] = (raising, placeholder, shard)
+        step = link.lane_reserve(0, block.nbytes)
+        got = []
+        assert link.lane_claim(1, step.seq, got.append)
+        assert link.lane_send(0, step, block) == ErrorCode.EFAILEDSOCKET
+        assert link._closed and link._lane_inflight == 0 and not link._lane_pending
+        # CONNECTED == 0: the sockets went down with the link
+        assert all(s.state != 0 for s in socks) and not got
+        assert link.lane_reserve(0, 4) is None  # a dead link reserves nothing
+        started = time.monotonic()
+        dl._quiesce_links(timeout=5.0)
+        assert time.monotonic() - started < 1.0
+
+    @pytest.mark.parametrize("geometry", ["ppermute", "device-swap"])
+    def test_a_train_whose_dispatch_raises_gives_its_slots_back(self, geometry):
+        """ROADMAP D10's small fault: the slots a failed dispatch took stayed
+        on ``_inflight``, so the idle check at exit waited out its whole
+        timeout on a dead link."""
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link(geometry, window=8)
+
+        def failing(slots):
+            raise RuntimeError("injected device fault")
+
+        link._step = failing
+        assert link.send(0, b"z" * (5 * 1024)) == 0  # a train of four slots
+        assert _wait(lambda: link._closed, timeout=10)
+        assert _wait(lambda: not link._driving)
+        assert link.inflight_steps == 0 and not link._steps
+        started = time.monotonic()
+        dl._quiesce_links(timeout=5.0)
+        assert time.monotonic() - started < 1.0
