@@ -18,13 +18,21 @@ Kept design points (and where they live in the reference):
   ``on_closed``, and the registry entry dies (versioned ids are not needed:
   ids are never reused).
 - A message may be a device array. Over a ``DeviceSocket`` whose link
-  runs between two devices, ``write`` of a ``jax.Array`` sends a data
-  frame with an empty body that names shape, dtype and the lane's
-  sequence number, and hands the array to the link's lane
-  (``transport/device_link.py``): the body crosses chip to chip and the
-  handler is handed a ``jax.Array`` on its own device, in the stream's
-  order among its other messages. The window counts its ``nbytes``. Over
-  any other socket the array's bytes go as a bytes message.
+  runs between two devices, ``write`` of a ``jax.Array`` hands the array
+  to the link's lane (``transport/device_link.py``) with its **tag**: the
+  data frame that would head it, empty body and all, as the lane's opaque
+  words. Both cross chip to chip in one program, nothing of the message
+  rides the byte stream, and the handler is handed a ``jax.Array`` on its
+  own device. The window counts its ``nbytes``. Over any other socket the
+  array's bytes go as a bytes message.
+- Two FIFO carriers then feed one stream, the byte stream (bytes
+  messages, close) and the lane (device messages), and either may be the
+  faster. Each message names how many messages of the *other* carrier its
+  writer had sent on the stream before it (``arrays_before`` in a frame's
+  meta, absent while the stream has sent no array; ``frames_before`` in a
+  tag), and the reader releases it to the ordered consumer when that many
+  have been released: the handler sees the messages in the order written.
+  A stream that never carried an array never enters that stage.
 
 Always-on recorders (docs/OBSERVABILITY.md, "Streams"): a stream counts
 under ``device_link_stream_*`` once it rides a ``DeviceSocket`` and under
@@ -53,8 +61,10 @@ from incubator_brpc_tpu.protocol.tbus_std import (
     FLAG_STREAM,
     Meta,
     ParsedFrame,
+    ParseError,
     pack_frame,
     pack_frame_iobuf,
+    try_parse_frame,
 )
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.execution_queue import ExecutionQueue, TaskIterator
@@ -77,10 +87,11 @@ FT_DATA = "data"
 FT_FEEDBACK = "fb"
 FT_CLOSE = "close"
 FT_RST = "rst"
-# the consumer queue's own kind, never on the wire: a data frame whose
-# body came over the link's lane and is a device array
+# the consumer queue's own kind, never on the wire: a data frame that came
+# over the link's lane as a tag, beside its body, a device array
 _FT_DEVICE = "device"
-_WAITING = object()  # in place of a body the lane has not delivered yet
+# the two carriers of one stream's messages, as the order stage indexes them
+_BYTE_STREAM, _LANE = 0, 1
 
 IDLE = 0
 CONNECTING = 1
@@ -124,7 +135,8 @@ class _StreamVars:
             name=f"{prefix}_feedbacks", ring_rows=_RING_ROWS,
             call=(("admitted", "applied"),),
         )
-        # reader: _on_frame -> the handler entered for that message
+        # reader: _on_frame, or the lane's hand-over -> the handler entered
+        # for that message (holds a message's wait for the other carrier)
         self.delivers = RecorderFeed(
             ((recorder("deliver_us"), 1e-3, ("frame", "handler")),),
             stamps=("frame", "handler"),
@@ -229,12 +241,20 @@ class Stream:
         )
         self._close_sent = False
         self._connected_event = threading.Event()
-        # frames held back behind a device message whose body the lane has
-        # not delivered yet, in the order cut: [kind, payload, stamp,
-        # shape, dtype]. None until the stream's first device message, so
-        # a stream of bytes never looks at it twice
-        self._await: Optional[deque] = None
-        self._await_lock = threading.Lock()
+        # writer side of the order across the two carriers: messages this
+        # stream has sent for certain, [data frames, device messages], and
+        # the lock a carrier that keeps its sends one at a time
+        self._wrote = [0, 0]
+        self._send_locks = (threading.Lock(), threading.Lock())
+        # reader side. _held is None until this stream is handed a device
+        # message or a frame that names one (a stream of bytes stays
+        # there); then, a carrier, the messages that arrived and wait for
+        # the other carrier, oldest first: (task, messages of the other
+        # carrier that go before it). _released counts what went on to the
+        # consumer, [data frames, device messages]
+        self._held: Optional[tuple] = None
+        self._released = [0, 0]
+        self._order_lock = threading.Lock()
 
     # -- connection plumbing (module-level handshake hooks call these) ------
 
@@ -247,6 +267,7 @@ class Stream:
             self.state = CONNECTED
             if hasattr(sock, "link"):  # a DeviceSocket
                 self._vars = LINK_VARS
+                sock.lane_receiver = process_lane_message
         sock.on_failed.append(self._on_socket_failed)
         self._connected_event.set()
 
@@ -271,13 +292,13 @@ class Stream:
           array has to lie whole on the device this side of the link
           drives, with at least one dimension and one element (else
           EINVAL). It is admitted against ``max_buf_size`` by its
-          ``nbytes``; its header goes over the byte stream, the array to
-          the link's lane, and the far handler is handed a ``jax.Array``
-          of that shape and dtype on its own device. The stream keeps the
-          array until the lane's program has it; **the writer may not
-          write into, donate or delete it until the message was consumed**
-          (``unconsumed_bytes`` has fallen past it): the program reads it
-          where it lies.
+          ``nbytes`` and goes whole to the link's lane; the far handler is
+          handed a ``jax.Array`` of that shape and dtype on its own
+          device, in its place among the stream's other messages. The
+          stream keeps the array until the lane's program has it; **the
+          writer may not write into, donate or delete it until the message
+          was consumed** (``unconsumed_bytes`` has fallen past it): the
+          program reads it where it lies.
         - a multi-controller link (``transport/mc_link.py``) has no lane
           yet: EINVAL.
         - a host socket, or a link on one shared device (the host swap):
@@ -289,6 +310,7 @@ class Stream:
             if data is None:
                 return ErrorCode.EINVAL
         n = len(data) if array is None else array.nbytes
+        carrier = _BYTE_STREAM if array is None else _LANE
         limit = self.options.max_buf_size
         deadline = None if timeout is None else time.monotonic() + timeout
         t_enter = time.monotonic_ns()
@@ -332,39 +354,56 @@ class Stream:
             (t_enter, admitted[1] if parked else t_enter, ahead)
         )
         meta = Meta(stream_id=rid, extra={"ft": FT_DATA, "from": self.id})
-        # IOBuf pack: no body/frame concat copies on the data hot path.
-        # drain_inline: this thread is blocking-capable (it just passed the
-        # credit window), so it drives the kernel-buffer drain itself —
-        # no KeepWrite fiber + reactor wakeup relay per buffer-full cycle.
-        # The drain gets the REMAINING budget (the window wait above may
-        # have consumed most of ``timeout``), and its expiry only falls
-        # back to the KeepWrite fiber — the frame is still sent.
-        drain_budget = None
-        if deadline is not None:
-            drain_budget = max(0.0, deadline - time.monotonic())
-        if array is None:
-            rc = sock.write(
-                pack_frame_iobuf(meta, data, 0, flags=FLAG_STREAM),
-                timeout=drain_budget,
-                drain_inline=True,
-            )
-        else:
-            rc = self._send_array(sock, meta, array, drain_budget)
-        if rc == ErrorCode.EOVERCROWDED:
-            # transient socket backpressure (socket.cpp:1537): surface it,
-            # don't kill the stream; the rollback reopens the window so any
-            # writer parked on it must be woken (no feedback will do it)
+        # One send at a time a carrier, and the message counted before the
+        # next is sent: a count is then a place in the carrier's order, and
+        # what a later message of the other carrier names has all been
+        # sent. Counted once the carrier has it and not before: a refused
+        # write leaves nothing to wait for.
+        with self._send_locks[carrier]:
+            before = self._wrote[1 - carrier]  # of the other carrier
+            if array is not None:
+                meta.extra["frames_before"] = before
+                rc = sock.lane.lane_send(
+                    sock.side, array, pack_frame(meta, b"", 0, flags=FLAG_STREAM)
+                )
+            else:
+                if before:  # a stream that sent no array: today's frame
+                    meta.extra["arrays_before"] = before
+                # IOBuf pack: no body/frame concat copies on the data hot
+                # path. drain_inline: this thread is blocking-capable (it
+                # just passed the credit window), so it drives the
+                # kernel-buffer drain itself — no KeepWrite fiber + reactor
+                # wakeup relay per buffer-full cycle. The drain gets the
+                # REMAINING budget (the window wait above may have consumed
+                # most of ``timeout``), and its expiry only falls back to
+                # the KeepWrite fiber — the frame is still sent.
+                drain_budget = None
+                if deadline is not None:
+                    drain_budget = max(0.0, deadline - time.monotonic())
+                rc = sock.write(
+                    pack_frame_iobuf(meta, data, 0, flags=FLAG_STREAM),
+                    timeout=drain_budget,
+                    drain_inline=True,
+                )
+            if rc == 0:
+                self._wrote[carrier] += 1  # this lock is its one writer's
+                return 0
+        refused = array is not None and rc == ErrorCode.EINVAL  # its tag too long
+        if rc == ErrorCode.EOVERCROWDED or refused:
+            # transient socket backpressure (socket.cpp:1537), or a message
+            # the lane took nothing of: surface it, don't kill the stream;
+            # the rollback reopens the window so any writer parked on it
+            # must be woken (no feedback will do it)
             with self._lock:
                 self._produced -= n
                 self._forget_write_locked(admitted, n)
             self._wbutex.add(1)
             self._wbutex.wake_all()
-            self._vars.write_retries << 1
+            if not refused:
+                self._vars.write_retries << 1
             return rc
-        if rc != 0:
-            self._fail(rc, "stream data write failed")
-            return rc
-        return 0
+        self._fail(rc, "stream data write failed")
+        return rc
 
     def _device_message(self, array) -> tuple:
         """What ``write`` sends for a message that is no host bytes:
@@ -388,28 +427,6 @@ class Stream:
         if not lane.lane_accepts(sock.side, array):
             return None, None
         return array, b""
-
-    def _send_array(self, sock, meta: Meta, array, drain_budget) -> int:
-        """An admitted device message: its header (an empty body; shape,
-        dtype and the lane's sequence number in the meta) over the byte
-        stream, then the array to the lane, which pairs them again at the
-        far socket. The header goes first: it has the longer way."""
-        lane = sock.lane
-        step = lane.lane_reserve(sock.side, array.nbytes)
-        if step is None:
-            return ErrorCode.EFAILEDSOCKET
-        meta.extra.update(
-            lane=step.seq, shape=list(array.shape), dtype=array.dtype.name
-        )
-        rc = sock.write(
-            pack_frame(meta, b"", 0, flags=FLAG_STREAM),
-            timeout=drain_budget,
-            drain_inline=True,
-        )
-        if rc != 0:
-            lane.lane_abandon(step)  # no header names it: nothing to pair
-            return rc
-        return lane.lane_send(sock.side, step, array)
 
     def _set_remote_consumed(self, consumed: int) -> None:
         """Feedback arrived (SetRemoteConsumed stream.cpp:287): lift the
@@ -446,72 +463,54 @@ class Stream:
         if ft == FT_FEEDBACK:
             self._set_remote_consumed(int(extra.get("consumed", 0)))
             return
-        if "lane" in extra:
-            self._on_lane_header(extra)
-            return
         # the native parse path leaves stream payloads as zero-copy IOBuf
         # cuts; the consumer materializes only when the handler wants bytes
         data = frame.payload_iobuf
         task = (ft, frame.payload if data is None else data, time.monotonic_ns())
-        if self._await is None:
-            self._rq.execute(task)
-        else:
-            self._queue_in_order(task)
-
-    def _queue_in_order(self, task: tuple) -> None:
-        """A frame cut after the stream's first device message: behind
-        whatever still waits for its body, else straight to the consumer."""
-        with self._await_lock:
-            if self._await:
-                self._await.append(list(task))
-            else:
+        after = int(extra.get("arrays_before", 0))
+        with self._order_lock:
+            if self._held is None and not after:
+                self._released[_BYTE_STREAM] += ft == FT_DATA
                 self._rq.execute(task)
+            else:
+                self._in_order(_BYTE_STREAM, task, after)
 
-    def _on_lane_header(self, extra: dict) -> None:
-        """A data frame with no body of its own: the lane has it, or will.
-        It keeps the place in the stream's order its header was cut at."""
-        sock = self._sock
-        lane = getattr(sock, "lane", None)
-        entry = [
-            _FT_DEVICE, _WAITING, time.monotonic_ns(),
-            tuple(extra.get("shape", ())), extra.get("dtype"),
-        ]
-        with self._await_lock:
-            if self._await is None:
-                self._await = deque()
-            self._await.append(entry)
-        if lane is None or not lane.lane_claim(
-            sock.side, int(extra["lane"]),
-            lambda body: self._on_lane_body(entry, body),
-        ):
-            self._fail(
-                ErrorCode.EREQUEST, "a device message the link's lane does not know"
-            )
+    def _on_device_message(self, extra: dict, body) -> None:
+        """The lane handed over a device message of this stream: its tag's
+        meta and its array on this side's device. This moment is its
+        ``arrived`` stamp: ``deliver_us`` holds its wait for the byte
+        stream."""
+        task = (_FT_DEVICE, body, time.monotonic_ns())
+        with self._order_lock:
+            self._in_order(_LANE, task, int(extra.get("frames_before", 0)))
 
-    def _on_lane_body(self, entry: list, body) -> None:
-        """The lane delivered a waiting message's array (on the reader's
-        thread if it was there before its header, else on a completion
-        watcher): everything at the head of the order that is whole goes to
-        the consumer, under the lock that keeps the order."""
-        if (tuple(body.shape), body.dtype.name) != (entry[3], entry[4]):
-            self._fail(
-                ErrorCode.EREQUEST,
-                f"lane delivered {body.dtype.name}{tuple(body.shape)} for a "
-                f"header that names {entry[4]}{entry[3]}",
-            )
-            return
-        with self._await_lock:
-            entry[1] = body
-            waiting = self._await
-            while waiting and waiting[0][1] is not _WAITING:
-                self._rq.execute(tuple(waiting.popleft()[:3]))
+    def _in_order(self, carrier: int, task: tuple, after: int) -> None:
+        """Under the order lock, a message that arrived on ``carrier`` and
+        goes ``after`` so many messages of the other one: behind what its
+        own carrier brought before it, and on to the consumer with
+        everything that no longer waits. Each carrier is FIFO and a
+        message names only what was sent before it, so whatever is held
+        waits for something still on its way."""
+        if self._held is None:
+            self._held = (deque(), deque())
+        held, released = self._held, self._released
+        held[carrier].append((task, after))
+        while True:
+            for c, queue in enumerate(held):
+                if queue and queue[0][1] <= released[1 - c]:
+                    task = queue.popleft()[0]
+                    released[c] += task[0] in (FT_DATA, _FT_DEVICE)
+                    self._rq.execute(task)
+                    break
+            else:
+                return
 
     def _consume(self, it: TaskIterator) -> None:
         """Ordered consumer fiber (stream.cpp:86): batch data messages to the
         handler, then feed consumption back to the writer."""
         handler = self.options.handler
         batch: List[Message] = []
-        arrived: List[int] = []  # _on_frame's stamp of each message
+        arrived: List[int] = []  # when each message reached this stream
         closed = False
         raw = self.options.raw_messages
         nbytes = device_messages = device_bytes = 0
@@ -579,17 +578,28 @@ class Stream:
                 _registry_remove(self.id)
                 return
             self._close_sent = True
-            sock, rid = self._sock, self.remote_id
+            sock, rid, arrays = self._sock, self.remote_id, self._wrote[_LANE]
         meta = Meta(stream_id=rid, stream_close=True, extra={"ft": FT_CLOSE})
+        if arrays:  # the far consumer closes after the arrays still on the lane
+            meta.extra["arrays_before"] = arrays
         sock.write(pack_frame(meta, b"", 0, flags=FLAG_STREAM))
         # the local side is closed immediately; the consumer queue keeps
         # draining whatever the peer already sent
         self._finish_close(notify=False)
 
+    def _drop_held(self) -> None:
+        """What the order stage held back will not be consumed now: a
+        closed or failed stream has left the registry, so what a held
+        message waits for can no longer reach it."""
+        with self._order_lock:
+            for queue in self._held or ():
+                queue.clear()
+
     def _finish_close(self, notify: bool) -> None:
         with self._lock:
             was_closed = self.state == CLOSED
             self.state = CLOSED
+        self._drop_held()
         self._connected_event.set()
         self._wbutex.add(1)
         self._wbutex.wake_all()
@@ -645,9 +655,7 @@ class Stream:
             self.state = CLOSED
             self.error_code = code
             self.error_text = reason
-        with self._await_lock:
-            if self._await:
-                self._await.clear()  # no body will come for these now
+        self._drop_held()
         self._connected_event.set()
         self._wbutex.add(1)
         self._wbutex.wake_all()
@@ -732,10 +740,8 @@ def stream_accept(cntl, options: Optional[StreamOptions] = None) -> Optional[Str
     return s
 
 
-def process_stream(sock, frame: ParsedFrame) -> None:
-    """tbus_std Protocol.process_stream hook: route a FLAG_STREAM frame to
-    its stream by meta.stream_id (ParseStreamingMessage →
-    Stream::OnReceived, SURVEY §3.4)."""
+def _stream_of(sock, frame: ParsedFrame) -> Optional[Stream]:
+    """The open stream a frame names, or None after answering the frame."""
     s = get_stream(frame.meta.stream_id)
     if s is None:
         # peer doesn't know we're gone yet: answer data with RST so its
@@ -744,8 +750,35 @@ def process_stream(sock, frame: ParsedFrame) -> None:
         if frame.meta.extra.get("ft", FT_DATA) == FT_DATA and sender:
             meta = Meta(stream_id=sender, extra={"ft": FT_RST})
             sock.write(pack_frame(meta, b"", 0, flags=FLAG_STREAM))
+    return s
+
+
+def process_stream(sock, frame: ParsedFrame) -> None:
+    """tbus_std Protocol.process_stream hook: route a FLAG_STREAM frame to
+    its stream by meta.stream_id (ParseStreamingMessage →
+    Stream::OnReceived, SURVEY §3.4)."""
+    s = _stream_of(sock, frame)
+    if s is not None:
+        s._on_frame(frame)
+
+
+def process_lane_message(sock, tag, body) -> None:
+    """``DeviceSocket.lane_receiver`` hook: a device message as the link's
+    lane handed it over, its tag's words and its body on this side's
+    device. The tag is the data frame that would head the message on the
+    byte stream, cut by the parser that cuts those: magic and checksum
+    hold for it too, and a tag that does not parse fails the socket. One
+    that names no open stream is dropped as such a frame is."""
+    try:
+        frame, _ = try_parse_frame(tag.tobytes())
+        if frame is None or not frame.is_stream:
+            raise ParseError("not a whole stream frame")
+    except (ParseError, ValueError) as e:
+        sock.set_failed(ErrorCode.EREQUEST, f"a device message's tag: {e}")
         return
-    s._on_frame(frame)
+    s = _stream_of(sock, frame)
+    if s is not None:
+        s._on_device_message(frame.meta.extra, body)
 
 
 proto_pkg.TBUS_STD.process_stream = process_stream

@@ -42,14 +42,17 @@ re-thought for XLA devices:
   passes the window's byte budget (EOVERCROWDED past a hard cap); slot
   headers carry cumulative seq/ack words like the RDMA endpoint's
   piggybacked imm-data acks (rdma_endpoint.h:176-195).
-- **The lane carries tensors.** Beside the byte stream a ``ppermute``
-  link has a second program: a committed device array on the sender's
-  device lands on the receiver's device by one one-way ``ppermute`` over
-  the link's own mesh, and is handed to the receiver **as a device
-  array** (``lane_send`` / ``lane_claim``): no host copy on either side.
-  Its header crosses the byte stream as any frame does and names the
-  lane's sequence number; body and header pair at the receiver,
-  whichever comes first (docs/DEVICE_PLANE.md, "The lane").
+- **The lane carries tensors, whole.** Beside the byte stream a
+  ``ppermute`` link has a second program: a committed device array on the
+  sender's device lands on the receiver's device by one one-way
+  ``ppermute`` over the link's own mesh, and is handed to the receiver
+  **as a device array** (``lane_send``): no host copy on either side. The
+  message's **tag**, a few opaque ``uint32`` words its sender gives with
+  it, crosses in the same program beside the body and is read back from
+  the receiver's shard; the lane hands ``(tag, body)`` to the receiving
+  ``DeviceSocket`` in the order ``lane_send`` took the messages. Nothing
+  of a device message rides the byte stream (docs/DEVICE_PLANE.md, "The
+  lane").
 """
 
 from __future__ import annotations
@@ -113,6 +116,8 @@ link_capacity = Adder(name="device_link_capacity_bytes")
 lane_steps = Adder(name="device_link_lane_steps")
 lane_messages = Adder(name="device_link_lane_messages")
 lane_bytes = Adder(name="device_link_lane_bytes")
+# of those programs, the ones that carried their message's tag beside it
+lane_tagged = Adder(name="device_link_lane_tagged_steps")
 link_acks = Adder(name="device_link_ack_steps")  # wire-mode catch-up steps
 link_errors = Adder(name="device_link_errors")  # fail() calls, all links
 # send() attempts refused with EOVERCROWDED after a full window-stall wait
@@ -237,47 +242,82 @@ class _Step:
         self.t_launched, self.c_launched = clocks(self.timed)
 
 
-# A paired lane program's row, as _lane_pair writes it: stamps
+# What a lane program carries beside its body: the message's tag, opaque
+# words of the sender's that the receiver is handed with the array
+LANE_TAG_WORDS = 64
+LANE_TAG_BYTES = LANE_TAG_WORDS * 4
+
+# A delivered lane program's row, as _lane_drain writes it: stamps
 # (time.monotonic_ns()) in the order taken, the message's bytes, then the
 # sender's CPU clock around the launch (one program in
-# bvar.CPU_CLOCK_EVERY carries it, the others -1). ``first`` and ``paired``
-# are the earlier and the later of the body seen ready and its header
-# claiming it: the wait of whichever came first.
+# bvar.CPU_CLOCK_EVERY carries it, the others -1). ``ready`` is the body
+# seen ready on the receiver's device; ``paired`` the moment its tag was in
+# hand on the host and its turn in the lane's order had come.
 LANE_STAMPS = (
-    "seq", "taken", "launched", "ready", "first", "paired", "queued",
+    "seq", "taken", "launched", "ready", "paired", "queued",
     "nbytes", "taken_cpu", "launched_cpu",
 )
 # (the link's recorder device_link_<n>_lane_<this>, scale, what it is fed)
 LANE_COLUMNS = (
     ("step_us", 1e-3, (
-        ("taken", "launched"), ("launched", "ready"), ("first", "paired"),
+        ("taken", "launched"), ("launched", "ready"), ("ready", "paired"),
         ("paired", "queued"),
     )),
     ("launch_us", 1e-3, ("taken", "launched")),
     ("ready_us", 1e-3, ("launched", "ready")),
-    ("pair_wait_us", 1e-3, ("first", "paired")),
+    ("pair_wait_us", 1e-3, ("ready", "paired")),
     ("deliver_us", 1e-3, ("paired", "queued")),
     ("launch_cpu_us", 1e-3, ("taken_cpu", "launched_cpu")),
 )
 
 
 class _LaneStep:
-    """One lane program's timeline and its place in the pairing: made when
-    a writer reserves the message's sequence number, kept in the link's
-    table until body and header have met (or the link failed)."""
+    """One lane program's timeline and what it landed: made when
+    ``lane_send`` takes the message, kept until the receiving socket was
+    handed it in its turn (or the link failed)."""
 
     __slots__ = (
         "seq", "to", "nbytes", "t_taken", "c_taken", "t_launched",
-        "c_launched", "watcher", "t_header", "body", "on_body",
+        "c_launched", "watcher", "body", "landed_tag", "tag", "outputs",
     )
 
     def __init__(self, seq: int, to: int, nbytes: int):
         self.seq, self.to, self.nbytes = seq, to, nbytes
-        self.t_taken = self.t_launched = self.t_header = 0
+        self.t_taken = self.t_launched = 0
         self.c_taken = self.c_launched = RecorderFeed.MISSING
         self.watcher = [0, 0]  # DeviceCompletionButex.watch fills these
-        self.body = None  # the array on the receiver's device, once ready
-        self.on_body = None  # the header's claim, once it came
+        self.body = None  # the array on the receiver's device
+        self.landed_tag = None  # the tag's shard there, its host copy asked for
+        self.tag = None  # its words on the host, once the body was seen ready
+        self.outputs = None  # the program's whole outputs, until then
+
+
+def lane_program(mesh, sharding, side: int):
+    """The lane's program over a link's two-device ``mesh`` (``sharding``
+    cuts a first dimension in two along it), from ``side`` to the other:
+    it permutes its two operands one way, the bodies' halves and the tags'
+    rows, and returns both as they landed. Jitted here and named
+    ``device_link_lane``: the benchmark finds its executions in a trace by
+    that name."""
+    import jax
+    from jax.sharding import PartitionSpec as P
+
+    one_way = [(side, 1 - side)]
+
+    def device_link_lane(halves, tags):
+        return jax.shard_map(
+            lambda x, t: (
+                jax.lax.ppermute(x, "link", one_way),
+                jax.lax.ppermute(t, "link", one_way),
+            ),
+            mesh=mesh, in_specs=(P("link"), P("link")),
+            out_specs=(P("link"), P("link")),
+        )(halves, tags)
+
+    return jax.jit(
+        device_link_lane,
+        in_shardings=(sharding, sharding), out_shardings=(sharding, sharding),
+    )
 
 
 class DeviceLink:
@@ -416,15 +456,18 @@ class DeviceLink:
         self._steps_taken = 0  # trains dispatched: which carry the CPU clock
         self._last_dispatch_ns = 0  # this drive's previous dispatch; 0 = none
         self._held_since_ns = 0  # the drive is holding a train back; 0 = not
-        # -- the lane (ppermute geometry only; _build_step makes its feed)
+        # -- the lane (ppermute geometry only; _build_step makes its feed).
+        # A list of two is indexed by the receiving side: each direction
+        # has its own sequence and its own in-order deliverer
         self._lane_lock = threading.Lock()
-        self._lane_seq = itertools.count(1)
-        # seq -> _LaneStep, from the reservation until body met header
-        self._lane_pending: Dict[int, _LaneStep] = {}
+        self._lane_seq = [0, 0]  # messages lane_send took for that side
+        self._lane_next = [0, 0]  # next seq to hand to that side's socket
+        # seq -> _LaneStep seen ready, tag in hand, waiting for its turn
+        self._lane_ready: List[Dict[int, _LaneStep]] = [{}, {}]
+        self._lane_deliver_locks = [threading.Lock(), threading.Lock()]
         # (side, shape, dtype) -> (program, placeholder, receiver's shard)
         self._lane_programs: Dict[tuple, tuple] = {}
         self._lane_inflight = 0  # programs dispatched, body not yet seen ready
-        self._lane_taken = 0  # programs launched: which carry the CPU clock
         self._lane_feed: Optional[RecorderFeed] = None
         self._build_step()
         with _links_lock:
@@ -521,7 +564,7 @@ class DeviceLink:
         if not self.carries_arrays:
             return
         # the lane exists where the exchange is a ppermute between two
-        # devices: a row a paired program, fed by the sampler as the
+        # devices: a row a delivered program, fed by the sampler as the
         # steps' rows are
         lane = f"device_link_{self.link_id}_lane"
         recorders = {
@@ -603,41 +646,37 @@ class DeviceLink:
 
     def _lane_program(self, side: int, shape: tuple, dtype) -> tuple:
         """The lane's program for messages of one shape and dtype from
-        ``side``: ``(program, placeholder, shard)``. The operand is one
-        global array cut in two along its first dimension, the sender's
-        half the message as it lies, the receiver's half a placeholder made
-        here once and kept; the output's shard ``shard`` is the message on
+        ``side``: ``(program, placeholder, shard)``. It permutes two
+        operands one way. The body is one global array cut in two along
+        its first dimension, the sender's half the message as it lies, the
+        receiver's half a placeholder made here once and kept. The tags are
+        one ``(2, LANE_TAG_WORDS)`` array, a row a device (``_lane_tags``).
+        Shard ``shard`` of either output is what landed on
         the receiver's device. Compiled and run once at the first use of a
-        shape (``warm_lane``), so that no message of live traffic
-        compiles."""
+        shape (``warm_lane``), asked for and read back as a live message's
+        is, so that no message of live traffic compiles or pays a first
+        use."""
         import jax
-        from jax.sharding import PartitionSpec as P
 
         key = (side, tuple(shape), np.dtype(dtype).name)
         found = self._lane_programs.get(key)
         if found is not None:
             return found
-        mesh = self._mesh
-
-        def device_link_lane(halves):
-            return jax.shard_map(
-                lambda x: jax.lax.ppermute(x, "link", [(side, 1 - side)]),
-                mesh=mesh, in_specs=P("link"), out_specs=P("link"),
-            )(halves)
-
-        program = jax.jit(
-            device_link_lane,
-            in_shardings=self._sharding, out_shardings=self._sharding,
-        )
+        program = lane_program(self._mesh, self._sharding, side)
         placeholder = jax.device_put(
             np.zeros(shape, dtype=dtype), self.devices[1 - side]
         )
         warm = jax.device_put(np.zeros(shape, dtype=dtype), self.devices[side])
-        out = program(self._lane_operand(side, warm, placeholder))
+        out, tags = program(
+            self._lane_operand(side, warm, placeholder), self._lane_tags(side, b"")
+        )
         shard = [s.device for s in out.addressable_shards].index(
             self.devices[1 - side]
         )
+        landed = tags.addressable_data(shard)
+        self._request_host(landed)
         jax.block_until_ready(out.addressable_data(shard))
+        self._tag_to_host(landed)
         with self._lane_lock:
             found = self._lane_programs.setdefault(
                 key, (program, placeholder, shard)
@@ -653,53 +692,76 @@ class DeviceLink:
             (2 * array.shape[0],) + tuple(array.shape[1:]), self._sharding, halves
         )
 
+    @staticmethod
+    def _lane_tags(side: int, tag: bytes) -> np.ndarray:
+        """The program's second operand for a message of ``side`` tagged
+        ``tag``: ``(2, LANE_TAG_WORDS)`` words, the sender's row the tag,
+        zero-padded. One host buffer made anew for each message, which the
+        program's ``in_shardings`` place (a train's staging: no
+        ``device_put``), and never written after it was handed over: the
+        runtime may still be reading it when the call returns."""
+        tags = np.zeros((2, LANE_TAG_WORDS), dtype=np.uint32)
+        tags[side].view(np.uint8)[: len(tag)] = np.frombuffer(tag, dtype=np.uint8)
+        return tags
+
+    @staticmethod
+    def _tag_to_host(landed) -> np.ndarray:
+        """A landed tag's ``LANE_TAG_WORDS`` words on the host, from the
+        copy ``lane_send`` asked for at the dispatch: the receiver is
+        handed what crossed, not what the sender holds."""
+        return np.asarray(landed).reshape(-1)
+
     def warm_lane(self, side: int, shape: tuple, dtype) -> None:
         """Compile the lane's program for messages of ``shape`` and
-        ``dtype`` sent from ``side`` and run it once, placeholder and all.
-        A deployment calls this for the shapes it will send before it
+        ``dtype`` sent from ``side`` and run it once, placeholder, tag and
+        all. A deployment calls this for the shapes it will send before it
         opens a measured window; a shape never warmed compiles at its
         first message."""
         if not self.has_lane:
             raise ValueError("this link has no lane (one shared device)")
         self._lane_program(side, shape, dtype)
 
-    def lane_reserve(self, side: int, nbytes: int) -> Optional[_LaneStep]:
-        """Take the lane's next sequence number for a message ``side`` is
-        about to send: its header names ``.seq`` and goes over the byte
-        stream first, then ``lane_send`` takes the array. ``None`` on a
-        dead link. A reservation whose header was never sent goes back
-        with ``lane_abandon``."""
-        step = _LaneStep(next(self._lane_seq), 1 - side, nbytes)
+    def lane_send(self, side: int, array, tag) -> int:
+        """Send ``array`` (``lane_accepts`` said yes) and its ``tag`` to
+        the other side, whole, by one dispatch of the lane's program on the
+        caller's thread: one call into the runtime, no host copy of the
+        body. ``tag`` is at most ``LANE_TAG_BYTES`` bytes the link does not
+        read; the receiving socket is handed them, zero-padded to
+        ``LANE_TAG_WORDS`` words, with the array on its device, after every
+        message ``lane_send`` took for that side before this one. 0;
+        ``EINVAL`` for a longer tag, with nothing taken or sent;
+        ``EFAILEDSOCKET`` on a dead link or where the dispatch raised,
+        which fails the link. The array may be dropped by the caller once
+        this returns (the program holds it) but not written or donated
+        until the message was consumed."""
+        if len(tag) > LANE_TAG_BYTES:
+            return ErrorCode.EINVAL
+        to = 1 - side
         with self._lane_lock:
             if self._closed:
-                return None
-            self._lane_pending[step.seq] = step
-        return step
-
-    def lane_abandon(self, step: _LaneStep) -> None:
-        with self._lane_lock:
-            self._lane_pending.pop(step.seq, None)
-
-    def lane_send(self, side: int, step: _LaneStep, array) -> int:
-        """Dispatch the lane's program on ``array`` (``lane_accepts`` said
-        yes; ``step`` is its reservation) on the caller's thread: one call
-        into the runtime, no host copy. 0, or ``EFAILEDSOCKET`` where the
-        dispatch raised, which fails the link. The array may be dropped by
-        the caller once this returns (the program holds it) but not
-        written or donated until the message was consumed."""
-        if self._closed:
-            return ErrorCode.EFAILEDSOCKET
-        timed = self._lane_taken % CPU_CLOCK_EVERY == 0
-        self._lane_taken += 1
-        step.t_taken, step.c_taken = clocks(timed)
-        with self._lane_lock:
+                return ErrorCode.EFAILEDSOCKET
+            step = _LaneStep(self._lane_seq[to], to, array.nbytes)
+            self._lane_seq[to] += 1
             self._lane_inflight += 1
+        timed = step.seq % CPU_CLOCK_EVERY == 0
+        step.t_taken, step.c_taken = clocks(timed)
         try:
             program, placeholder, shard = self._lane_program(
                 side, array.shape, array.dtype
             )
-            out = program(self._lane_operand(side, array, placeholder))
-            body = out.addressable_data(shard)
+            out, landed = program(
+                self._lane_operand(side, array, placeholder),
+                self._lane_tags(side, bytes(tag)),
+            )
+            step.body = out.addressable_data(shard)
+            step.landed_tag = landed.addressable_data(shard)
+            self._request_host(step.landed_tag)
+            # the sender's shards of the outputs are nobody's, and dropping
+            # a buffer of a program still running waits the program out
+            # (0.8 ms of the writer a message on the chip; PERF.md section
+            # 6, PR 40): the completion watcher drops them, once it has
+            # handed the message over
+            step.outputs = (out, landed)
         except Exception:
             logger.exception("device link lane dispatch failed")
             with self._lane_lock:
@@ -709,70 +771,67 @@ class DeviceLink:
         step.t_launched, step.c_launched = clocks(timed)
         lane_steps << 1
         lane_messages << 1
+        lane_tagged << 1
         lane_bytes << step.nbytes
         self._cq.watch(
-            body,
-            on_complete=lambda arrays, error, _step=step: (
-                self._lane_landed(_step, arrays, error)
+            step.body,
+            on_complete=lambda _body, error, _step=step: (
+                self._lane_landed(_step, error)
             ),
             stamps=step.watcher,
         )
         return 0
 
-    def _lane_landed(self, step: _LaneStep, body, error) -> None:
-        """Completion watcher: a lane program's output is ready on the
-        receiver's device (or failed). Hand it to its header's claim if
-        that came first, else keep it for the claim."""
+    def _lane_landed(self, step: _LaneStep, error) -> None:
+        """Completion watcher: a lane program's body is ready on the
+        receiver's device (or failed). Read its tag from the host copy
+        asked for at the dispatch and hand both over when the message's
+        turn comes."""
+        # the program's whole outputs die with this call, on this thread
+        # and after the hand-over: dropping them costs 0.7 ms on the chip
+        # even now (PERF.md section 6, PR 40), and the message does not
+        # wait for it
+        outputs, step.outputs = step.outputs, None  # noqa: F841
+        if error is None:
+            try:
+                step.tag = self._tag_to_host(step.landed_tag)
+            except Exception as e:  # noqa: BLE001 — a device failure is data here
+                error = e
         with self._lane_lock:
             self._lane_inflight -= 1
-            if error is None and self._lane_pending.get(step.seq) is step:
-                step.body = body
-                claimed = step.on_body is not None
-                if claimed:
-                    del self._lane_pending[step.seq]
-            else:
-                claimed = False  # failed, or fail() dropped the table
+            if error is None and not self._closed:
+                self._lane_ready[step.to][step.seq] = step
         if error is not None:
             logger.error("device link lane program failed: %s", error)
             self.fail(f"lane program failed: {error}")
-        elif claimed:
-            self._lane_pair(step)
+            return
+        self._lane_drain(step.to)
 
-    def lane_claim(self, side: int, seq: int, on_body) -> bool:
-        """The receiver's half of the pairing: the header of lane message
-        ``seq`` was cut off ``side``'s byte stream. ``on_body(array)`` runs
-        once with the message on this side's device: here and now if its
-        program was seen ready already, else on the completion watcher
-        when it is. False where the lane knows no such message (the link
-        failed, or the header names a number never reserved); a link that
-        fails later drops the claim, and the socket's failure is what its
-        owner hears."""
-        with self._lane_lock:
-            step = self._lane_pending.get(seq)
-            if step is None or step.to != side or step.on_body is not None:
-                return False
-            step.on_body, step.t_header = on_body, time.monotonic_ns()
-            ready = step.body is not None
-            if ready:
-                del self._lane_pending[seq]
-        if ready:
-            self._lane_pair(step)
-        return True
-
-    def _lane_pair(self, step: _LaneStep) -> None:
-        """Body and header have met, on the thread of whichever came
-        second: hand the array over and write the program's row."""
-        t_ready = step.watcher[1]
-        paired = time.monotonic_ns()
-        try:
-            step.on_body(step.body)
-        except Exception:
-            logger.exception("device link lane delivery raised")
-        self._lane_feed.rows.append((
-            step.seq, step.t_taken, step.t_launched, t_ready,
-            min(t_ready, step.t_header), paired, time.monotonic_ns(),
-            step.nbytes, step.c_taken, step.c_launched,
-        ))
+    def _lane_drain(self, to: int) -> None:
+        """Hand landed messages to side ``to``'s socket strictly in the
+        order ``lane_send`` took them. Completion watchers finish out of
+        order; as in ``_drain_ready`` one deliverer a direction is admitted
+        at a time and the pop of the next seq happens under the lane's
+        lock. Each hand-over writes its program's row."""
+        while True:
+            with self._lane_deliver_locks[to]:
+                with self._lane_lock:
+                    step = self._lane_ready[to].pop(self._lane_next[to], None)
+                    if step is None:
+                        return
+                    self._lane_next[to] += 1
+                paired = time.monotonic_ns()
+                sock = self.socks[to]
+                try:
+                    if sock is not None:
+                        sock._lane_deliver(step.tag, step.body)
+                except Exception:
+                    logger.exception("device link lane delivery raised")
+                self._lane_feed.rows.append((
+                    step.seq, step.t_taken, step.t_launched, step.watcher[1],
+                    paired, time.monotonic_ns(),
+                    step.nbytes, step.c_taken, step.c_launched,
+                ))
 
     # -- send side -----------------------------------------------------------
 
@@ -1234,10 +1293,11 @@ class DeviceLink:
                 self._out_nbytes[side] = 0
             self._steps.clear()
         with self._lane_lock:
-            # bodies kept for a header and claims kept for a body: neither
-            # will meet now; the sockets' failure below is what the
-            # streams on both ends hear
-            self._lane_pending.clear()
+            # landed messages kept for their turn: it will not come now;
+            # the sockets' failure below is what the streams on both ends
+            # hear
+            for ready in self._lane_ready:
+                ready.clear()
         link_errors << 1
         self._retire_metrics()
         # party-death feedback for the collective fault plane: a session
@@ -1324,6 +1384,10 @@ class DeviceSocket:
         self.on_revived: List = []
         self._read_buf = IOBuf()
         self._feed_lock = threading.Lock()
+        # who is handed this side's device messages, (sock, tag, body) in
+        # the lane's order: whoever reads them sets it (rpc/stream.py does
+        # when a stream connects over this socket). None: dropped
+        self.lane_receiver = None
         self.id = _registry.insert(self)
         link.attach(side, self)
 
@@ -1354,8 +1418,8 @@ class DeviceSocket:
     @property
     def lane(self) -> Optional[DeviceLink]:
         """The link, where its exchange runs between two devices: a writer
-        of device arrays asks it (``lane_accepts``) and hands them over, or
-        is refused. ``None`` on one shared device, where there is nothing
+        of device arrays asks it (``lane_accepts``) and hands them over
+        with their tags (``lane_send``), or is refused. ``None`` on one shared device, where there is nothing
         to cross and a writer of arrays sends their bytes. A host
         ``Socket`` has no such attribute."""
         return self.link if self.link.geometry == "ppermute" else None
@@ -1379,6 +1443,17 @@ class DeviceSocket:
                     self._read_buf.append(bytes(data))
             if self.messenger is not None and len(self._read_buf):
                 self.messenger.process(self)
+
+    def _lane_deliver(self, tag: np.ndarray, body) -> None:
+        """Lane delivery: one device message, its tag's words as they were
+        read back on this side and its body on this side's device, in the
+        order the far side sent them. To the link and to this socket the
+        tag is opaque; a receiver that cannot read it fails the socket."""
+        from incubator_brpc_tpu.transport.sock import CONNECTED
+
+        receiver = self.lane_receiver
+        if receiver is not None and self.state == CONNECTED:
+            receiver(self, tag, body)
 
     # -- lifecycle -----------------------------------------------------------
 

@@ -157,6 +157,7 @@ LANE = {
     "device_link_lane_steps": 640,
     "device_link_lane_messages": 640,
     "device_link_lane_bytes": 640 * 2097152,
+    "device_link_lane_tagged_steps": 640,  # PR 40: each with its message's tag
     "device_link_stream_device_messages": 640,
     "device_link_stream_device_bytes": 640 * 2097152,
     "device_link_stream_bytes": 40 * 64,
@@ -218,6 +219,7 @@ EXPECTED = {
     **{f"lane_{s}_us": (LANE, us) for s, us in LANE_STAGES.items()},
     "lane_launch_cpu_us": (LANE, 250.0),
     "lane_messages_per_step": (LANE, 1.0),
+    "lane_tagged_pct": (LANE, 100.0),
     "stream_device_bytes_pct": (
         LANE, 100.0 * 640 * 2097152 / (640 * 2097152 + 40 * 64)),
 }
@@ -414,14 +416,21 @@ def test_the_new_entries_only_follow_the_old():
         "workloads": ["link_echo_ici_1m", "link_stream_ici", KV_CELL],
     }
     # PR 39's eleven follow it, each in its one cell
-    assert names[77:] == [
+    assert names[77:88] == [
         "lane_step_us", "lane_launch_us", "lane_ready_us", "lane_pair_wait_us",
         "lane_deliver_us", "lane_launch_cpu_us", "lane_messages_per_step",
         "lane_step_ici_pct", "stream_device_bytes_pct",
         "kv_page_write_kernel_us", "kv_page_write_hbm_pct"]
     assert all(m["workloads"] == [KV_CELL] for m in BENCH["per_layer"][77:])
-    assert [m["layer"] for m in BENCH["per_layer"][77:]] == (
+    assert [m["layer"] for m in BENCH["per_layer"][77:88]] == (
         ["link"] * 8 + ["stream"] + ["device program"] * 2)
+    # PR 40's one entry follows them, the last
+    assert names[88:] == ["lane_tagged_pct"]
+    assert BENCH["per_layer"][88] == {
+        "name": "lane_tagged_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "link", "moves": "goodput",
+        "workloads": [KV_CELL],
+    }
     for entry, source, layer, moves in zip(
             BENCH["per_layer"][73:76],
             ("device_trace", "device_trace", "program_counter"),
@@ -481,6 +490,26 @@ def test_prefetched_share_counts_the_trains_asked_for_at_dispatch():
 )
 def test_staged_share_counts_the_trains_launched_from_one_host_buffer(counters, share):
     read = manifest.load_module("layers", "link_staged_pct.py").read
+    value = read(hand_made_run(dict(counters)))
+    assert value is None if share is None else value == pytest.approx(share)
+
+
+@pytest.mark.parametrize(
+    "counters,share",
+    [
+        # every lane program of the window carried its message's tag
+        (LANE, 100.0),
+        # a window that opened over a lane from before the tag: 160 of 640
+        ({**LANE, "device_link_lane_tagged_steps": 160}, 25.0),
+        # a program without the adder (the parent: a header on the byte
+        # stream paired with the body), or a window with no lane program
+        ({k: v for k, v in LANE.items() if k != "device_link_lane_tagged_steps"}, None),
+        ({**LINK, "device_link_lane_tagged_steps": 0}, None),
+    ],
+    ids=["every-program", "a-quarter", "no-adder", "no-lane-program"],
+)
+def test_tagged_share_counts_the_lane_programs_that_carried_their_tag(counters, share):
+    read = manifest.load_module("layers", "lane_tagged_pct.py").read
     value = read(hand_made_run(dict(counters)))
     assert value is None if share is None else value == pytest.approx(share)
 

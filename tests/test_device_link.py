@@ -1800,9 +1800,10 @@ class TestTrainStagedOnce:
 
 
 class TestLane:
-    """PR 39: the link's second program. A committed array on the sender's
+    """The link's second program (PR 39). A committed array on the sender's
     device lands on the receiver's by one one-way ``ppermute`` and is
-    handed over as a device array; the byte stream beside it is as it was."""
+    handed over as a device array, its tag's words beside it from the same
+    program (PR 40); the byte stream beside it is as it was."""
 
     SLOT_WORDS = TestSlotTrains.SLOT_WORDS
     _make_link = TestSlotTrains._make_link
@@ -1840,6 +1841,20 @@ class TestLane:
         link.fail("retire")
         assert not self._names(link)  # the lane's recorders retire with the rest
 
+    @staticmethod
+    def _receive(sock):
+        """Keeps what the lane hands ``sock``: ``[(tag bytes, body)]``."""
+        got = []
+        sock.lane_receiver = lambda _sock, tag, body: got.append(
+            (tag.tobytes(), body))
+        return got
+
+    @staticmethod
+    def _padded(tag: bytes) -> bytes:
+        from incubator_brpc_tpu.transport.device_link import LANE_TAG_BYTES
+
+        return tag.ljust(LANE_TAG_BYTES, b"\0")
+
     @pytest.mark.parametrize("side", [0, 1])
     def test_an_array_crosses_either_way_beside_the_byte_stream(self, side):
         import jax
@@ -1854,17 +1869,18 @@ class TestLane:
         assert link.lane_accepts(side, block)
         assert not link.lane_accepts(1 - side, block)  # not that side's device
         assert not link.lane_accepts(side, data)  # host memory
-        before = {a: getattr(dl, a).get_value()
-                  for a in ("link_bytes", "lane_bytes", "lane_steps")}
-        got = []
+        counted = ("link_bytes", "lane_bytes", "lane_steps", "lane_messages",
+                   "lane_tagged")
+        before = {a: getattr(dl, a).get_value() for a in counted}
+        got = self._receive(socks[1 - side])
         frames, stream = _framed_stream(39, 20 * 1024)
         assert link.send(side, stream) == 0
-        for _ in range(3):
-            step = link.lane_reserve(side, block.nbytes)
-            assert link.lane_claim(1 - side, step.seq, got.append)
-            assert link.lane_send(side, step, block) == 0
+        tags = [b"tag %d of side %d" % (i, side) for i in range(3)]
+        for tag in tags:
+            assert link.lane_send(side, block, tag) == 0
         assert _wait(lambda: len(got) == 3, timeout=30)
-        for landed in got:
+        assert [tag for tag, _ in got] == [self._padded(tag) for tag in tags]
+        for _tag, landed in got:
             assert isinstance(landed, jax.Array)
             assert landed.devices() == {link.devices[1 - side]}
             assert (landed.shape, landed.dtype) == (data.shape, data.dtype)
@@ -1872,9 +1888,149 @@ class TestLane:
         assert _wait(lambda: sinks[1 - side].nbytes == len(stream), timeout=30)
         assert sinks[1 - side].frames() == frames
         gained = {a: getattr(dl, a).get_value() - v for a, v in before.items()}
-        assert gained == {"link_bytes": len(stream), "lane_bytes": 3 * data.nbytes,
-                          "lane_steps": 3}
+        # the bodies' nbytes and nothing else: the tags are counted nowhere
+        assert gained == {
+            "link_bytes": len(stream), "lane_bytes": 3 * data.nbytes,
+            "lane_steps": 3, "lane_messages": 3, "lane_tagged": 3}
         assert _wait(lambda: link._lane_inflight == 0)
+        # a tag too long is refused and takes no place in the lane's order
+        seqs = list(link._lane_seq)
+        assert link.lane_send(
+            side, block, b"x" * (dl.LANE_TAG_BYTES + 1)) == ErrorCode.EINVAL
+        assert link._lane_seq == seqs and link._lane_inflight == 0
+
+    def test_the_lane_hands_over_in_the_order_taken_when_watchers_finish_in_reverse(self):
+        """Completion watchers finish out of order: the first message's is
+        kept inside its read of the tag until the two after it have landed,
+        which wait for its turn."""
+        import jax
+        import numpy as np
+
+        link, socks, sinks = self._make_link("ppermute")
+        block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
+        link.warm_lane(0, block.shape, block.dtype)
+        got = self._receive(socks[1])
+        release, read_back = threading.Event(), link._tag_to_host
+
+        def first_one_late(landed):
+            words = read_back(landed)
+            if words.tobytes().startswith(b"message 0"):
+                release.wait(30)
+            return words
+
+        link._tag_to_host = first_one_late
+        for i in range(3):
+            assert link.lane_send(0, block, b"message %d" % i) == 0
+        assert _wait(lambda: set(link._lane_ready[1]) == {1, 2}, timeout=30)
+        assert got == [] and link._lane_next[1] == 0
+        release.set()
+        assert _wait(lambda: len(got) == 3, timeout=30)
+        assert [tag for tag, _ in got] == [
+            self._padded(b"message %d" % i) for i in range(3)]
+        assert not any(link._lane_ready) and link._lane_next == [0, 3]
+
+    def test_the_tag_handed_over_is_what_was_read_back_from_the_receivers_shard(self):
+        """Not the sender's Python object: a double that alters the shard's
+        host copy alters what the socket is handed."""
+        import jax
+        import numpy as np
+
+        link, socks, sinks = self._make_link("ppermute")
+        block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
+        link.warm_lane(0, block.shape, block.dtype)
+        got = self._receive(socks[1])
+        seen, read_back = [], link._tag_to_host
+
+        def altered(landed):
+            assert landed.devices() == {link.devices[1]}
+            words = read_back(landed).copy()
+            seen.append(words.tobytes())
+            words[0] ^= 0xFFFFFFFF
+            return words
+
+        link._tag_to_host = altered
+        assert link.lane_send(0, block, b"as the sender gave it") == 0
+        assert _wait(lambda: len(got) == 1, timeout=30)
+        assert seen == [self._padded(b"as the sender gave it")]
+        handed = np.frombuffer(got[0][0], np.uint32)
+        sent = np.frombuffer(seen[0], np.uint32)
+        assert handed[0] == sent[0] ^ 0xFFFFFFFF
+        assert np.array_equal(handed[1:], sent[1:])
+
+    def test_every_message_hands_the_program_a_host_buffer_of_its_own(self):
+        """The tags operand is made anew for each message, equal tags in a
+        row too: the runtime may still be reading the one before. Each is
+        handed over as sent."""
+        import jax
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link("ppermute")
+        block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
+        link.warm_lane(0, block.shape, block.dtype)
+        key = (0, (64,), "uint32")
+        program, placeholder, shard = link._lane_programs[key]
+        operands = []
+
+        def seen(halves, tags):
+            operands.append(tags)
+            return program(halves, tags)
+
+        link._lane_programs[key] = (seen, placeholder, shard)
+        got = self._receive(socks[1])
+        sent = [b"one"] * 3 + [b"two", b"one"]
+        for tag in sent:
+            assert link.lane_send(0, block, tag) == 0
+        assert _wait(lambda: len(got) == len(sent), timeout=30)
+        assert [tag for tag, _ in got] == [self._padded(tag) for tag in sent]
+        assert all(
+            type(tags) is np.ndarray and tags.shape == (2, dl.LANE_TAG_WORDS)
+            for tags in operands)
+        assert len({id(tags) for tags in operands}) == len(sent)
+        for tags, tag in zip(operands, sent):  # the receiver's row stays zero
+            assert tags[0].tobytes() == self._padded(tag) and not tags[1].any()
+
+    def test_the_program_keeps_its_name_and_every_recorder_has_a_row_a_program(self):
+        import jax
+        import numpy as np
+
+        from incubator_brpc_tpu import bvar
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link("ppermute")
+        block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
+        link.warm_lane(0, block.shape, block.dtype)
+        program, placeholder, _shard = link._lane_programs[(0, (64,), "uint32")]
+        lowered = program.lower(
+            link._lane_operand(0, block, placeholder),
+            np.zeros((2, dl.LANE_TAG_WORDS), np.uint32))
+        # benchmark/roofline_lane.py finds the program's executions by it
+        assert "jit_device_link_lane" in lowered.as_text()
+        got = self._receive(socks[1])
+        before = {a: getattr(dl, a).get_value() for a in ("lane_steps", "lane_tagged")}
+        programs = 2 * bvar.CPU_CLOCK_EVERY
+        for i in range(programs):
+            assert link.lane_send(0, block, b"%d" % i) == 0
+        assert _wait(lambda: len(got) == programs, timeout=30)
+        link._lane_feed.flush()
+        rows = {
+            what: recorder.count()
+            for (recorder, *_), (what, *_) in zip(link._lane_feed.columns, dl.LANE_COLUMNS)
+        }
+        cpu = rows.pop("launch_cpu_us")  # one program in CPU_CLOCK_EVERY
+        assert set(rows) == {"step_us", "launch_us", "ready_us", "pair_wait_us",
+                             "deliver_us"}
+        assert set(rows.values()) == {programs} and cpu == 2
+        gained = {a: getattr(dl, a).get_value() - v for a, v in before.items()}
+        assert gained == {"lane_steps": programs, "lane_tagged": programs}
+        stamps, kept = link._lane_feed.timeline()
+        at = {s: i for i, s in enumerate(stamps)}
+        for row in kept:
+            order = [row[at[s]] for s in
+                     ("taken", "launched", "ready", "paired", "queued")]
+            assert order == sorted(order) and row[at["nbytes"]] == block.nbytes
+        assert sorted(kept[:, at["seq"]]) == list(range(programs))
 
     def test_a_lane_dispatch_that_raises_fails_the_link_and_leaves_nothing_in_flight(self):
         import jax
@@ -1888,18 +2044,18 @@ class TestLane:
         key = (0, (64,), "uint32")
         _program, placeholder, shard = link._lane_programs[key]
 
-        def raising(_operand):
+        def raising(*_operands):
             raise RuntimeError("injected lane fault")
 
         link._lane_programs[key] = (raising, placeholder, shard)
-        step = link.lane_reserve(0, block.nbytes)
-        got = []
-        assert link.lane_claim(1, step.seq, got.append)
-        assert link.lane_send(0, step, block) == ErrorCode.EFAILEDSOCKET
-        assert link._closed and link._lane_inflight == 0 and not link._lane_pending
+        got = self._receive(socks[1])
+        assert link.lane_send(0, block, b"tag") == ErrorCode.EFAILEDSOCKET
+        assert link._closed and link._lane_inflight == 0 and not any(link._lane_ready)
         # CONNECTED == 0: the sockets went down with the link
         assert all(s.state != 0 for s in socks) and not got
-        assert link.lane_reserve(0, 4) is None  # a dead link reserves nothing
+        # a dead link takes nothing
+        assert link.lane_send(0, block, b"tag") == ErrorCode.EFAILEDSOCKET
+        assert link._lane_seq == [0, 1]
         started = time.monotonic()
         dl._quiesce_links(timeout=5.0)
         assert time.monotonic() - started < 1.0

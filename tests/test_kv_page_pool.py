@@ -228,15 +228,20 @@ def test_a_program_without_the_lane_is_refused_before_a_stream_is_opened(monkeyp
 
 
 @pytest.fixture(scope="module")
-def decode_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
 
     try:
-        topo = topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
     except Exception as e:  # noqa: BLE001 — no compiler for the chip here
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def decode_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
     return SingleDeviceSharding(topo.devices[1])
 
 
@@ -277,3 +282,30 @@ def test_the_read_compiles_without_a_second_pool(decode_chip):
     memory = jax.jit(kv_page_read).lower(pool, pages).compile().memory_analysis()
     assert memory.output_size_in_bytes == 16 * 4 * words
     assert memory.temp_size_in_bytes < 64 << 20
+
+
+def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(topo):
+    """A 2 MiB block and its tag cross in one program: two one-way
+    collective permutes between the prefill and the decode chip, and
+    nothing of a block's size beside what lands."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from incubator_brpc_tpu.transport import device_link
+
+    mesh = Mesh(np.asarray(topo.devices[:2]), ("link",))
+    sharding = NamedSharding(mesh, P("link"))
+    words = CONFIG["block_bytes"] // 4
+    halves = jax.ShapeDtypeStruct((2 * words,), jnp.uint32, sharding=sharding)
+    tags = jax.ShapeDtypeStruct(
+        (2, device_link.LANE_TAG_WORDS), jnp.uint32, sharding=sharding)
+    compiled = device_link.lane_program(mesh, sharding, 0).lower(halves, tags).compile()
+    text = compiled.as_text()
+    assert "jit_device_link_lane" in text
+    assert text.count("collective-permute-start(") == 2
+    memory = compiled.memory_analysis()
+    # the block and the tag's row, which the chip pads to a tile
+    assert 4 * words < memory.output_size_in_bytes <= 4 * words + 4096
+    assert memory.temp_size_in_bytes < 1 << 20
