@@ -14,15 +14,29 @@ inside PJRT's ready-event wait (jax.block_until_ready) and then
 bumps/wakes the butex. Callbacks registered via ``on_complete`` run on the
 watcher thread and must be cheap — same contract as the reference's
 HandleCompletion.
+
+The hand-over of a watch to the pool is on every dispatching thread's
+path (an endpoint's drain or ``-tx`` thread, a link's drive, a stream's
+writer), so it takes no lock a watcher holds: ``_WatcherPool.submit`` puts
+the job on a ``queue.SimpleQueue``, whose ``put`` is one C call that wakes
+at most one parked watcher, and a job's end wakes nobody. One row a
+``submit`` feeds ``device_transport_cq_submit_us`` (the dispatching
+thread's time inside it) and ``device_transport_cq_backlog`` (jobs handed
+over that no watcher is free to take, this one included: over 0, every
+watcher was inside a job and this one waits for one to end).
 """
 
 from __future__ import annotations
 
+import atexit
+import logging
+import queue
 import threading
 import time
+from collections import deque
 from typing import Any, Callable, List, Optional
 
-from incubator_brpc_tpu.bvar import PassiveStatus
+from incubator_brpc_tpu.bvar import LatencyRecorder, PassiveStatus, RecorderFeed
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 
 # CPU time of the whole process, us: every thread of it, the runtime's own
@@ -34,15 +48,41 @@ process_cpu_us = PassiveStatus(
     lambda: time.process_time_ns() / 1e3, name="device_transport_process_cpu_us"
 )
 
+# one row a submit waits here for the sampler thread: (entered, handed,
+# backlog), two stamps of the submitting thread and a count of jobs
+m_cq_submit = LatencyRecorder(name="device_transport_cq_submit_us")
+m_cq_backlog = LatencyRecorder(name="device_transport_cq_backlog")
+
+
+def _submit_feed(submit_us: LatencyRecorder, backlog: LatencyRecorder) -> RecorderFeed:
+    return RecorderFeed(
+        [(submit_us, 1e-3, ("entered", "handed")), (backlog, 1, "backlog")],
+        stamps=("entered", "handed", "backlog"),
+    )
+
+
+_cq_feed = _submit_feed(m_cq_submit, m_cq_backlog)
+
 
 class _WatcherPool:
     """Dedicated completion threads (NOT the worker pool: a watcher blocks in
-    the PJRT event wait, which would starve RPC fibers)."""
+    the PJRT event wait, which would starve RPC fibers). Jobs run in the
+    order they were handed over, each exactly once, and a raising job
+    leaves its watcher alive."""
 
-    def __init__(self, nthreads: int):
-        self._jobs: List = []
-        self._cond = threading.Condition()
-        self._active = 0  # jobs currently executing
+    def __init__(self, nthreads: int, feed: RecorderFeed = _cq_feed):
+        # put() takes no Python-level lock and wakes at most one watcher;
+        # get() parks with the interpreter released
+        self._jobs: queue.SimpleQueue = queue.SimpleQueue()
+        # A token a job, from before its hand-over until its end: the
+        # length counts the jobs pending and executing. A deque and not an
+        # int because append and popleft are each one C call, atomic under
+        # the interpreter lock, where ``+= 1`` from two threads is not; and
+        # kept from the submitting side on because counts the watchers kept
+        # alone would miss the job a watcher has taken off the queue and
+        # not yet counted.
+        self._open: deque = deque()
+        self._submits = feed.rows
         self._threads = [
             threading.Thread(target=self._run, name=f"tbrpc-cq-{i}", daemon=True)
             for i in range(nthreads)
@@ -55,42 +95,36 @@ class _WatcherPool:
         # aborts ("terminate called ... FATAL: exception not rethrown").
         # Draining pending/active jobs first (bounded) removes the race;
         # device work completes on its own, we only need to outwait it.
-        import atexit
-
         atexit.register(self.quiesce)
 
     def submit(self, job: Callable[[], None]) -> None:
-        with self._cond:
-            self._jobs.append(job)
-            self._cond.notify()
+        entered = time.monotonic_ns()
+        self._open.append(None)
+        # jobs no watcher is free to take, this one included
+        backlog = max(0, len(self._open) - len(self._threads))
+        self._jobs.put(job)
+        self._submits.append((entered, time.monotonic_ns(), backlog))
 
     def quiesce(self, timeout: float = 10.0) -> bool:
+        """No job pending and none executing, within ``timeout``. Nobody
+        tells it: it looks again every 10 ms."""
         deadline = time.monotonic() + timeout
-        with self._cond:
-            while self._jobs or self._active:
-                remaining = deadline - time.monotonic()
-                if remaining <= 0:
-                    return False
-                self._cond.wait(timeout=min(remaining, 0.1))
+        while self._open:
+            remaining = deadline - time.monotonic()
+            if remaining <= 0:
+                return False
+            time.sleep(min(remaining, 0.01))
         return True
 
     def _run(self) -> None:
         while True:
-            with self._cond:
-                while not self._jobs:
-                    self._cond.wait()
-                job = self._jobs.pop(0)
-                self._active += 1
+            job = self._jobs.get()
             try:
                 job()
             except Exception:  # noqa: BLE001
-                import logging
-
                 logging.getLogger(__name__).exception("completion watcher raised")
             finally:
-                with self._cond:
-                    self._active -= 1
-                    self._cond.notify_all()
+                self._open.popleft()
 
 
 # Completion-watcher threads of the process (the reference's rdma_cq_num,

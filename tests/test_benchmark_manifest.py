@@ -167,6 +167,18 @@ HBM_DISPATCHED = (
     100.0 * 4 * (2 * 128 * 64 + roofline.FRAME_HEADER_WORDS * 128)
     / 819e9 / (50 * 2_000 / 1e9)
 )
+# PR 42's hand-over over a window: 40 submits of 12.5 us, ten of which found
+# every watcher inside a job
+CQ = {
+    "device_transport_cq_submit_us": recorder(40, 12.5),
+    "device_transport_cq_backlog": recorder(40, 0.25),
+}
+# the cells whose dispatches call ``watch``: all but the star, whose fused
+# call reads its answer on the caller's own thread
+CQ_CELLS = [
+    "echo_256b_c16", "echo_4m_c2", "link_echo_ici_1m", "echo_mixed_c16",
+    "echo_256b_c16_native", "link_stream_ici", "ycsb_b_zipf_c16", KV_CELL,
+]
 EXPECTED = {
     **{f"device_{s}_us": (DEVICE, 100.0) for s in DEVICE_STAGES},
     "device_path_unattributed_pct": (DEVICE, 10.0),
@@ -220,6 +232,9 @@ EXPECTED = {
     "lane_launch_cpu_us": (LANE, 250.0),
     "lane_messages_per_step": (LANE, 1.0),
     "lane_tagged_pct": (LANE, 100.0),
+    # PR 42: the hand-over to the completion watchers, a row a submit
+    "cq_submit_us": (CQ, 12.5),
+    "cq_backlog": (CQ, 0.25),
     "stream_device_bytes_pct": (
         LANE, 100.0 * 640 * 2097152 / (640 * 2097152 + 40 * 64)),
 }
@@ -290,6 +305,8 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
             # sums every collective-permute: it would count the lane's
             # programs as the trains', so PR 39's cell stays off its list
             assert cells[name] == ["link_echo_ici_1m", "link_stream_ici"]
+        elif name.startswith("cq_"):
+            assert cells[name] == CQ_CELLS, name
         elif name.startswith("link_"):
             # every link cell drives the link: PR 31's and PR 39's joined them
             assert cells[name] == ["link_echo_ici_1m", "link_stream_ici", KV_CELL], name
@@ -421,16 +438,28 @@ def test_the_new_entries_only_follow_the_old():
         "lane_deliver_us", "lane_launch_cpu_us", "lane_messages_per_step",
         "lane_step_ici_pct", "stream_device_bytes_pct",
         "kv_page_write_kernel_us", "kv_page_write_hbm_pct"]
-    assert all(m["workloads"] == [KV_CELL] for m in BENCH["per_layer"][77:])
+    assert all(m["workloads"] == [KV_CELL] for m in BENCH["per_layer"][77:89])
     assert [m["layer"] for m in BENCH["per_layer"][77:88]] == (
         ["link"] * 8 + ["stream"] + ["device program"] * 2)
-    # PR 40's one entry follows them, the last
-    assert names[88:] == ["lane_tagged_pct"]
+    # PR 40's one entry follows them
+    assert names[88] == "lane_tagged_pct"
     assert BENCH["per_layer"][88] == {
         "name": "lane_tagged_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "link", "moves": "goodput",
         "workloads": [KV_CELL],
     }
+    # PR 42's two follow it, the last, in the eight cells that call ``watch``
+    assert BENCH["per_layer"][89:] == [
+        {"name": "cq_submit_us", "unit": "us", "better": "lower",
+         "source": "program_counter",
+         "layer": "host to HBM crossing and completion",
+         "moves": "latency_p50_us", "workloads": CQ_CELLS},
+        {"name": "cq_backlog", "unit": "jobs", "better": "lower",
+         "source": "program_counter",
+         "layer": "host to HBM crossing and completion",
+         "moves": "latency_p50_us", "workloads": CQ_CELLS},
+    ]
+    assert set(CELLS) - set(CQ_CELLS) == {"partition_star_4"}
     for entry, source, layer, moves in zip(
             BENCH["per_layer"][73:76],
             ("device_trace", "device_trace", "program_counter"),
@@ -512,6 +541,30 @@ def test_tagged_share_counts_the_lane_programs_that_carried_their_tag(counters, 
     read = manifest.load_module("layers", "lane_tagged_pct.py").read
     value = read(hand_made_run(dict(counters)))
     assert value is None if share is None else value == pytest.approx(share)
+
+
+@pytest.mark.parametrize("metric", ["cq_submit_us", "cq_backlog"])
+@pytest.mark.parametrize(
+    "gain,value",
+    [
+        (recorder(40, 3.5), 3.5),  # a window of 40 submits
+        # a backlog of 0 at every submit is a reading, not a silence
+        (recorder(40, 0.0), 0.0),
+        # a program without the recorder (the parent), or a window in which
+        # nothing was handed over (the star's)
+        (None, None),
+        (recorder(0, 0.0), None),
+    ],
+    ids=["a-window", "all-zero", "no-recorder", "no-submit"],
+)
+def test_hand_over_readers_give_a_number_with_the_recorder_and_none_without(
+        metric, gain, value):
+    read = manifest.load_module("layers", metric + ".py").read
+    counters = dict(DEVICE)
+    if gain is not None:
+        counters["device_transport_" + metric] = gain
+    got = read(hand_made_run(counters))
+    assert got is None if value is None else got == pytest.approx(value)
 
 
 def test_unattributed_share_needs_every_stage_and_a_handler_span():

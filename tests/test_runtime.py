@@ -7,6 +7,9 @@ import time
 
 import pytest
 
+from incubator_brpc_tpu.bvar import LatencyRecorder
+from incubator_brpc_tpu.runtime import device_butex
+from incubator_brpc_tpu.runtime.device_butex import CQ_THREADS, _WatcherPool
 from incubator_brpc_tpu.runtime import (
     Butex,
     CallIdSpace,
@@ -503,3 +506,235 @@ def test_device_completion_raising_callback_does_not_strand_waiters():
 
     cq.watch(jax.jit(lambda x: x * 2)(jnp.ones(4)), on_complete=bad_cb)
     assert cq.wait_for(1, timeout=10)  # bump/wake happened before the callback
+
+
+# ------------------------------------------------ the completion watchers ----
+# The pool's contract (PR 42): a watch is handed over without a lock a
+# watcher holds, and a job's end wakes nobody. Every wait below has its own
+# timeout, the blocking jobs' included, so none can hang the suite.
+
+HOLD_S = 20.0  # a held job lets go by itself after this, whatever the test did
+
+
+class _Pool:
+    """A pool of its own, fed into recorders of its own: the process's pool
+    and its rows belong to whatever else the worker runs."""
+
+    def __init__(self, nthreads=CQ_THREADS):
+        self.submit_us, self.backlog = LatencyRecorder(), LatencyRecorder()
+        self.feed = device_butex._submit_feed(self.submit_us, self.backlog)
+        self.pool = _WatcherPool(nthreads, self.feed)
+        self.started = []
+
+    def hold(self, n):
+        """``n`` jobs that block, each on an event of its own; returns the
+        events once every one of them is inside its job."""
+        gates = [threading.Event() for _ in range(n)]
+        before = len(self.started)
+        for gate in gates:
+            def held(gate=gate):
+                self.started.append(gate)
+                gate.wait(HOLD_S)
+            self.pool.submit(held)
+        assert wait_until(lambda: len(self.started) == before + n)
+        return gates
+
+
+def test_watcher_pool_runs_jobs_in_the_order_submitted():
+    p = _Pool(nthreads=1)
+    ran = []
+    for i in range(500):
+        p.pool.submit(lambda i=i: ran.append(i))
+    assert p.pool.quiesce(timeout=10.0)
+    assert ran == list(range(500))
+
+
+def test_watcher_pool_takes_held_jobs_in_the_order_submitted():
+    # all watchers held: what queued behind them runs in its order on the
+    # first watcher that comes free
+    p = _Pool()
+    gates = p.hold(CQ_THREADS)
+    ran = []
+    for i in range(4):
+        p.pool.submit(lambda i=i: ran.append(i))
+    gates[5].set()
+    assert wait_until(lambda: len(ran) == 4)
+    assert ran == [0, 1, 2, 3]  # the one free watcher took all four, in order
+    for gate in gates:
+        gate.set()
+    assert p.pool.quiesce(timeout=10.0)
+
+
+def test_watch_stamps_of_successive_watches_are_monotonic():
+    class _Ready:
+        def block_until_ready(self):
+            return self
+
+    cq = DeviceCompletionButex()
+    stamps = [[0, 0] for _ in range(64)]
+    for i, s in enumerate(stamps):
+        cq.watch(_Ready(), stamps=s)
+        assert cq.wait_for(i + 1, timeout=10)
+    took = [s[0] for s in stamps]
+    assert all(took) and took == sorted(took)
+    assert all(s[1] >= s[0] for s in stamps)
+
+
+def test_watcher_pool_submit_returns_while_every_watcher_is_held():
+    p = _Pool()
+    gates = p.hold(CQ_THREADS)
+    ninth = threading.Event()
+    t0 = time.monotonic()
+    p.pool.submit(ninth.set)
+    assert time.monotonic() - t0 < 1.0  # nobody to stand behind
+    assert not ninth.wait(0.2)  # no watcher is free to run it
+    gates[3].set()
+    assert ninth.wait(10.0)  # the one released took it
+    for gate in gates:
+        gate.set()
+    assert p.pool.quiesce(timeout=10.0)
+
+
+def test_watcher_pool_runs_every_job_of_every_submitter_exactly_once():
+    import sys
+
+    p = _Pool()
+    submitters, jobs_each = 12, 400
+    ran = [[0] * jobs_each for _ in range(submitters)]
+
+    def job(s, j):
+        ran[s][j] += 1  # its own cell: only a second run could make it 2
+
+    def submitter(s):
+        for j in range(jobs_each):
+            p.pool.submit(lambda s=s, j=j: job(s, j))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [
+            threading.Thread(target=submitter, args=(s,)) for s in range(submitters)
+        ]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(30.0)
+        assert not any(t.is_alive() for t in threads)
+        assert p.pool.quiesce(timeout=30.0)
+    finally:
+        sys.setswitchinterval(interval)
+    assert all(count == 1 for row in ran for count in row)
+    assert not p.pool._open and p.pool._jobs.empty()
+    p.feed.flush()
+    assert p.submit_us.count() == p.backlog.count() == submitters * jobs_each
+
+
+def test_watcher_pool_job_that_raises_leaves_its_watcher_alive():
+    p = _Pool(nthreads=1)
+
+    def boom():
+        raise RuntimeError("job bug")
+
+    after = threading.Event()
+    p.pool.submit(boom)
+    p.pool.submit(after.set)
+    assert after.wait(10.0)  # the one watcher went on to the next job
+    assert p.pool.quiesce(timeout=10.0)  # the raising job counts as ended
+    assert all(t.is_alive() for t in p.pool._threads)
+
+
+def test_watcher_pool_quiesce_is_true_once_nothing_is_pending_or_executing():
+    p = _Pool()
+    assert p.pool.quiesce(timeout=0.0)  # a pool that never ran a job
+    gates = p.hold(CQ_THREADS)
+    queued = threading.Event()
+    p.pool.submit(queued.set)  # pending behind the held ones
+    answer = []
+    waiter = threading.Thread(target=lambda: answer.append(p.pool.quiesce(10.0)))
+    waiter.start()
+    for gate in gates[:-1]:
+        gate.set()
+    assert queued.wait(10.0)
+    waiter.join(0.3)
+    assert waiter.is_alive()  # one job still executes: not yet
+    gates[-1].set()
+    waiter.join(10.0)
+    assert not waiter.is_alive() and answer == [True]
+
+
+def test_watcher_pool_quiesce_is_false_at_its_timeout_while_a_job_blocks():
+    p = _Pool()
+    (gate,) = p.hold(1)
+    t0 = time.monotonic()
+    assert p.pool.quiesce(timeout=0.3) is False
+    assert 0.3 <= time.monotonic() - t0 < 5.0
+    gate.set()
+    assert p.pool.quiesce(timeout=10.0)
+
+
+def test_watcher_pool_quiesce_never_blocks_a_concurrent_submit():
+    p = _Pool()
+    (gate,) = p.hold(1)
+    answer = []
+    waiter = threading.Thread(target=lambda: answer.append(p.pool.quiesce(10.0)))
+    waiter.start()
+    time.sleep(0.05)  # the waiter is inside quiesce
+    ran = threading.Event()
+    t0 = time.monotonic()
+    p.pool.submit(ran.set)
+    assert time.monotonic() - t0 < 1.0
+    assert ran.wait(10.0)  # and a free watcher ran it meanwhile
+    assert waiter.is_alive()
+    gate.set()
+    waiter.join(10.0)
+    assert not waiter.is_alive() and answer == [True]
+
+
+def test_watcher_pool_feeds_one_row_a_submit_with_backlog_0_while_idle():
+    p = _Pool()
+    for _ in range(5):
+        done = threading.Event()
+        p.pool.submit(done.set)
+        assert done.wait(10.0)
+        assert p.pool.quiesce(timeout=10.0)
+    p.feed.flush()
+    assert p.submit_us.count() == p.backlog.count() == 5
+    assert p.backlog.latency_sum() == 0 and p.backlog.max_latency() == 0
+    assert 0 < p.submit_us.latency_sum() < 5 * 1e6  # us, each under a second
+
+
+def test_watcher_pool_backlog_counts_the_jobs_no_watcher_is_free_to_take():
+    p = _Pool()
+    gates = p.hold(CQ_THREADS)
+    p.feed.flush()
+    assert p.backlog.count() == CQ_THREADS and p.backlog.latency_sum() == 0
+    for _ in range(3):
+        p.pool.submit(lambda: None)
+    p.feed.flush()
+    assert p.backlog.count() == CQ_THREADS + 3
+    assert p.backlog.latency_sum() == 1 + 2 + 3 and p.backlog.max_latency() == 3
+    for gate in gates:
+        gate.set()
+    assert p.pool.quiesce(timeout=10.0)
+
+
+def test_the_process_pool_feeds_the_exposed_recorders():
+    from incubator_brpc_tpu.bvar import expose_registry
+
+    exposed = {name for name, _ in expose_registry.snapshot("device_transport_cq_")}
+    assert {"device_transport_cq_submit_us", "device_transport_cq_backlog"} <= exposed
+    device_butex._cq_feed.flush()
+    before = device_butex.m_cq_submit.count(), device_butex.m_cq_backlog.count()
+
+    class _Ready:
+        def block_until_ready(self):
+            return self
+
+    cq = DeviceCompletionButex()
+    for i in range(3):
+        cq.watch(_Ready())
+    assert cq.wait_for(3, timeout=10)
+    device_butex._cq_feed.flush()
+    assert device_butex.m_cq_submit.count() >= before[0] + 3
+    assert device_butex.m_cq_backlog.count() >= before[1] + 3
+    assert device_butex.CQ_THREADS == 8
