@@ -139,27 +139,17 @@ _links_lock = threading.Lock()
 
 
 def _quiesce_links(timeout: float = 10.0) -> None:
-    import time as _time
-
-    deadline = _time.monotonic() + timeout
+    deadline = time.monotonic() + timeout
     with _links_lock:
         links = list(_all_links)
     for link in links:
-        while _time.monotonic() < deadline:
-            with link._lock:
-                idle = (
-                    not link._driving
-                    and link._inflight == 0
-                    and link._lane_inflight == 0
-                )
-            if idle:
-                break
-            _time.sleep(0.01)
+        while link.busy and time.monotonic() < deadline:
+            time.sleep(0.01)
     # the drives above may have submitted completion watches: drain them
     from incubator_brpc_tpu.runtime import device_butex as _db
 
     if _db._watchers is not None:
-        _db._watchers.quiesce(timeout=max(0.1, deadline - _time.monotonic()))
+        _db._watchers.quiesce(timeout=max(0.1, deadline - time.monotonic()))
 
 
 import atexit
@@ -167,7 +157,7 @@ import atexit
 atexit.register(_quiesce_links)
 
 
-# A delivered step's row, as _record_step writes it: the train's first
+# A delivered step's row, as _hand_over_train writes it: the train's first
 # seq, its stamps in the order written (time.monotonic_ns()), three counts,
 # then the CPU clock (time.thread_time_ns()) of the thread that wrote the
 # stamp, where a stage begins and ends on it. A stage is the difference of
@@ -201,6 +191,11 @@ STEP_COLUMNS = (
     ("readback_cpu", 1e-3, ("deliver_cpu", "host_cpu")),
     ("pump_cpu", 1e-3, ("host_cpu", "delivered_cpu")),
 )
+# a recorder _m_<this> is exposed as device_link_<n>_<this>_us, but for
+STEP_EXPOSED = {
+    "rtt": "step_rtt_us", "inflight": "inflight_at_dispatch",
+    "backlog": "backlog_slots_at_dispatch", "credit": "credit_at_dispatch",
+}
 
 
 class _Step:
@@ -247,7 +242,7 @@ class _Step:
 LANE_TAG_WORDS = 64
 LANE_TAG_BYTES = LANE_TAG_WORDS * 4
 
-# A delivered lane program's row, as _lane_drain writes it: stamps
+# A delivered lane program's row, as _hand_over_message writes it: stamps
 # (time.monotonic_ns()) in the order taken, the message's bytes, then the
 # sender's CPU clock around the launch (one program in
 # bvar.CPU_CLOCK_EVERY carries it, the others -1). ``ready`` is the body
@@ -320,6 +315,62 @@ def lane_program(mesh, sharding, side: int):
     )
 
 
+def _recorders(prefix: str, exposed: Dict[str, str]) -> Dict[str, LatencyRecorder]:
+    """A recorder a key of ``exposed``, exposed as ``<prefix>_<its value>``."""
+    return {k: LatencyRecorder(name=f"{prefix}_{v}") for k, v in exposed.items()}
+
+
+class _InOrder:
+    """The path from a completion to its delivery, for either carrier of a
+    link. Programs dispatched under consecutive sequence numbers (a train
+    takes ``span`` = its slots a side, a lane message one) finish out of
+    order on the completion watchers' threads and are handed over strictly
+    in the order taken: ``drain`` admits ONE deliverer at a time and pops
+    the next sequence number under the owner's lock, so what is handed
+    over can never interleave (a mis-ordered chunk of the byte stream
+    would corrupt every frame after it). That lock guards ``next`` and
+    ``waiting`` beside what the owner counts under it."""
+
+    def __init__(self, lock, hand_over, handed=None):
+        self._lock = lock
+        # hand_over(seq, item): the carrier's delivery, inside the turn;
+        # handed(span): what follows it once the turn was given up
+        self._hand_over, self._handed = hand_over, handed
+        self._turn = threading.Lock()  # one in-order deliverer
+        self.next = 0  # next seq to hand over
+        # seq -> (span, item): finished, waiting for its turn. None: cleared
+        self.waiting: Optional[Dict[int, tuple]] = {}
+
+    def land(self, seq: int, span: int, item) -> bool:
+        """Under the lock: ``item`` finished. False once cleared: nothing
+        is kept, its turn would not come."""
+        if self.waiting is None:
+            return False
+        self.waiting[seq] = (span, item)
+        return True
+
+    def clear(self) -> int:
+        """Under the lock, for good (the link failed): drop what waits for
+        its turn. Returns how many sequence numbers went with it."""
+        dropped, self.waiting = self.waiting or {}, None
+        return sum(span for span, _item in dropped.values())
+
+    def drain(self) -> None:
+        """Hand over what has landed and is next, until the next has not.
+        Whoever landed an item calls it, on any thread."""
+        while True:
+            with self._turn:
+                with self._lock:
+                    found = self.waiting and self.waiting.pop(self.next, None)
+                    if not found:
+                        return
+                    seq, (span, item) = self.next, found
+                    self.next += span
+                self._hand_over(seq, item)
+            if self._handed is not None:
+                self._handed(span)
+
+
 class DeviceLink:
     """One established two-party link: the QP pair + CQ + window."""
 
@@ -377,11 +428,10 @@ class DeviceLink:
         self._send_blocked = False
         # seq, credit and acks count SLOTS: a train of k takes k seqs
         self._seq = 0  # slots dispatched
-        self._next_deliver = 0  # next seq to hand to the sockets
         self._inflight = 0  # slots dispatched, not yet drained
-        # completed trains by first seq -> (step output, slots a side)
-        self._reorder: Dict[int, tuple] = {}
-        self._deliver_lock = threading.Lock()  # one in-order deliverer
+        # completed trains, (step output, timeline) each, to the sockets in
+        # order; its next is the count of slots delivered
+        self._trains = _InOrder(self._lock, self._hand_over_train, self._train_handed)
         self._deliver_tid: Optional[int] = None  # thread inside _deliver
         self._driving = False
         self._wbutex = Butex(0)  # writers park here on backlog
@@ -407,34 +457,20 @@ class DeviceLink:
         pfx = f"device_link_{self.link_id}"
         self._m_out_bytes = Adder()
         self._m_in_bytes = Adder()
-        self._m_flush = LatencyRecorder(name=f"{pfx}_flush_us")
-        self._m_send_wait = LatencyRecorder(name=f"{pfx}_send_wait_us")
-        self._m_rtt = LatencyRecorder(name=f"{pfx}_step_rtt_us")
-        self._m_launch = LatencyRecorder(name=f"{pfx}_launch_us")
-        self._m_ready = LatencyRecorder(name=f"{pfx}_ready_us")
-        self._m_reorder_wait = LatencyRecorder(name=f"{pfx}_reorder_wait_us")
-        self._m_readback = LatencyRecorder(name=f"{pfx}_readback_us")
-        self._m_pump = LatencyRecorder(name=f"{pfx}_pump_us")
-        self._m_dispatch_interval = LatencyRecorder(
-            name=f"{pfx}_dispatch_interval_us"
-        )
-        self._m_inflight = LatencyRecorder(name=f"{pfx}_inflight_at_dispatch")
-        self._m_backlog = LatencyRecorder(name=f"{pfx}_backlog_slots_at_dispatch")
-        self._m_credit = LatencyRecorder(name=f"{pfx}_credit_at_dispatch")
-        self._m_hold = LatencyRecorder(name=f"{pfx}_hold_us")
-        self._m_launch_cpu = LatencyRecorder(name=f"{pfx}_launch_cpu_us")
-        self._m_readback_cpu = LatencyRecorder(name=f"{pfx}_readback_cpu_us")
-        self._m_pump_cpu = LatencyRecorder(name=f"{pfx}_pump_cpu_us")
+        # a recorder a column of the steps' feed, and the two without one
+        made = _recorders(pfx, {
+            what: STEP_EXPOSED.get(what, what + "_us")
+            for what in (*(c[0] for c in STEP_COLUMNS), "flush", "send_wait")
+        })
+        for what, recorder in made.items():
+            setattr(self, "_m_" + what, recorder)
         self._m_out_rate = PerSecond(self._m_out_bytes, name=f"{pfx}_out_bytes_second")
         self._m_in_rate = PerSecond(self._m_in_bytes, name=f"{pfx}_in_bytes_second")
         # a delivered step's row waits here for the sampler thread: fourteen
         # feeds a step on the delivering thread would sit between one step
         # and the next. The last 16 Ki rows stay (30 s of a busy link)
         self._step_feed = RecorderFeed(
-            [
-                (getattr(self, "_m_" + attr), scale, span)
-                for attr, scale, span in STEP_COLUMNS
-            ],
+            [(made[what], scale, span) for what, scale, span in STEP_COLUMNS],
             stamps=STEP_STAMPS,
             name=f"{pfx}_steps",
             ring_rows=1 << 14,
@@ -452,7 +488,6 @@ class DeviceLink:
             call=(("parked", "admitted"),),
         )
         self._metrics_retired = False
-        self._steps: Dict[int, _Step] = {}  # first seq -> timeline, until delivered
         self._steps_taken = 0  # trains dispatched: which carry the CPU clock
         self._last_dispatch_ns = 0  # this drive's previous dispatch; 0 = none
         self._held_since_ns = 0  # the drive is holding a train back; 0 = not
@@ -461,10 +496,10 @@ class DeviceLink:
         # has its own sequence and its own in-order deliverer
         self._lane_lock = threading.Lock()
         self._lane_seq = [0, 0]  # messages lane_send took for that side
-        self._lane_next = [0, 0]  # next seq to hand to that side's socket
-        # seq -> _LaneStep seen ready, tag in hand, waiting for its turn
-        self._lane_ready: List[Dict[int, _LaneStep]] = [{}, {}]
-        self._lane_deliver_locks = [threading.Lock(), threading.Lock()]
+        # _LaneSteps seen ready, tag in hand, to that side's socket in order
+        self._lanes = [
+            _InOrder(self._lane_lock, self._hand_over_message) for _to in (0, 1)
+        ]
         # (side, shape, dtype) -> (program, placeholder, receiver's shard)
         self._lane_programs: Dict[tuple, tuple] = {}
         self._lane_inflight = 0  # programs dispatched, body not yet seen ready
@@ -479,17 +514,12 @@ class DeviceLink:
         if self._metrics_retired:
             return
         self._metrics_retired = True
-        self._step_feed.flush()  # profile() still reads the recorders
-        self._send_feed.flush()
-        lane = ()
-        if self._lane_feed is not None:
-            self._lane_feed.flush()
-            lane = (recorder for recorder, *_rest in self._lane_feed.columns)
-        for v in (
-            *(getattr(self, "_m_" + attr) for attr, *_rest in STEP_COLUMNS),
-            self._m_flush, self._m_send_wait, self._m_out_rate, self._m_in_rate,
-            *lane,
-        ):
+        retired = [self._m_flush, self._m_out_rate, self._m_in_rate]
+        for feed in (self._step_feed, self._send_feed, self._lane_feed):
+            if feed is not None:
+                feed.flush()  # profile() still reads the recorders
+                retired += [recorder for recorder, *_rest in feed.columns]
+        for v in retired:
             try:
                 v.hide()
             except Exception:
@@ -567,16 +597,9 @@ class DeviceLink:
         # devices: a row a delivered program, fed by the sampler as the
         # steps' rows are
         lane = f"device_link_{self.link_id}_lane"
-        recorders = {
-            "step_us": LatencyRecorder(name=f"{lane}_step_us"),
-            "launch_us": LatencyRecorder(name=f"{lane}_launch_us"),
-            "ready_us": LatencyRecorder(name=f"{lane}_ready_us"),
-            "pair_wait_us": LatencyRecorder(name=f"{lane}_pair_wait_us"),
-            "deliver_us": LatencyRecorder(name=f"{lane}_deliver_us"),
-            "launch_cpu_us": LatencyRecorder(name=f"{lane}_launch_cpu_us"),
-        }
+        made = _recorders(lane, {what: what for what, *_rest in LANE_COLUMNS})
         self._lane_feed = RecorderFeed(
-            [(recorders[what], scale, span) for what, scale, span in LANE_COLUMNS],
+            [(made[what], scale, span) for what, scale, span in LANE_COLUMNS],
             stamps=LANE_STAMPS,
             name=f"{lane}_steps",
             ring_rows=1 << 14,
@@ -618,7 +641,6 @@ class DeviceLink:
         finds them landed or landing instead of asking for one after the
         other (PERF.md, PR 36)."""
         out.copy_to_host_async()
-
 
     # -- the lane: device arrays, HBM to HBM ---------------------------------
 
@@ -797,41 +819,33 @@ class DeviceLink:
                 step.tag = self._tag_to_host(step.landed_tag)
             except Exception as e:  # noqa: BLE001 — a device failure is data here
                 error = e
+        lane = self._lanes[step.to]
         with self._lane_lock:
             self._lane_inflight -= 1
-            if error is None and not self._closed:
-                self._lane_ready[step.to][step.seq] = step
+            if error is None:
+                lane.land(step.seq, 1, step)
         if error is not None:
             logger.error("device link lane program failed: %s", error)
             self.fail(f"lane program failed: {error}")
             return
-        self._lane_drain(step.to)
+        lane.drain()
 
-    def _lane_drain(self, to: int) -> None:
-        """Hand landed messages to side ``to``'s socket strictly in the
-        order ``lane_send`` took them. Completion watchers finish out of
-        order; as in ``_drain_ready`` one deliverer a direction is admitted
-        at a time and the pop of the next seq happens under the lane's
-        lock. Each hand-over writes its program's row."""
-        while True:
-            with self._lane_deliver_locks[to]:
-                with self._lane_lock:
-                    step = self._lane_ready[to].pop(self._lane_next[to], None)
-                    if step is None:
-                        return
-                    self._lane_next[to] += 1
-                paired = time.monotonic_ns()
-                sock = self.socks[to]
-                try:
-                    if sock is not None:
-                        sock._lane_deliver(step.tag, step.body)
-                except Exception:
-                    logger.exception("device link lane delivery raised")
-                self._lane_feed.rows.append((
-                    step.seq, step.t_taken, step.t_launched, step.watcher[1],
-                    paired, time.monotonic_ns(),
-                    step.nbytes, step.c_taken, step.c_launched,
-                ))
+    def _hand_over_message(self, seq: int, step: _LaneStep) -> None:
+        """The lane's in-order hand-over: a landed message to the socket
+        of the side it was sent to, then its program's row, a number a
+        position of ``LANE_STAMPS``."""
+        paired = time.monotonic_ns()
+        sock = self.socks[step.to]
+        try:
+            if sock is not None:
+                sock._lane_deliver(step.tag, step.body)
+        except Exception:
+            logger.exception("device link lane delivery raised")
+        self._lane_feed.rows.append((
+            seq, step.t_taken, step.t_launched, step.watcher[1],
+            paired, time.monotonic_ns(),
+            step.nbytes, step.c_taken, step.c_launched,
+        ))
 
     # -- send side -----------------------------------------------------------
 
@@ -949,14 +963,10 @@ class DeviceLink:
             (backlog, credit),
         )
 
-    def _take_seq_locked(
-        self, k: int = 1,
-        seen: tuple = (RecorderFeed.MISSING, RecorderFeed.MISSING),
-    ) -> tuple:
+    def _take_seq_locked(self, k: int, seen: tuple) -> tuple:
         """Under the link lock, a train of ``k`` slots a side filled: take
         its seqs, count its slots in flight and start its timeline. ``seen``
-        is what ``_train_len_locked`` took ``k`` from (a link that never
-        asks it, ``MultiControllerLink``, records neither and never holds)."""
+        is what ``_train_len_locked`` took ``k`` from."""
         seq = self._seq
         self._seq += k
         self._inflight += k
@@ -964,10 +974,7 @@ class DeviceLink:
         self._steps_taken += 1
         last, self._last_dispatch_ns = self._last_dispatch_ns, now
         held, self._held_since_ns = self._held_since_ns, 0
-        step = self._steps[seq] = _Step(
-            now, now_cpu, last, self._inflight, seen, held
-        )
-        return seq, step
+        return seq, _Step(now, now_cpu, last, self._inflight, seen, held)
 
     def _drive(self) -> None:
         while True:
@@ -994,7 +1001,7 @@ class DeviceLink:
                     # two-sided stall cannot wedge.
                     if (
                         self.ack_mode == "wire"
-                        and self._next_deliver - self._acks_sent
+                        and self._trains.next - self._acks_sent
                         >= max(1, self.window - 1)
                     ):
                         ack_only = True
@@ -1045,7 +1052,7 @@ class DeviceLink:
                 step.watcher[0] = step.watcher[1] = step.t_launched
                 try:
                     self._on_step_done(
-                        seq, ("host", [both[1], both[0]]), None, k
+                        seq, ("host", [both[1], both[0]]), None, k, step
                     )
                 except Exception:
                     logger.exception("loopback link delivery failed")
@@ -1061,7 +1068,7 @@ class DeviceLink:
                 self._request_host(out)
             except Exception:
                 logger.exception("device link step dispatch failed")
-                self._dispatch_failed(seq, k)
+                self._dispatch_failed(k)
                 return
             step.launched()
             link_steps << 1
@@ -1072,42 +1079,36 @@ class DeviceLink:
                 link_held << 1
             self._cq.watch(
                 out,
-                on_complete=lambda arrays, error, _seq=seq, _k=k: (
-                    self._on_step_done(_seq, arrays, error, _k)
+                on_complete=lambda arrays, error, _seq=seq, _k=k, _step=step: (
+                    self._on_step_done(_seq, arrays, error, _k, _step)
                 ),
                 stamps=step.watcher,
             )
 
-    def _dispatch_failed(self, seq: int, k: int) -> None:
-        """The drive's dispatch of the train at ``seq`` raised: nothing of
+    def _dispatch_failed(self, k: int) -> None:
+        """The drive's dispatch of a train raised: nothing of
         it will ever be delivered, so its ``k`` slots come back off the
         credit here (the idle check would wait out its timeout on them),
         the link fails and the drive ends."""
         self.fail("link step dispatch failed")
         with self._lock:
             self._inflight -= k
-            self._steps.pop(seq, None)
             self._driving = False
 
-    def _fill_train_locked(
-        self, side: int, k: int, train: Optional[np.ndarray] = None
-    ) -> np.ndarray:
+    def _fill_train_locked(self, side: int, k: int, train: np.ndarray) -> None:
         """Pack queued views head-to-tail into one side's train: ``k``
-        slots, rows of ``train``, the ``(k, width)`` half of the drive's
-        staging buffer that is this side's (none given,
-        ``MultiControllerLink``: an array of its own). Byte stream: a frame
+        slots, rows of ``train``, the ``(k, width)`` part of the drive's
+        staging buffer that is this side's. Byte stream: a frame
         may split across slots and trains; the receiver's messenger re-cuts.
         ONE gather copy per byte — the staging write into the 'ring'.
         np.empty, not np.zeros: the receiver only reads ``used`` bytes,
         so a full-slot memset per step would touch every byte twice
         (VERDICT r3 weak #5); only the header words are written below."""
         t0 = time.perf_counter()
-        if train is None:
-            train = np.empty((k, self._width), dtype=np.uint32)
         q = self._out[side]
         cap = self._slot_bytes
         base = LINK_HEADER_WORDS * 4
-        ack = self._next_deliver
+        ack = self._trains.next
         total = 0
         for j in range(k):
             row = train[j]
@@ -1155,69 +1156,54 @@ class DeviceLink:
             link_bytes << total
             self._m_out_bytes << total
         self._m_flush << (time.perf_counter() - t0) * 1e6
-        return train
 
     # -- receive side --------------------------------------------------------
 
-    def _on_step_done(self, seq: int, arrays, error, k: int = 1) -> None:
+    def _on_step_done(self, seq: int, arrays, error, k: int, step: _Step) -> None:
         """A step settled: ``arrays`` is its output, a train of ``k``
-        slots a side whose first seq is ``seq``."""
+        slots a side whose first seq is ``seq``, ``step`` its timeline."""
         if error is not None:
             logger.error("device link step failed: %s", error)
             self.fail(f"link step failed: {error}")
-            return
         with self._lock:
-            self._reorder[seq] = (arrays, k)
-        self._drain_ready()
-        self._kick()
-
-    def _drain_ready(self) -> None:
-        """Deliver completed trains strictly in sequence. CQ watcher threads
-        complete out of order; _deliver_lock admits ONE deliverer at a time
-        and the pop of _next_deliver happens under the link lock, so the
-        byte stream can never interleave (a mis-ordered chunk would corrupt
-        every frame after it). The window credit (inflight) is released
-        only after delivery — un-drained outputs are the occupied ring."""
-        while True:
-            with self._deliver_lock:
-                with self._lock:
-                    done = self._reorder.pop(self._next_deliver, None)
-                    if done is None:
-                        return
-                    arrays, k = done
-                    seq = self._next_deliver
-                    step = self._steps.pop(seq, None)
-                    self._next_deliver += k
-                self._deliver_tid = threading.get_ident()
-                timed = step is not None and step.timed
-                begin = host = clocks(timed)
-                try:
-                    rows = self._rows_to_host(arrays)
-                    host = clocks(timed)
-                    self._deliver(rows)
-                finally:
-                    self._deliver_tid = None
-                    self._record_step(seq, step, begin, host, clocks(timed))
-            with self._lock:
+            landed = error is None and self._trains.land(seq, k, (arrays, step))
+            if not landed:
+                # it failed, or the link did: nobody will be handed it, so
+                # its slots come back off the credit here (_dispatch_failed)
                 self._inflight -= k
-            self._wbutex.add(1)
-            self._wbutex.wake_all()
+        if landed:
+            self._trains.drain()
+            self._kick()
 
-    def _record_step(self, seq: int, step, begin, host, end) -> None:
-        """Hand a delivered step's timeline to the recorders' feed, a number
-        a position of ``STEP_STAMPS``. ``begin``, ``host`` and ``end`` are
-        the deliverer's ``clocks()`` around the readback and the pump.
-        ``step`` is None when fail() dropped the timelines under a late
-        completion."""
-        if step is None:
-            return
-        self._step_feed.rows.append((
-            seq, step.t_previous, step.t_held,
-            step.t_dispatch, step.t_launched, step.watcher[1],
-            begin[0], host[0], end[0],
-            step.inflight, *step.seen,
-            step.c_dispatch, step.c_launched, begin[1], host[1], end[1],
-        ))
+    def _hand_over_train(self, seq: int, landed: tuple) -> None:
+        """The trains' in-order hand-over: a completed train read back and
+        fed to the sockets, then its row, a number a position of
+        ``STEP_STAMPS``, the deliverer's ``clocks()`` around both in it."""
+        arrays, step = landed
+        self._deliver_tid = threading.get_ident()
+        begin = host = clocks(step.timed)
+        try:
+            rows = self._rows_to_host(arrays)
+            host = clocks(step.timed)
+            self._deliver(rows)
+        finally:
+            self._deliver_tid = None
+            end = clocks(step.timed)
+            self._step_feed.rows.append((
+                seq, step.t_previous, step.t_held,
+                step.t_dispatch, step.t_launched, step.watcher[1],
+                begin[0], host[0], end[0],
+                step.inflight, *step.seen,
+                step.c_dispatch, step.c_launched, begin[1], host[1], end[1],
+            ))
+
+    def _train_handed(self, k: int) -> None:
+        """The window credit (inflight) is released only after delivery —
+        un-drained outputs are the occupied ring."""
+        with self._lock:
+            self._inflight -= k
+        self._wbutex.add(1)
+        self._wbutex.wake_all()
 
     def _rows_to_host(self, arrays) -> List[Optional[np.ndarray]]:
         """A step's output on the host: per side the train it received,
@@ -1291,13 +1277,13 @@ class DeviceLink:
             for side in (0, 1):
                 self._out[side].clear()
                 self._out_nbytes[side] = 0
-            self._steps.clear()
+            # what landed and waits for its turn: it will not come now
+            # (a train's slots come back off the credit); the sockets'
+            # failure below is what the streams on both ends hear
+            self._inflight -= self._trains.clear()
         with self._lane_lock:
-            # landed messages kept for their turn: it will not come now;
-            # the sockets' failure below is what the streams on both ends
-            # hear
-            for ready in self._lane_ready:
-                ready.clear()
+            for lane in self._lanes:
+                lane.clear()
         link_errors << 1
         self._retire_metrics()
         # party-death feedback for the collective fault plane: a session
@@ -1325,6 +1311,15 @@ class DeviceLink:
     def inflight_steps(self) -> int:
         with self._lock:
             return self._inflight
+
+    @property
+    def busy(self) -> bool:
+        """A drive runs, a train's slots are out or a lane program's body
+        was not yet seen ready: each carrier read under its own lock."""
+        with self._lock:
+            trains = self._driving or self._inflight != 0
+        with self._lane_lock:
+            return trains or self._lane_inflight != 0
 
     def profile(self) -> dict:
         """Structured snapshot of this link's PR 1 recorders — the

@@ -58,7 +58,7 @@ from typing import Callable, Dict, List, Optional
 
 import numpy as np
 
-from incubator_brpc_tpu.bvar import Adder
+from incubator_brpc_tpu.bvar import Adder, RecorderFeed
 from incubator_brpc_tpu.transport.device_link import (
     HANDSHAKE_SERVICE,
     HANDSHAKE_METHOD,
@@ -265,11 +265,15 @@ class MultiControllerLink(DeviceLink):
                         # one slot a step, whatever the backlog: both
                         # hosts must dispatch the same shape, and the
                         # agreed budget (target) counts steps
-                        row = self._fill_train_locked(self.own_side, 1)
+                        row = np.empty((1, self._width), dtype=np.uint32)
+                        self._fill_train_locked(self.own_side, 1, row)
                         # the step's timeline feeds the per-link
-                        # recorders exactly like the base _drive: popped
-                        # at in-order delivery
-                        seq, step = self._take_seq_locked()
+                        # recorders exactly like the base _drive's; this
+                        # link never cuts a train (_train_len_locked), so it
+                        # records neither of what one is cut from
+                        seq, step = self._take_seq_locked(
+                            1, (RecorderFeed.MISSING,) * 2
+                        )
             if finish:
                 self._finish_close()
                 return
@@ -301,15 +305,15 @@ class MultiControllerLink(DeviceLink):
                 out = self._step(self._make_local_slots(row))
             except Exception:
                 logger.exception("mc link step dispatch failed")
-                self._dispatch_failed(seq, 1)
+                self._dispatch_failed(1)
                 return
             step.launched()
             link_steps << 1
             link_slots << 1
             self._cq.watch(
                 out,
-                on_complete=lambda arrays, error, _seq=seq: self._on_step_done(
-                    _seq, arrays, error
+                on_complete=lambda arrays, error, _seq=seq, _step=step: (
+                    self._on_step_done(_seq, arrays, error, 1, _step)
                 ),
                 stamps=step.watcher,
             )

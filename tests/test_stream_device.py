@@ -30,6 +30,11 @@ from incubator_brpc_tpu.utils.status import ErrorCode
 WORDS = 1024  # a block of 4 KiB
 
 
+def nothing_waits(link) -> bool:
+    """No landed message waits for its turn in either direction of the lane."""
+    return not any(lane.waiting for lane in link._lanes)
+
+
 def wait(pred, timeout=10.0):
     deadline = time.monotonic() + timeout
     while time.monotonic() < deadline:
@@ -312,7 +317,7 @@ def test_a_lane_program_that_raises_fails_both_halves(pair):
     assert rc not in (0, ErrorCode.EAGAIN, ErrorCode.EOVERCROWDED)
     assert p.client.failed.wait(5) and p.sink.failed.wait(5)
     assert time.monotonic() - t0 < 5
-    assert link._closed and link._lane_inflight == 0 and not any(link._lane_ready)
+    assert link._closed and link._lane_inflight == 0 and nothing_waits(link)
     assert p.stream.write(p.block(3)[0], timeout=1) == ErrorCode.EINVAL
     assert len(p.sink.got) == 1  # nothing of the message crossed
 
@@ -340,7 +345,7 @@ def test_a_link_failed_mid_transfer_wakes_the_writer_and_ends_both_halves(pair):
     assert not writer.is_alive() and parked["rc"] == ErrorCode.EINVAL
     assert p.client.failed.wait(5) and p.sink.failed.wait(5)
     assert time.monotonic() - t0 < 5
-    assert not any(p.link._lane_ready)
+    assert nothing_waits(p.link)
     assert all(not any(s._held or ()) for s in served)
     assert wait(lambda: p.link._lane_inflight == 0)
 
@@ -365,7 +370,7 @@ def served_stream(p):
 
 def lane_handed(p, messages: int) -> bool:
     """The lane has handed side 1's socket so many messages."""
-    return wait(lambda: p.link._lane_next[1] == messages)
+    return wait(lambda: p.link._lanes[1].next == messages)
 
 
 def write_all(p, messages):
@@ -585,7 +590,7 @@ def test_the_handler_sees_the_order_written_across_the_two_carriers(pair, case):
     if want is not None:
         assert wait(lambda: len(p.sink.got) == len(want))
         assert all(same(g, w) for g, w in zip(p.sink.got, want))
-    assert not any(p.link._lane_ready) and wait(lambda: p.link._lane_inflight == 0)
+    assert nothing_waits(p.link) and wait(lambda: p.link._lane_inflight == 0)
     for s in stream_mod.open_streams():
         assert not any(s._held or ())
 
@@ -657,7 +662,7 @@ def test_many_writers_and_streams_share_one_lane_in_order():
         sys.setswitchinterval(interval)
     for sink, wrote in zip(sinks, written):
         assert all(same(g, w) for g, w in zip(sink.got, wrote))
-    assert not any(link._lane_ready) and wait(lambda: link._lane_inflight == 0)
+    assert nothing_waits(link) and wait(lambda: link._lane_inflight == 0)
     for s in opened:
         s.close()
     server.stop()
