@@ -8,6 +8,16 @@ Run (self-contained: starts its own server):
     python examples/link_performance.py                      # device links
     python examples/link_performance.py --transport tcp      # host sockets
     python examples/link_performance.py --attachment-kb 32 --threads 4
+    python examples/link_performance.py --attachment-in device  # use_rdma=true
+
+``--attachment-in device`` is upstream's ``use_rdma`` switch as it was
+meant: the attachment lies in memory the transport owns, here the client
+device's HBM. The program has no switch for it: ``call_method`` is handed a
+``jax.Array`` instead of bytes, and over a link between two devices the
+array crosses the link's lane as it lies, the handler reads a ``jax.Array``
+on its own device and the caller gets one back (docs/DEVICE_PLANE.md, "A
+unary call carries a tensor"). Over ``--transport tcp``, or where both ends
+share one device, the same call sends the array's bytes.
 """
 
 import argparse
@@ -33,6 +43,9 @@ def main(argv=None) -> None:
                    help="the use_rdma flip: device links vs host sockets")
     p.add_argument("--attachment-kb", type=int, default=4,
                    help="echoed attachment size in KiB (attachment_size)")
+    p.add_argument("--attachment-in", choices=("host", "device"), default="host",
+                   help="where the attachment lies: host bytes, or a "
+                        "jax.Array on the client's device (use_rdma=true)")
     p.add_argument("--threads", type=int, default=2, help="caller threads")
     p.add_argument("--seconds", type=float, default=3.0, help="test_seconds")
     args = p.parse_args(argv)
@@ -60,6 +73,24 @@ def main(argv=None) -> None:
         cntl=Controller(timeout_ms=120000),
     )
     assert warm.ok(), warm.error_text
+    nbytes_a_call = 2 * len(attachment)  # echoed both ways
+    if args.attachment_in == "device":
+        import jax
+        import numpy as np
+
+        # the link exists now: its client side's device is where the
+        # caller's tensors lie (any device over tcp: the bytes are sent)
+        sock = ch._device_sock
+        device = sock.link.devices[sock.side] if sock is not None else jax.devices()[0]
+        attachment = jax.device_put(np.frombuffer(attachment, np.uint32), device)
+        warm = ch.call_method(
+            "perf", "echo", b"warm", attachment=attachment,
+            cntl=Controller(timeout_ms=120000),
+        )
+        assert warm.ok(), warm.error_text
+        print(f"attachment {attachment.dtype}{list(attachment.shape)} on "
+              f"{device}; the answer came back as "
+              f"{type(warm.response_attachment).__name__}")
 
     latency = LatencyRecorder(name=None)
     stop_at = time.monotonic() + args.seconds
@@ -76,7 +107,7 @@ def main(argv=None) -> None:
             )
             if c.ok():
                 calls += 1
-                nbytes += 2 * len(attachment)  # echoed both ways
+                nbytes += nbytes_a_call
                 latency << (time.perf_counter() - t0) * 1e6
             else:
                 fail += 1
@@ -94,6 +125,7 @@ def main(argv=None) -> None:
     wall = time.monotonic() - t0
     print(
         f"transport={args.transport} attachment={args.attachment_kb}KiB "
+        f"in={args.attachment_in} "
         f"threads={args.threads}: {totals['calls'] / wall:.0f} qps, "
         f"{totals['bytes'] / wall / 1e9:.3f} GB/s, "
         f"p50={latency.latency_percentile(0.5):.0f}us "

@@ -18,6 +18,7 @@ from incubator_brpc_tpu.rpc import (  # noqa: E402
     CallMapper,
     Channel,
     ChannelOptions,
+    Controller,
     ParallelChannel,
     Server,
     ServerOptions,
@@ -94,7 +95,14 @@ def main() -> None:
         )
         fused.add_channel(ch, call_mapper=RowMapper())
     before = lowerings()
-    cntl = fused.call_method("dsvc", "inc", b"\x01\x02\x03\x04\x05")
+    # the first call makes the three links' handshakes, which compile the
+    # links' programs: the call's own deadline bounds them (the default 500
+    # ms does not hold on a loaded host, and the call then falls back to
+    # the host fan-out)
+    cntl = fused.call_method(
+        "dsvc", "inc", b"\x01\x02\x03\x04\x05",
+        cntl=Controller(timeout_ms=60000),
+    )
     assert cntl.ok(), cntl.error_text
     assert cntl.response_payload == b"\x02\x03\x04\x05\x06"
     took = [how for how, n in lowerings().items() if n > before[how]]

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import logging
 import threading
+import time
 import weakref
 from typing import Callable, Optional, Union
 
@@ -29,13 +30,19 @@ from incubator_brpc_tpu.protocol.tbus_std import (
     ParsedFrame,
     pack_frame_iobuf,
 )
-from incubator_brpc_tpu.rpc.controller import RETRIABLE, Controller
+from incubator_brpc_tpu.rpc.controller import (
+    HOST_BYTES,
+    RETRIABLE,
+    Attachment,
+    Controller,
+)
 from incubator_brpc_tpu.runtime.correlation_id import call_id_space
 from incubator_brpc_tpu.runtime.timer_thread import global_timer_thread
 from incubator_brpc_tpu.runtime.worker_pool import global_worker_pool
+from incubator_brpc_tpu.transport import device_link
 from incubator_brpc_tpu.transport.messenger import InputMessenger
 from incubator_brpc_tpu.transport.socket_map import SocketMap
-from incubator_brpc_tpu.bvar import Adder, PassiveStatus
+from incubator_brpc_tpu.bvar import Adder, PassiveStatus, RecorderFeed
 from incubator_brpc_tpu.utils.endpoint import EndPoint, str2endpoint
 from incubator_brpc_tpu.utils.flags import define_flag, get_flag
 from incubator_brpc_tpu.utils.status import ErrorCode, berror
@@ -409,12 +416,30 @@ class Channel:
         request: bytes,
         cntl: Optional[Controller] = None,
         done: Optional[Callable[[Controller], None]] = None,
-        attachment: bytes = b"",
+        attachment: Attachment = b"",
         request_stream=None,
     ) -> Controller:
         """The CallMethod entry (channel.cpp:285). Synchronous when ``done``
-        is None (joins the call id); asynchronous otherwise."""
+        is None (joins the call id); asynchronous otherwise.
+
+        ``attachment`` is host bytes or a ``jax.Array``. What becomes of an
+        array follows from the socket the call goes out on, as for a
+        stream's message (``device_link.array_carrier``; nothing configures
+        it): over a ``transport="tpu"`` link between two devices it crosses
+        the link's lane as it lies, the handler reads
+        ``cntl.request_attachment`` as a ``jax.Array`` of that shape and
+        dtype on its own device, and what it sets as
+        ``cntl.response_attachment``, bytes or an array on its device,
+        comes back the same way: no byte of either lies in host memory. A
+        host socket or a link on one shared device sends the array's bytes;
+        an array that is not whole on the link's client device, has no
+        element, or was deleted or donated fails the call with ``EINVAL``
+        and nothing is sent, as does any array over a multi-controller
+        link. The controller holds the array until the call ends; the
+        caller may not write into, donate or delete it before (a retry or a
+        backup request sends it again)."""
         assert self._init_done, "Channel.init() not called"
+        entered_ns = 0 if isinstance(attachment, HOST_BYTES) else time.monotonic_ns()
         if self._retry_budget is not None:
             self._retry_budget.on_call()
         if cntl is None:
@@ -428,6 +453,8 @@ class Channel:
         cntl._method = method
         cntl._request_payload = request
         cntl.request_attachment = attachment
+        if entered_ns:
+            cntl._unary = [entered_ns, RecorderFeed.MISSING, RecorderFeed.MISSING]
         cntl._done = done
         if request_stream is not None:
             cntl._request_stream = request_stream
@@ -467,6 +494,7 @@ class Channel:
             done is None
             and request_stream is None
             and self._options.native_plane
+            and cntl._unary is None
             and self._native_eligible(cntl)
             and self._native_call(cntl, service, method, request, attachment)
         ):
@@ -531,7 +559,21 @@ class Channel:
 
         if done is None:
             self._sync_wait(cntl, cid)
+            if cntl._unary is not None:
+                self._unary_row(cntl)
         return cntl
+
+    @staticmethod
+    def _unary_row(cntl: Controller) -> None:
+        """The caller's row of a call whose request crossed a link's lane,
+        once the caller runs again (an asynchronous call: before ``done``);
+        a failed call leaves none."""
+        stamps = cntl._unary
+        if stamps[1] < 0 or cntl.failed():
+            return
+        link = getattr(cntl._sent_sockets[-1], "link", None)
+        if link is not None and link.unary_calls is not None:
+            link.unary_calls.rows.append((*stamps, time.monotonic_ns()))
 
     def _sync_wait(self, cntl: Controller, cid: int) -> None:
         """Synchronous completion. When the request's socket is otherwise
@@ -1053,17 +1095,37 @@ class Channel:
             from incubator_brpc_tpu.rpc.auth import attach_credential
 
             attach_credential(meta, sock, self._options.auth)
+        attachment, array = cntl.request_attachment, None
         try:
             payload = cntl._request_payload
             if cntl.compress_type:
                 payload = compress_mod.compress(cntl.compress_type, payload)
             proto_name = self._options.protocol
-            if proto_name == "tbus_std":
+            if cntl._unary is not None:
+                # a device array: the lane where this socket has one, its
+                # bytes where there is no second device, else refused
+                array, attachment = device_link.array_carrier(sock, attachment)
+                if attachment is None:
+                    cntl.set_failed(
+                        ErrorCode.EINVAL,
+                        f"the attachment cannot cross to {sock.remote}: not "
+                        "whole on the link's device, empty, deleted or "
+                        "donated, or the link has no lane yet",
+                    )
+                    self._end_rpc(cntl)
+                    return
+                if array is None:
+                    device_link.unary_bytes_fallbacks << 1
+                elif proto_name != "tbus_std":
+                    raise ValueError("a device array rides tbus_std frames only")
+            if array is not None:
+                data = None  # the frame is the array's tag: packed by the socket
+            elif proto_name == "tbus_std":
                 data = pack_frame_iobuf(
                     meta,
                     payload,
                     cid,
-                    attachment=cntl.request_attachment,
+                    attachment=attachment,
                 )
             else:
                 # protocol selected by name (reference AdaptiveProtocolType):
@@ -1086,7 +1148,7 @@ class Channel:
                     meta,
                     payload,
                     cid,
-                    attachment=cntl.request_attachment,
+                    attachment=attachment,
                 )
                 if proto.fifo_responses:
                     # no wire correlation id: record the cid in the
@@ -1107,15 +1169,22 @@ class Channel:
         if cntl._deadline:
             remaining = max(0.001, cntl._deadline - _time.monotonic())
         _track_inflight(sock, cid)
-        rc = sock.write(
-            data,
-            on_error=lambda code, text: (
-                pool.spawn(call_id_space.error, cid, code, text)
-                if _claim_inflight(sock, cid)
-                else None
-            ),
-            timeout=remaining,
-        )
+        if array is not None:
+            rc = sock.write_device_message(meta, payload, cid, array)
+            cntl._unary[1] = time.monotonic_ns()
+            if rc == 0:
+                device_link.unary_lane_requests << 1
+                device_link.unary_lane_bytes << array.nbytes
+        else:
+            rc = sock.write(
+                data,
+                on_error=lambda code, text: (
+                    pool.spawn(call_id_space.error, cid, code, text)
+                    if _claim_inflight(sock, cid)
+                    else None
+                ),
+                timeout=remaining,
+            )
         if rc != 0:
             self._arbitrate_error(cntl, rc, f"write to {sock.remote} failed")
 
@@ -1311,6 +1380,8 @@ class Channel:
             cntl.response_payload = payload
             cntl.response_attachment = frame.attachment
             cntl.response_meta = frame.meta
+            if cntl._unary is not None:  # missing: the answer came as bytes
+                cntl._unary[2] = getattr(frame, "handed_ns", RecorderFeed.MISSING)
             if self._options.auth is not None:
                 # a successful response proves the connection: stop sending
                 # credentials on it (FightAuthentication settled)
@@ -1410,4 +1481,6 @@ class Channel:
             # a different path (other socket, timer), wake it now
             ps.kick_poller()
         if cntl._done is not None:
+            if cntl._unary is not None:
+                self._unary_row(cntl)
             global_worker_pool().spawn(cntl._done, cntl)

@@ -38,7 +38,7 @@ from incubator_brpc_tpu.bvar import (
     clocks,
 )
 from incubator_brpc_tpu.rpc.channel import Channel, ChannelOptions
-from incubator_brpc_tpu.rpc.controller import RETRIABLE, Controller
+from incubator_brpc_tpu.rpc.controller import HOST_BYTES, RETRIABLE, Controller
 from incubator_brpc_tpu.utils.endpoint import EndPoint
 from incubator_brpc_tpu.utils.status import ErrorCode, berror
 
@@ -275,6 +275,9 @@ class ParallelChannel:
         refused = (
             "ParallelChannel has no sub channels" if nchan == 0
             else "a combo channel carries no stream" if request_stream is not None
+            # ROADMAP.md, Reach: a device array through a combo channel
+            else "a combo channel's attachment is host bytes"
+            if not isinstance(attachment, HOST_BYTES)
             else None
         )
         if refused:

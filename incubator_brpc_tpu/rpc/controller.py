@@ -13,11 +13,22 @@ One Controller accompanies one RPC on either side:
 from __future__ import annotations
 
 import time
-from typing import Any, Callable, List, Optional
+from typing import TYPE_CHECKING, Any, Callable, List, Optional, Union
 
 from incubator_brpc_tpu.protocol.tbus_std import Meta
 from incubator_brpc_tpu.utils.endpoint import EndPoint
 from incubator_brpc_tpu.utils.status import ErrorCode, berror
+
+if TYPE_CHECKING:
+    import jax
+
+# what an attachment is on the host; anything else has to be a device array
+HOST_BYTES = (bytes, bytearray, memoryview)
+# an attachment, either way: host bytes, or a ``jax.Array`` that crosses a
+# device link's lane as it lies (docs/DEVICE_PLANE.md, "A unary call
+# carries a tensor"). The controller holds the array until the call ends:
+# a retry or a backup request sends the same one again
+Attachment = Union[bytes, "jax.Array"]
 
 
 class Controller:
@@ -41,7 +52,7 @@ class Controller:
         self.backup_request_ms = backup_request_ms
         self.log_id = log_id
         self.compress_type: str = ""
-        self.request_attachment: bytes = b""
+        self.request_attachment: Attachment = b""
         # protocol-specific request meta extras copied into Meta.extra
         # (hulu/nova method_index, esp addressing, ...)
         self.request_extra: dict = {}
@@ -51,7 +62,7 @@ class Controller:
         self.error_code: int = 0
         self.error_text: str = ""
         self.response_payload: bytes = b""
-        self.response_attachment: bytes = b""
+        self.response_attachment: Attachment = b""
         self.response_meta: Optional[Meta] = None
         self.request_meta: Optional[Meta] = None  # server side
         self.remote_side: Optional[EndPoint] = None
@@ -113,6 +124,12 @@ class Controller:
         # disposed together at EndRPC (never mid-call: a backup request
         # keeps the original attempt's connection in flight)
         self._call_socks: List[Any] = []
+        # a call whose request attachment is a device array: the caller's
+        # stamps (device_link.UNARY_CALL_STAMPS but the last), and on the
+        # serving side, for a request the lane handed over, the server's
+        # (UNARY_SERVE_STAMPS but the last). None: host bytes
+        self._unary: Optional[List[int]] = None
+        self._unary_serve: Optional[List[int]] = None
 
     # -- status surface (reference Controller::Failed/ErrorCode/ErrorText) --
 

@@ -29,7 +29,7 @@ import time
 from typing import Callable, Dict, Optional, Union
 
 from incubator_brpc_tpu import protocol as proto_pkg
-from incubator_brpc_tpu.bvar import Adder, LatencyRecorder
+from incubator_brpc_tpu.bvar import Adder, LatencyRecorder, RecorderFeed
 from incubator_brpc_tpu.protocol import compress as compress_mod
 from incubator_brpc_tpu.protocol.tbus_std import (
     FLAG_RESPONSE,
@@ -37,11 +37,12 @@ from incubator_brpc_tpu.protocol.tbus_std import (
     ParsedFrame,
     pack_frame_iobuf,
 )
-from incubator_brpc_tpu.rpc.controller import Controller
+from incubator_brpc_tpu.rpc.controller import HOST_BYTES, Controller
 
 # imported at module scope so the rpc_dump* flags exist (and show in
 # /flags) before the first request arrives
 from incubator_brpc_tpu.rpc.dump import maybe_dump_request
+from incubator_brpc_tpu.transport import device_link
 from incubator_brpc_tpu.transport.acceptor import Acceptor
 from incubator_brpc_tpu.transport.messenger import InputMessenger
 from incubator_brpc_tpu.utils.endpoint import EndPoint, str2endpoint
@@ -1228,6 +1229,9 @@ class Server:
         cntl._arrival_ts = getattr(frame, "arrival_ts", None)
         cntl._plane_callback_ns = getattr(frame, "plane_callback_ns", None)
         cntl._after_send = []
+        # a request a link's lane handed over, its attachment a device
+        # array on this server's device: the serving side's stamps
+        handed_ns = getattr(frame, "handed_ns", None)
         cntl._mark_start()
 
         # deadline propagation (reference RpcRequestMeta.timeout_ms +
@@ -1323,7 +1327,10 @@ class Server:
             return
         cntl._request_payload = payload
 
-        maybe_dump_request(meta, payload, frame.attachment)
+        # a dump holds host bytes: a device attachment stays where it lies
+        maybe_dump_request(
+            meta, payload, frame.attachment if handed_ns is None else b""
+        )
 
         from incubator_brpc_tpu.builtin.rpcz import start_server_span
 
@@ -1374,6 +1381,8 @@ class Server:
         from incubator_brpc_tpu.rpc.deadline import pop_deadline, push_deadline
 
         _prev_deadline = push_deadline(cntl._deadline or None)
+        if handed_ns is not None:
+            cntl._unary_serve = [handed_ns, time.monotonic_ns(), RecorderFeed.MISSING]
         try:
             response = prop.handler(cntl, payload)
         except Exception as e:
@@ -1381,6 +1390,8 @@ class Server:
             cntl.set_failed(ErrorCode.EINTERNAL, f"handler raised: {e!r}")
             response = b""
         finally:
+            if handed_ns is not None:
+                cntl._unary_serve[2] = time.monotonic_ns()
             pop_deadline(_prev_deadline)
             _usercode_tls.server = _prev_server
             # the parent-span window is handler execution on THIS thread;
@@ -1707,7 +1718,32 @@ class Server:
         append attachment, write. The response meta carries only what the
         client reads back (error text / stream id / compress / attachment
         size — the reference's response RpcMeta is equally narrow); a plain
-        success with a bare payload travels with NO meta at all."""
+        success with a bare payload travels with NO meta at all.
+
+        A ``response_attachment`` that is a device array goes the way a
+        request's does (``device_link.array_carrier``): over the lane of
+        the link the request came on, its bytes where the connection has
+        no second device; one that cannot cross fails the call."""
+        wire = getattr(cntl, "_wire_protocol", "tbus_std")
+        array, carried = None, cntl.response_attachment
+        if not cntl.failed() and not isinstance(carried, HOST_BYTES):
+            try:
+                array, carried = device_link.array_carrier(sock, carried)
+            except TypeError as e:
+                carried = None
+                logger.warning("%s.%s: %s", cntl._service, cntl._method, e)
+            if carried is None:
+                cntl.set_failed(
+                    ErrorCode.EINTERNAL,
+                    "the response attachment cannot cross: no bytes and no "
+                    "jax.Array whole on the server's device of the link",
+                )
+            elif array is None:
+                device_link.unary_bytes_fallbacks << 1
+            elif wire != "tbus_std":
+                import numpy as np
+
+                array, carried = None, np.asarray(array).tobytes()
         failed = cntl.failed()
         payload = b"" if failed else response
         meta = None
@@ -1728,10 +1764,26 @@ class Server:
                     meta = Meta()
                 meta.compress = cntl.compress_type
                 payload = compress_mod.compress(cntl.compress_type, payload)
-        attachment = b"" if failed else cntl.response_attachment
+        attachment = b"" if failed else carried
         if attachment and meta is None:
             meta = Meta()
-        wire = getattr(cntl, "_wire_protocol", "tbus_std")
+        if array is not None:
+            rc = sock.write_device_message(
+                meta, payload, cntl.call_id, array, flags=FLAG_RESPONSE
+            )
+            if rc == 0:
+                device_link.unary_lane_replies << 1
+                device_link.unary_lane_bytes << array.nbytes
+                serve = cntl._unary_serve
+                if serve is not None and sock.link.unary_serves is not None:
+                    sock.link.unary_serves.rows.append(
+                        (*serve, time.monotonic_ns())
+                    )
+            else:
+                logger.warning(
+                    "response write to %s failed: %s", sock.remote, berror(rc)
+                )
+            return
         wire_proto = None
         if wire != "tbus_std":
             from incubator_brpc_tpu.protocol.registry import protocol_registry

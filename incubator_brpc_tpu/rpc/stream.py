@@ -61,13 +61,17 @@ from incubator_brpc_tpu.protocol.tbus_std import (
     FLAG_STREAM,
     Meta,
     ParsedFrame,
-    ParseError,
     pack_frame,
     pack_frame_iobuf,
-    try_parse_frame,
 )
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.execution_queue import ExecutionQueue, TaskIterator
+from incubator_brpc_tpu.transport.device_link import (
+    BYTE_STREAM as _BYTE_STREAM,
+    LANE as _LANE,
+    CarrierOrder,
+    array_carrier,
+)
 from incubator_brpc_tpu.utils.status import ErrorCode
 
 if TYPE_CHECKING:
@@ -90,8 +94,6 @@ FT_RST = "rst"
 # the consumer queue's own kind, never on the wire: a data frame that came
 # over the link's lane as a tag, beside its body, a device array
 _FT_DEVICE = "device"
-# the two carriers of one stream's messages, as the order stage indexes them
-_BYTE_STREAM, _LANE = 0, 1
 
 IDLE = 0
 CONNECTING = 1
@@ -246,15 +248,9 @@ class Stream:
         # the lock a carrier that keeps its sends one at a time
         self._wrote = [0, 0]
         self._send_locks = (threading.Lock(), threading.Lock())
-        # reader side. _held is None until this stream is handed a device
-        # message or a frame that names one (a stream of bytes stays
-        # there); then, a carrier, the messages that arrived and wait for
-        # the other carrier, oldest first: (task, messages of the other
-        # carrier that go before it). _released counts what went on to the
-        # consumer, [data frames, device messages]
-        self._held: Optional[tuple] = None
-        self._released = [0, 0]
-        self._order_lock = threading.Lock()
+        # reader side: a message goes on to the consumer once the messages
+        # of the other carrier that were written before it have gone
+        self._order = CarrierOrder(self._rq.execute)
 
     # -- connection plumbing (module-level handshake hooks call these) ------
 
@@ -267,7 +263,6 @@ class Stream:
             self.state = CONNECTED
             if hasattr(sock, "link"):  # a DeviceSocket
                 self._vars = LINK_VARS
-                sock.lane_receiver = process_lane_message
         sock.on_failed.append(self._on_socket_failed)
         self._connected_event.set()
 
@@ -408,25 +403,14 @@ class Stream:
     def _device_message(self, array) -> tuple:
         """What ``write`` sends for a message that is no host bytes:
         ``(array, b"")`` for the lane, ``(None, its bytes)`` where the
-        socket has no second device, ``(None, None)`` where it is refused."""
-        import jax
-
-        if not isinstance(array, jax.Array):
-            raise TypeError(
-                f"a stream message is bytes, an IOBuf or a jax.Array, "
-                f"not {type(array).__name__}"
-            )
+        socket has no second device, ``(None, None)`` where it is refused:
+        ``array_carrier``'s rule, which a unary call's attachment follows
+        too."""
         sock = self._sock
         if sock is None:
+            array_carrier(None, array)  # still a TypeError for what is no array
             return None, None
-        lane = getattr(sock, "lane", None)
-        if lane is None:
-            import numpy as np
-
-            return None, np.asarray(array).tobytes()
-        if not lane.lane_accepts(sock.side, array):
-            return None, None
-        return array, b""
+        return array_carrier(sock, array)
 
     def _set_remote_consumed(self, consumed: int) -> None:
         """Feedback arrived (SetRemoteConsumed stream.cpp:287): lift the
@@ -467,13 +451,9 @@ class Stream:
         # cuts; the consumer materializes only when the handler wants bytes
         data = frame.payload_iobuf
         task = (ft, frame.payload if data is None else data, time.monotonic_ns())
-        after = int(extra.get("arrays_before", 0))
-        with self._order_lock:
-            if self._held is None and not after:
-                self._released[_BYTE_STREAM] += ft == FT_DATA
-                self._rq.execute(task)
-            else:
-                self._in_order(_BYTE_STREAM, task, after)
+        self._order.arrive(
+            _BYTE_STREAM, task, int(extra.get("arrays_before", 0)), ft == FT_DATA
+        )
 
     def _on_device_message(self, extra: dict, body) -> None:
         """The lane handed over a device message of this stream: its tag's
@@ -481,29 +461,7 @@ class Stream:
         ``arrived`` stamp: ``deliver_us`` holds its wait for the byte
         stream."""
         task = (_FT_DEVICE, body, time.monotonic_ns())
-        with self._order_lock:
-            self._in_order(_LANE, task, int(extra.get("frames_before", 0)))
-
-    def _in_order(self, carrier: int, task: tuple, after: int) -> None:
-        """Under the order lock, a message that arrived on ``carrier`` and
-        goes ``after`` so many messages of the other one: behind what its
-        own carrier brought before it, and on to the consumer with
-        everything that no longer waits. Each carrier is FIFO and a
-        message names only what was sent before it, so whatever is held
-        waits for something still on its way."""
-        if self._held is None:
-            self._held = (deque(), deque())
-        held, released = self._held, self._released
-        held[carrier].append((task, after))
-        while True:
-            for c, queue in enumerate(held):
-                if queue and queue[0][1] <= released[1 - c]:
-                    task = queue.popleft()[0]
-                    released[c] += task[0] in (FT_DATA, _FT_DEVICE)
-                    self._rq.execute(task)
-                    break
-            else:
-                return
+        self._order.arrive(_LANE, task, int(extra.get("frames_before", 0)))
 
     def _consume(self, it: TaskIterator) -> None:
         """Ordered consumer fiber (stream.cpp:86): batch data messages to the
@@ -587,19 +545,13 @@ class Stream:
         # draining whatever the peer already sent
         self._finish_close(notify=False)
 
-    def _drop_held(self) -> None:
-        """What the order stage held back will not be consumed now: a
-        closed or failed stream has left the registry, so what a held
-        message waits for can no longer reach it."""
-        with self._order_lock:
-            for queue in self._held or ():
-                queue.clear()
-
     def _finish_close(self, notify: bool) -> None:
         with self._lock:
             was_closed = self.state == CLOSED
             self.state = CLOSED
-        self._drop_held()
+        # a closed or failed stream has left the registry: what a held
+        # message waits for can no longer reach it
+        self._order.clear()
         self._connected_event.set()
         self._wbutex.add(1)
         self._wbutex.wake_all()
@@ -655,7 +607,9 @@ class Stream:
             self.state = CLOSED
             self.error_code = code
             self.error_text = reason
-        self._drop_held()
+        # a closed or failed stream has left the registry: what a held
+        # message waits for can no longer reach it
+        self._order.clear()
         self._connected_event.set()
         self._wbutex.add(1)
         self._wbutex.wake_all()
@@ -666,6 +620,11 @@ class Stream:
                 self.options.handler.on_failed(self, code, reason)
             except Exception:
                 logger.exception("stream %d on_failed raised", self.id)
+
+    @property
+    def _held(self) -> tuple:
+        """A carrier, the messages that wait for the other one."""
+        return self._order.held
 
     @property
     def unconsumed_bytes(self) -> int:
@@ -756,28 +715,18 @@ def _stream_of(sock, frame: ParsedFrame) -> Optional[Stream]:
 def process_stream(sock, frame: ParsedFrame) -> None:
     """tbus_std Protocol.process_stream hook: route a FLAG_STREAM frame to
     its stream by meta.stream_id (ParseStreamingMessage →
-    Stream::OnReceived, SURVEY §3.4)."""
+    Stream::OnReceived, SURVEY §3.4). A frame the messenger cut from a
+    lane message's tag (``InputMessenger.process_device_message``) heads a
+    device message: its attachment is the message's body, a device array
+    on this side's device. One that names no open stream is dropped as a
+    frame off the byte stream is."""
     s = _stream_of(sock, frame)
-    if s is not None:
-        s._on_frame(frame)
-
-
-def process_lane_message(sock, tag, body) -> None:
-    """``DeviceSocket.lane_receiver`` hook: a device message as the link's
-    lane handed it over, its tag's words and its body on this side's
-    device. The tag is the data frame that would head the message on the
-    byte stream, cut by the parser that cuts those: magic and checksum
-    hold for it too, and a tag that does not parse fails the socket. One
-    that names no open stream is dropped as such a frame is."""
-    try:
-        frame, _ = try_parse_frame(tag.tobytes())
-        if frame is None or not frame.is_stream:
-            raise ParseError("not a whole stream frame")
-    except (ParseError, ValueError) as e:
-        sock.set_failed(ErrorCode.EREQUEST, f"a device message's tag: {e}")
+    if s is None:
         return
-    s = _stream_of(sock, frame)
-    if s is not None:
+    body = frame.attachment
+    if isinstance(body, (bytes, bytearray)):
+        s._on_frame(frame)
+    else:
         s._on_device_message(frame.meta.extra, body)
 
 

@@ -57,6 +57,7 @@ re-thought for XLA devices:
 
 from __future__ import annotations
 
+import dataclasses
 import itertools
 import json
 import logging
@@ -75,6 +76,7 @@ from incubator_brpc_tpu.bvar import (
     RecorderFeed,
     clocks,
 )
+from incubator_brpc_tpu.protocol.tbus_std import Meta, pack_frame, pack_frame_iobuf
 from incubator_brpc_tpu.runtime.butex import Butex, ETIMEDOUT
 from incubator_brpc_tpu.runtime.device_butex import DeviceCompletionButex
 from incubator_brpc_tpu.runtime.worker_pool import global_worker_pool
@@ -118,6 +120,13 @@ lane_messages = Adder(name="device_link_lane_messages")
 lane_bytes = Adder(name="device_link_lane_bytes")
 # of those programs, the ones that carried their message's tag beside it
 lane_tagged = Adder(name="device_link_lane_tagged_steps")
+# unary calls whose attachment was a device array: the requests and the
+# answers that took the lane, their bodies' bytes, and the attachments that
+# went as host bytes instead where the socket under the call has no lane
+unary_lane_requests = Adder(name="device_link_unary_lane_requests")
+unary_lane_replies = Adder(name="device_link_unary_lane_replies")
+unary_lane_bytes = Adder(name="device_link_unary_lane_bytes")
+unary_bytes_fallbacks = Adder(name="device_link_unary_bytes_fallbacks")
 link_acks = Adder(name="device_link_ack_steps")  # wire-mode catch-up steps
 link_errors = Adder(name="device_link_errors")  # fail() calls, all links
 # send() attempts refused with EOVERCROWDED after a full window-stall wait
@@ -264,6 +273,98 @@ LANE_COLUMNS = (
     ("deliver_us", 1e-3, ("paired", "queued")),
     ("launch_cpu_us", 1e-3, ("taken_cpu", "launched_cpu")),
 )
+
+
+# A unary call whose attachment crossed by the lane leaves a row on each
+# side of the link (rpc/channel.py, rpc/server.py write them), stamps of
+# time.monotonic_ns() in the order taken; one never taken is MISSING (an
+# answer that came as bytes has no ``answer_handed``). The caller's row:
+UNARY_CALL_STAMPS = ("entered", "request_sent", "answer_handed", "returned")
+UNARY_CALL_COLUMNS = (
+    ("request_tx_us", 1e-3, ("entered", "request_sent")),
+    ("client_wake_us", 1e-3, ("answer_handed", "returned")),
+    ("call_us", 1e-3, ("entered", "returned")),
+)
+# and the server's, ``request_handed`` the lane's hand-over of the request
+UNARY_SERVE_STAMPS = ("request_handed", "handler_in", "handler_out", "reply_sent")
+UNARY_SERVE_COLUMNS = (
+    ("server_dispatch_us", 1e-3, ("request_handed", "handler_in")),
+    ("reply_tx_us", 1e-3, ("handler_out", "reply_sent")),
+)
+# the two FIFO carriers of a link, as CarrierOrder indexes them
+BYTE_STREAM, LANE = 0, 1
+
+
+class CarrierOrder:
+    """The reader's side of an order kept across two FIFO carriers, a
+    link's byte stream and its lane, either of which may be the faster.
+    Each item names how many counted items of the *other* carrier its
+    writer had sent before it (the writer counts an item once its carrier
+    has it, one send at a time a carrier); ``arrive`` releases it once
+    that many have been released, behind what its own carrier brought
+    before it: ``release`` sees the items in the order written. Each
+    carrier is FIFO and an item names only what was sent before it, so
+    whatever is held waits for something still on its way. A stream's
+    messages (rpc/stream.py) and the frames and bodies of unary calls whose
+    frame is too long for the lane's tag (``DeviceSocket``) take the same
+    stage."""
+
+    def __init__(self, release):
+        self._release = release  # called under the lock, in the order written
+        self._lock = threading.Lock()
+        # a carrier: what arrived and waits for the other carrier, oldest
+        # first, (item, items of the other carrier before it, counted)
+        self.held = (deque(), deque())
+        self.released = [0, 0]  # counted items released, a carrier
+
+    def arrive(self, carrier: int, item, after: int, counted: bool = True) -> None:
+        with self._lock:
+            held, released = self.held, self.released
+            held[carrier].append((item, after, counted))
+            while True:
+                for c, queue in enumerate(held):
+                    if queue and queue[0][1] <= released[1 - c]:
+                        item, _after, counted = queue.popleft()
+                        released[c] += counted
+                        self._release(item)
+                        break
+                else:
+                    return
+
+    def clear(self) -> None:
+        """What is held will not be released: what it waits for can no
+        longer arrive (a closed stream, a failed socket)."""
+        with self._lock:
+            for queue in self.held:
+                queue.clear()
+
+
+def array_carrier(sock, array) -> tuple:
+    """What becomes of a device array handed to ``sock`` as a stream's
+    message or a unary call's attachment, chosen from what the socket is
+    and what the array is; nothing configures it. ``(array, b"")``: the
+    socket's link has a lane and takes the array whole. ``(None, its
+    bytes)``: there is no second device to land on (a host socket, a link
+    on one shared device), so its bytes go as host bytes. ``(None, None)``:
+    refused (``lane_accepts`` said no: not whole on this side's device, no
+    dimension or element, deleted or donated; or a multi-controller link,
+    which has no lane yet). Anything but a ``jax.Array`` is a
+    ``TypeError``."""
+    import jax
+
+    if not isinstance(array, jax.Array):
+        raise TypeError(
+            f"a stream message or an attachment is bytes, an IOBuf or a "
+            f"jax.Array, not {type(array).__name__}"
+        )
+    if array.is_deleted():
+        return None, None
+    lane = getattr(sock, "lane", None)
+    if lane is None:
+        return None, np.asarray(array).tobytes()
+    if not lane.lane_accepts(sock.side, array):
+        return None, None
+    return array, b""
 
 
 class _LaneStep:
@@ -503,7 +604,12 @@ class DeviceLink:
         # (side, shape, dtype) -> (program, placeholder, receiver's shard)
         self._lane_programs: Dict[tuple, tuple] = {}
         self._lane_inflight = 0  # programs dispatched, body not yet seen ready
+        self._launch_order = None  # the process's order of collective launches
         self._lane_feed: Optional[RecorderFeed] = None
+        # a row a unary call that carried a device attachment: the caller's
+        # side's and the serving side's (made with the lane)
+        self.unary_calls: Optional[RecorderFeed] = None
+        self.unary_serves: Optional[RecorderFeed] = None
         self._build_step()
         with _links_lock:
             _all_links.add(self)
@@ -515,7 +621,8 @@ class DeviceLink:
             return
         self._metrics_retired = True
         retired = [self._m_flush, self._m_out_rate, self._m_in_rate]
-        for feed in (self._step_feed, self._send_feed, self._lane_feed):
+        for feed in (self._step_feed, self._send_feed, self._lane_feed,
+                     self.unary_calls, self.unary_serves):
             if feed is not None:
                 feed.flush()  # profile() still reads the recorders
                 retired += [recorder for recorder, *_rest in feed.columns]
@@ -598,6 +705,9 @@ class DeviceLink:
         # steps' rows are
         lane = f"device_link_{self.link_id}_lane"
         made = _recorders(lane, {what: what for what, *_rest in LANE_COLUMNS})
+        from incubator_brpc_tpu.parallel.collective import launch_order
+
+        self._launch_order = launch_order
         self._lane_feed = RecorderFeed(
             [(made[what], scale, span) for what, scale, span in LANE_COLUMNS],
             stamps=LANE_STAMPS,
@@ -605,6 +715,25 @@ class DeviceLink:
             ring_rows=1 << 14,
             worker=(("taken", "launched"), ("paired", "queued")),
             call=(("taken", "queued"),),
+        )
+        unary = f"device_link_{self.link_id}_unary"
+        columns = (*UNARY_CALL_COLUMNS, *UNARY_SERVE_COLUMNS)
+        made = _recorders(unary, {what: what for what, *_rest in columns})
+        self.unary_calls = RecorderFeed(
+            [(made[what], scale, span) for what, scale, span in UNARY_CALL_COLUMNS],
+            stamps=UNARY_CALL_STAMPS,
+            name=f"{unary}_calls",
+            ring_rows=1 << 14,
+            worker=(("entered", "request_sent"),),
+            call=(("entered", "returned"),),
+        )
+        self.unary_serves = RecorderFeed(
+            [(made[what], scale, span) for what, scale, span in UNARY_SERVE_COLUMNS],
+            stamps=UNARY_SERVE_STAMPS,
+            name=f"{unary}_serves",
+            ring_rows=1 << 14,
+            worker=(("handler_in", "handler_out"), ("handler_out", "reply_sent")),
+            call=(("request_handed", "reply_sent"),),
         )
 
     def _warm_step(self) -> None:
@@ -655,12 +784,14 @@ class DeviceLink:
     def lane_accepts(self, side: int, array) -> bool:
         """Whether ``lane_send`` can take ``array`` from ``side``: a
         ``jax.Array`` of at least one dimension and one element that lies
-        whole on the device this side of the link drives."""
+        whole on the device this side of the link drives and was neither
+        deleted nor donated to a program."""
         import jax
 
         return (
             self.has_lane
             and isinstance(array, jax.Array)
+            and not array.is_deleted()
             and array.ndim >= 1
             and array.size > 0
             and array.devices() == {self.devices[side]}
@@ -759,22 +890,31 @@ class DeviceLink:
         if len(tag) > LANE_TAG_BYTES:
             return ErrorCode.EINVAL
         to = 1 - side
-        with self._lane_lock:
-            if self._closed:
-                return ErrorCode.EFAILEDSOCKET
-            step = _LaneStep(self._lane_seq[to], to, array.nbytes)
-            self._lane_seq[to] += 1
-            self._lane_inflight += 1
-        timed = step.seq % CPU_CLOCK_EVERY == 0
-        step.t_taken, step.c_taken = clocks(timed)
+        # Lane programs are launched from many threads once unary calls
+        # ride the lane (callers one way, handlers' workers the other), and
+        # each is a collective over both devices: the launches are ordered
+        # (``collective.launch_order``), or the two devices could see two
+        # of them in different orders and each wait in a permute the other
+        # has not reached. Held from the seq to the program call's return,
+        # so the order launched is the order handed over; the host work
+        # before and the watch after lie outside it.
         try:
-            program, placeholder, shard = self._lane_program(
-                side, array.shape, array.dtype
-            )
-            out, landed = program(
-                self._lane_operand(side, array, placeholder),
-                self._lane_tags(side, bytes(tag)),
-            )
+            with self._launch_order:
+                with self._lane_lock:
+                    if self._closed:
+                        return ErrorCode.EFAILEDSOCKET
+                    step = _LaneStep(self._lane_seq[to], to, array.nbytes)
+                    self._lane_seq[to] += 1
+                    self._lane_inflight += 1
+                timed = step.seq % CPU_CLOCK_EVERY == 0
+                step.t_taken, step.c_taken = clocks(timed)
+                program, placeholder, shard = self._lane_program(
+                    side, array.shape, array.dtype
+                )
+                out, landed = program(
+                    self._lane_operand(side, array, placeholder),
+                    self._lane_tags(side, bytes(tag)),
+                )
             step.body = out.addressable_data(shard)
             step.landed_tag = landed.addressable_data(shard)
             self._request_host(step.landed_tag)
@@ -1379,10 +1519,18 @@ class DeviceSocket:
         self.on_revived: List = []
         self._read_buf = IOBuf()
         self._feed_lock = threading.Lock()
-        # who is handed this side's device messages, (sock, tag, body) in
-        # the lane's order: whoever reads them sets it (rpc/stream.py does
-        # when a stream connects over this socket). None: dropped
+        # who is handed this side's device messages raw, (sock, tag, body)
+        # in the lane's order, in the messenger's place (a test's sink).
+        # None: the messenger cuts the tag as the tbus_std frame it is
         self.lane_receiver = None
+        # a unary call's frame too long for the lane's tag rides the byte
+        # stream, its attachment the lane: the writer's counts a carrier
+        # and their lock, and the reader's order stage, which lays each
+        # body by for the frame written after it
+        self._unary_wrote = [0, 0]
+        self._unary_send_lock = threading.Lock()
+        self._unary_order = CarrierOrder(self._unary_released)
+        self._unary_body = None
         self.id = _registry.insert(self)
         link.attach(side, self)
 
@@ -1409,6 +1557,70 @@ class DeviceSocket:
         # arbitrate the same failure twice (a queued id error delivered
         # at unlock), burning a retry attempt.
         return self.link.send(self.side, data, timeout=timeout)
+
+    def write_device_message(
+        self, meta, payload: bytes, correlation_id: int, array,
+        flags: int = 0, error_code: int = 0,
+    ) -> int:
+        """A unary call's tbus_std frame (request or answer) whose
+        attachment is ``array``, which ``array_carrier`` gave to the lane.
+        The frame, attachment-less, is the array's tag where it fits
+        ``LANE_TAG_BYTES``: both cross in one program and nothing of the
+        call rides the byte stream. A longer frame rides the byte stream
+        and names the bodies sent before it, its own included
+        (``arrays_before``); its body crosses the lane first, tagged with
+        the frames sent before it (``frames_before``), and the far socket's
+        ``CarrierOrder`` brings the two together, as a stream's carriers
+        are. ``lane_send``'s and ``write``'s codes."""
+        meta = meta if meta is not None else Meta()
+        frame = pack_frame(meta, payload, correlation_id, flags, error_code)
+        if len(frame) <= LANE_TAG_BYTES:
+            return self.link.lane_send(self.side, array, frame)
+        with self._unary_send_lock:
+            wrote = self._unary_wrote
+            body = Meta(extra={"unary_body": 1, "frames_before": wrote[BYTE_STREAM]})
+            rc = self.link.lane_send(self.side, array, pack_frame(body, b"", 0))
+            if rc != 0:
+                return rc
+            wrote[LANE] += 1
+            meta = dataclasses.replace(
+                meta, extra=dict(meta.extra, arrays_before=wrote[LANE])
+            )
+            rc = self.write(
+                pack_frame_iobuf(meta, payload, correlation_id, flags, error_code)
+            )
+            if rc == 0:
+                wrote[BYTE_STREAM] += 1
+            return rc
+
+    def hold_for_body(self, proto, frame) -> bool:
+        """The messenger's question for every frame cut off this socket's
+        byte stream, in wire order: a unary frame that names a body on the
+        lane (``arrays_before``) is kept here until the lane has handed that
+        body over, and dispatched with it as its attachment."""
+        extra = getattr(getattr(frame, "meta", None), "extra", None)
+        after = extra.get("arrays_before") if extra else None
+        if not after or getattr(frame, "is_stream", False):
+            return False
+        self._unary_order.arrive(BYTE_STREAM, (proto, frame), int(after))
+        return True
+
+    def hold_body(self, body, after: int) -> None:
+        """The lane handed over the body of such a frame, sent after
+        ``after`` of them."""
+        self._unary_order.arrive(LANE, body, after)
+
+    def _unary_released(self, item) -> None:
+        """``CarrierOrder``'s release, in the order written: a body, then
+        the frame it belongs to, which goes where a frame cut off the byte
+        stream goes, on a worker (a handler may block)."""
+        if not isinstance(item, tuple):
+            self._unary_body = item
+            return
+        proto, frame = item
+        frame.attachment, self._unary_body = self._unary_body, None
+        frame.handed_ns = time.monotonic_ns()  # whole: frame and body in hand
+        global_worker_pool().spawn(self.messenger._process_one, self, proto, frame)
 
     @property
     def lane(self) -> Optional[DeviceLink]:
@@ -1442,13 +1654,21 @@ class DeviceSocket:
     def _lane_deliver(self, tag: np.ndarray, body) -> None:
         """Lane delivery: one device message, its tag's words as they were
         read back on this side and its body on this side's device, in the
-        order the far side sent them. To the link and to this socket the
-        tag is opaque; a receiver that cannot read it fails the socket."""
+        order the far side sent them. To the link the tag is opaque; the
+        messenger cuts it as a tbus_std frame (a stream's data frame, a
+        unary call's request or answer) and fails the socket where it is
+        none."""
         from incubator_brpc_tpu.transport.sock import CONNECTED
 
+        if self.state != CONNECTED:
+            return
         receiver = self.lane_receiver
-        if receiver is not None and self.state == CONNECTED:
+        if receiver is not None:
             receiver(self, tag, body)
+            return
+        process = getattr(self.messenger, "process_device_message", None)
+        if process is not None:
+            process(self, tag, body)
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -1460,6 +1680,9 @@ class DeviceSocket:
         self.state = FAILED
         self.error_code = code
         self.error_text = reason
+        # a frame or a body held for its other half: that will not come
+        self._unary_order.clear()
+        self._unary_body = None
         if code != ErrorCode.ECLOSE:
             self.link.fail(reason)
         else:

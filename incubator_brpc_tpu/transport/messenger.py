@@ -15,6 +15,7 @@ Kept semantics:
 from __future__ import annotations
 
 import logging
+import time
 from typing import List, Optional, Tuple
 
 from incubator_brpc_tpu.protocol.registry import (
@@ -215,9 +216,7 @@ class InputMessenger:
         # off the wire, so time spent queued behind the worker pool or
         # earlier frames of this burst counts against it (the server sheds
         # expired-mid-queue work with EDEADLINE). One clock read per burst.
-        import time as _time
-
-        now = _time.monotonic()
+        now = time.monotonic()
         for _proto, frame in cut:
             try:
                 frame.arrival_ts = now
@@ -232,7 +231,13 @@ class InputMessenger:
         #   must be written in request order.
         # Everything else gets the N-1-fibers + last-inline treatment.
         rest = []
+        # a DeviceSocket keeps a unary frame that names a body on its
+        # link's lane until the lane has handed that body over; it is
+        # asked here, in wire order, as its order stage needs
+        hold = getattr(sock, "hold_for_body", None)
         for proto, frame in cut:
+            if hold is not None and hold(proto, frame):
+                continue
             pre = getattr(frame, "pre_dispatch", None)
             if pre is not None:
                 # ordering hooks (HTTP response-order gates) run at
@@ -267,6 +272,44 @@ class InputMessenger:
             return (proto, frame)
         self._process_one(sock, proto, frame)  # last message inline
         return None
+
+    def process_device_message(self, sock, tag, body) -> None:
+        """A device message as a link's lane hands it over
+        (``DeviceSocket._lane_deliver``): its tag's words and its body, a
+        device array on this side's device. The tag is the tbus_std frame
+        that would head the body on the byte stream, cut by the parser
+        that cuts those, so magic and checksum hold for it too; one that
+        does not parse fails the socket. The frame goes where a frame off
+        the byte stream goes, the body its attachment: a stream's data
+        frame to its stream and an answer to its waiting call, both here
+        on the lane's in-order deliverer (neither blocks); a request to a
+        worker, where the server's handlers run, so that a slow handler
+        holds no later message of this side."""
+        from incubator_brpc_tpu import protocol as proto_pkg
+        from incubator_brpc_tpu.protocol.tbus_std import try_parse_frame
+
+        handed_ns = time.monotonic_ns()
+        try:
+            frame, _ = try_parse_frame(tag.tobytes())
+            if frame is None:
+                raise ParseError("not a whole frame")
+        except (ParseError, ValueError) as e:
+            sock.set_failed(ErrorCode.EREQUEST, f"a device message's tag: {e}")
+            return
+        extra = frame.meta.extra
+        if extra.get("unary_body"):
+            # the body of a unary frame too long for a tag: paired with
+            # that frame, which rides the byte stream, by the socket
+            sock.hold_body(body, int(extra.get("frames_before", 0)))
+            return
+        frame.attachment = body
+        frame.handed_ns = handed_ns
+        frame.arrival_ts = handed_ns / 1e9
+        proto = proto_pkg.TBUS_STD
+        if frame.is_stream or frame.is_response:
+            self._process_one(sock, proto, frame)
+        else:
+            global_worker_pool().spawn(self._process_one, sock, proto, frame)
 
     @staticmethod
     def _process_one(sock, proto: Protocol, frame) -> None:

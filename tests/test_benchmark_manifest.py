@@ -25,6 +25,10 @@ with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
     BENCH = json.load(_f)
 CELLS = [w["name"] for w in BENCH["workloads"]]
 KV_CELL = "kv_blocks_ici_32m_c1"  # PR 39's: device blocks over the lane
+HBM_CELL = "link_echo_ici_hbm_1m_c4"  # PR 44's: a unary call carries a tensor
+# the three readers of the lane that go by adders and the trace, not by the
+# link with most trains: PR 44's cell, whose windows need no train, joined them
+LANE_BY_ADDERS = ("lane_messages_per_step", "lane_step_ici_pct", "lane_tagged_pct")
 ECHO_CELLS = ["echo_256b_c16", "echo_4m_c2", "echo_mixed_c16"]
 DEVICE_STAGES = (
     "copy", "credit_wait", "queue_wait", "stack", "launch",
@@ -178,7 +182,34 @@ CQ = {
 CQ_CELLS = [
     "echo_256b_c16", "echo_4m_c2", "link_echo_ici_1m", "echo_mixed_c16",
     "echo_256b_c16_native", "link_stream_ici", "ycsb_b_zipf_c16", KV_CELL,
+    HBM_CELL,
 ]
+# PR 44's unary calls over a window, on the busiest link by such calls (3;
+# no train crossed, so no step_rtt names it): 400 calls of 10,000 us whose
+# four stages cover 5,000, the lane's two flights 3,000 (800 programs, 900 +
+# 600 us from launch to hand-over) and the hand-made handler 1,000; 100
+# attachments went as host bytes
+UNARY_STAGES = {
+    "request_tx": 2200.0, "server_dispatch": 300.0, "reply_tx": 2100.0,
+    "client_wake": 400.0,
+}
+UNARY_LANE_STAGES = {
+    "launch": 1900.0, "ready": 900.0, "pair_wait": 600.0, "deliver": 80.0,
+}
+UNARY = {
+    "device_link_2_unary_call_us": recorder(5, 9e9),
+    "device_link_2_unary_request_tx_us": recorder(5, 9e9),
+    "device_link_2_lane_ready_us": recorder(5, 9e9),
+    "device_link_3_unary_call_us": recorder(400, 10000.0),
+    **{f"device_link_3_unary_{s}_us": recorder(400, us)
+       for s, us in UNARY_STAGES.items()},
+    **{f"device_link_3_lane_{s}_us": recorder(800, us)
+       for s, us in UNARY_LANE_STAGES.items()},
+    "device_link_unary_lane_requests": 400,
+    "device_link_unary_lane_replies": 400,
+    "device_link_unary_lane_bytes": 800 << 20,
+    "device_link_unary_bytes_fallbacks": 100,
+}
 EXPECTED = {
     **{f"device_{s}_us": (DEVICE, 100.0) for s in DEVICE_STAGES},
     "device_path_unattributed_pct": (DEVICE, 10.0),
@@ -237,6 +268,12 @@ EXPECTED = {
     "cq_backlog": (CQ, 0.25),
     "stream_device_bytes_pct": (
         LANE, 100.0 * 640 * 2097152 / (640 * 2097152 + 40 * 64)),
+    # PR 44: a unary call's stages, a row a call on each side of the link
+    "unary_call_us": (UNARY, 10000.0),
+    **{f"unary_{s}_us": (UNARY, us) for s, us in UNARY_STAGES.items()},
+    **{f"unary_lane_{s}_us": (UNARY, us) for s, us in UNARY_LANE_STAGES.items()},
+    "unary_unattributed_pct": (UNARY, 10.0),
+    "unary_device_calls_pct": (UNARY, 80.0),
 }
 # PR 37's record table over a window: 40 dispatches that ran 128 rows, 90 of
 # them reads and 10 updates, two of which a later row of their dispatch replaced
@@ -310,9 +347,14 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
         elif name.startswith("link_"):
             # every link cell drives the link: PR 31's and PR 39's joined them
             assert cells[name] == ["link_echo_ici_1m", "link_stream_ici", KV_CELL], name
+        elif name in LANE_BY_ADDERS:
+            assert cells[name] == [KV_CELL, HBM_CELL], name
         elif name.startswith(("lane_", "kv_")) or name == "stream_device_bytes_pct":
-            # only the KV block stream sends a device array
+            # only the KV block stream sends a device array over trains too
             assert cells[name] == [KV_CELL], name
+        elif name.startswith("unary_"):
+            # only PR 44's cell makes a unary call with a device attachment
+            assert cells[name] == [HBM_CELL], name
         elif name.startswith("combo_"):
             # only the partitioned deployment builds a combo channel
             assert cells[name] == ["partition_star_4"], name
@@ -355,6 +397,9 @@ def test_each_configuration_is_the_file_the_manifest_names():
         # the KV block stream rides it too, letter for letter
         "kv_block_stream_ici": (
             "kv_block_stream", "kv_block_pool", link_options, None),
+        # and the unary tensor call: link_performance_ici's link as it is
+        "link_performance_ici_hbm": (
+            "link_echo_hbm", "tensor_echo_identity", link_options, None),
     }
     configs = {c["name"]: c for c in BENCH["configs"]}
     assert set(expected) <= set(configs)  # a later PR may add more
@@ -438,7 +483,10 @@ def test_the_new_entries_only_follow_the_old():
         "lane_deliver_us", "lane_launch_cpu_us", "lane_messages_per_step",
         "lane_step_ici_pct", "stream_device_bytes_pct",
         "kv_page_write_kernel_us", "kv_page_write_hbm_pct"]
-    assert all(m["workloads"] == [KV_CELL] for m in BENCH["per_layer"][77:89])
+    assert all(
+        m["workloads"] == (
+            [KV_CELL, HBM_CELL] if m["name"] in LANE_BY_ADDERS else [KV_CELL])
+        for m in BENCH["per_layer"][77:89])
     assert [m["layer"] for m in BENCH["per_layer"][77:88]] == (
         ["link"] * 8 + ["stream"] + ["device program"] * 2)
     # PR 40's one entry follows them
@@ -446,10 +494,10 @@ def test_the_new_entries_only_follow_the_old():
     assert BENCH["per_layer"][88] == {
         "name": "lane_tagged_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "link", "moves": "goodput",
-        "workloads": [KV_CELL],
+        "workloads": [KV_CELL, HBM_CELL],
     }
-    # PR 42's two follow it, the last, in the eight cells that call ``watch``
-    assert BENCH["per_layer"][89:] == [
+    # PR 42's two follow it, in the nine cells that call ``watch``
+    assert BENCH["per_layer"][89:91] == [
         {"name": "cq_submit_us", "unit": "us", "better": "lower",
          "source": "program_counter",
          "layer": "host to HBM crossing and completion",
@@ -460,6 +508,18 @@ def test_the_new_entries_only_follow_the_old():
          "moves": "latency_p50_us", "workloads": CQ_CELLS},
     ]
     assert set(CELLS) - set(CQ_CELLS) == {"partition_star_4"}
+    # PR 44's eleven follow them, the last, each in its one cell
+    assert names[91:] == [
+        "unary_request_tx_us", "unary_server_dispatch_us", "unary_reply_tx_us",
+        "unary_client_wake_us", "unary_call_us", "unary_unattributed_pct",
+        "unary_device_calls_pct", "unary_lane_launch_us", "unary_lane_ready_us",
+        "unary_lane_pair_wait_us", "unary_lane_deliver_us"]
+    assert all(
+        (m["workloads"], m["source"]) == ([HBM_CELL], "program_counter")
+        for m in BENCH["per_layer"][91:])
+    assert [m["layer"] for m in BENCH["per_layer"][91:]] == [
+        "link", "host plane", "link", "host plane", "host plane", "host plane",
+        "host plane", "link", "link", "link", "link"]
     for entry, source, layer, moves in zip(
             BENCH["per_layer"][73:76],
             ("device_trace", "device_trace", "program_counter"),
@@ -470,14 +530,18 @@ def test_the_new_entries_only_follow_the_old():
             source, layer, moves)
         assert entry["workloads"] == ["ycsb_b_zipf_c16"]
     assert [c["name"] for c in BENCH["configs"]][5:] == [
-        "ycsb_b_device_table", "kv_block_stream_ici"]
-    assert CELLS[7:] == ["ycsb_b_zipf_c16", KV_CELL]
-    assert [w["chips"] for w in BENCH["workloads"][7:]] == [1, 4]
-    for m in BENCH["end_to_end"] + BENCH["per_layer"][:77]:
-        # an older metric gained a cell's name at the end of its list or not at all
-        listed = [w for w in m.get("workloads", ()) if w != KV_CELL]
-        if KV_CELL in m.get("workloads", ()):
-            assert m["workloads"][-1] == KV_CELL, m["name"]
+        "ycsb_b_device_table", "kv_block_stream_ici", "link_performance_ici_hbm"]
+    assert CELLS[7:] == ["ycsb_b_zipf_c16", KV_CELL, HBM_CELL]
+    assert [w["chips"] for w in BENCH["workloads"][7:]] == [1, 4, 4]
+    for m in BENCH["end_to_end"] + BENCH["per_layer"][:91]:
+        # an older metric gained a cell's name at the end of its list or not
+        # at all: PR 44's last, PR 39's before it
+        workloads = list(m.get("workloads", ()))
+        if HBM_CELL in workloads:
+            assert workloads.pop() == HBM_CELL, m["name"]
+        listed = [w for w in workloads if w != KV_CELL]
+        if KV_CELL in workloads:
+            assert workloads[-1] == KV_CELL, m["name"]
         if "ycsb_b_zipf_c16" in listed and m["name"] not in (
                 "table_step_kernel_us", "table_step_hbm_pct", "table_state_wait_us"):
             assert listed[-1] == "ycsb_b_zipf_c16", m["name"]
@@ -898,3 +962,112 @@ def test_lane_and_page_write_shares_from_a_fabricated_trace():
     for lines in run.devices.values():
         lines["steps"] = xplane.Events(["jit_exchange(99)"], [T_OPEN], [T_OPEN + 9])
     assert ici(run) is None and kernel(run) is None and hbm(run) is None
+
+
+# -- PR 44: the unary tensor call's configuration, cell and readers ---------------
+
+
+def test_the_tensor_echo_configuration_keeps_the_sources_shape():
+    config = manifest.load_json("configs", "link_performance_ici_hbm.json")
+    host = manifest.load_json("configs", "link_performance_ici.json")
+    cell = manifest.Cell(BENCH, HBM_CELL)
+    # upstream's attachment_size, echoed, in the device's memory
+    assert config["attachment"] == {
+        "dtype": "uint32", "words": 262144, "bytes": 1048576}
+    assert cell.traffic["sizes"] == [config["attachment"]["bytes"]]
+    assert 4 * config["attachment"]["words"] == config["attachment"]["bytes"]
+    assert (cell.traffic["callers"], cell.traffic["arrival"]) == (4, "closed")
+    assert cell.traffic["carrier"] == "attachment"
+    assert (cell.traffic["service"], cell.traffic["method"]) == ("EchoService", "Echo")
+    assert cell.traffic["warm_calls_per_caller"] == 4
+    # link_performance_ici's link, checks, generator and allocator as they are
+    for key in ("channel_options", "link", "generator", "allocator", "chips"):
+        assert config[key] == host[key], key
+    assert config["generator"] == "in_process" and cell.chips == 4
+    assert config["architecture"] is None and config["reduced"] == []
+    assert "use_rdma=true" in config["source"]
+    assert {m["name"] for m in cell.end_to_end} == {
+        "goodput", "latency_p50_us", "setup_s"}
+    entry = [c for c in BENCH["configs"] if c["name"] == "link_performance_ici_hbm"]
+    assert entry[0]["reduced"] == [] and entry[0]["source"].startswith(
+        "apache/brpc example/rdma_performance")
+    four_chip = [w["chips"] for w in BENCH["workloads"]].count(4)
+    assert four_chip <= len(CELLS) // 2  # the half a benchmark may give four chips
+
+
+def test_the_tensor_echo_deployment_has_the_four_controls():
+    assert manifest.Cell(BENCH, HBM_CELL).deployment().CONTROLS == (
+        "flip_bit", "stale", "swap", "host_bytes")
+
+
+def test_the_tensor_echo_reference_is_the_identity_on_a_seeded_tensor():
+    reference = manifest.Cell(BENCH, HBM_CELL).reference()
+    tensor = reference.content(2**31 + 7, 3, 11, 262144)
+    assert tensor.dtype == np.uint32 and tensor.nbytes == 1048576
+    assert np.array_equal(tensor, reference.content(2**31 + 7, 3, 11, 262144))
+    for other in ((2**31 + 8, 3, 11), (2**31 + 7, 2, 11), (2**31 + 7, 3, 12)):
+        assert not np.array_equal(tensor, reference.content(*other, 262144))
+    request, answer = reference.expected(b"ping", tensor)
+    assert request == b"ping" and answer is tensor
+
+
+def test_the_unary_readers_go_by_the_link_with_most_unary_calls_not_by_trains():
+    """A window of the cell need hold no train: the readers find their link
+    by its unary calls, where ``stages.link_recorder`` finds none."""
+    from benchmark import stages, stages_unary
+
+    run = hand_made_run(dict(UNARY))
+    assert stages.link_recorder(run, "lane_ready_us") is None
+    assert stages_unary.link_recorder(run, "lane_ready_us") == pytest.approx(900.0)
+    # trains on another link do not mislead them
+    run = hand_made_run({**UNARY, "device_link_2_step_rtt_us": recorder(680, 1.0)})
+    assert stages_unary.link_recorder(run, "unary_call_us") == pytest.approx(10000.0)
+
+
+def test_unary_unattributed_share_needs_every_stage_and_a_handler_span():
+    read = manifest.load_module("layers", "unary_unattributed_pct.py").read
+    for missing in ("device_link_3_unary_client_wake_us", "device_link_3_lane_ready_us",
+                    "device_link_3_unary_call_us"):
+        short = dict(UNARY)
+        del short[missing]
+        assert read(hand_made_run(short)) is None, missing
+    run = hand_made_run(dict(UNARY))
+    run.handler = run.handler[:0]
+    assert read(run) is None
+
+
+@pytest.mark.parametrize(
+    "counters,share",
+    [
+        ({"device_link_unary_lane_requests": 300,
+          "device_link_unary_bytes_fallbacks": 0}, 100.0),
+        ({"device_link_unary_lane_requests": 0,
+          "device_link_unary_bytes_fallbacks": 50}, 0.0),
+        # a window without such a call (the host_bytes control), and the parent
+        ({"device_link_unary_lane_requests": 0,
+          "device_link_unary_bytes_fallbacks": 0}, None),
+        ({}, None),
+    ],
+    ids=["every-call", "all-fell-back", "no-device-call", "no-adder"],
+)
+def test_unary_device_share_counts_the_requests_that_took_the_lane(counters, share):
+    read = manifest.load_module("layers", "unary_device_calls_pct.py").read
+    value = read(hand_made_run(dict(counters)))
+    assert value is None if share is None else value == pytest.approx(share)
+
+
+def test_the_lane_share_counts_a_unary_cells_two_directions_right():
+    """Both directions' bytes over both executions on TPU_0, the sending
+    one and the receiving one: each moves one message through that chip's
+    interconnect, so the share cannot pass 100."""
+    ici = manifest.load_module("layers", "lane_step_ici_pct.py").read
+    calls, nbytes = 400, 1048576
+    start = T_OPEN + np.arange(2 * calls, dtype=np.int64) * 1_000_000
+    lane = xplane.Events(["jit_device_link_lane(7)"] * 2 * calls, start, start + 12_000)
+    run = hand_made_run({**UNARY, "device_link_lane_bytes": 2 * calls * nbytes})
+    none = xplane.Events([], [], [])
+    run.devices = {"/device:TPU:0": {"steps": lane, "ops": none},
+                   "/device:TPU:1": {"steps": lane, "ops": none}}
+    run.cell = types.SimpleNamespace(config={})  # no prefill_device: TPU_0
+    share = 100.0 * (2 * calls * nbytes / (2 * calls * 12e-6)) / (1600e9 / 8)
+    assert ici(run) == pytest.approx(share) and 0 < share < 100

@@ -284,10 +284,17 @@ def test_the_read_compiles_without_a_second_pool(decode_chip):
     assert memory.temp_size_in_bytes < 64 << 20
 
 
-def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(topo):
+@pytest.mark.parametrize(
+    "words,side",
+    [(CONFIG["block_bytes"] // 4, 0), (262144, 0), (262144, 1)],
+    ids=["a-2MiB-block", "a-1MiB-tensor-out", "a-1MiB-tensor-back"],
+)
+def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(
+        topo, words, side):
     """A 2 MiB block and its tag cross in one program: two one-way
     collective permutes between the prefill and the decode chip, and
-    nothing of a block's size beside what lands."""
+    nothing of a block's size beside what lands. So does the 1 MiB tensor
+    of a unary call (``link_performance_ici_hbm``), out and back."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -297,11 +304,10 @@ def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(topo)
 
     mesh = Mesh(np.asarray(topo.devices[:2]), ("link",))
     sharding = NamedSharding(mesh, P("link"))
-    words = CONFIG["block_bytes"] // 4
     halves = jax.ShapeDtypeStruct((2 * words,), jnp.uint32, sharding=sharding)
     tags = jax.ShapeDtypeStruct(
         (2, device_link.LANE_TAG_WORDS), jnp.uint32, sharding=sharding)
-    compiled = device_link.lane_program(mesh, sharding, 0).lower(halves, tags).compile()
+    compiled = device_link.lane_program(mesh, sharding, side).lower(halves, tags).compile()
     text = compiled.as_text()
     assert "jit_device_link_lane" in text
     assert text.count("collective-permute-start(") == 2
