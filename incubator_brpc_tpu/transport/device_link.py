@@ -44,15 +44,18 @@ re-thought for XLA devices:
   piggybacked imm-data acks (rdma_endpoint.h:176-195).
 - **The lane carries tensors, whole.** Beside the byte stream a
   ``ppermute`` link has a second program: a committed device array on the
-  sender's device lands on the receiver's device by one one-way
-  ``ppermute`` over the link's own mesh, and is handed to the receiver
-  **as a device array** (``lane_send``): no host copy on either side. The
-  message's **tag**, a few opaque ``uint32`` words its sender gives with
-  it, crosses in the same program beside the body and is read back from
-  the receiver's shard; the lane hands ``(tag, body)`` to the receiving
-  ``DeviceSocket`` in the order ``lane_send`` took the messages. Nothing
-  of a device message rides the byte stream (docs/DEVICE_PLANE.md, "The
-  lane").
+  sender's device lands on the receiver's device by one ``ppermute``
+  over the link's own mesh, and is handed to the receiver **as a device
+  array** (``lane_send``): no host copy on either side. The message's
+  **tag**, a few opaque ``uint32`` words its sender gives with it, crosses
+  in the same program beside the body and is read back from the receiver's
+  shard; the lane hands ``(tag, body)`` to the receiving ``DeviceSocket``
+  in the order ``lane_send`` took the messages. The program is an
+  exchange, as the trains' step is: a launch takes the head message of
+  each direction, so a request and an answer that wait together cross in
+  one program, and a message alone crosses with a placeholder in the other
+  half. Nothing of a device message rides the byte stream
+  (docs/DEVICE_PLANE.md, "The lane").
 """
 
 from __future__ import annotations
@@ -112,9 +115,9 @@ link_bytes = Adder(name="device_link_bytes")
 # payload capacity of every slot side filled: set against device_link_bytes
 # it says how full the slots travel
 link_capacity = Adder(name="device_link_capacity_bytes")
-# the lane: programs dispatched, the messages they carried (one a program
-# today; over lane_steps it is what a program that carries several would
-# save) and those messages' bytes, which never enter device_link_bytes
+# the lane: programs dispatched, the messages they carried (one, or one
+# each way: over lane_steps it is how often a pair formed) and those
+# messages' bytes, which never enter device_link_bytes
 lane_steps = Adder(name="device_link_lane_steps")
 lane_messages = Adder(name="device_link_lane_messages")
 lane_bytes = Adder(name="device_link_lane_bytes")
@@ -251,12 +254,16 @@ class _Step:
 LANE_TAG_WORDS = 64
 LANE_TAG_BYTES = LANE_TAG_WORDS * 4
 
-# A delivered lane program's row, as _hand_over_message writes it: stamps
+# A delivered lane message's row, as _hand_over_message writes it: stamps
 # (time.monotonic_ns()) in the order taken, the message's bytes, then the
-# sender's CPU clock around the launch (one program in
-# bvar.CPU_CLOCK_EVERY carries it, the others -1). ``ready`` is the body
-# seen ready on the receiver's device; ``paired`` the moment its tag was in
-# hand on the host and its turn in the lane's order had come.
+# launching thread's CPU clock around the launch (one program in
+# bvar.CPU_CLOCK_EVERY carries it, on the row of the message whose sender
+# launched; the others -1). ``taken`` is the launch taking the message off
+# its direction's queue and ``launched`` the program call's return: the two
+# messages of one program share both, whoever's thread made the call.
+# ``ready`` is the body seen ready on the receiver's device; ``paired`` the
+# moment its tag was in hand on the host and its turn in the lane's order
+# had come.
 LANE_STAMPS = (
     "seq", "taken", "launched", "ready", "paired", "queued",
     "nbytes", "taken_cpu", "launched_cpu",
@@ -368,43 +375,58 @@ def array_carrier(sock, array) -> tuple:
 
 
 class _LaneStep:
-    """One lane program's timeline and what it landed: made when
+    """One lane message's timeline and what it landed: made when
     ``lane_send`` takes the message, kept until the receiving socket was
     handed it in its turn (or the link failed)."""
 
     __slots__ = (
-        "seq", "to", "nbytes", "t_taken", "c_taken", "t_launched",
-        "c_launched", "watcher", "body", "landed_tag", "tag", "outputs",
+        "seq", "to", "nbytes", "array", "sent_tag", "parked", "rc",
+        "t_taken", "c_taken", "t_launched", "c_launched", "watcher", "body",
+        "landed_tag", "tag", "outputs",
     )
 
-    def __init__(self, seq: int, to: int, nbytes: int):
-        self.seq, self.to, self.nbytes = seq, to, nbytes
+    def __init__(self, seq: int, to: int, array, sent_tag: bytes):
+        self.seq, self.to, self.nbytes = seq, to, array.nbytes
+        # what the sender gave, until a program holds it
+        self.array, self.sent_tag = array, sent_tag
+        # its sender waits here while another thread of the link launches:
+        # set once the message has an ``rc`` (it crossed as that thread's
+        # passenger, or the link failed) or the launch is its sender's
+        self.parked: Optional[threading.Event] = None
+        self.rc: Optional[int] = None  # lane_send's code, once it has one
         self.t_taken = self.t_launched = 0
         self.c_taken = self.c_launched = RecorderFeed.MISSING
-        self.watcher = [0, 0]  # DeviceCompletionButex.watch fills these
+        # DeviceCompletionButex.watch fills these; a program's messages share it
+        self.watcher = [0, 0]
         self.body = None  # the array on the receiver's device
         self.landed_tag = None  # the tag's shard there, its host copy asked for
         self.tag = None  # its words on the host, once the body was seen ready
         self.outputs = None  # the program's whole outputs, until then
 
+    def pairs_with(self, other: "_LaneStep") -> bool:
+        """One program's two halves are one global array: the messages it
+        carries each way have one shape and dtype."""
+        mine, theirs = self.array, other.array
+        return mine.shape == theirs.shape and mine.dtype == theirs.dtype
 
-def lane_program(mesh, sharding, side: int):
+
+def lane_program(mesh, sharding):
     """The lane's program over a link's two-device ``mesh`` (``sharding``
-    cuts a first dimension in two along it), from ``side`` to the other:
-    it permutes its two operands one way, the bodies' halves and the tags'
-    rows, and returns both as they landed. Jitted here and named
+    cuts a first dimension in two along it): it exchanges its two operands,
+    the bodies' halves and the tags' rows, and returns both as they landed,
+    a device's shard what the other device sent. Jitted here and named
     ``device_link_lane``: the benchmark finds its executions in a trace by
     that name."""
     import jax
     from jax.sharding import PartitionSpec as P
 
-    one_way = [(side, 1 - side)]
+    both_ways = [(0, 1), (1, 0)]
 
     def device_link_lane(halves, tags):
         return jax.shard_map(
             lambda x, t: (
-                jax.lax.ppermute(x, "link", one_way),
-                jax.lax.ppermute(t, "link", one_way),
+                jax.lax.ppermute(x, "link", both_ways),
+                jax.lax.ppermute(t, "link", both_ways),
             ),
             mesh=mesh, in_specs=(P("link"), P("link")),
             out_specs=(P("link"), P("link")),
@@ -601,9 +623,15 @@ class DeviceLink:
         self._lanes = [
             _InOrder(self._lane_lock, self._hand_over_message) for _to in (0, 1)
         ]
-        # (side, shape, dtype) -> (program, placeholder, receiver's shard)
+        # and by the SENDING side: the messages taken that no program has
+        # yet, oldest first; a launch takes the head of each
+        self._lane_out = (deque(), deque())
+        # a thread of this link is on its way to a launch or inside one:
+        # whoever sends meanwhile parks, and is carried or handed the next
+        self._lane_launching = False
+        # (shape, dtype) -> (program, a placeholder a device, a device's shard)
         self._lane_programs: Dict[tuple, tuple] = {}
-        self._lane_inflight = 0  # programs dispatched, body not yet seen ready
+        self._lane_inflight = 0  # programs dispatched, bodies not yet seen ready
         self._launch_order = None  # the process's order of collective launches
         self._lane_feed: Optional[RecorderFeed] = None
         # a row a unary call that carried a device attachment: the caller's
@@ -797,178 +825,232 @@ class DeviceLink:
             and array.devices() == {self.devices[side]}
         )
 
-    def _lane_program(self, side: int, shape: tuple, dtype) -> tuple:
-        """The lane's program for messages of one shape and dtype from
-        ``side``: ``(program, placeholder, shard)``. It permutes two
-        operands one way. The body is one global array cut in two along
-        its first dimension, the sender's half the message as it lies, the
-        receiver's half a placeholder made here once and kept. The tags are
-        one ``(2, LANE_TAG_WORDS)`` array, a row a device (``_lane_tags``).
-        Shard ``shard`` of either output is what landed on
-        the receiver's device. Compiled and run once at the first use of a
-        shape (``warm_lane``), asked for and read back as a live message's
-        is, so that no message of live traffic compiles or pays a first
-        use."""
+    def _lane_program(self, shape: tuple, dtype) -> tuple:
+        """The lane's program for messages of one shape and dtype, either
+        way or both: ``(program, placeholders, shards)``. It exchanges two
+        operands. The body is one global array cut in two along its first
+        dimension, a device's half the message that device sends as it
+        lies, or ``placeholders[device]``, made here once and kept, where
+        it sends none. The tags are one ``(2, LANE_TAG_WORDS)`` array, a
+        row a device. Shard ``shards[side]`` of either output is what
+        landed on ``side``'s device. Compiled and run once at the first use
+        of a shape (``warm_lane``), asked for and read back as a live
+        message's is, so that no message of live traffic, alone or one of
+        a pair, compiles or pays a first use."""
         import jax
 
-        key = (side, tuple(shape), np.dtype(dtype).name)
+        key = (tuple(shape), np.dtype(dtype).name)
         found = self._lane_programs.get(key)
         if found is not None:
             return found
-        program = lane_program(self._mesh, self._sharding, side)
-        placeholder = jax.device_put(
-            np.zeros(shape, dtype=dtype), self.devices[1 - side]
-        )
-        warm = jax.device_put(np.zeros(shape, dtype=dtype), self.devices[side])
+        program = lane_program(self._mesh, self._sharding)
+        placeholders = [
+            jax.device_put(np.zeros(shape, dtype=dtype), d) for d in self.devices
+        ]
         out, tags = program(
-            self._lane_operand(side, warm, placeholder), self._lane_tags(side, b"")
+            self._lane_operand(placeholders),
+            np.zeros((2, LANE_TAG_WORDS), dtype=np.uint32),
         )
-        shard = [s.device for s in out.addressable_shards].index(
-            self.devices[1 - side]
-        )
-        landed = tags.addressable_data(shard)
-        self._request_host(landed)
-        jax.block_until_ready(out.addressable_data(shard))
-        self._tag_to_host(landed)
+        held = [s.device for s in out.addressable_shards]
+        shards = [held.index(d) for d in self.devices]
+        landed = [tags.addressable_data(shard) for shard in shards]
+        for tag in landed:
+            self._request_host(tag)
+        jax.block_until_ready(out)
+        for tag in landed:
+            self._tag_to_host(tag)
         with self._lane_lock:
             found = self._lane_programs.setdefault(
-                key, (program, placeholder, shard)
+                key, (program, placeholders, shards)
             )
         return found
 
-    def _lane_operand(self, side: int, array, placeholder):
-        """Both halves as one global array, neither copied."""
+    def _lane_operand(self, halves):
+        """Both halves, side 0's then side 1's, as one global array,
+        neither copied."""
         import jax
 
-        halves = [array, placeholder] if side == 0 else [placeholder, array]
+        first = halves[0]
         return jax.make_array_from_single_device_arrays(
-            (2 * array.shape[0],) + tuple(array.shape[1:]), self._sharding, halves
+            (2 * first.shape[0],) + tuple(first.shape[1:]), self._sharding, halves
         )
-
-    @staticmethod
-    def _lane_tags(side: int, tag: bytes) -> np.ndarray:
-        """The program's second operand for a message of ``side`` tagged
-        ``tag``: ``(2, LANE_TAG_WORDS)`` words, the sender's row the tag,
-        zero-padded. One host buffer made anew for each message, which the
-        program's ``in_shardings`` place (a train's staging: no
-        ``device_put``), and never written after it was handed over: the
-        runtime may still be reading it when the call returns."""
-        tags = np.zeros((2, LANE_TAG_WORDS), dtype=np.uint32)
-        tags[side].view(np.uint8)[: len(tag)] = np.frombuffer(tag, dtype=np.uint8)
-        return tags
 
     @staticmethod
     def _tag_to_host(landed) -> np.ndarray:
         """A landed tag's ``LANE_TAG_WORDS`` words on the host, from the
-        copy ``lane_send`` asked for at the dispatch: the receiver is
+        copy the launch asked for at the dispatch: the receiver is
         handed what crossed, not what the sender holds."""
         return np.asarray(landed).reshape(-1)
 
     def warm_lane(self, side: int, shape: tuple, dtype) -> None:
         """Compile the lane's program for messages of ``shape`` and
-        ``dtype`` sent from ``side`` and run it once, placeholder, tag and
-        all. A deployment calls this for the shapes it will send before it
-        opens a measured window; a shape never warmed compiles at its
-        first message."""
+        ``dtype`` and run it once, placeholders, tags and all. One program
+        serves both sides, a message alone and a pair, so ``side`` picks
+        nothing: warmed for one it is warm for the other. A deployment
+        calls this for the shapes it will send before it opens a measured
+        window; a shape never warmed compiles at its first message."""
         if not self.has_lane:
             raise ValueError("this link has no lane (one shared device)")
-        self._lane_program(side, shape, dtype)
+        self._lane_program(shape, dtype)
 
     def lane_send(self, side: int, array, tag) -> int:
         """Send ``array`` (``lane_accepts`` said yes) and its ``tag`` to
-        the other side, whole, by one dispatch of the lane's program on the
-        caller's thread: one call into the runtime, no host copy of the
-        body. ``tag`` is at most ``LANE_TAG_BYTES`` bytes the link does not
-        read; the receiving socket is handed them, zero-padded to
-        ``LANE_TAG_WORDS`` words, with the array on its device, after every
-        message ``lane_send`` took for that side before this one. 0;
-        ``EINVAL`` for a longer tag, with nothing taken or sent;
-        ``EFAILEDSOCKET`` on a dead link or where the dispatch raised,
-        which fails the link. The array may be dropped by the caller once
-        this returns (the program holds it) but not written or donated
-        until the message was consumed."""
+        the other side, whole, by one dispatch of the lane's program: one
+        call into the runtime, no host copy of the body. ``tag`` is at most
+        ``LANE_TAG_BYTES`` bytes the link does not read; the receiving
+        socket is handed them, zero-padded to ``LANE_TAG_WORDS`` words,
+        with the array on its device, after every message ``lane_send``
+        took for that side before this one. Returns when the program call
+        that carries the message has returned, made on this thread or, for
+        a message that crossed beside the other direction's, on that
+        one's. 0; ``EINVAL`` for a longer tag, with nothing taken or sent;
+        ``EFAILEDSOCKET`` on a dead link or where the dispatch raised (for
+        both messages of the program), which fails the link. The array may
+        be dropped by the caller once this returns (the program holds it)
+        but not written or donated until the message was consumed."""
         if len(tag) > LANE_TAG_BYTES:
             return ErrorCode.EINVAL
         to = 1 - side
+        with self._lane_lock:
+            if self._closed:
+                return ErrorCode.EFAILEDSOCKET
+            message = _LaneStep(self._lane_seq[to], to, array, bytes(tag))
+            self._lane_seq[to] += 1
+            self._lane_out[side].append(message)
+            if self._lane_launching:
+                message.parked = threading.Event()
+            self._lane_launching = True
+        if message.parked is not None:
+            # a launch of this link is under way: it takes this message
+            # with it if it is the head of its direction and fits, and
+            # whoever launches hands the next launch to a head that waits
+            message.parked.wait()
+        if message.rc is None:
+            self._lane_launch(side, message)
+        return message.rc
+
+    def _lane_launch(self, side: int, mine: _LaneStep) -> None:
+        """One program, on the thread of ``mine``'s sender, whose turn it
+        is: ``mine`` is the head of its direction. The program carries it
+        and, if the other direction's head waits and is of the same shape
+        and dtype, that one too, each in its own half with its tag in its
+        own row; a half nobody fills is the placeholder, its row zero. Both
+        get the launch's code. The next launch is handed on at the take, to
+        a head that still waits, the other direction's first: its sender
+        wakes beside this launch and stands at the order when it ends."""
         # Lane programs are launched from many threads once unary calls
         # ride the lane (callers one way, handlers' workers the other), and
         # each is a collective over both devices: the launches are ordered
         # (``collective.launch_order``), or the two devices could see two
         # of them in different orders and each wait in a permute the other
-        # has not reached. Held from the seq to the program call's return,
-        # so the order launched is the order handed over; the host work
-        # before and the watch after lie outside it.
+        # has not reached. Held from the take off the queues to the program
+        # call's return, so the order launched is the order handed over;
+        # the host work before and the watch after lie outside it.
+        carried: List[_LaneStep] = []
+        out = self._lane_out
+        following = None
         try:
             with self._launch_order:
                 with self._lane_lock:
-                    if self._closed:
-                        return ErrorCode.EFAILEDSOCKET
-                    step = _LaneStep(self._lane_seq[to], to, array.nbytes)
-                    self._lane_seq[to] += 1
-                    self._lane_inflight += 1
-                timed = step.seq % CPU_CLOCK_EVERY == 0
-                step.t_taken, step.c_taken = clocks(timed)
-                program, placeholder, shard = self._lane_program(
-                    side, array.shape, array.dtype
+                    if out[side] and out[side][0] is mine:
+                        carried.append(out[side].popleft())
+                        other = out[1 - side]
+                        if other and other[0].pairs_with(mine):
+                            carried.append(other.popleft())
+                        self._lane_inflight += 1
+                        # whose launch is next: woken now, it comes to the
+                        # order while this launch holds it
+                        waiting = out[1 - side] or out[side]
+                        following = waiting[0] if waiting else None
+                        self._lane_launching = following is not None
+                if not carried:
+                    return  # the link failed meanwhile, and mine with it
+                timed = mine.seq % CPU_CLOCK_EVERY == 0
+                t_taken, c_taken = clocks(timed)
+                if following is not None:
+                    following.parked.set()
+                program, placeholders, shards = self._lane_program(
+                    mine.array.shape, mine.array.dtype
                 )
-                out, landed = program(
-                    self._lane_operand(side, array, placeholder),
-                    self._lane_tags(side, bytes(tag)),
-                )
-            step.body = out.addressable_data(shard)
-            step.landed_tag = landed.addressable_data(shard)
-            self._request_host(step.landed_tag)
-            # the sender's shards of the outputs are nobody's, and dropping
-            # a buffer of a program still running waits the program out
-            # (0.8 ms of the writer a message on the chip; PERF.md section
-            # 6, PR 40): the completion watcher drops them, once it has
-            # handed the message over
-            step.outputs = (out, landed)
+                # one host buffer of tags made anew for each program, which
+                # its ``in_shardings`` place (a train's staging: no
+                # ``device_put``), and never written after it was handed
+                # over: the runtime may still be reading it
+                halves = list(placeholders)
+                tags = np.zeros((2, LANE_TAG_WORDS), dtype=np.uint32)
+                for message in carried:
+                    sender, tag = 1 - message.to, message.sent_tag
+                    halves[sender] = message.array
+                    tags[sender].view(np.uint8)[: len(tag)] = np.frombuffer(
+                        tag, dtype=np.uint8
+                    )
+                bodies, landed = program(self._lane_operand(halves), tags)
+            rc = 0
         except Exception:
             logger.exception("device link lane dispatch failed")
             with self._lane_lock:
                 self._lane_inflight -= 1  # never dispatched: nothing to land
-            self.fail("lane dispatch failed")
-            return ErrorCode.EFAILEDSOCKET
-        step.t_launched, step.c_launched = clocks(timed)
+            rc = ErrorCode.EFAILEDSOCKET
+        for message in carried:
+            message.array = None  # the program holds it, or nothing will
+            message.rc = rc
+        for message in carried[1:]:
+            message.parked.set()
+        if rc != 0:
+            self.fail("lane dispatch failed")  # whoever waits for a launch hears it
+            return
+        for message in carried:
+            message.body = bodies.addressable_data(shards[message.to])
+            message.landed_tag = landed.addressable_data(shards[message.to])
+            self._request_host(message.landed_tag)
+            message.watcher = mine.watcher
+            lane_bytes << message.nbytes
+        t_launched, c_launched = clocks(timed)
+        mine.c_taken, mine.c_launched = c_taken, c_launched
+        for message in carried:
+            message.t_taken, message.t_launched = t_taken, t_launched
+        # the sending halves of the outputs are nobody's, and dropping
+        # a buffer of a program still running waits the program out
+        # (0.8 ms of the writer a message on the chip; PERF.md section
+        # 6, PR 40): the completion watcher drops them, once it has
+        # handed the messages over
+        mine.outputs = (bodies, landed)
         lane_steps << 1
-        lane_messages << 1
+        lane_messages << len(carried)
         lane_tagged << 1
-        lane_bytes << step.nbytes
         self._cq.watch(
-            step.body,
-            on_complete=lambda _body, error, _step=step: (
-                self._lane_landed(_step, error)
-            ),
-            stamps=step.watcher,
+            [message.body for message in carried],
+            on_complete=lambda _bodies, error: self._lane_landed(carried, error),
+            stamps=mine.watcher,
         )
-        return 0
 
-    def _lane_landed(self, step: _LaneStep, error) -> None:
-        """Completion watcher: a lane program's body is ready on the
-        receiver's device (or failed). Read its tag from the host copy
-        asked for at the dispatch and hand both over when the message's
-        turn comes."""
+    def _lane_landed(self, carried: List[_LaneStep], error) -> None:
+        """Completion watcher: a lane program's bodies are ready on their
+        receivers' devices (or failed). Read each message's tag from the
+        host copy asked for at the dispatch and hand it over, on its own
+        side, when its turn there comes."""
         # the program's whole outputs die with this call, on this thread
         # and after the hand-over: dropping them costs 0.7 ms on the chip
-        # even now (PERF.md section 6, PR 40), and the message does not
+        # even now (PERF.md section 6, PR 40), and the messages do not
         # wait for it
-        outputs, step.outputs = step.outputs, None  # noqa: F841
+        outputs, carried[0].outputs = carried[0].outputs, None  # noqa: F841
         if error is None:
             try:
-                step.tag = self._tag_to_host(step.landed_tag)
+                for step in carried:
+                    step.tag = self._tag_to_host(step.landed_tag)
             except Exception as e:  # noqa: BLE001 — a device failure is data here
                 error = e
-        lane = self._lanes[step.to]
         with self._lane_lock:
             self._lane_inflight -= 1
             if error is None:
-                lane.land(step.seq, 1, step)
+                for step in carried:
+                    self._lanes[step.to].land(step.seq, 1, step)
         if error is not None:
             logger.error("device link lane program failed: %s", error)
             self.fail(f"lane program failed: {error}")
             return
-        lane.drain()
+        for step in carried:
+            self._lanes[step.to].drain()
 
     def _hand_over_message(self, seq: int, step: _LaneStep) -> None:
         """The lane's in-order hand-over: a landed message to the socket
@@ -1424,6 +1506,14 @@ class DeviceLink:
         with self._lane_lock:
             for lane in self._lanes:
                 lane.clear()
+            # the messages no program has yet: none will, and their senders
+            # (one of them may be on its way to the launch) hear it
+            for queue in self._lane_out:
+                while queue:
+                    message = queue.popleft()
+                    message.array, message.rc = None, ErrorCode.EFAILEDSOCKET
+                    if message.parked is not None:
+                        message.parked.set()
         link_errors << 1
         self._retire_metrics()
         # party-death feedback for the collective fault plane: a session

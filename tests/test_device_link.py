@@ -1831,9 +1831,10 @@ class TestTrainStagedOnce:
 
 class TestLane:
     """The link's second program (PR 39). A committed array on the sender's
-    device lands on the receiver's by one one-way ``ppermute`` and is
-    handed over as a device array, its tag's words beside it from the same
-    program (PR 40); the byte stream beside it is as it was."""
+    device lands on the receiver's by one ``ppermute`` and is handed over
+    as a device array, its tag's words beside it from the same program
+    (PR 40); the byte stream beside it is as it was. The program is an
+    exchange (PR 45): a launch takes the head message of each direction."""
 
     SLOT_WORDS = TestSlotTrains.SLOT_WORDS
     _make_link = TestSlotTrains._make_link
@@ -1937,7 +1938,8 @@ class TestLane:
         release, inner = threading.Event(), getattr(link, hook)
 
         def late(first, *rest):
-            if getattr(first, "seq", first) == 0:
+            head = first[0] if isinstance(first, list) else first  # a program's messages
+            if getattr(head, "seq", head) == 0:
                 assert release.wait(30)
             return inner(first, *rest)
 
@@ -2048,15 +2050,15 @@ class TestLane:
         link, socks, sinks = self._make_link("ppermute")
         block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
         link.warm_lane(0, block.shape, block.dtype)
-        key = (0, (64,), "uint32")
-        program, placeholder, shard = link._lane_programs[key]
+        key = ((64,), "uint32")
+        program, placeholders, shards = link._lane_programs[key]
         operands = []
 
         def seen(halves, tags):
             operands.append(tags)
             return program(halves, tags)
 
-        link._lane_programs[key] = (seen, placeholder, shard)
+        link._lane_programs[key] = (seen, placeholders, shards)
         got = self._receive(socks[1])
         sent = [b"one"] * 3 + [b"two", b"one"]
         for tag in sent:
@@ -2080,9 +2082,9 @@ class TestLane:
         link, socks, sinks = self._make_link("ppermute")
         block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
         link.warm_lane(0, block.shape, block.dtype)
-        program, placeholder, _shard = link._lane_programs[(0, (64,), "uint32")]
+        program, placeholders, _shards = link._lane_programs[((64,), "uint32")]
         lowered = program.lower(
-            link._lane_operand(0, block, placeholder),
+            link._lane_operand([block, placeholders[1]]),
             np.zeros((2, dl.LANE_TAG_WORDS), np.uint32))
         # benchmark/roofline_lane.py finds the program's executions by it
         assert "jit_device_link_lane" in lowered.as_text()
@@ -2120,13 +2122,13 @@ class TestLane:
         link, socks, sinks = self._make_link("ppermute")
         block = jax.device_put(np.arange(64, dtype=np.uint32), link.devices[0])
         link.warm_lane(0, block.shape, block.dtype)
-        key = (0, (64,), "uint32")
-        _program, placeholder, shard = link._lane_programs[key]
+        key = ((64,), "uint32")
+        _program, placeholders, shards = link._lane_programs[key]
 
         def raising(*_operands):
             raise RuntimeError("injected lane fault")
 
-        link._lane_programs[key] = (raising, placeholder, shard)
+        link._lane_programs[key] = (raising, placeholders, shards)
         got = self._receive(socks[1])
         assert link.lane_send(0, block, b"tag") == ErrorCode.EFAILEDSOCKET
         assert link._closed and link._lane_inflight == 0
@@ -2139,6 +2141,196 @@ class TestLane:
         started = time.monotonic()
         dl._quiesce_links(timeout=5.0)
         assert time.monotonic() - started < 1.0
+
+    # -- the exchange (PR 45): a launch takes the head of each direction ------
+
+    @staticmethod
+    def _sent_with_the_order_held(link, sends):
+        """``lane_send(*send)`` for each of ``sends`` on a thread of its
+        own, started in that order while the test holds the process's launch
+        order, each on its direction's queue before the next starts: what
+        the first launch finds waiting is all of them. Their codes."""
+        from incubator_brpc_tpu.parallel.collective import launch_order
+
+        codes, threads = [None] * len(sends), []
+
+        def run(i, send):
+            codes[i] = link.lane_send(*send)
+
+        with launch_order:
+            for i, send in enumerate(sends):
+                threads.append(threading.Thread(target=run, args=(i, send), daemon=True))
+                threads[-1].start()
+                assert _wait(lambda: sum(map(len, link._lane_out)) == i + 1, timeout=30)
+        for thread in threads:
+            thread.join(30)
+            assert not thread.is_alive()
+        return codes
+
+    # (side, shape, dtype) a send, in the order queued; the programs they ride
+    EXCHANGES = {
+        "a_request_and_an_answer_ride_one_program": (
+            [(0, (64,), "uint32"), (1, (64,), "uint32")], 1),
+        "led_by_the_other_side": ([(1, (64,), "uint32"), (0, (64,), "uint32")], 1),
+        "rows_of_more_than_one_dimension": (
+            [(0, (3, 50), "uint32"), (1, (3, 50), "uint32")], 1),
+        "different_shapes_ride_alone": ([(0, (64,), "uint32"), (1, (32,), "uint32")], 2),
+        "different_dtypes_ride_alone": ([(0, (64,), "uint32"), (1, (64,), "float32")], 2),
+        "one_direction_only_rides_alone": ([(0, (64,), "uint32"), (0, (64,), "uint32")], 2),
+        "two_each_way_ride_two": ([(0, (64,), "uint32")] * 2 + [(1, (64,), "uint32")] * 2, 2),
+        "three_against_one_ride_three": (
+            [(1, (64,), "uint32")] * 3 + [(0, (64,), "uint32")], 3),
+    }
+
+    @pytest.mark.parametrize("case", list(EXCHANGES))
+    def test_a_launch_takes_the_head_of_each_direction(self, case):
+        """With the order held by the test: what waits each way of one
+        shape and dtype crosses in one program, each side handed its own
+        tags and bodies in the order sent; anything else rides alone. A
+        row a message, a program's two rows sharing ``taken`` and
+        ``launched``."""
+        import jax
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        spec, programs = self.EXCHANGES[case]
+        link, socks, sinks = self._make_link("ppermute")
+        rng = np.random.default_rng(45)
+        sends, sent = [], ([], [])
+        for i, (side, shape, dtype) in enumerate(spec):
+            link.warm_lane(side, shape, dtype)
+            data = rng.integers(0, 2**31, size=shape).astype(dtype)
+            tag = b"message %d from side %d" % (i, side)
+            sends.append((side, jax.device_put(data, link.devices[side]), tag))
+            sent[1 - side].append((self._padded(tag), data))
+        got = [self._receive(sock) for sock in socks]
+        counted = ("lane_steps", "lane_messages", "lane_tagged", "lane_bytes")
+        before = {a: getattr(dl, a).get_value() for a in counted}
+        assert self._sent_with_the_order_held(link, sends) == [0] * len(sends)
+        assert _wait(lambda: [len(g) for g in got] == [len(x) for x in sent], timeout=30)
+        for side in (0, 1):
+            assert [tag for tag, _ in got[side]] == [tag for tag, _ in sent[side]]
+            for (_tag, landed), (_sent_tag, data) in zip(got[side], sent[side]):
+                assert isinstance(landed, jax.Array)
+                assert landed.devices() == {link.devices[side]}
+                assert (landed.shape, landed.dtype) == (data.shape, data.dtype)
+                assert np.array_equal(np.asarray(landed), data)
+        gained = {a: getattr(dl, a).get_value() - v for a, v in before.items()}
+        assert gained == {
+            "lane_steps": programs, "lane_messages": len(sends),
+            "lane_tagged": programs,
+            "lane_bytes": sum(array.nbytes for _side, array, _tag in sends)}
+        assert _wait(lambda: not link.busy)
+        assert not any(link._lane_out) and not link._lane_launching
+        link._lane_feed.flush()
+        stamps, kept = link._lane_feed.timeline()
+        at = {s: i for i, s in enumerate(stamps)}
+        assert len(kept) == len(sends)  # a row a message
+        launches = {(row[at["taken"]], row[at["launched"]]) for row in kept}
+        assert len(launches) == programs
+        for row in kept:
+            order = [row[at[s]] for s in ("taken", "launched", "ready", "paired", "queued")]
+            assert order == sorted(order)
+
+    def test_eight_threads_four_a_direction_keep_each_directions_order(self):
+        """Senders of both directions meet at the launch as they come: every
+        message is handed over once, on its own side, in the order
+        ``lane_send`` took it, and no sender is left parked."""
+        import jax
+        import numpy as np
+
+        from incubator_brpc_tpu.transport import device_link as dl
+
+        link, socks, sinks = self._make_link("ppermute")
+        link.warm_lane(0, (64,), np.uint32)
+        got = [self._receive(sock) for sock in socks]
+        handed, hand_over = ([], []), link._hand_over_message
+
+        def seen(seq, step):
+            handed[step.to].append(seq)
+            return hand_over(seq, step)
+
+        link._hand_over_message = seen
+        for lane in link._lanes:
+            lane._hand_over = seen
+        each, codes = 6, []
+        before = {a: getattr(dl, a).get_value() for a in ("lane_steps", "lane_messages")}
+
+        def sender(side, thread):
+            for i in range(each):
+                block = jax.device_put(
+                    np.full(64, 1000 * thread + i, np.uint32), link.devices[side])
+                codes.append(link.lane_send(side, block, b"%d %d" % (thread, i)))
+
+        threads = [
+            threading.Thread(target=sender, args=(t % 2, t), daemon=True) for t in range(8)
+        ]
+        for thread in threads:
+            thread.start()
+        for thread in threads:
+            thread.join(60)
+            assert not thread.is_alive()
+        assert codes == [0] * (8 * each)
+        assert _wait(lambda: [len(g) for g in got] == [4 * each] * 2, timeout=60)
+        assert handed == (list(range(4 * each)), list(range(4 * each)))
+        for side in (0, 1):
+            last = {}
+            for tag, body in got[side]:
+                thread, i = map(int, tag.rstrip(b"\0").split())
+                assert thread % 2 == 1 - side and i == last.get(thread, -1) + 1
+                last[thread] = i
+                assert body.devices() == {link.devices[side]}
+                assert np.asarray(body)[0] == 1000 * thread + i
+        gained = {a: getattr(dl, a).get_value() - v for a, v in before.items()}
+        assert gained["lane_messages"] == 8 * each
+        assert 4 * each <= gained["lane_steps"] <= 8 * each
+        assert _wait(lambda: not link.busy)
+        assert not any(link._lane_out) and not link._lane_launching
+
+    def test_a_program_that_raises_fails_both_its_messages_and_whoever_waits(self):
+        import jax
+        import numpy as np
+
+        link, socks, sinks = self._make_link("ppermute")
+        link.warm_lane(0, (64,), np.uint32)
+        key = ((64,), "uint32")
+        _program, placeholders, shards = link._lane_programs[key]
+
+        def raising(*_operands):
+            raise RuntimeError("injected lane fault")
+
+        link._lane_programs[key] = (raising, placeholders, shards)
+        got = [self._receive(sock) for sock in socks]
+        blocks = [jax.device_put(np.arange(64, dtype=np.uint32), d) for d in link.devices]
+        # the first launch carries the heads; the third message waits behind it
+        codes = self._sent_with_the_order_held(link, [
+            (0, blocks[0], b"request"), (1, blocks[1], b"answer"),
+            (0, blocks[0], b"the next request")])
+        assert codes == [ErrorCode.EFAILEDSOCKET] * 3
+        assert link._closed and link._lane_inflight == 0 and not any(link._lane_out)
+        assert all(s.state != 0 for s in socks) and got == [[], []]
+        assert link.lane_send(1, blocks[1], b"tag") == ErrorCode.EFAILEDSOCKET
+
+    def test_warm_lane_leaves_nothing_to_compile_paired_or_alone(self):
+        import jax
+        import numpy as np
+
+        with _backend_compiles() as compiles:
+            link, socks, sinks = self._make_link("ppermute")
+            link.warm_lane(0, (64,), np.uint32)
+            built, programs = len(compiles), dict(link._lane_programs)
+            assert list(programs) == [((64,), "uint32")]  # one program, either way
+            link.warm_lane(1, (64,), np.uint32)
+            got = [self._receive(sock) for sock in socks]
+            blocks = [
+                jax.device_put(np.arange(64, dtype=np.uint32), d) for d in link.devices]
+            for side in (0, 1):
+                assert link.lane_send(side, blocks[side], b"alone") == 0
+            assert self._sent_with_the_order_held(
+                link, [(0, blocks[0], b"paired"), (1, blocks[1], b"paired")]) == [0, 0]
+            assert _wait(lambda: [len(g) for g in got] == [2, 2], timeout=30)
+            assert len(compiles) == built and link._lane_programs == programs
 
     @pytest.mark.parametrize("geometry", ["ppermute", "device-swap"])
     def test_a_train_whose_dispatch_raises_gives_its_slots_back(self, geometry):

@@ -285,16 +285,19 @@ def test_the_read_compiles_without_a_second_pool(decode_chip):
 
 
 @pytest.mark.parametrize(
-    "words,side",
-    [(CONFIG["block_bytes"] // 4, 0), (262144, 0), (262144, 1)],
-    ids=["a-2MiB-block", "a-1MiB-tensor-out", "a-1MiB-tensor-back"],
+    "shape,dtype",
+    [((CONFIG["block_bytes"] // 4,), "uint32"), ((262144,), "uint32"),
+     ((512, 8, 128, 2), "bfloat16")],
+    ids=["a-2MiB-block", "a-1MiB-tensor", "a-2MiB-block-as-the-model-shapes-it"],
 )
 def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(
-        topo, words, side):
-    """A 2 MiB block and its tag cross in one program: two one-way
-    collective permutes between the prefill and the decode chip, and
-    nothing of a block's size beside what lands. So does the 1 MiB tensor
-    of a unary call (``link_performance_ici_hbm``), out and back."""
+        topo, shape, dtype):
+    """A 2 MiB block and its tag cross in one program: two collective
+    permutes between the prefill and the decode chip, each an exchange (a
+    message each way, or a placeholder back), and nothing of a block's
+    size beside what lands. So does the 1 MiB tensor of a unary call
+    (``link_performance_ici_hbm``), a request out and an answer back in
+    the one program, and a block of more than one dimension."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -304,14 +307,17 @@ def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(
 
     mesh = Mesh(np.asarray(topo.devices[:2]), ("link",))
     sharding = NamedSharding(mesh, P("link"))
-    halves = jax.ShapeDtypeStruct((2 * words,), jnp.uint32, sharding=sharding)
+    nbytes = int(np.prod(shape)) * jnp.dtype(dtype).itemsize
+    halves = jax.ShapeDtypeStruct(
+        (2 * shape[0],) + shape[1:], jnp.dtype(dtype), sharding=sharding)
     tags = jax.ShapeDtypeStruct(
         (2, device_link.LANE_TAG_WORDS), jnp.uint32, sharding=sharding)
-    compiled = device_link.lane_program(mesh, sharding, side).lower(halves, tags).compile()
+    compiled = device_link.lane_program(mesh, sharding).lower(halves, tags).compile()
     text = compiled.as_text()
     assert "jit_device_link_lane" in text
     assert text.count("collective-permute-start(") == 2
+    assert text.count("source_target_pairs={{0,1},{1,0}}") >= 2
     memory = compiled.memory_analysis()
     # the block and the tag's row, which the chip pads to a tile
-    assert 4 * words < memory.output_size_in_bytes <= 4 * words + 4096
+    assert nbytes < memory.output_size_in_bytes <= nbytes + 4096
     assert memory.temp_size_in_bytes < 1 << 20

@@ -309,9 +309,9 @@ def test_a_lane_program_that_raises_fails_both_halves(pair):
     def raising(*_operands):
         raise RuntimeError("injected lane fault")
 
-    key = (0, (WORDS,), "uint32")
-    _program, placeholder, shard = link._lane_programs[key]
-    link._lane_programs[key] = (raising, placeholder, shard)
+    key = ((WORDS,), "uint32")
+    _program, placeholders, shards = link._lane_programs[key]
+    link._lane_programs[key] = (raising, placeholders, shards)
     t0 = time.monotonic()
     rc = p.stream.write(p.block(2)[0], timeout=30)
     assert rc not in (0, ErrorCode.EAGAIN, ErrorCode.EOVERCROWDED)

@@ -216,6 +216,59 @@ def test_four_callers_on_one_connection_get_their_own_answers_out_of_order(echo)
 
 
 @limited(120)
+def test_requests_and_answers_of_overlapping_calls_share_lane_programs(echo):
+    """Four callers on one connection: a request of one call and an answer
+    of another that wait at the launch together cross in one program (the
+    test holds the launch order once, so that they do), and every caller
+    still gets the tensor it sent."""
+    from incubator_brpc_tpu.parallel.collective import launch_order
+
+    kept = threading.Event()
+
+    def answer(cntl, request):
+        if (request[0], request[1]) == (0, 0):  # caller 0's first answer waits
+            assert kept.wait(60)
+        return cntl.request_attachment
+
+    e = echo(answer=answer)
+    for side in (0, 1):
+        e.link.warm_lane(side, (WORDS,), np.uint32)
+    calls, errors = 6, []
+    before = (dl.lane_messages.get_value(), dl.lane_steps.get_value())
+
+    def caller(who):
+        try:
+            for call in range(calls):
+                tensor, data = e.tensor(who, call)
+                cntl = e.call(bytes([who, call]), attachment=tensor)
+                assert cntl.ok(), cntl.error_text
+                assert cntl.response_payload == bytes([who, call])
+                answered = cntl.response_attachment
+                assert answered.devices() == {e.link.devices[0]}
+                assert np.array_equal(np.asarray(answered), data)
+        except BaseException as err:  # noqa: BLE001 — reported below
+            errors.append(repr(err))
+
+    threads = [threading.Thread(target=caller, args=(who,)) for who in range(4)]
+    threads[0].start()
+    assert wait(lambda: len(e.saw) == 2)  # the handshake's call and caller 0's first
+    out = e.link._lane_out
+    with launch_order:
+        for t in threads[1:]:
+            t.start()
+        assert wait(lambda: len(out[0]) == 3)  # three requests wait for the launch
+        kept.set()
+        assert wait(lambda: len(out[1]) == 1)  # and caller 0's answer beside them
+    for t in threads:
+        t.join(120)
+    assert not any(t.is_alive() for t in threads) and not errors, errors
+    messages = dl.lane_messages.get_value() - before[0]
+    programs = dl.lane_steps.get_value() - before[1]
+    assert messages == 2 * 4 * calls and programs < messages
+    assert nothing_left(e.link) and not any(out) and not e.link._lane_launching
+
+
+@limited(120)
 @pytest.mark.parametrize("request_is", ["array", "bytes"])
 def test_bytes_one_way_and_an_array_the_other(echo, request_is):
     import jax
