@@ -93,6 +93,8 @@ class _ComboVars:
             call=(("call", "end"),),
         )
         self.fused = Adder(name=f"{prefix}_fused")
+        # fused calls whose answer was one join of the gathered rows
+        self.joined = Adder(name=f"{prefix}_joined")
         self.host_fanout = Adder(name=f"{prefix}_host_fanout")
         self.mc_lowered = Adder(name=f"{prefix}_mc_lowered")
         self.rows = Adder(name=f"{prefix}_rows")  # sub-requests a fused program ran
@@ -111,7 +113,7 @@ class _FusedCall:
 
     __slots__ = (
         "service", "method", "lowering", "devices", "nbytes", "stamps", "cpu",
-        "timed",
+        "timed", "joined",
     )
 
     def __init__(self, service: str, method: str, t_call: int):
@@ -119,6 +121,7 @@ class _FusedCall:
         self.lowering = ""
         self.devices: list = []
         self.nbytes = 0
+        self.joined = False  # the answer was one join (merge_responses)
         self.stamps = [t_call]
         self.cpu: list = []
         self.timed = next(_fused_calls) % CPU_CLOCK_EVERY == 0
@@ -143,6 +146,8 @@ class _FusedCall:
             row = (*self.stamps, end, *self.cpu)
             COMBO_VARS.calls.rows.append(row)
             COMBO_VARS.fused << 1
+            if self.joined:
+                COMBO_VARS.joined << 1
             COMBO_VARS.rows << len(self.devices)
             COMBO_VARS.bytes << self.nbytes
         else:
@@ -202,10 +207,35 @@ class CallMapper:
 
 class ResponseMerger:
     """Incremental merge in channel-index order (parallel_channel.h:103).
-    Default: concatenate payload bytes."""
+    Default: concatenate payload bytes.
+
+    A subclass's ``merge`` (or one set on an instance) is called once a
+    successful sub-call, in channel order, with ``bytes``. Where every
+    merger of a call is this class's own, ``merge`` is not called: the
+    answers are joined at once (``merge_responses``), which gives the same
+    bytes and copies each once."""
 
     def merge(self, merged: bytes, sub_response: bytes) -> bytes:
         return merged + sub_response
+
+
+def merge_responses(mergers, answers) -> Tuple[bytes, bool]:
+    """The merged answer of one call, every lowering's: ``mergers`` and
+    their sub-calls' ``answers`` in channel order. Where every merger's
+    ``merge`` is ``ResponseMerger.merge`` itself, one join writes each
+    answer byte once (an answer may be any buffer, a view of the gathered
+    rows too); else each merger is called in turn with ``bytes``. Returns
+    the bytes and whether they were joined."""
+    if all(
+        getattr(m.merge, "__func__", None) is ResponseMerger.merge for m in mergers
+    ):
+        return b"".join(answers), True
+    merged = b""
+    for merger, answer in zip(mergers, answers):
+        merged = merger.merge(
+            merged, answer if isinstance(answer, bytes) else bytes(answer)
+        )
+    return merged, False
 
 
 class ParallelChannel:
@@ -225,12 +255,16 @@ class ParallelChannel:
     method plane instead: a 1-step N-party session of the same kernel,
     scheduled over the host plane (parallel/mc_dispatch.py) — one API,
     the transport picks the lowering. Every path runs the same jitted
-    kernel over the same "par" axis, so fused, mc-lowered and host
-    fan-out produce byte-identical merged responses. A precondition that
-    does not hold chooses the host path — a choice from observed
-    geometry; once the device path is chosen, a failure of its program
-    fails the call with the program's text (a fan-out that quietly
-    succeeded instead would hide a device plane that cannot run)."""
+    kernel over the same "par" axis and merges through one function
+    (``merge_responses``: a merger's ``merge`` is called once a successful
+    sub-call, in channel order, with ``bytes``; where every merger is the
+    default ``ResponseMerger`` the answers are joined at once instead), so
+    fused, mc-lowered and host fan-out produce byte-identical merged
+    responses. A precondition that does not hold chooses the host path —
+    a choice from observed geometry; once the device path is chosen, a
+    failure of its program fails the call with the program's text (a
+    fan-out that quietly succeeded instead would hide a device plane that
+    cannot run)."""
 
     def __init__(self, fail_limit: int = -1, fuse_device_calls: bool = True):
         self.fail_limit = fail_limit
@@ -345,16 +379,17 @@ class ParallelChannel:
                     f"(fail_limit={fail_limit}): {text}",
                 )
             else:
-                merged, attached = b"", []
-                for i, p in enumerate(plan):
-                    if p is None:
-                        continue
-                    sc = sub_cntls[i]
-                    if sc is not None and sc.ok():
-                        merged = p[1].merge(merged, sc.response_payload)
-                        attached.append(sc.response_attachment)
-                cntl.response_payload = merged
-                cntl.response_attachment = b"".join(attached)
+                served = [
+                    (p[1], sc) for p, sc in zip(plan, sub_cntls)
+                    if p is not None and sc is not None and sc.ok()
+                ]
+                cntl.response_payload, _ = merge_responses(
+                    [merger for merger, _sc in served],
+                    [sc.response_payload for _merger, sc in served],
+                )
+                cntl.response_attachment = b"".join(
+                    sc.response_attachment for _merger, sc in served
+                )
             all_done.set()
             if done is not None:
                 done(cntl)
@@ -427,6 +462,7 @@ class ParallelChannel:
         full = f"{service}.{method}"
         fp = dm.fingerprint()
         subs = [(i, p) for i, p in enumerate(plan) if p is not None]
+        mergers = [merger for _i, (_ch, merger, _sub) in subs]
         requests: List[bytes] = []
         devices = []
         probed: List[tuple] = []  # (channel, device socket) picks to settle
@@ -507,10 +543,7 @@ class ParallelChannel:
             for pch, pds in probed:
                 if pch._lb is not None:
                     pch._lb.feedback(pds, latency_us, 0)
-            merged = b""
-            for pos, (_i, (ch, merger, _sub)) in enumerate(subs):
-                merged = merger.merge(merged, outs[pos])
-            return merged
+            return merge_responses(mergers, outs)[0]
         call.lowering = "fused"
         call.stamp()  # pack
         try:
@@ -524,11 +557,11 @@ class ParallelChannel:
         for pch, pds in probed:
             if pch._lb is not None:
                 pch._lb.feedback(pds, latency_us, 0)
-        # merge in channel-index order with each sub's merger — the exact
-        # host-path semantics, so the merged bytes are identical
-        merged = b""
-        for pos, (_i, (ch, merger, _sub)) in enumerate(subs):
-            merged = merger.merge(merged, dm.unpack(rows_out[pos], ns_out[pos]))
+        # each partition's answer is a view of its gathered row: the default
+        # mergers' join is the one copy, a user's merger gets bytes of it
+        merged, call.joined = merge_responses(
+            mergers, [row[:n] for row, n in zip(rows_out, ns_out.tolist())]
+        )
         call.stamp()  # the merge is over
         return merged
 
@@ -592,8 +625,9 @@ class ParallelChannel:
             call.stamp()  # launch: the program call until it returned
             g, gm = fused(data, ns_sharded)
         call.stamp()  # gather: the gathered rows on the host
-        out = np.asarray(g), np.asarray(gm)
-        call.stamp()  # merge: LB feedback, unpack and the mergers
+        # uint8 rows, as DeviceMethod.unpack reads a row
+        out = np.asarray(g, dtype=np.uint8), np.asarray(gm)
+        call.stamp()  # merge: LB feedback, then the answers' one join or the mergers
         return out
 
 

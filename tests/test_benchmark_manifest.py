@@ -140,6 +140,7 @@ COMBO = {
     **{f"device_link_combo_{s}_cpu_us": recorder(200, COMBO_STAGES[s] / 4)
        for s in ("pack", "put", "launch", "gather", "merge")},
     "device_link_combo_fused": 200,
+    "device_link_combo_joined": 150,  # PR 47: 50 calls ran a user's merger
     "device_link_combo_host_fanout": 40,
     "device_link_combo_mc_lowered": 10,
     "device_link_combo_rows": 600,
@@ -245,6 +246,7 @@ EXPECTED = {
     **{f"combo_{s}_us": (COMBO, us) for s, us in COMBO_STAGES.items()},
     "combo_unattributed_pct": (COMBO, 10.0),
     "combo_fused_pct": (COMBO, 80.0),
+    "combo_joined_pct": (COMBO, 75.0),  # PR 47: of the fused calls
     # PR 35: the second clock
     **{f"device_{s}_cpu_us": (DEVICE, us) for s, us in DEVICE_CPU.items()},
     "link_launch_cpu_us": (LINK, 700.0),
@@ -508,18 +510,24 @@ def test_the_new_entries_only_follow_the_old():
          "moves": "latency_p50_us", "workloads": CQ_CELLS},
     ]
     assert set(CELLS) - set(CQ_CELLS) == {"partition_star_4"}
-    # PR 44's eleven follow them, the last, each in its one cell
-    assert names[91:] == [
+    # PR 44's eleven follow them, each in its one cell
+    assert names[91:102] == [
         "unary_request_tx_us", "unary_server_dispatch_us", "unary_reply_tx_us",
         "unary_client_wake_us", "unary_call_us", "unary_unattributed_pct",
         "unary_device_calls_pct", "unary_lane_launch_us", "unary_lane_ready_us",
         "unary_lane_pair_wait_us", "unary_lane_deliver_us"]
     assert all(
         (m["workloads"], m["source"]) == ([HBM_CELL], "program_counter")
-        for m in BENCH["per_layer"][91:])
-    assert [m["layer"] for m in BENCH["per_layer"][91:]] == [
+        for m in BENCH["per_layer"][91:102])
+    assert [m["layer"] for m in BENCH["per_layer"][91:102]] == [
         "link", "host plane", "link", "host plane", "host plane", "host plane",
         "host plane", "link", "link", "link", "link"]
+    # PR 47's one entry follows them, the last, in the star's cell
+    assert BENCH["per_layer"][102:] == [{
+        "name": "combo_joined_pct", "unit": "%", "better": "higher",
+        "source": "program_counter", "layer": "combo", "moves": "goodput",
+        "workloads": ["partition_star_4"],
+    }]
     for entry, source, layer, moves in zip(
             BENCH["per_layer"][73:76],
             ("device_trace", "device_trace", "program_counter"),
@@ -773,6 +781,30 @@ def test_combo_shares_need_every_recorder_and_a_call():
     idle = dict(COMBO, device_link_combo_fused=0, device_link_combo_host_fanout=0,
                 device_link_combo_mc_lowered=0)
     assert fused(hand_made_run(idle)) is None
+
+
+@pytest.mark.parametrize(
+    "joined,fused,share",
+    [
+        (200, 200, 100.0),  # every merger of every call the default one
+        (0, 200, 0.0),  # a user's merger ran in every call: a reading
+        (None, 200, None),  # a program without the adder (the parent)
+        (0, 0, None),  # a window without a fused call
+        (None, None, None),  # a program from before PR 33
+    ],
+    ids=["every-call", "no-call-joined", "no-adder", "no-fused-call", "no-combo"],
+)
+def test_joined_share_counts_the_fused_calls_joined_once(joined, fused, share):
+    read = manifest.load_module("layers", "combo_joined_pct.py").read
+    counters = {
+        k: v for k, v in COMBO.items()
+        if k not in ("device_link_combo_joined", "device_link_combo_fused")}
+    if joined is not None:
+        counters["device_link_combo_joined"] = joined
+    if fused is not None:
+        counters["device_link_combo_fused"] = fused
+    value = read(hand_made_run(counters))
+    assert value is None if share is None else value == pytest.approx(share)
 
 
 def test_window_share_needs_the_configuration_to_state_a_window():

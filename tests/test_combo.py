@@ -144,6 +144,125 @@ class TestParallelChannel:
         assert out["payload"] == b"s0:as1:as2:a"
 
 
+# -- the one merge of every lowering (rpc/combo.py merge_responses) ----------
+
+
+class Recording(ResponseMerger):
+    """A subclass that merges as the default does and notes every call."""
+
+    def __init__(self, log, who):
+        self.log, self.who = log, who
+
+    def merge(self, merged, sub_response):
+        self.log.append((self.who, type(merged), type(sub_response), sub_response))
+        return merged + sub_response
+
+
+def attribute_set(log, who):
+    """A plain ``ResponseMerger`` whose instance carries a ``merge`` of its
+    own: a user's merger, not the class's function."""
+    merger, noting = ResponseMerger(), Recording(log, who)
+    merger.merge = lambda merged, sub: noting.merge(merged, sub)
+    return merger
+
+
+MERGER_KINDS = {
+    "default_mergers": lambda log: [ResponseMerger() for _ in range(3)],
+    "one_subclass_among_defaults": lambda log: [
+        ResponseMerger(), Recording(log, 1), ResponseMerger()],
+    "all_subclasses": lambda log: [Recording(log, i) for i in range(3)],
+    "merge_set_on_an_instance": lambda log: [
+        ResponseMerger(), ResponseMerger(), attribute_set(log, 2)],
+}
+# which partitions' mergers note their calls, and whether the answer is joined
+MERGER_NOTES = {
+    "default_mergers": ([], True),
+    "one_subclass_among_defaults": ([1], False),
+    "all_subclasses": ([0, 1, 2], False),
+    "merge_set_on_an_instance": ([2], False),
+}
+WIDTH = 64
+ANSWER_ROWS = {
+    "full_rows": [WIDTH, WIDTH, WIDTH],
+    "a_short_row": [WIDTH, 17, WIDTH],
+    "an_empty_last_row": [WIDTH, WIDTH, 0],
+    "every_n_under_the_width": [WIDTH - 9, 1, WIDTH - 1],
+}
+
+
+def incremental(answers):
+    """What the default mergers gave before the join: ``merged + answer``."""
+    merged = b""
+    for answer in answers:
+        merged = merged + answer
+    return merged
+
+
+@pytest.mark.parametrize("handed", ["bytes", "views_of_gathered_rows"])
+@pytest.mark.parametrize("rows", list(ANSWER_ROWS))
+@pytest.mark.parametrize("kind", list(MERGER_KINDS))
+def test_merge_responses_joins_once_or_calls_each_merger_in_order(kind, rows, handed):
+    import numpy as np
+
+    from incubator_brpc_tpu.rpc.combo import merge_responses
+
+    gathered = np.frombuffer(
+        np.random.default_rng(7).bytes(3 * WIDTH), dtype=np.uint8).reshape(3, WIDTH)
+    ns = ANSWER_ROWS[rows]
+    plain = [bytes(row[:n]) for row, n in zip(gathered, ns)]
+    answers = plain if handed == "bytes" else [
+        row[:n] for row, n in zip(gathered, ns)]
+    log = []
+    merged, joined = merge_responses(MERGER_KINDS[kind](log), answers)
+    assert type(merged) is bytes
+    assert merged == incremental(plain) == b"".join(plain)
+    noting, joins = MERGER_NOTES[kind]
+    assert joined is joins
+    # a user's merger: once a partition, in channel order, bytes both ways
+    assert [who for who, *_ in log] == noting
+    assert all(a is bytes and b is bytes for _who, a, b, _sub in log)
+    assert [sub for *_, sub in log] == [plain[who] for who in noting]
+
+
+def test_merge_responses_keeps_what_a_merger_makes_of_the_answers():
+    from incubator_brpc_tpu.rpc.combo import merge_responses
+
+    class Framed(ResponseMerger):
+        def merge(self, merged, sub_response):
+            return merged + b"[" + sub_response + b"]"
+
+    merged, joined = merge_responses(
+        [ResponseMerger(), Framed(), ResponseMerger()], [b"a", b"", b"c"])
+    assert (merged, joined) == (b"a[]c", False)
+    assert merge_responses([], []) == (b"", True)
+
+
+@pytest.mark.parametrize("kind", list(MERGER_KINDS))
+def test_the_host_fan_out_merges_through_the_same_function(kind, three_servers):
+    """Channel order whatever order the sub-calls end in, a failed sub-call
+    left out, and a user's merger called with ``bytes`` for the others."""
+    log = []
+    mergers = MERGER_KINDS[kind](log)
+
+    class FailFirst(CallMapper):
+        def map(self, i, n, service, method, request):
+            return SubCall(method="fail" if i == 0 else "echo")
+
+    for mapper, want, left_out in (
+            (CallMapper(), [b"s0:hi", b"s1:hi", b"s2:hi"], []),
+            (FailFirst(), [None, b"s1:hi", b"s2:hi"], [0])):
+        del log[:]
+        pc = ParallelChannel()
+        for s, merger in zip(three_servers, mergers):
+            pc.add_channel(sub_channel(s), call_mapper=mapper, response_merger=merger)
+        cntl = pc.call_method("svc", "echo", b"hi")
+        assert cntl.ok(), cntl.error_text
+        assert cntl.response_payload == b"".join(w for w in want if w is not None)
+        noting = [who for who in MERGER_NOTES[kind][0] if who not in left_out]
+        assert [(who, sub) for who, _a, _b, sub in log] == [
+            (who, want[who]) for who in noting]
+
+
 class TestSelectiveChannel:
     def test_round_robins_across_channels(self, three_servers):
         sc = SelectiveChannel()

@@ -104,6 +104,68 @@ def test_fused_host_fanout_and_reference_give_the_same_bytes(size):
         deployment.close()
 
 
+# -- PR 47: the answers joined once, or the mergers called in turn ------------
+# three mergers of a call, the partitions whose merger notes its calls, and
+# whether a fused call's answer is one join (tests/test_combo.py has the cases
+# on ``merge_responses`` alone)
+from test_combo import MERGER_KINDS, MERGER_NOTES, incremental  # noqa: E402
+
+# the request's size and the rows the mapper cuts it into: ``ROW`` is the
+# kernel's width, so a cut of ROW - 9 leaves every ``n`` under it
+MERGED_ROWS = {
+    "full_rows": (3 * ROW, ROW),
+    "a_short_row": (2 * ROW + 17, ROW),
+    "an_empty_last_row": (2 * ROW, ROW),
+    "every_n_under_the_width": (3 * (ROW - 9), ROW - 9),
+}
+
+
+@pytest.mark.parametrize("rows", list(MERGED_ROWS))
+@pytest.mark.parametrize("kind", list(MERGER_KINDS))
+@limited(120)
+def test_the_answer_is_one_join_or_the_mergers_called_in_turn(kind, rows):
+    """Fused and fanned out over the host: the default mergers' answer equals
+    the incremental merge's and the plain reference's; a user's merger (a
+    subclass, or ``merge`` set on an instance) is called once a partition, in
+    channel order, with ``bytes``, and then the default ones are too; the
+    adder counts the fused calls that were joined."""
+    from incubator_brpc_tpu.rpc import CallMapper, SubCall
+
+    size, cut = MERGED_ROWS[rows]
+    noting, joins = MERGER_NOTES[kind]
+
+    class Cut(CallMapper):
+        def map(self, i, n, service, method, request):
+            return SubCall(request=request[i * cut:(i + 1) * cut])
+
+    _, deployment = deploy()
+    try:
+        channel = deployment.channel()
+        log = []
+        channel._subs = [
+            (sub, Cut(), merger)
+            for (sub, _mapper, _merger), merger in zip(
+                channel._subs, MERGER_KINDS[kind](log))]
+        request = payload(size, size)
+        slices = REFERENCE.slices(request, cut, 3)
+        want = incremental(slices)
+        assert want == REFERENCE.merged(request, cut, 3) == request
+        for fuse in (True, False):
+            del log[:]
+            channel.fuse_device_calls = fuse
+            before = combo_vars()
+            cntl = call(channel, request)
+            after = combo_vars()
+            assert getattr(cntl, "collective_fused", False) is fuse
+            assert type(cntl.response_payload) is bytes
+            assert cntl.response_payload == want, (kind, rows, fuse)
+            assert log == [(who, bytes, bytes, slices[who]) for who in noting]
+            assert gained(before, after, "fused") == int(fuse)
+            assert gained(before, after, "joined") == int(fuse and joins)
+    finally:
+        deployment.close()
+
+
 def test_the_reference_refuses_what_does_not_fit_its_rows():
     assert REFERENCE.expected(b"a", b"b") == (b"a", b"b")
     assert (REFERENCE.PARTITIONS, REFERENCE.ROW_BYTES) == (
@@ -414,6 +476,7 @@ def test_stage_recorders_add_up_to_the_call_and_adders_count_exactly():
         covered = sum(s["sum"] for s in stages)
         assert 0.6 * calls["sum"] <= covered <= calls["sum"]
         assert gained(before, after, "fused") == len(sizes)
+        assert gained(before, after, "joined") == len(sizes)  # the default merger
         assert gained(before, after, "rows") == 3 * len(sizes)
         assert gained(before, after, "bytes") == sum(sizes)
         assert gained(before, after, "host_fanout") == 0
@@ -460,7 +523,10 @@ def test_a_control_comes_out_mismatched(control):
         statuses = [send(payload(seed, 3 * ROW))[1] for seed in (41, 42)]
         # warm() made the call a first stale answer is taken from
         assert statuses == [generator.MISMATCH, generator.MISMATCH]
-        assert gained(before, combo_vars(), "fused") == 2  # broken under the fused path
+        after = combo_vars()
+        assert gained(before, after, "fused") == 2  # broken under the fused path
+        # a control's merger is a subclass and always runs; flip_bit keeps the default
+        assert gained(before, after, "joined") == (2 if control == "flip_bit" else 0)
     finally:
         deployment.close()
 
