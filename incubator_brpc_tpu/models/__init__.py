@@ -10,11 +10,17 @@ workloads:
   calls, behind the same ``DeviceEndpoint`` (YCSB's ``usertable``; a
   parameter or embedding shard): reads gather, updates change the state the
   next dispatch reads.
+- ``expert_shard``: one rank of an expert-parallel unit behind the same
+  ``DeviceEndpoint``: DeepSeek-V3's routed experts, weights in HBM that a
+  step reads and never replaces, the tokens a caller's router sent here
+  served whatever their split over the experts; the one service whose step
+  is bound on the device (a Pallas kernel over the weights where they lie).
 - ``fabricnet``: the flagship multi-chip workload — a sharded MoE/pipeline
   network whose forward/backward exercises every combo-channel lowering
   (dp fan-out, tp partition, pp pipeline stream, sp ring, ep all_to_all).
 """
 
+from incubator_brpc_tpu.models.expert_shard import ExpertShardService
 from incubator_brpc_tpu.models.record_table import RecordTableService
 from incubator_brpc_tpu.models.tensor_echo import TensorEchoService, make_echo_step
 from incubator_brpc_tpu.models.fabricnet import (
@@ -25,6 +31,7 @@ from incubator_brpc_tpu.models.fabricnet import (
 )
 
 __all__ = [
+    "ExpertShardService",
     "RecordTableService",
     "TensorEchoService",
     "make_echo_step",

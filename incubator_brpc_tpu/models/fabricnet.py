@@ -157,6 +157,13 @@ def _moe(moe_w1, moe_w2, gate_w, x):
     by token index) are exchanged with a tiled all_to_all, processed by the
     rank-local experts, and exchanged back (all_to_all is an involution for
     equal tiles).
+
+    Data-dependent routing at a published model's widths lives in
+    ``models/expert_shard`` (one rank's share of DeepSeek-V3's routed
+    experts: any split of a block's tokens over the experts held, no
+    capacity, none dropped) with its reference, the published router
+    included, in ``benchmark/references/moe_expert_share.py``; the exchange
+    between ranks is still this one.
     """
     ep = lax.axis_size("ep")
     e_local = moe_w1.shape[0]

@@ -25,7 +25,10 @@ A ``DeviceEndpoint`` is the RdmaEndpoint re-thought for XLA:
 - a service may keep state in HBM between calls (models/record_table):
   the endpoint owns it, hands it to every dispatch, donated, and takes
   the next one back; dispatches launch in the order they took it, and a
-  dispatch sees its batch whole (docs/DEVICE_PLANE.md has the contract).
+  dispatch sees its batch whole (docs/DEVICE_PLANE.md has the contract);
+- or state that a step reads and never replaces (models/expert_shard: a
+  rank's expert weights): nothing is donated, no dispatch waits its turn
+  and a program that raises loses nothing.
 
 ``DeviceEndpoint.call_bytes`` adapts the host byte world: payloads are
 padded into the bucket and responses cut at the length the service says
@@ -43,7 +46,6 @@ from __future__ import annotations
 import itertools
 import threading
 from collections import deque
-from contextlib import nullcontext
 from functools import partial
 from typing import Optional, Tuple
 
@@ -364,10 +366,11 @@ _LOST = object()
 
 class _StepProgram:
     """The service's jitted step as the endpoint runs it: ``program(rows,
-    cids, mids) -> response frames``, the service's state taken from the
-    endpoint in turn, donated to the step, and the next one put back
-    before the turn passes on. ``dispatch`` gets the moment the state was
-    in hand."""
+    cids, mids) -> response frames``. State that the step replaces is
+    taken from the endpoint in turn, donated to the step, and the next one
+    put back before the turn passes on; state that it only reads (or none)
+    is handed to every call as it lies, side by side. ``dispatch`` gets
+    the moment the state was in hand."""
 
     __slots__ = ("_endpoint", "_jitted")
 
@@ -376,6 +379,10 @@ class _StepProgram:
 
     def __call__(self, rows, cids, mids, dispatch: Optional["_Dispatch"] = None):
         ep = self._endpoint
+        if ep._state_turn is None:  # nothing to take in turn, nothing to lose
+            if dispatch is not None:
+                dispatch.t_state = _time.monotonic_ns()
+            return self._jitted(ep._state, rows, cids, mids)[1]
         with ep._state_turn:
             if dispatch is not None:
                 dispatch.t_state = _time.monotonic_ns()
@@ -385,7 +392,7 @@ class _StepProgram:
                     "the endpoint lost its service's state: an earlier "
                     "dispatch raised with the state donated to it"
                 )
-            ep._state = ep._state_lost  # until the step hands the next one back
+            ep._state = _LOST  # until the step hands the next one back
             ep._state, frames = self._jitted(state, rows, cids, mids)
         return frames
 
@@ -397,11 +404,12 @@ class _StepProgram:
 class DeviceEndpoint:
     """One device-resident service behind a credit window.
 
-    The service (``models/tensor_echo``, ``models/record_table``) gives
-    ``init_state(device)``: what it keeps in HBM between calls, or
-    ``None``; ``dispatch_step(state, rows, cids, mids) -> (state',
-    response frames)``, jittable, over a whole batch of zero-padded
-    payload rows; ``answer_bytes(method_id, request_bytes)``: how long
+    The service (``models/tensor_echo``, ``models/record_table``,
+    ``models/expert_shard``) gives ``init_state(device)``: what it keeps
+    in HBM between calls, or ``None``; ``dispatch_step(state, rows, cids,
+    mids) -> (state', response frames)``, jittable, over a whole batch of
+    zero-padded payload rows, ``state'`` ``None`` where the step only
+    reads its state; ``answer_bytes(method_id, request_bytes)``: how long
     that method's answer to such a request is; and ``account(mids,
     frames)``, called on the host with a completed dispatch's method ids
     and response frames (its counters). A call's bucket is the larger of
@@ -419,7 +427,10 @@ class DeviceEndpoint:
     among themselves. A program call that raises with the state in its
     hand fails the endpoint: the state may be gone, every later dispatch
     fails, none is answered from a copy. A service that keeps nothing has
-    no turn to wait for and nothing to lose.
+    no turn to wait for and nothing to lose, and neither has one whose
+    step hands no state back (a rank's expert weights): the endpoint
+    learns that from the step's abstract result when it is built, donates
+    nothing, and every dispatch reads the state where it lies.
 
     A dispatch launches with one call of the jitted step program on the
     stacked host rows: the call stages its numpy arguments itself, onto
@@ -456,13 +467,13 @@ class DeviceEndpoint:
         self._draining = False
         self._dispatch_seq = itertools.count(1)
         # what the service keeps on the device between dispatches (None:
-        # nothing), whose turn it is to hold it, and what is left once a
-        # program call raised with it: a service without state takes no
-        # turn (its dispatches launch side by side) and loses nothing
+        # nothing) and whose turn it is to hold it. A service without
+        # state, or whose step hands none back (it only reads what it
+        # keeps), takes no turn: its dispatches launch side by side,
+        # nothing is donated and a program call that raises loses nothing
         self._state = self.service.init_state(self.device)
-        stateless = self._state is None
-        self._state_turn = nullcontext() if stateless else threading.Lock()
-        self._state_lost = None if stateless else _LOST
+        replaced = self._state is not None and self._step_replaces_state()
+        self._state_turn = threading.Lock() if replaced else None
         # the service's step over (state, stacked rows, cids, mids), the
         # state donated; jit's per-shape cache gives one compiled program
         # per (batch, bucket) geometry — the fixed-block discipline. One
@@ -482,16 +493,27 @@ class DeviceEndpoint:
             state, frames = step_batch(state, padded[None], cid_lo[None], mid[None])
             return state, frames[0]
 
+        donated = (0,) if replaced else ()
         self._program = _StepProgram(
-            self, jax.jit(step_row, in_shardings=on_device, donate_argnums=0))
+            self, jax.jit(step_row, in_shardings=on_device, donate_argnums=donated))
         self._batch_program = _StepProgram(
-            self, jax.jit(step_batch, in_shardings=on_device, donate_argnums=0))
+            self, jax.jit(step_batch, in_shardings=on_device, donate_argnums=donated))
+
+    def _step_replaces_state(self) -> bool:
+        """Whether the service's step hands a next state back, from its
+        abstract result on one row of the narrowest bucket: nothing runs."""
+        ids = jax.ShapeDtypeStruct((1,), np.uint32)
+        rows = jax.ShapeDtypeStruct((1, MIN_BUCKET_WORDS), np.uint32)
+        state, _frames = jax.eval_shape(
+            self.service.dispatch_step, self._state, rows, ids, ids)
+        return state is not None
 
     def _lose_state(self) -> None:
         """A dispatch failed once its program held the state: what the
         endpoint holds now was computed from it."""
-        with self._state_turn:
-            self._state = self._state_lost
+        if self._state_turn is not None:
+            with self._state_turn:
+                self._state = _LOST
 
     # -- credit window (rdma_endpoint.h:176-195) ----------------------------
 

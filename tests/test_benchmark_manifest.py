@@ -26,6 +26,7 @@ with open(os.path.join(ROOT, "BENCHMARK.json"), encoding="utf-8") as _f:
 CELLS = [w["name"] for w in BENCH["workloads"]]
 KV_CELL = "kv_blocks_ici_32m_c1"  # PR 39's: device blocks over the lane
 HBM_CELL = "link_echo_ici_hbm_1m_c4"  # PR 44's: a unary call carries a tensor
+EXPERT_CELL = "expert_ffn_ep32_n256_c16"  # PR 48's: a step bound on the device
 # the three readers of the lane that go by adders and the trace, not by the
 # link with most trains: PR 44's cell, whose windows need no train, joined them
 LANE_BY_ADDERS = ("lane_messages_per_step", "lane_step_ici_pct", "lane_tagged_pct")
@@ -183,7 +184,7 @@ CQ = {
 CQ_CELLS = [
     "echo_256b_c16", "echo_4m_c2", "link_echo_ici_1m", "echo_mixed_c16",
     "echo_256b_c16_native", "link_stream_ici", "ycsb_b_zipf_c16", KV_CELL,
-    HBM_CELL,
+    HBM_CELL, EXPERT_CELL,
 ]
 # PR 44's unary calls over a window, on the busiest link by such calls (3;
 # no train crossed, so no step_rtt names it): 400 calls of 10,000 us whose
@@ -367,8 +368,9 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
             # only the native plane feeds it
             assert cells[name] == ["echo_256b_c16_native"]
         elif name == "table_state_wait_us":
-            # only the record table keeps a state to wait for
-            assert cells[name] == ["ycsb_b_zipf_c16"]
+            # the record table keeps a state to wait for; the expert shard
+            # keeps one that no dispatch waits for, and reports that
+            assert cells[name] == ["ycsb_b_zipf_c16", EXPERT_CELL]
         elif name in ("dispatch_pad_pct", "dispatch_widened_pct"):
             assert {"echo_256b_c16", "echo_mixed_c16"} <= set(cells[name])
             assert "echo_4m_c2" not in cells[name]
@@ -522,12 +524,19 @@ def test_the_new_entries_only_follow_the_old():
     assert [m["layer"] for m in BENCH["per_layer"][91:102]] == [
         "link", "host plane", "link", "host plane", "host plane", "host plane",
         "host plane", "link", "link", "link", "link"]
-    # PR 47's one entry follows them, the last, in the star's cell
-    assert BENCH["per_layer"][102:] == [{
+    # PR 47's one entry follows them, in the star's cell
+    assert BENCH["per_layer"][102] == {
         "name": "combo_joined_pct", "unit": "%", "better": "higher",
         "source": "program_counter", "layer": "combo", "moves": "goodput",
         "workloads": ["partition_star_4"],
-    }]
+    }
+    # PR 48's five follow it, the last, in the expert shard's cell
+    assert names[103:] == [
+        "expert_step_kernel_us", "expert_step_hbm_pct", "expert_step_mxu_pct",
+        "expert_layers_per_dispatch", "expert_tokens_per_dispatch"]
+    assert all(
+        (m["workloads"], m["layer"]) == ([EXPERT_CELL], "device program")
+        for m in BENCH["per_layer"][103:])
     for entry, source, layer, moves in zip(
             BENCH["per_layer"][73:76],
             ("device_trace", "device_trace", "program_counter"),
@@ -536,15 +545,21 @@ def test_the_new_entries_only_follow_the_old():
             ("latency_p50_us", "call_rate", "latency_p50_us")):
         assert (entry["source"], entry["layer"], entry["moves"]) == (
             source, layer, moves)
-        assert entry["workloads"] == ["ycsb_b_zipf_c16"]
+        # the table's step is the table's alone; the wait for the state is
+        # also reported where a state is kept and never waited for (PR 48)
+        assert entry["workloads"] == ["ycsb_b_zipf_c16"] + (
+            [EXPERT_CELL] if entry["name"] == "table_state_wait_us" else [])
     assert [c["name"] for c in BENCH["configs"]][5:] == [
-        "ycsb_b_device_table", "kv_block_stream_ici", "link_performance_ici_hbm"]
-    assert CELLS[7:] == ["ycsb_b_zipf_c16", KV_CELL, HBM_CELL]
-    assert [w["chips"] for w in BENCH["workloads"][7:]] == [1, 4, 4]
+        "ycsb_b_device_table", "kv_block_stream_ici", "link_performance_ici_hbm",
+        "expert_shard_dsv3_ep32"]
+    assert CELLS[7:] == ["ycsb_b_zipf_c16", KV_CELL, HBM_CELL, EXPERT_CELL]
+    assert [w["chips"] for w in BENCH["workloads"][7:]] == [1, 4, 4, 1]
     for m in BENCH["end_to_end"] + BENCH["per_layer"][:91]:
         # an older metric gained a cell's name at the end of its list or not
-        # at all: PR 44's last, PR 39's before it
+        # at all: PR 48's last, PR 44's before it, PR 39's before that
         workloads = list(m.get("workloads", ()))
+        if EXPERT_CELL in workloads:
+            assert workloads.pop() == EXPERT_CELL, m["name"]
         if HBM_CELL in workloads:
             assert workloads.pop() == HBM_CELL, m["name"]
         listed = [w for w in workloads if w != KV_CELL]

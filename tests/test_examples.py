@@ -31,6 +31,7 @@ EXAMPLES = [
     "examples/asynchronous_echo.py",
     "examples/ubrpc_compack.py",
     "examples/nshead_extension.py",
+    "examples/expert_shard.py",
 ]
 
 

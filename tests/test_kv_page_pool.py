@@ -321,3 +321,43 @@ def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(
     # the block and the tag's row, which the chip pads to a tile
     assert nbytes < memory.output_size_in_bytes <= nbytes + 4096
     assert memory.temp_size_in_bytes < 1 << 20
+
+
+# -- the expert shard's step at the cell's size (PR 48; here because the chip's
+# compiler is described once a process, by this file's fixture) ------------------
+
+
+@pytest.mark.parametrize("batch", [1, 4])
+def test_the_expert_step_compiles_on_the_weights_where_they_lie(topo, batch):
+    """``expert_shard_dsv3_ep32`` at its published widths: 8,455,716,864 B
+    of weights are the program's arguments, its Pallas kernel takes them as
+    they are, and beside them it holds the rows, the frames and the
+    kernel's scratch: no copy of a layer (705 MB) or of an expert (88 MB),
+    which is what a ``dynamic_slice`` of the stacked weights compiled to."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from incubator_brpc_tpu.models.expert_shard import ExpertShardService
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    service = ExpertShardService(7168, 2048, 8, 12)
+    service.interpret = False  # the kernel itself, for the chip's compiler
+
+    def on_chip(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    state = (on_chip(12, 8, 7168, 2048), on_chip(12, 8, 7168, 2048),
+             on_chip(12, 8, 2048, 7168))
+    assert sum(2 * 12 * 8 * 7168 * 2048 for _ in state) == service.weight_bytes
+    rows = on_chip(batch, 262144, dtype=jnp.uint32)
+    ids = on_chip(batch, dtype=jnp.uint32)
+    compiled = jax.jit(
+        lambda s, r, c, m: service.step(s, r, c, m)[1]
+    ).lower(state, rows, ids, ids).compile()
+    assert "expert_ffn" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= service.weight_bytes
+    # the frames, each padded to the chip's tile
+    assert 0 <= memory.output_size_in_bytes - batch * 4 * (262144 + 8) <= batch * 512
+    assert memory.temp_size_in_bytes < 64 << 20
