@@ -634,15 +634,36 @@ def _dir(server, frame) -> Resp:
 def _threads(server, frame) -> Resp:
     """threads_service.cpp (pstack): a live stack dump of every thread —
     worker fibers, reactors, CQ watchers, timer thread — straight from the
-    interpreter (sys._current_frames), no external pstack needed."""
+    interpreter (sys._current_frames), no external pstack needed. Above
+    the stacks, where the process's processors went (bvar/processors.py):
+    a row a thread name with its tasks' time on a processor and runnable
+    but waiting for one, since each began; the same two above each stack."""
     import sys
     import threading as _threading
     import traceback
 
-    names = {t.ident: t.name for t in _threading.enumerate()}
+    from incubator_brpc_tpu.bvar import processors
+
+    threads = {t.ident: t for t in _threading.enumerate()}
+    reading = processors.TABLE.read()
+    tasks = reading.tasks if reading is not None else {}
     lines = []
+    if reading is not None:
+        lines.append(f"{'thread':<32}{'tasks':>6}{'cpu_s':>12}{'runq_s':>12}")
+        by_name = sorted(reading.by_name().items(), key=lambda row: -row[1][0])
+        for name, (cpu_ns, runq_ns, n) in by_name:
+            lines.append(f"{name:<32}{n:>6}{cpu_ns / 1e9:>12.3f}{runq_ns / 1e9:>12.3f}")
+        lines.append("")
     for tid, frm in sorted(sys._current_frames().items()):
-        lines.append(f"-- thread {names.get(tid, '?')} (tid={tid}) --")
+        thread = threads.get(tid)
+        task = tasks.get(thread.native_id) if thread is not None else None
+        times = (
+            f" cpu={task.cpu_ns / 1e9:.3f}s runq={(task.runq_ns or 0) / 1e9:.3f}s"
+            if task is not None else ""
+        )
+        lines.append(
+            f"-- thread {thread.name if thread is not None else '?'} (tid={tid}){times} --"
+        )
         lines.extend(
             ln.rstrip("\n") for ln in traceback.format_stack(frm)
         )

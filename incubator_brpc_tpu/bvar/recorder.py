@@ -231,10 +231,16 @@ class RecorderFeed:
                         int(values.max()) * scale,
                         (values[::16] * scale).tolist(),
                     )
+            self._fed(table)
             if self.ring is not None:
                 self.ring.extend(table)
 
     _take_sample = flush  # what the sampler thread calls
+
+    def _fed(self, table: np.ndarray) -> None:
+        """The rows a flush has just fed, for a feed that counts beside
+        its recorders (bvar/lock_probe.py's adders)."""
+
 
     def timeline(self):
         """``(stamps, rows)``: what waits is fed, then the ring's rows as

@@ -4,7 +4,8 @@ A timeline row is a tuple of ``time.monotonic_ns()`` stamps written by
 the threads that do the work. Where one thread both begins and ends a
 stage it reads its own CPU clock beside the wall clock (``clocks``): the
 stage's wall time less its CPU time is the time that thread was off the
-processor, waiting for the interpreter, for the runtime, or parked.
+processor, waiting for the interpreter (bvar/lock_probe.py is the number
+for that wait), for the runtime, or parked.
 ``Ring`` keeps the last rows the sampler fed, as numbers, for a reader
 that lines them up with a device trace (docs/OBSERVABILITY.md).
 """

@@ -40,10 +40,14 @@ class _SamplerThread:
     def register(self, sampler: "Window") -> None:
         with self._lock:
             self._samplers.append(weakref.ref(sampler))
-            if not self._started:
-                self._started = True
-                t = threading.Thread(target=self._run, name="bvar_sampler", daemon=True)
-                t.start()
+            first, self._started = not self._started, True
+        if first:
+            threading.Thread(target=self._run, name="bvar_sampler", daemon=True).start()
+            # a process that measures itself measures its interpreter lock;
+            # outside the lock above: the probe's recorders register too
+            from incubator_brpc_tpu.bvar import lock_probe
+
+            lock_probe.start()
 
     def _run(self) -> None:
         while True:

@@ -203,6 +203,11 @@ SIGNATURES = {
     "tb_fast_rand": (ctypes.c_uint64, []),
     "tb_fast_rand_less_than": (ctypes.c_uint64, [ctypes.c_uint64]),
     "tb_monotonic_ns": (ctypes.c_uint64, []),
+    "tb_sleep_until_ns": (ctypes.c_uint64, [ctypes.c_uint64]),
+    "tb_task_times": (
+        ctypes.c_long,
+        [ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64), ctypes.c_long],
+    ),
     "tb_respool_create": (b, [ctypes.c_size_t]),
     "tb_respool_destroy": (None, [b]),
     "tb_respool_get": (b, [b, ctypes.POINTER(ctypes.c_uint64)]),

@@ -170,7 +170,7 @@ atexit.register(_quiesce_links)
 
 
 # A delivered step's row, as _hand_over_train writes it: the train's first
-# seq, its stamps in the order written (time.monotonic_ns()), three counts,
+# seq, its stamps in the order written (time.monotonic_ns()), two counts,
 # then the CPU clock (time.thread_time_ns()) of the thread that wrote the
 # stamp, where a stage begins and ends on it. A stage is the difference of
 # the stamps beside it (us), a count is fed as it stands; launch, ready,
@@ -181,7 +181,7 @@ STEP_STAMPS = (
     "previous_dispatch",  # the drive's dispatch before this one; -1 = none
     "held_since",  # the drive began to hold the train back, else = dispatch
     "dispatch", "launched", "ready", "deliver", "host", "delivered",
-    "inflight", "backlog_slots", "credit",
+    "inflight", "backlog_slots",
     "dispatch_cpu", "launched_cpu",  # the drive's thread
     "deliver_cpu", "host_cpu", "delivered_cpu",  # the in-order deliverer's
 )
@@ -197,7 +197,6 @@ STEP_COLUMNS = (
     ("hold", 1e-3, ("held_since", "dispatch")),
     ("inflight", 1, "inflight"),
     ("backlog", 1, "backlog_slots"),
-    ("credit", 1, "credit"),
     # one thread begins and ends these: its CPU clock beside the wall clock
     ("launch_cpu", 1e-3, ("dispatch_cpu", "launched_cpu")),
     ("readback_cpu", 1e-3, ("deliver_cpu", "host_cpu")),
@@ -206,7 +205,7 @@ STEP_COLUMNS = (
 # a recorder _m_<this> is exposed as device_link_<n>_<this>_us, but for
 STEP_EXPOSED = {
     "rtt": "step_rtt_us", "inflight": "inflight_at_dispatch",
-    "backlog": "backlog_slots_at_dispatch", "credit": "credit_at_dispatch",
+    "backlog": "backlog_slots_at_dispatch",
 }
 
 
@@ -216,21 +215,21 @@ class _Step:
     (``bvar.clocks``: ``t_*`` wall, ``c_*`` that thread's CPU)."""
 
     __slots__ = (
-        "t_dispatch", "c_dispatch", "t_previous", "inflight", "seen", "t_held",
+        "t_dispatch", "c_dispatch", "t_previous", "inflight", "backlog", "t_held",
         "t_launched", "c_launched", "watcher",
     )
 
     def __init__(
         self, t_dispatch: int, c_dispatch: int, t_previous: int, inflight: int,
-        seen: tuple, t_held: int,
+        backlog: int, t_held: int,
     ):
         self.t_dispatch = t_dispatch  # slots filled, seq taken
         self.c_dispatch = c_dispatch  # -1: this step is not timed on the CPU clock
         # the drive's previous dispatch; none: never taken
         self.t_previous = t_previous or RecorderFeed.MISSING
         self.inflight = inflight  # undrained slots, this train's included
-        # (backlog slots, free credit) the train's length was taken from
-        self.seen = seen
+        # slots the fuller side's backlog would fill when the train was cut
+        self.backlog = backlog
         # the drive's first look that found less credit than the train its
         # backlog wanted needs; a train never held: its dispatch
         self.t_held = t_held or t_dispatch
@@ -1171,24 +1170,23 @@ class DeviceLink:
         Both are one where a step goes out with no data or no credit
         (close-only, wire-mode catch-up), and on the host swap, which
         dispatches no program a train could save. Returns the two lengths
-        and the ``(backlog slots, free credit)`` they were taken from, for
-        the train's timeline."""
+        and the backlog's slots they were taken from, for the train's
+        timeline."""
         backlog = -(-max(self._out_nbytes) // self._slot_bytes)
-        credit = self._credit_locked()
         if self._step is None:
-            return 1, 1, (backlog, credit)
-        admitted = max(1, min(backlog, credit))
+            return 1, 1, backlog
+        admitted = max(1, min(backlog, self._credit_locked()))
         wanted = max(1, min(backlog, self.window))
         return (
             1 << (admitted.bit_length() - 1),
             1 << (wanted.bit_length() - 1),
-            (backlog, credit),
+            backlog,
         )
 
-    def _take_seq_locked(self, k: int, seen: tuple) -> tuple:
+    def _take_seq_locked(self, k: int, backlog: int) -> tuple:
         """Under the link lock, a train of ``k`` slots a side filled: take
-        its seqs, count its slots in flight and start its timeline. ``seen``
-        is what ``_train_len_locked`` took ``k`` from."""
+        its seqs, count its slots in flight and start its timeline.
+        ``backlog`` is what ``_train_len_locked`` took ``k`` from."""
         seq = self._seq
         self._seq += k
         self._inflight += k
@@ -1196,7 +1194,7 @@ class DeviceLink:
         self._steps_taken += 1
         last, self._last_dispatch_ns = self._last_dispatch_ns, now
         held, self._held_since_ns = self._held_since_ns, 0
-        return seq, _Step(now, now_cpu, last, self._inflight, seen, held)
+        return seq, _Step(now, now_cpu, last, self._inflight, backlog, held)
 
     def _drive(self) -> None:
         while True:
@@ -1236,7 +1234,7 @@ class DeviceLink:
                         # window has no later completion to wake for
                         need = self._wbutex.load()
                 if need is None:
-                    k, wanted, seen = self._train_len_locked()
+                    k, wanted, backlog = self._train_len_locked()
                     if k < wanted and self._inflight and not ack_only:
                         # the backlog would fill a longer train than the
                         # free credit admits, and slots are still out: a
@@ -1256,7 +1254,7 @@ class DeviceLink:
                         both = np.empty((2, k, self._width), dtype=np.uint32)
                         for side in (0, 1):
                             self._fill_train_locked(side, k, both[side])
-                        seq, step = self._take_seq_locked(k, seen)
+                        seq, step = self._take_seq_locked(k, backlog)
             if need is not None:
                 self._wbutex.wait(need, timeout=1.0)
                 continue
@@ -1415,7 +1413,7 @@ class DeviceLink:
                 seq, step.t_previous, step.t_held,
                 step.t_dispatch, step.t_launched, step.watcher[1],
                 begin[0], host[0], end[0],
-                step.inflight, *step.seen,
+                step.inflight, step.backlog,
                 step.c_dispatch, step.c_launched, begin[1], host[1], end[1],
             ))
 

@@ -270,10 +270,8 @@ class MultiControllerLink(DeviceLink):
                         # the step's timeline feeds the per-link
                         # recorders exactly like the base _drive's; this
                         # link never cuts a train (_train_len_locked), so it
-                        # records neither of what one is cut from
-                        seq, step = self._take_seq_locked(
-                            1, (RecorderFeed.MISSING,) * 2
-                        )
+                        # never records what one is cut from
+                        seq, step = self._take_seq_locked(1, RecorderFeed.MISSING)
             if finish:
                 self._finish_close()
                 return

@@ -154,6 +154,18 @@ uint64_t tb_fast_rand_less_than(uint64_t bound);
 // monotonic ns (CLOCK_MONOTONIC; the cpuwide_time analog).
 uint64_t tb_monotonic_ns(void);
 
+// ---- the server process: the lock probe's sleep, the processors by task ----
+// Sleep until `due_ns` on CLOCK_MONOTONIC (an absolute time; one that has
+// passed returns at once) and return tb_monotonic_ns() as it reads then:
+// stamped before the caller queues for any lock on its way back.
+uint64_t tb_sleep_until_ns(uint64_t due_ns);
+// One row (tid, on-processor ns, run-queue wait ns) into `out` for each task
+// under `task_dir` (/proc/self/task), from <tid>/schedstat; where the kernel
+// keeps none, from <tid>/stat's utime + stime, and the run-queue wait is -1.
+// A task that ended between the listing and the read gives no row. Returns
+// the rows there were (`out` holds the first `cap`), -1 with no such directory.
+long tb_task_times(const char* task_dir, int64_t* out, long cap);
+
 // ---- ResourcePool: versioned-id slab, never frees (ABA-safe ids) ----
 typedef struct tb_respool tb_respool;
 tb_respool* tb_respool_create(size_t item_size);

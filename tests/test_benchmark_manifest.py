@@ -218,7 +218,26 @@ HELD_FRAMES = {
     "device_transport_frames_held": 300,
     "device_transport_frames_released": 100,
 }
+# PR 51: the lock probe's 2,000 ticks of a window, 1,800 of which found the
+# lock taken and 10 of which waited the switch interval out, one stall of
+# 150 ms; 30 + 10 CPU seconds by thread
+LOCK = {
+    "device_transport_lock_wait_us": recorder(2000, 900.0),
+    "device_transport_machine_late_us": recorder(2000, 60.0),
+    "device_transport_lock_probes": 2000,
+    "device_transport_lock_busy": 1800,
+    "device_transport_lock_forced": 10,
+    "device_transport_lock_stall_us": 150_000,
+    "device_transport_cpu_python_threads_us": 30e6,
+    "device_transport_cpu_other_threads_us": 10e6,
+}
+LOCK_READERS = {
+    "lock_wait_us": 900.0, "lock_busy_pct": 90.0, "lock_forced_pct": 0.5,
+    "machine_late_us": 60.0, "stall_ms": 150.0,
+    "host_cpu_python_threads_cores": 1.5, "host_cpu_other_threads_cores": 0.5,
+}
 EXPECTED = {
+    **{metric: (LOCK, value) for metric, value in LOCK_READERS.items()},
     "host_plane_held_frames_pct": (HELD_FRAMES, 75.0),
     "host_plane_held_frames_pct.goodput": (HELD_FRAMES, 75.0),
     **{f"device_{s}_us": (DEVICE, 100.0) for s in DEVICE_STAGES},
@@ -563,6 +582,12 @@ def test_the_new_entries_only_follow_the_old():
             for m in BENCH["per_layer"][108:110]] == [
         ("call_rate", "host plane", "program_counter", "%", "higher"),
         ("goodput", "host plane", "program_counter", "%", "higher")]
+    # PR 51's seven follow them, the last: the server process in every cell
+    assert names[110:117] == list(LOCK_READERS)
+    assert all(
+        (m["layer"], m["moves"], m["source"], m["better"], m["workloads"])
+        == ("server process", "latency_p50_us", "program_counter", "lower", CELLS)
+        for m in BENCH["per_layer"][110:117])
     egress = next(m for m in BENCH["per_layer"] if m["name"] == "host_plane_egress_us")
     assert sorted(
         BENCH["per_layer"][108]["workloads"] + BENCH["per_layer"][109]["workloads"]
@@ -1148,3 +1173,109 @@ def test_the_lane_share_counts_a_unary_cells_two_directions_right():
     run.cell = types.SimpleNamespace(config={})  # no prefill_device: TPU_0
     share = 100.0 * (2 * calls * nbytes / (2 * calls * 12e-6)) / (1600e9 / 8)
     assert ici(run) == pytest.approx(share) and 0 < share < 100
+
+
+# -- PR 51: the lock probe's readers and the gap lines ------------------------
+
+
+@pytest.mark.parametrize(
+    "metric", ["lock_wait_us", "lock_busy_pct", "lock_forced_pct",
+               "machine_late_us", "stall_ms"])
+def test_lock_reader_gives_none_in_a_window_without_a_tick(metric):
+    """A program that has the probe's names and no probe (no native
+    library) counts nothing: no number, and no 0 that reads as a finding."""
+    silent = {
+        **LOCK, "device_transport_lock_probes": 0, "device_transport_lock_busy": 0,
+        "device_transport_lock_forced": 0, "device_transport_lock_stall_us": 0,
+        "device_transport_lock_wait_us": recorder(0, 0.0),
+        "device_transport_machine_late_us": recorder(0, 0.0),
+    }
+    read = manifest.load_module("layers", metric + ".py").read
+    assert read(hand_made_run(silent)) is None
+
+
+def test_the_two_cpu_gains_add_up_to_the_process():
+    cores = [
+        manifest.load_module("layers", f"host_cpu_{kind}_threads_cores.py").read(
+            hand_made_run({**LOCK, "device_transport_process_cpu_us": 40e6}))
+        for kind in ("python", "other")
+    ]
+    whole = manifest.load_module("layers", "host_cpu_cores.py").read(
+        hand_made_run({"device_transport_process_cpu_us": 40e6}))
+    assert sum(cores) == pytest.approx(whole)
+
+
+def lock_ticks(*ticks):
+    """``(due, woken, running)`` arrays from ``(due, late, wait)`` triples."""
+    due = np.array([t[0] for t in ticks], np.int64)
+    woken = due + np.array([t[1] for t in ticks], np.int64)
+    return due, woken, woken + np.array([t[2] for t in ticks], np.int64)
+
+
+def test_the_probe_line_of_a_gap_says_who_had_the_lock():
+    from benchmark import timeline_lock
+
+    ms = 1_000_000
+    # five gaps, the longest first: held, free, none inside, a stall, late
+    starts = T_OPEN + np.array([0, 100, 200, 300, 600], np.int64) * ms
+    gaps = (starts, starts + np.array([50, 40, 4, 30, 20], np.int64) * ms)
+    busy_ns = 100_000
+    ticks = lock_ticks(
+        *[(T_OPEN + at * ms, 50_000, 5 * ms) for at in (5, 20, 35)],  # waited
+        *[(T_OPEN + at * ms, 60_000, 9_000) for at in (105, 118, 131)],  # prompt
+        (T_OPEN + 301 * ms, 40_000, 150 * ms),  # a stall across the fourth
+        (T_OPEN + 601 * ms, 15 * ms, 8_000),  # the machine woke it 15 ms late
+    )
+    stalls = [(int(ticks[1][6]), 150 * ms, "interpreter lock stall: thread 'gc'")]
+    lines = timeline_lock.describe_gaps(gaps, ticks, stalls, busy_ns, T_OPEN)
+    assert len(lines) == 5
+    held, free, stalled, late, empty = lines
+    assert held.startswith("lock in gap 0.050000 s at +0.000 s: 3 ticks,")
+    assert "longest wait 5000.0 us" in held and held.endswith("lock held throughout")
+    assert "3 ticks, longest wait 9.0 us, longest lateness 60.0 us" in free
+    assert "lock free" in free and "look in the runtime" in free
+    assert "1 ticks" in stalled and stalled.endswith(
+        "lock held throughout; interpreter lock stall: thread 'gc'")
+    assert late.endswith("machine late") and "longest lateness 15000.0 us" in late
+    assert empty == "lock in gap 0.004000 s at +0.200 s: no tick inside"
+    # of two waiting and one prompt tick the line counts, and names no stall
+    mixed = timeline_lock.describe_gaps(
+        (starts[:1], starts[:1] + 50 * ms),
+        lock_ticks((T_OPEN + 5 * ms, 0, 5 * ms), (T_OPEN + 20 * ms, 0, 5 * ms),
+                   (T_OPEN + 35 * ms, 0, 9_000)),
+        [], busy_ns, T_OPEN)
+    assert mixed == [
+        "lock in gap 0.050000 s at +0.000 s: 3 ticks, longest wait 5000.0 us, "
+        "longest lateness 0.0 us: lock held at 2 of 3 ticks"]
+
+
+def test_the_windows_line_differences_the_readings_nearest_its_edges():
+    from benchmark import timeline_lock
+
+    s = 1_000_000_000
+    readings = [
+        (T_OPEN - 3 * s, {"worker": (1 * s, 0, 4)}),
+        (T_OPEN + s // 10, {"worker": (2 * s, 1 * s, 4), "gone": (5 * s, 0, 1)}),
+        (T_OPEN + 10 * s, {"worker": (9 * s, 2 * s, 4)}),
+        (T_CLOSE - s // 10, {"worker": (22 * s, 3 * s, 4), "tpu": (2 * s, 0, 9),
+                             "gone": (1 * s, 0, 1)}),
+        (T_CLOSE + 4 * s, {"worker": (30 * s, 3 * s, 4)}),
+    ]
+    line = timeline_lock.describe_threads(readings, T_OPEN, T_CLOSE)
+    # 20 s of CPU and 2 s of waiting over 19.8 s; a name born in the window
+    # counts from nothing, one whose tasks ended counts for nothing
+    assert line == (
+        "processors by thread over 19.80 s: worker 1.010 (runq 9.1%), "
+        "tpu 0.101 (runq 0.0%); in all 1.111")
+    assert timeline_lock.describe_threads(readings[:1], T_OPEN, T_CLOSE) is None
+
+
+def test_the_lock_report_is_silent_on_a_run_without_a_device_plane_or_a_probe(capsys):
+    """The parent's tree under these files, and a rehearsal: nothing to
+    read prints nothing and returns ``None``, once."""
+    from benchmark import timeline_lock
+
+    run = hand_made_run(dict(LOCK))
+    run.devices = {}
+    assert timeline_lock.report(run) is None and run.lock_report is None
+    assert capsys.readouterr().out == ""

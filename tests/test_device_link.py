@@ -939,7 +939,7 @@ class TestStepStageRecorders:
             "step_rtt_us", "flush_us", "launch_us", "ready_us",
             "reorder_wait_us", "readback_us", "pump_us",
             "dispatch_interval_us", "inflight_at_dispatch",
-            "backlog_slots_at_dispatch", "credit_at_dispatch", "send_wait_us",
+            "backlog_slots_at_dispatch", "send_wait_us",
             "hold_us", "out_bytes_second", "in_bytes_second",
             # PR 35: the CPU clock of the stages one thread begins and ends
             "launch_cpu_us", "readback_cpu_us", "pump_cpu_us",
@@ -1224,10 +1224,10 @@ class TestSlotTrains:
         flight before it)."""
         cuts, take = [], link._take_seq_locked
 
-        def spy(k=1, seen=(None, None)):
-            backlog = max(1, min(seen[0], link.window))
-            cuts.append((k, 1 << (backlog.bit_length() - 1), link._inflight))
-            return take(k, seen)
+        def spy(k=1, backlog=None):
+            wanted = max(1, min(backlog, link.window))
+            cuts.append((k, 1 << (wanted.bit_length() - 1), link._inflight))
+            return take(k, backlog)
 
         link._take_seq_locked = spy
         return cuts

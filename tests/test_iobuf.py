@@ -238,6 +238,9 @@ FD_CALLS = {
     "tb_iobuf_cut_into_fd", "tb_iobuf_append_from_fd",
     "tb_iobuf_append_from_fd_bulk", "tb_conn_write",
 }
+# the calls that sleep or walk /proc (PR 51: the lock probe's tick and the
+# processors by thread, which may run beside any test): never held either
+BLOCKING_CALLS = {"tb_sleep_until_ns", "tb_task_times"}
 # the calls whose work is their byte count, and the argument that gives it
 SIZED_CALLS = {
     "tb_iobuf_append": lambda a: a[2],
@@ -526,7 +529,9 @@ class TestWhichHandleServesACall:
         serve_with(monkeypatch, lib, held)
         self.frame_trip(256, over_a_socket=True)
         monkeypatch.undo()
-        assert lib.names() == ["tb_iobuf_cut_into_fd", "tb_iobuf_append_from_fd"]
+        # (the lock probe's reading of the processors may fall beside it)
+        assert [name for name in lib.names() if name not in BLOCKING_CALLS] == [
+            "tb_iobuf_cut_into_fd", "tb_iobuf_append_from_fd"]
         assert {"tb_tbus_pack", "tb_tbus_peek", "tb_tbus_cut", "tb_iobuf_copy_to",
                 "tb_iobuf_create", "tb_iobuf_size", "tb_iobuf_destroy"} <= set(
                     held.names())
@@ -538,12 +543,13 @@ class TestWhichHandleServesACall:
         self.frame_trip(n, over_a_socket=n < LIMIT)
         assert native.crc32c(pattern(n)) == native._crc32c_py(pattern(n))
         monkeypatch.undo()
-        assert not FD_CALLS & set(held.names())
+        assert not (FD_CALLS | BLOCKING_CALLS) & set(held.names())
         for name, args in held.calls:
             if name in SIZED_CALLS:
                 assert SIZED_CALLS[name](args) <= LIMIT, (name, n)
         for name, args in lib.calls:
-            assert name in FD_CALLS or SIZED_CALLS[name](args) > LIMIT, (name, n)
+            assert (name in FD_CALLS | BLOCKING_CALLS
+                    or SIZED_CALLS[name](args) > LIMIT), (name, n)
         if n > LIMIT:
             # the cut, the copy and the pack of a long frame do release
             assert {"tb_tbus_pack", "tb_tbus_cut", "tb_iobuf_copy_to"} <= set(

@@ -88,17 +88,17 @@ def link_table():
         held, dispatch, launched, ready, begin, host, end = at
         if i % 4:
             held = dispatch  # a train that went at once
-        seen = (-1, -1) if i % 5 == 0 else (int(rng.integers(1, 20)), i % 8)
+        seen = -1 if i % 5 == 0 else int(rng.integers(1, 20))
         rows.append((
             8 * i, previous or -1, held, dispatch, launched, ready, begin, host, end,
-            1 + i % 8, *seen, 0, 0, 0, 0, 0,
+            1 + i % 8, seen, 0, 0, 0, 0, 0,
         ))
         # the parent's _take_seq_locked and _record_step
         interval = dispatch - previous if previous else 0
         old.append((
             end - dispatch, launched - dispatch, ready - launched, begin - ready,
             host - begin, end - host, interval or None, dispatch - held,
-            1 + i % 8, *(None if v < 0 else v for v in seen),
+            1 + i % 8, None if seen < 0 else seen,
         ))
         previous = dispatch
     feed = fresh([(scale, span) for _a, scale, span in wall], device_link.STEP_STAMPS)
