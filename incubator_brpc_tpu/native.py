@@ -166,6 +166,18 @@ SIGNATURES = {
         [b, ctypes.c_int, ctypes.c_char_p, ctypes.c_size_t],
     ),
     "tb_region_free_blocks": (ctypes.c_size_t, [ctypes.c_int]),
+    # ---- a dispatch's operand (transport/device.py _stack_rows) ----
+    "tb_stack_rows": (
+        ctypes.c_int,
+        [
+            b,  # dst
+            ctypes.c_size_t,  # rows
+            ctypes.c_size_t,  # row_bytes
+            ctypes.POINTER(ctypes.c_void_p),  # srcs
+            ctypes.POINTER(ctypes.c_size_t),  # lens
+            ctypes.c_size_t,  # n
+        ],
+    ),
     "tb_crc32": (
         ctypes.c_uint32,
         [ctypes.c_uint32, ctypes.c_char_p, ctypes.c_size_t],
@@ -584,12 +596,13 @@ NATIVE_AVAILABLE = LIB is not None
 # every other thread of the process: with 16 handler threads that turn
 # costs ~0.5 ms on the chip's host (PERF.md, PR 27), a thousand times the
 # work of a size, a few hundred bytes copied or a free. So this file is the
-# one home of a rule, and iobuf.py, protocol/tbus_std.py and
-# transport/native_plane.py are its callers: **a native call that cannot
-# block and whose work is bounded keeps the lock; only a call that touches
-# a file descriptor, or copies or checksums more than _HELD_COPY_MAX bytes,
-# lets it go.** What must hold for a call made through LIB_HELD (audited
-# against src/tbutil, PR 49; docs/OBSERVABILITY.md): it takes no native
+# one home of a rule, and iobuf.py, protocol/tbus_std.py,
+# transport/native_plane.py and transport/device.py are its callers: **a
+# native call that cannot block and whose work is bounded keeps the lock;
+# only a call that touches a file descriptor, or copies or checksums more
+# than _HELD_COPY_MAX bytes, lets it go.** What must hold for a call made
+# through LIB_HELD (audited against src/tbutil, PR 49;
+# docs/OBSERVABILITY.md): it takes no native
 # lock that a thread can hold while it waits for the interpreter (the
 # block cache's, the regions', the object pools' and the flat map's are
 # leaf mutexes around a few pointer moves, and a release callback is

@@ -30,11 +30,13 @@ A ``DeviceEndpoint`` is the RdmaEndpoint re-thought for XLA:
   rank's expert weights): nothing is donated, no dispatch waits its turn
   and a program that raises loses nothing.
 
-``DeviceEndpoint.call_bytes`` adapts the host byte world: payloads are
-padded into the bucket and responses cut at the length the service says
-its method answers that request with (an echo: the request's own; a
-record read: 1,000 B to 8), the bucket being the larger of the request
-and the answer. ``server_handler`` plugs
+``DeviceEndpoint.call_bytes`` adapts the host byte world: a request's
+bytes are queued as they lie, a dispatch pads them into the bucket (or
+hands them to the program as they are, where one call fills its row), and
+responses are cut at the length the service says its method answers that
+request with (an echo: the request's own; a record read: 1,000 B to 8),
+the bucket being the larger of the request and the answer.
+``server_handler`` plugs
 an endpoint into an ordinary Server method map, giving the full
 host-RPC → HBM → fused-step → response path — the reference's
 "flip transport=tpu and rerun the same example pair" moment (SURVEY §7
@@ -43,6 +45,7 @@ step 5).
 
 from __future__ import annotations
 
+import ctypes
 import itertools
 import threading
 from collections import deque
@@ -55,6 +58,7 @@ import jax
 import numpy as np
 from jax.sharding import SingleDeviceSharding
 
+from incubator_brpc_tpu import native
 from incubator_brpc_tpu.bvar import (
     CPU_CLOCK_EVERY,
     Adder,
@@ -71,14 +75,16 @@ MIN_BUCKET_WORDS = 64
 MAX_BUCKET_WORDS = 1 << 24  # 64 MiB of uint32
 # The most words (padded rows x widest bucket) a dispatch may stack once
 # it holds calls of different buckets: 1 MiB, sixteen rows of a 64 KiB
-# bucket. What it stands on (PERF_LEDGER.jsonl, PR 27): per byte a
-# dispatch costs about 0.7 ms a MiB (device_stack_us 806 +
-# device_readback_us 1,516 + the copy inside the launch, for 4 MiB,
-# echo_4m_c2), per dispatch the launch alone costs 2.2-3.5 ms
-# (device_launch_us, same lines), so under 1 MiB widening adds less than
-# a third of what one saved dispatch gives back. Calls of one bucket
-# stack as they always did, whatever their size. Move it only on chip
-# evidence, written into PERF.md.
+# bucket. What it stands on (PERF.md section 6, PR 53's chip runs): per
+# byte a dispatch costs about 0.6 ms a MiB where two callers share the
+# interpreter (4 MiB alone, echo_4m_c2: device_readback_us 1,428 + the copy
+# inside the launch, ~1.0 of its 1,481; the stack is borrowed) and up to
+# 1.5 where sixteen do (2.2 MiB stacked in the expert shard's cell:
+# device_stack_us 1,469 + device_readback_us 1,953), per dispatch the
+# launch alone costs 2.5-4.1 ms at sixteen callers (device_launch_us, same
+# runs), so under 1 MiB widening adds about a third or less of what one
+# saved dispatch gives back. Calls of one bucket stack as they always did,
+# whatever their size. Move it only on chip evidence, written into PERF.md.
 MAX_STACKED_WORDS = 1 << 18
 
 # credit held -> response parsed, per call: the total the stages split
@@ -89,6 +95,10 @@ m_dispatch_rows = Adder(name="device_transport_dispatch_rows")
 m_dispatch_pad_rows = Adder(name="device_transport_dispatch_pad_rows")
 m_dispatch_words = Adder(name="device_transport_dispatch_words")
 m_dispatch_widened_rows = Adder(name="device_transport_dispatch_widened_rows")
+# words of the operands that no call wrote and the dispatch zeroed (rows'
+# tails, pad rows), and dispatches whose operand was the request's own memory
+m_dispatch_zeroed_words = Adder(name="device_transport_dispatch_zeroed_words")
+m_dispatch_borrowed = Adder(name="device_transport_dispatch_borrowed")
 
 # A completed call's row, as _PendingCall.row writes it: the dispatch it
 # rode, its stamps in the order written (time.monotonic_ns()), the host
@@ -120,8 +130,8 @@ STAMPS = ("seq",) + _WALL + (
 # connection's write. docs/OBSERVABILITY.md has the table. The stage is
 # defined here and nowhere else: the sampler does the subtraction.
 STAGES = {
-    # the adapter's host copies: bytes to words, words into the zeroed
-    # bucket, response words back to bytes
+    # the adapter's host work: the request's bytes seen as words, a
+    # writeable array copied as it is queued, response words back to bytes
     "copy": (("entry", "words"), ("credit_held", "enqueued"), ("woke", "exit")),
     "credit_wait": ("words", "credit_held"),
     "queue_wait": ("enqueued", "batched"),
@@ -237,6 +247,33 @@ def _pad_rows(b: int) -> int:
     return 1 << (b - 1).bit_length()
 
 
+def _stack_rows(rows: np.ndarray, sources: list) -> int:
+    """Write ``rows``, a fresh ``(bpad, bucket) uint32`` array, every word
+    once: call ``i``'s bytes (``sources[i]``: contiguous ``uint32`` or
+    ``uint8``) at the head of row ``i``, zeros in the row's tail and in the
+    pad rows whole. One native call, which keeps the interpreter lock or lets
+    it go once by the bytes it writes (the rule at ``native.LIB_HELD``); the
+    same words with numpy where the library is absent. Returns the words
+    zeroed."""
+    n, row_bytes = len(sources), rows.shape[1] * 4
+    lens = [src.nbytes for src in sources]
+    if native.LIB is not None:
+        rc = native.lib_for(rows.nbytes).tb_stack_rows(
+            rows.ctypes.data, rows.shape[0], row_bytes,
+            (ctypes.c_void_p * n)(*[src.ctypes.data for src in sources]),
+            (ctypes.c_size_t * n)(*lens), n,
+        )
+        if rc != 0:
+            raise ValueError(f"a call's words overrun a row of {row_bytes} bytes")
+    else:
+        as_bytes = rows.view(np.uint8)
+        for i, src in enumerate(sources):
+            as_bytes[i, : lens[i]] = src.view(np.uint8)
+            as_bytes[i, lens[i] :] = 0
+        as_bytes[n:] = 0
+    return rows.size - sum(-(-k // 4) for k in lens)
+
+
 class _Dispatch:
     """One (batch, bucket) program execution, shared by the calls stacked
     into it; ``bucket`` is the widest among theirs. Its stamps are each
@@ -246,7 +283,8 @@ class _Dispatch:
     a completion watcher from there."""
 
     __slots__ = (
-        "seq", "rows", "pad_rows", "bucket", "widened_rows", "timed",
+        "seq", "rows", "pad_rows", "bucket", "widened_rows", "zeroed_words",
+        "borrowed", "timed",
         "t_batched", "t_stacked", "t_launched", "watcher", "t_readback",
         "c_batched", "c_stacked", "c_launched", "c_readback", "t_state",
     )
@@ -259,9 +297,11 @@ class _Dispatch:
         self.pad_rows = pad_rows  # rows the program ran (next power of two)
         self.bucket = bucket  # payload words per row as the program ran it
         self.widened_rows = widened_rows  # calls whose own bucket is narrower
+        self.zeroed_words = 0  # of the operand: tails and pad rows
+        self.borrowed = 0  # 1: the operand is the one call's own words
         self.timed = seq % CPU_CLOCK_EVERY == 0  # its stamps carry the CPU clock
         self.t_batched, self.c_batched = clocks(self.timed)  # taken off the queue
-        self.t_stacked, self.c_stacked = 0, -1  # rows copied into one array
+        self.t_stacked, self.c_stacked = 0, -1  # the operand built
         self.t_state = -1  # the service's state in hand, its turn come
         # the program call, which stages the rows, returned
         self.t_launched, self.c_launched = 0, -1
@@ -291,7 +331,7 @@ class _PendingCall:
         self.t_entry = 0  # call_bytes entered (call_words: same as t_words)
         self.t_words = 0  # payload is words: call_words entered
         self.t_credit = 0  # credit held
-        self.t_enqueued = 0  # padded into its bucket and queued
+        self.t_enqueued = 0  # its words queued with their bucket
         self.dispatch: Optional[_Dispatch] = None
         self.t_woke = 0  # caller running again after wait
         self.t_exit = 0  # call_bytes returns (call_words: stays 0)
@@ -461,7 +501,8 @@ class DeviceEndpoint:
         self.max_batch = max(1, min(max_batch, window_size))
         self._credits = Butex(window_size)
         self._cq = DeviceCompletionButex()
-        # (bucket, mid_u32, row, cid_u32, pending, words of the answer)
+        # (bucket, mid_u32, the call's words as call_words keeps them,
+        # cid_u32, pending, words of the answer)
         self._queue = deque()
         self._qlock = threading.Lock()
         self._draining = False
@@ -551,7 +592,12 @@ class DeviceEndpoint:
     ) -> _PendingCall:
         """Async: frame → HBM → dispatch fused step → watch completion.
         Returns a _PendingCall the caller can wait on; the credit is held
-        until the response settles (the per-WR ack discipline)."""
+        until the response settles (the per-WR ack discipline).
+        ``payload_words``: ``uint32`` words, or their bytes as ``uint8``
+        (``call_bytes``, a length that is no multiple of 4). A read-only
+        array is queued as it is and must stay unchanged until the call
+        settles; a writeable one is copied here, so the caller may write to
+        it once this returns."""
         pending = _PendingCall()
         pending.t_entry = pending.t_words = _time.monotonic_ns()
         if not self._acquire_credit(timeout):
@@ -561,7 +607,10 @@ class DeviceEndpoint:
         pending.t_credit = _time.monotonic_ns()
         # the words of the answer the caller is owed, and the bucket that
         # holds both the request and it
-        sent = payload_words.shape[0]
+        words = payload_words
+        if words.dtype not in (np.uint32, np.uint8):
+            words = words.astype(np.uint32)
+        sent = -(-words.nbytes // 4)
         n = -(-self.service.answer_bytes(method_id, 4 * sent) // 4)
         try:
             bucket = _bucket_words(max(1, n, sent))
@@ -573,15 +622,21 @@ class DeviceEndpoint:
             pending.error_code = ErrorCode.EREQUEST
             pending.settle()
             return pending
-        padded = np.zeros(bucket, dtype=np.uint32)
-        padded[:sent] = payload_words
+        # no padded row is built: the dispatch writes the words into its
+        # operand, or hands them over as they lie. They must not change
+        # under it, and they are read as one aligned run of bytes
+        flags = words.flags
+        if words is payload_words and (
+            flags.writeable or not (flags.c_contiguous and flags.aligned)
+        ):
+            words = words.copy()
         pending.t_enqueued = _time.monotonic_ns()
         with self._qlock:
             self._queue.append(
                 (
                     bucket,
                     np.uint32(method_id),
-                    padded,
+                    words,
                     np.uint32(correlation_id & 0xFFFFFFFF),
                     pending,
                     n,
@@ -651,25 +706,34 @@ class DeviceEndpoint:
             next(self._dispatch_seq), b, bpad, bucket,
             sum(entry[0] != bucket for entry in batch),
         )
-        rows = np.zeros((bpad, bucket), dtype=np.uint32)
         cids = np.zeros(bpad, dtype=np.uint32)
         mids = np.zeros(bpad, dtype=np.uint32)
-        for i, (_, mid, padded, cid, pending, _n) in enumerate(batch):
-            rows[i, : padded.size] = padded
+        for i, (_, mid, _words, cid, pending, _n) in enumerate(batch):
             cids[i] = cid
             mids[i] = mid
             pending.dispatch = dispatch
-        dispatch.t_stacked, dispatch.c_stacked = clocks(dispatch.timed)
-        # the launch is the program call alone: it stages the host arrays
-        # itself. rows, cids and mids are this dispatch's own and are not
-        # written again (the runtime may still be reading them)
         try:
+            alone = batch[0][2]
+            if bpad == 1 and alone.dtype == np.uint32 and alone.size == bucket:
+                # a call alone that fills its row: the row is word for word
+                # the request, which the entry keeps alive until it settles
+                rows, dispatch.borrowed = alone, 1
+            else:
+                rows = np.empty((bpad, bucket), dtype=np.uint32)
+                dispatch.zeroed_words = _stack_rows(
+                    rows, [entry[2] for entry in batch])
+                if bpad == 1:
+                    rows = rows[0]
+            dispatch.t_stacked, dispatch.c_stacked = clocks(dispatch.timed)
+            # the launch is the program call alone: it stages the host
+            # arrays itself. rows, cids and mids are not written again (the
+            # runtime may still be reading them)
             if bpad == 1:  # a call alone: the one-row program, one frame back
-                response = self._program(rows[0], cids[0], mids[0], dispatch)
+                response = self._program(rows, cids[0], mids[0], dispatch)
             else:
                 response = self._batch_program(rows, cids, mids, dispatch)
         except Exception as e:  # dispatch failed: settle the whole batch
-            for _, _mid, _padded, _cid, pending, _n in batch:
+            for _, _mid, _words, _cid, pending, _n in batch:
                 self._release_credit()
                 pending.error = e
                 pending.error_code = ErrorCode.EINTERNAL
@@ -687,7 +751,7 @@ class DeviceEndpoint:
             except Exception as e:  # noqa: BLE001 — fetch failed
                 error, host = e, None
             dispatch.t_readback, dispatch.c_readback = clocks(dispatch.timed)
-            for i, (_, _mid, _padded, _cid, pending, n) in enumerate(_batch):
+            for i, (_, _mid, _words, _cid, pending, n) in enumerate(_batch):
                 try:
                     if error is not None:
                         pending.error = error
@@ -709,13 +773,15 @@ class DeviceEndpoint:
             if error is not None:
                 self._lose_state()  # what the next state was computed from
             # after the callers are awake: this thread is a pooled
-            # watcher, so the adders keep one agent each (the drain and
-            # -tx threads are born per dispatch)
+            # watcher, so the adders keep one agent each (a drain thread
+            # lives for a run of dispatches, a -tx thread for one)
             m_dispatches << 1
             m_dispatch_rows << dispatch.rows
             m_dispatch_pad_rows << dispatch.pad_rows
             m_dispatch_words << dispatch.pad_rows * dispatch.bucket
             m_dispatch_widened_rows << dispatch.widened_rows
+            m_dispatch_zeroed_words << dispatch.zeroed_words
+            m_dispatch_borrowed << dispatch.borrowed
             if host is not None:
                 self.service.account(mids[:b], host[:b])
 
@@ -731,7 +797,7 @@ class DeviceEndpoint:
         timeout: Optional[float] = 10.0,
         cntl=None,
     ) -> Tuple[int, bytes]:
-        """Sync byte adapter: pad to words, run, cut the response at the
+        """Sync byte adapter: see the bytes as words, run, cut the response at the
         byte length the service says this method answers such a request
         with (``answer_bytes``; an echo's is the request's own). ``cntl``:
         the server-side controller of the RPC this call serves, if any —
@@ -739,8 +805,8 @@ class DeviceEndpoint:
         sampled, gets the call's timeline as annotations."""
         t_entry = _time.monotonic_ns()
         nbytes = len(payload)
-        pad = (-nbytes) % 4
-        words = np.frombuffer(payload + b"\x00" * pad, dtype=np.uint32)
+        # the request's own memory, read-only and kept alive by the view
+        words = np.frombuffer(payload, dtype=np.uint8 if nbytes % 4 else np.uint32)
         # ONE deadline budget across credit-wait + completion-wait
         deadline = None if timeout is None else _time.monotonic() + timeout
         pending = self.call_words(

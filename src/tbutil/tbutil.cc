@@ -865,6 +865,23 @@ void tb_tbus_pack(tb_iobuf* out, const void* meta, size_t meta_len,
   }
 }
 
+// ---- a dispatch's operand ----
+
+int tb_stack_rows(void* dst, size_t rows, size_t row_bytes, const void** srcs,
+                  const size_t* lens, size_t n) {
+  if (n > rows) return -1;
+  for (size_t i = 0; i < n; ++i) {
+    if (lens[i] > row_bytes) return -1;
+  }
+  char* row = static_cast<char*>(dst);
+  for (size_t i = 0; i < n; ++i, row += row_bytes) {
+    if (lens[i]) memcpy(row, srcs[i], lens[i]);
+    memset(row + lens[i], 0, row_bytes - lens[i]);
+  }
+  memset(row, 0, (rows - n) * row_bytes);
+  return 0;
+}
+
 // ---- misc ----
 
 uint32_t tb_crc32(uint32_t seed, const void* data, size_t n) {

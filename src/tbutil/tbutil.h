@@ -147,6 +147,15 @@ void tb_tbus_pack(tb_iobuf* out, const void* meta, size_t meta_len,
                   size_t att_len, uint32_t cid_lo, uint32_t cid_hi,
                   uint32_t flags, uint32_t error_code, int copy_body);
 
+// ---- a dispatch's operand (transport/device.py _stack_rows) ----
+// Write a row-major array of `rows` rows of `row_bytes` bytes at `dst`,
+// every byte once: row i < n gets the lens[i] bytes at srcs[i] at its head
+// and zeros in its tail, rows n.. are zeros whole. No source may overlap
+// `dst`. 0 = written; -1 = n > rows or a lens[i] > row_bytes (nothing
+// written).
+int tb_stack_rows(void* dst, size_t rows, size_t row_bytes, const void** srcs,
+                  const size_t* lens, size_t n);
+
 // ---- misc ----
 uint32_t tb_crc32(uint32_t seed, const void* data, size_t n);
 uint64_t tb_fast_rand(void);

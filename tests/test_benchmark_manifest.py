@@ -80,6 +80,10 @@ DEVICE.update({
     "device_transport_dispatch_pad_rows": 128,
     "device_transport_dispatch_words": 128 * 64,
     "device_transport_dispatch_widened_rows": 60,
+    # PR 53: 28 pad rows whole and a quarter of each call's row; 8 of the 40
+    # dispatches were a call alone that filled its row
+    "device_transport_dispatch_zeroed_words": 28 * 64 + 100 * 16,
+    "device_transport_dispatch_borrowed": 8,
 })
 # PR 35: the CPU clock of the stages one thread begins and ends
 DEVICE_CPU = {"stack": 60.0, "launch": 45.0, "readback": 30.0}
@@ -248,6 +252,8 @@ EXPECTED = {
     "dispatch_rows": (DEVICE, 2.5),
     "dispatch_pad_pct": (DEVICE, 100.0 * 28 / 128),
     "dispatch_widened_pct": (DEVICE, 60.0),
+    "dispatch_zeroed_pct": (DEVICE, 100.0 * (28 * 64 + 100 * 16) / (128 * 64)),
+    "dispatch_borrowed_pct": (DEVICE, 20.0),
     "echo_step_hbm_pct_dispatched": (DEVICE, HBM_DISPATCHED),
     "link_flush_us": (LINK, 40.0),
     "link_launch_us": (LINK, 1200.0),
@@ -485,6 +491,23 @@ def test_reader_gives_none_where_the_program_lacks_the_recorder(metric):
     assert read(hand_made_run({})) is None
 
 
+@pytest.mark.parametrize("metric, adder", [
+    ("dispatch_zeroed_pct", "device_transport_dispatch_zeroed_words"),
+    ("dispatch_borrowed_pct", "device_transport_dispatch_borrowed"),
+])
+def test_operand_reader_is_none_on_the_parents_counters(metric, adder):
+    """PR 53's parent feeds every dispatch adder but these two, and the driver
+    reads it with this PR's readers: ``None``, and the line leaves it out. A
+    window without a dispatch has no share either; one in which the adder
+    stood still reads 0."""
+    read = manifest.load_module("layers", metric + ".py").read
+    parents = {k: v for k, v in DEVICE.items() if k != adder}
+    assert read(hand_made_run(parents)) is None
+    idle = dict(DEVICE, device_transport_dispatches=0, device_transport_dispatch_words=0)
+    assert read(hand_made_run(idle)) is None
+    assert read(hand_made_run(dict(DEVICE, **{adder: 0}))) == 0.0
+
+
 @pytest.mark.parametrize("metric", sorted(SPAN_READERS))
 def test_idle_share_reader_is_a_program_span_in_every_cell_and_none_without_rows(metric):
     (entry,) = [m for m in BENCH["per_layer"] if m["name"] == metric]
@@ -588,6 +611,17 @@ def test_the_new_entries_only_follow_the_old():
         (m["layer"], m["moves"], m["source"], m["better"], m["workloads"])
         == ("server process", "latency_p50_us", "program_counter", "lower", CELLS)
         for m in BENCH["per_layer"][110:117])
+    # PR 53's two follow them, the last: how far the one-pass operand engages,
+    # in the six cells with a DeviceEndpoint, beside device_stack_us
+    stack = next(m for m in BENCH["per_layer"] if m["name"] == "device_stack_us")
+    assert len(names) >= 119
+    assert names[117:119] == ["dispatch_zeroed_pct", "dispatch_borrowed_pct"]
+    assert [(m["better"], m["unit"], m["source"]) for m in BENCH["per_layer"][117:119]] == [
+        ("lower", "%", "program_counter"), ("higher", "%", "program_counter")]
+    assert all(
+        (m["layer"], m["moves"], m["workloads"])
+        == (stack["layer"], "latency_p50_us", stack["workloads"])
+        for m in BENCH["per_layer"][117:119])
     egress = next(m for m in BENCH["per_layer"] if m["name"] == "host_plane_egress_us")
     assert sorted(
         BENCH["per_layer"][108]["workloads"] + BENCH["per_layer"][109]["workloads"]
@@ -890,7 +924,8 @@ YCSB_JOINS = (
     [f"device_{s}_us" for s in DEVICE_STAGES]
     + ["device_path_unattributed_pct", "device_path_us", "host_plane_ingress_us",
        "host_plane_egress_us", "dispatch_rows", "dispatch_pad_pct",
-       "dispatch_widened_pct", "device_stack_cpu_us", "device_launch_cpu_us",
+       "dispatch_widened_pct", "dispatch_zeroed_pct", "dispatch_borrowed_pct",
+       "device_stack_cpu_us", "device_launch_cpu_us",
        "device_readback_cpu_us", "host_cpu_cores", "idle_worker_open_pct",
        "idle_waiting_only_pct", "idle_outside_pct", "device_idle_pct"])
 YCSB_STAYS_OUT = (

@@ -864,3 +864,258 @@ class TestWarmUpHolds:
             self._dispatch_all(ep)
         assert _compiles(caplog) == []
         assert self._sizes(ep) == sizes
+
+
+# -- PR 53: a dispatch's operand is built in one pass --------------------------
+
+
+@contextlib.contextmanager
+def _operands(ep):
+    """The arrays each dispatch of ``ep`` hands its program, as they are
+    (not copies: nothing writes to an operand once it is built)."""
+    seen = []
+    one, many = ep._program, ep._batch_program
+
+    def row(rows, cids, mids, dispatch=None):
+        seen.append(rows)
+        return one(rows, cids, mids, dispatch)
+
+    def stacked(rows, cids, mids, dispatch=None):
+        seen.append(rows)
+        return many(rows, cids, mids, dispatch)
+
+    ep._program, ep._batch_program = row, stacked
+    try:
+        yield seen
+    finally:
+        ep._program, ep._batch_program = one, many
+
+
+def _as_call_bytes_sees(payload: bytes) -> np.ndarray:
+    return np.frombuffer(payload, dtype=np.uint8 if len(payload) % 4 else np.uint32)
+
+
+def _the_parents_operand(payloads, bucket):
+    """The array PR 53's parent handed the program: every call's bytes
+    padded to words, copied into a zeroed row of its own bucket, and that
+    row into a zeroed ``(bpad, bucket)`` array."""
+    rows = np.zeros((1 << (len(payloads) - 1).bit_length(), bucket), np.uint32)
+    for i, payload in enumerate(payloads):
+        words = np.frombuffer(payload + b"\x00" * (-len(payload) % 4), np.uint32)
+        padded = np.zeros(_bucket_words(max(1, words.size)), np.uint32)
+        padded[: words.size] = words
+        rows[i, : padded.size] = padded
+    return rows
+
+
+def _operand_counters():
+    from incubator_brpc_tpu.transport import device
+
+    return {
+        name: getattr(device, "m_" + name).get_value()
+        for name in (
+            "dispatches", "dispatch_words", "dispatch_zeroed_words",
+            "dispatch_borrowed",
+        )
+    }
+
+
+@pytest.fixture(params=["native", "numpy"])
+def stacker(request, monkeypatch):
+    """Both writers of a stacked operand: ``tb_stack_rows``, and numpy where
+    the library is absent."""
+    from incubator_brpc_tpu import native
+
+    if request.param == "numpy":
+        monkeypatch.setattr(native, "LIB", None)
+    elif native.LIB is None:
+        pytest.skip("the native library could not be had here")
+    return request.param
+
+
+OPERAND_BYTES = [1, 3, 255, 256, 65_537, 1_048_576]
+
+
+class TestOperand:
+    """The array a dispatch hands its program is word for word the one the
+    parent built by zeroing twice and copying twice (PR 53)."""
+
+    @pytest.mark.parametrize("nbytes", OPERAND_BYTES)
+    def test_a_call_alone(self, two_method_endpoint, stacker, nbytes):
+        ep = two_method_endpoint
+        payload = np.random.default_rng(nbytes).bytes(nbytes)
+        with _operands(ep) as seen:
+            code, out = ep.call_bytes(payload, timeout=120)
+        assert code == 0 and out == payload
+        (operand,) = seen
+        bucket = _bucket_words(-(-nbytes // 4))
+        assert operand.dtype == np.uint32 and operand.shape == (bucket,)
+        np.testing.assert_array_equal(
+            operand, _the_parents_operand([payload], bucket)[0])
+
+    @pytest.mark.parametrize("nbytes", OPERAND_BYTES)
+    def test_a_batch_of_three_has_a_pad_row_of_zeros(
+        self, two_method_endpoint, stacker, monkeypatch, nbytes
+    ):
+        ep = two_method_endpoint
+        rng = np.random.default_rng(nbytes + 3)
+        # the second a word shorter where there is a word to spare
+        payloads = [rng.bytes(n) for n in (nbytes, max(1, nbytes - 4), nbytes)]
+        bucket = _bucket_words(-(-nbytes // 4))
+        calls = [(_as_call_bytes_sees(p), 0) for p in payloads]
+        with _operands(ep) as seen:
+            pendings, batches = _queue_then_drain(ep, calls, monkeypatch)
+        assert batches == [(bucket, [1, 2, 3])]
+        (operand,) = seen
+        assert operand.dtype == np.uint32 and operand.shape == (4, bucket)
+        np.testing.assert_array_equal(
+            operand, _the_parents_operand(payloads, bucket))
+        assert not operand[3].any()
+        for payload, pending in zip(payloads, pendings):
+            assert pending.error_code == 0
+            assert pending.response_words.tobytes()[: len(payload)] == payload
+
+    @pytest.mark.parametrize(
+        "sizes", [(100, 4000, 3), (4096, 1, 255, 256, 1024), (65_537, 256)])
+    def test_a_batch_of_mixed_buckets(
+        self, two_method_endpoint, stacker, monkeypatch, sizes
+    ):
+        ep = two_method_endpoint
+        rng = np.random.default_rng(sum(sizes))
+        payloads = [rng.bytes(n) for n in sizes]
+        widest = _bucket_words(-(-max(sizes) // 4))
+        calls = [(_as_call_bytes_sees(p), 0) for p in payloads]
+        with _operands(ep) as seen:
+            pendings, batches = _queue_then_drain(ep, calls, monkeypatch)
+        assert batches == [(widest, list(range(1, len(sizes) + 1)))]
+        (operand,) = seen
+        np.testing.assert_array_equal(
+            operand, _the_parents_operand(payloads, widest))
+        for payload, pending in zip(payloads, pendings):
+            assert pending.error_code == 0
+            assert pending.response_words.tobytes()[: len(payload)] == payload
+            assert pending.dispatch.zeroed_words == operand.size - sum(
+                -(-n // 4) for n in sizes)
+
+    @pytest.mark.parametrize("nbytes", [256, 65_536, 1_048_576])
+    def test_a_call_alone_that_fills_its_row_is_handed_over_as_it_lies(
+        self, two_method_endpoint, nbytes
+    ):
+        ep = two_method_endpoint
+        payload = np.random.default_rng(nbytes).bytes(nbytes)
+        with _operands(ep) as seen:
+            code, out = ep.call_bytes(payload, timeout=120)
+        assert code == 0 and out == payload
+        (operand,) = seen
+        assert np.shares_memory(operand, np.frombuffer(payload, np.uint8))
+        assert not operand.flags.writeable
+        assert operand.dtype == np.uint32 and operand.shape == (nbytes // 4,)
+
+    @pytest.mark.parametrize("nbytes, dtype", [
+        (252, np.uint32),  # a word short of its row
+        (255, np.uint8),  # bytes that end inside a word
+        (256, np.uint8),  # bytes that would fill the row, not given as words
+        (260, np.uint32),  # a word over: a row of 128
+    ])
+    def test_a_call_that_does_not_fill_its_row_as_words_is_copied(
+        self, two_method_endpoint, nbytes, dtype
+    ):
+        ep = two_method_endpoint
+        payload = np.random.default_rng(nbytes).bytes(nbytes)
+        given = np.frombuffer(payload, dtype)
+        with _operands(ep) as seen:
+            pending = ep.call_words(given, timeout=120)
+            assert pending.wait(120) and pending.error_code == 0
+        (operand,) = seen
+        assert not np.shares_memory(operand, given)
+        assert operand.flags.writeable  # the dispatch's own array
+        np.testing.assert_array_equal(
+            operand, _the_parents_operand([payload], operand.size)[0])
+        assert pending.response_words.tobytes()[:nbytes] == payload
+
+    @pytest.mark.parametrize("words", [10, 64, 100])
+    def test_a_writeable_array_may_be_written_once_call_words_returned(
+        self, two_method_endpoint, words
+    ):
+        ep = two_method_endpoint
+        with ep._qlock:
+            ep._draining = True  # the call waits in the queue
+        given = np.arange(1, words + 1, dtype=np.uint32)
+        sent = given.copy()
+        with _operands(ep) as seen:
+            pending = ep.call_words(given, timeout=120)
+            given[:] = 0xDEADBEEF
+            ep._drain()
+            assert pending.wait(120) and pending.error_code == 0
+        np.testing.assert_array_equal(pending.response_words, sent)
+        (operand,) = seen
+        assert not np.shares_memory(operand, given)
+        np.testing.assert_array_equal(operand[:words], sent)
+        assert not operand[words:].any()
+
+    @pytest.mark.parametrize("how", ["strided", "unaligned"])
+    def test_a_read_only_array_that_is_no_aligned_run_of_words_is_copied(
+        self, two_method_endpoint, how
+    ):
+        ep = two_method_endpoint
+        if how == "strided":
+            given = np.arange(128, dtype=np.uint32)[::2]
+            given.setflags(write=False)
+        else:
+            given = np.frombuffer(bytes(range(256)) + b"\0", np.uint8)[1:].view(np.uint32)
+            assert not given.flags.aligned and not given.flags.writeable
+        with _operands(ep) as seen:
+            pending = ep.call_words(given, timeout=120)
+            assert pending.wait(120) and pending.error_code == 0
+        np.testing.assert_array_equal(pending.response_words, given)
+        # it fills its row, and the row is the endpoint's copy of it
+        assert seen[0].flags.aligned and not np.shares_memory(seen[0], given)
+        assert pending.dispatch.borrowed == 1
+
+    def test_words_of_another_dtype_are_cast_as_the_padded_row_cast_them(
+        self, two_method_endpoint
+    ):
+        ep = two_method_endpoint
+        given = np.arange(1, 65, dtype=np.int64)
+        with _operands(ep) as seen:
+            pending = ep.call_words(given, timeout=120)
+            assert pending.wait(120) and pending.error_code == 0
+        np.testing.assert_array_equal(pending.response_words, given)
+        assert seen[0].dtype == np.uint32 and seen[0].shape == (64,)
+
+    def test_the_two_adders_over_a_known_sequence(
+        self, two_method_endpoint, stacker, monkeypatch
+    ):
+        ep = two_method_endpoint
+        _counters_at_rest()
+        before = _operand_counters()
+        full, short = bytes(range(256)), bytes(range(100))
+        # a call alone that fills its row: borrowed, nothing zeroed
+        assert ep.call_bytes(full, timeout=120) == (0, full)
+        # a call alone that does not: its tail, 64 - 25 words
+        assert ep.call_bytes(short, timeout=120) == (0, short)
+        # three in a dispatch: a tail of 39 words and a pad row of 64
+        calls = [(_as_call_bytes_sees(p), 0) for p in (full, short, full)]
+        _queue_then_drain(ep, calls, monkeypatch)
+        assert _wait_until(
+            lambda: _operand_counters()["dispatches"] - before["dispatches"] == 3)
+        after = _operand_counters()
+        assert {k: after[k] - before[k] for k in after} == {
+            "dispatches": 3,
+            "dispatch_words": 64 + 64 + 4 * 64,
+            "dispatch_zeroed_words": 0 + 39 + (39 + 64),
+            "dispatch_borrowed": 1,
+        }
+
+    def test_the_native_writer_refuses_a_call_longer_than_a_row(self):
+        from incubator_brpc_tpu import native
+        from incubator_brpc_tpu.transport.device import _stack_rows
+
+        if native.LIB is None:
+            pytest.skip("the native library could not be had here")
+        rows = np.full((2, 64), 7, dtype=np.uint32)
+        with pytest.raises(ValueError):
+            _stack_rows(rows, [np.zeros(65, np.uint32)])
+        with pytest.raises(ValueError):
+            _stack_rows(rows, [np.zeros(1, np.uint32)] * 3)
+        assert (rows == 7).all()  # nothing was written

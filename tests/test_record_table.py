@@ -303,6 +303,59 @@ def test_warm_compiles_every_program_sixteen_callers_can_form(endpoint):
     assert endpoint.call_bytes(KEY.pack(1), method_id=READ)[0] == 0
 
 
+@pytest.mark.parametrize("writer", ["native", "numpy"])
+def test_a_pad_row_and_a_rows_tail_never_carry_an_update(endpoint, monkeypatch, writer):
+    """PR 53: a dispatch's operand is an ``np.empty`` array written once. Its
+    pad row must read as a zero frame and every row's tail as zeros, with
+    either writer, or the step would update a record nobody named."""
+    from incubator_brpc_tpu import native
+
+    if writer == "numpy":
+        monkeypatch.setattr(native, "LIB", None)
+    elif native.LIB is None:
+        pytest.skip("the native library could not be had here")
+    # np.empty hands out what the allocator has: leave it rows that read as
+    # updates of record 0, field 0 (the size of this dispatch's operand)
+    for _ in range(4):
+        dirty = np.empty((4, 256), np.uint32)
+        dirty[:] = np.frombuffer(
+            (HEAD.pack(0, 0) + b"\xee" * 100).ljust(1024, b"\xee"), np.uint32)
+        del dirty
+    requests = [
+        (UPDATE, HEAD.pack(7, 3) + b"\x07" * 100),
+        (READ, KEY.pack(0)),
+        (UPDATE, HEAD.pack(9, 0) + b"\x09" * 100),
+    ]
+    before = np.array(endpoint._state)
+    operands, inner = [], endpoint._batch_program
+    monkeypatch.setattr(
+        endpoint, "_batch_program",
+        lambda rows, *rest: operands.append(rows) or inner(rows, *rest))
+    with endpoint._qlock:
+        endpoint._draining = True  # the three wait for one drain
+    pendings = [
+        endpoint.call_words(np.frombuffer(data, np.uint32), method_id=mid)
+        for mid, data in requests
+    ]
+    endpoint._drain()
+    assert all(p.wait(60) and p.error_code == 0 for p in pendings)
+    (operand,) = operands
+    assert operand.shape == (4, 256) and not operand[3].any()
+    for row, (_mid, data) in zip(operand, requests):
+        assert row[: len(data) // 4].tobytes() == data
+        assert not row[len(data) // 4 :].any()
+    assert pendings[1].response_words.tobytes()[:1000] == ref.first_content(SEED, 0)
+    after = np.array(endpoint._state)
+    changed = {int(k) for k in np.flatnonzero((before != after).any(axis=1))}
+    assert changed == {7, 9}
+    assert after[7, 75:100].tobytes() == b"\x07" * 100
+    assert after[9, :25].tobytes() == b"\x09" * 100
+    for key, field in ((7, 3), (9, 0)):
+        same = np.ones(256, bool)
+        same[25 * field : 25 * field + 25] = False
+        assert (after[key][same] == before[key][same]).all()
+
+
 @pytest.mark.parametrize("seed", [1, 2])
 def test_sixteen_threads_against_the_references_register(endpoint, seed):
     """The benchmark's client in small: operations read off seeded payloads,

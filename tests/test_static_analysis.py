@@ -185,6 +185,26 @@ class TestFfiCheckerCatchesDrift:
             for v in vs
         ), _fmt(vs)
 
+    def test_width_change_in_the_operand_writer(self):
+        # PR 53: the gate covers tb_stack_rows, the one tbutil export that
+        # takes an array of pointers: a row width narrowed to int, or the
+        # sources passed as one pointer, flips the checker red
+        with open(os.path.join(REPO, "src", "tbutil", "tbutil.h")) as fh:
+            tbutil_text = fh.read()
+        args = ffi_check.parse_repo_headers().funcs["tb_stack_rows"].args
+        assert [(a.kind, a.pointee) for a in args if a.kind == "ptr"] == [
+            ("ptr", "void"), ("ptr", "ptr"), ("ptr", "scalar:size_t")]
+        assert len(args) == 6
+        for old, new in (
+            ("size_t row_bytes, const void** srcs", "int row_bytes, const void** srcs"),
+            ("const void** srcs", "const void* srcs"),
+            ("const size_t* lens", "const uint32_t* lens"),
+        ):
+            vs = ffi_check.check(tbutil_text=self._mutate(tbutil_text, old, new))
+            assert any(
+                v.rule == "ffi-type" and "tb_stack_rows" in v.message for v in vs
+            ), (old, _fmt(vs))
+
     def test_skewed_telemetry_record_layout(self, tbnet_text):
         # ISSUE 15 acceptance: the record grew 48 -> 64 bytes (trace_id
         # + span_id); a skewed field width in the header flips the
