@@ -35,13 +35,27 @@ no capacity, none dropped; an expert no token of the row names is not
 read. Rows of one dispatch may name different layers, and a row's answer
 does not depend on its bucket or on the rows beside it.
 
-The products are one Pallas kernel (``expert_ffn``): the dispatch's (row,
-expert) pairs that have a token are its work items, a grid step is one
-item's slice of the intermediate, and the ``index_map`` of the three
-weight operands takes the item's layer and expert from prefetched
-scalars, so a layer's 705 MB are streamed through VMEM from where they
-lie and never copied (a ``dynamic_slice`` of the stacked weights compiled,
-for a v5e, to a 59 MB copy an expert before each product).
+The products are one grouped Pallas kernel (``expert_ffn_grouped``, the
+same for both kinds of operand): the dispatch's (token, expert) pairs, the
+stacked rows' tokens as one list, are sorted by (layer, expert) (a stable
+sort, so a group's tokens stay in order), each group's run is padded to
+whole tiles, the pairs' token rows are gathered into that order, and the
+kernel walks the tiles: a grid step is one tile's slice of the
+intermediate, the ``index_map`` of the three weight operands takes the
+tile's layer and expert from prefetched scalars, so a layer's 705 MB are
+streamed through VMEM from where they lie and never copied (a
+``dynamic_slice`` of the stacked weights compiled, for a v5e, to a 59 MB
+copy an expert before each product); the tile's answer is summed over the
+slices in float32 where it lies in VMEM and written once, weighted, and the
+weighted rows are added back at their tokens' places in float32 and rounded
+to bf16 once. Each pair is computed once (up to the padding of its group's
+last tile); a (layer, expert) whose pairs fill one tile is read once a
+program call, however many rows of the dispatch name it, and one that needs
+``n`` tiles ``n`` times. A pass of the step has a static capacity, 5/4
+pairs a token row and half a tile a group the rows can name: the router's
+splits (1.17 pairs a token) are one pass, any other split as many passes as
+its tiles need (a ``fori_loop`` over the count), so nothing of the worst
+case's size is ever held.
 
 A bad frame, a layer out of range, shapes that are not this rank's, a
 ``T`` that the row cannot hold or that is not the request's own (a token
@@ -54,6 +68,25 @@ The last four payload words of a response frame, past anything a caller
 is given, say what the row cost: tokens, (token, expert) pairs, the layer
 and the experts that got a token as a bit mask. ``account`` adds them up
 for a dispatch.
+
+**A tensor operand** (``dispatch_tensor``, what ``DeviceEndpoint`` asks of
+a service for a call whose operand is a ``jax.Array``; PR 54). The request's
+words are the four header words above and nothing else; the operand is one
+``uint32[R, hidden / 2 + experts_held]`` array on the rank's chip, row ``t``
+the token's ``hidden`` bf16 (two a word, the even column low) and then its
+``experts_held`` float32 gate weights, bit for bit; rows past ``T`` are zero
+and cost no product. ``R``, the operand's capacity, is whatever the caller
+built it with (a production micro-batch's share: 2,048). The answer is one
+array of the operand's own shape (so that a request and an answer that wait
+at a link's lane together cross in one program): row ``t`` the ``hidden``
+bf16 of ``y_t``, its last ``experts_held`` words zero. Same equations,
+precisions and error answers as above; besides, an operand of another
+shape or dtype, and rows past ``T`` that are not zero, are ``EREQUEST``.
+
+A tile holds ``tile_for`` pairs: 384 where one operand of 2,048 rows names
+one layer (256 pairs an expert and their deviation fit, so nearly every
+expert is read once a call), 16 for host rows of 72 tokens, which mostly
+name layers of their own.
 """
 
 from __future__ import annotations
@@ -122,23 +155,41 @@ def weight_values(salt, rows, columns, scale: float, xp=jnp):
     return (2 * k - 255).astype(xp.float32) * xp.float32(scale)
 
 
-def _ffn_kernel(
-    rows_ref, _layers_ref, _experts_ref, n_ref,  # the work items, prefetched
-    x_ref, w_ref, gate_ref, up_ref, down_ref, out_ref, acc_ref,
-):
-    """One grid step: item ``k``'s slice ``j`` of the intermediate.
-    ``acc`` gathers a row's answer in float32 over its items and their
-    slices and is rounded to bf16 once, at the row's last."""
-    k, j = pl.program_id(0), pl.program_id(1)
-    last_k, last_j = pl.num_programs(0) - 1, pl.num_programs(1) - 1
-    active = k < n_ref[0]
-    row = rows_ref[k]
-    opens = (k == 0) | (rows_ref[jnp.maximum(k - 1, 0)] != row)
-    closes = (k == n_ref[0] - 1) | (rows_ref[jnp.minimum(k + 1, last_k)] != row)
+# the widest slice of the intermediate a grid step takes: three weight blocks
+# of hidden x 512 bf16 are 22 MB at hidden 7,168, twice that double-buffered
+SLICE = 512
+VMEM_LIMIT_BYTES = 100 << 20
 
-    @pl.when(active & opens & (j == 0))
+
+# the most pairs a tile of the grouped product: a group's run of sorted pairs
+# is padded to whole tiles. 256 pairs an expert (a rank's share of a prefill
+# micro-batch of 8,192 tokens) with a deviation of 16 fit one tile of 384
+# nearly always, so the expert is read once; x, the answer in float32 and
+# the three weight blocks, double-buffered, are 77 MB of VMEM at hidden 7,168
+TILE = 384
+
+
+def tile_for(rows: int, held: int) -> int:
+    """Pairs a tile where ``rows`` token rows name one layer: half as many
+    again as an even split over the experts held gives one of them, in whole
+    bf16 tiles of 16, ``TILE`` at most: 384 for an operand of 2,048 rows, 16
+    for a host row of 72 tokens."""
+    return min(TILE, -(-(3 * rows) // (2 * held * 16)) * 16)
+
+
+def _grouped_kernel(
+    _layers_ref, _experts_ref, n_ref,  # prefetched: a tile's layer and expert, tiles
+    x_ref, w_ref, gate_ref, up_ref, down_ref, out_ref,
+):
+    """One grid step: tile ``k``'s slice ``j`` of the intermediate. The
+    tile's answer gathers in ``out_ref`` in float32 over the slices (its
+    block stays in VMEM while ``j`` runs) and leaves once."""
+    k, j = pl.program_id(0), pl.program_id(1)
+    active = k < n_ref[0]
+
+    @pl.when(active & (j == 0))
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        out_ref[...] = jnp.zeros_like(out_ref)
 
     @pl.when(active)
     def _():
@@ -146,77 +197,78 @@ def _ffn_kernel(
         gate = jnp.dot(x, gate_ref[...], preferred_element_type=jnp.float32)
         up = jnp.dot(x, up_ref[...], preferred_element_type=jnp.float32)
         inner = (gate / (1.0 + jnp.exp(-gate)) * up).astype(jnp.bfloat16)
-        acc_ref[...] += w_ref[...] * jnp.dot(
+        out_ref[...] += w_ref[...] * jnp.dot(
             inner, down_ref[...], preferred_element_type=jnp.float32)
 
-    @pl.when(active & closes & (j == last_j))
-    def _():
-        out_ref[...] = acc_ref[...].astype(out_ref.dtype)
 
-
-# the widest slice of the intermediate a grid step takes: three weight blocks
-# of hidden x 512 bf16 are 22 MB at hidden 7,168, twice that double-buffered
-SLICE = 512
-VMEM_LIMIT_BYTES = 100 << 20
-
-
-def expert_ffn(state, x, w, item_row, item_layer, item_expert, n_items, interpret):
-    """``y[b, tokens, hidden]`` bf16: for each of the first ``n_items`` work
-    items ``(row, layer, expert)``, in row order, ``w * down(silu(gate x) *
-    up x)`` added into the item's row; a row no item names is never
-    written, and what it holds is the caller's to mask.
-    ``x[b, tokens, hidden]`` bf16, ``w[items, tokens, 1]`` float32 an
-    item's gate weights; items past ``n_items`` must repeat the last one's
-    numbers: their steps then name the blocks the last step of the work
-    named, and nothing is fetched for them."""
+def expert_ffn_grouped(state, x, w, tile_layer, tile_expert, n_tiles, tile, interpret):
+    """``out[pairs, hidden]`` float32: for each of the first ``n_tiles``
+    tiles of ``tile`` rows of ``x[pairs, hidden]`` bf16 (a tile's rows are
+    pairs of one expert of one layer, ``tile_expert[k]`` of
+    ``tile_layer[k]``), ``w * down(silu(gate x) * up x)`` with ``w[pairs,
+    1]`` float32. Tiles past ``n_tiles`` must repeat the last one's layer
+    and expert: their steps name the blocks the last step of the work named,
+    nothing is fetched for them and their rows of ``out`` are never written
+    (the caller masks them)."""
     gate, up, down = state
-    _, tokens, h = x.shape
+    pairs, h = x.shape
     inter = gate.shape[3]
     ti = min(SLICE, inter)
-    if inter % ti:
-        raise ValueError(f"intermediate {inter} is not a multiple of {ti}")
-
+    if inter % ti or pairs % tile:
+        raise ValueError(f"{inter} x {pairs} is not whole slices of {ti} x {tile}")
     slices = inter // ti
 
-    def last(k, n):
-        """Item ``k``, or the last item where ``k`` is past the list: a step
-        past the work names the blocks of the last one that did any."""
-        return jnp.minimum(k, jnp.maximum(n[0] - 1, 0))
+    def of_tile(k, j, layers, experts, n):
+        return jnp.minimum(k, jnp.maximum(n[0] - 1, 0)), 0
 
     def weights(block, at):
-        def index(k, j, rows, layers, experts, n):
+        def index(k, j, layers, experts, n):
             j = jnp.where(k < n[0], j, slices - 1)
             return (layers[k], experts[k]) + at(j)
 
         return pl.BlockSpec((None, None) + block, index)
 
-    def of_row(k, j, rows, layers, experts, n):
-        return rows[k], 0, 0
-
     return pl.pallas_call(
-        _ffn_kernel,
-        out_shape=jax.ShapeDtypeStruct(x.shape, jnp.bfloat16),
+        _grouped_kernel,
+        out_shape=jax.ShapeDtypeStruct((pairs, h), jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
-            grid=(item_row.shape[0], slices),
+            num_scalar_prefetch=3,
+            grid=(pairs // tile, slices),
             in_specs=[
-                pl.BlockSpec((None, tokens, h), of_row),
-                pl.BlockSpec(
-                    (None, tokens, 1), lambda k, j, *refs: (last(k, refs[3]), 0, 0)),
+                pl.BlockSpec((tile, h), of_tile),
+                pl.BlockSpec((tile, 1), of_tile),
                 weights((h, ti), lambda j: (0, j)),
                 weights((h, ti), lambda j: (0, j)),
                 weights((ti, h), lambda j: (j, 0)),
             ],
-            out_specs=pl.BlockSpec((None, tokens, h), of_row),
-            scratch_shapes=[pltpu.VMEM((tokens, h), jnp.float32)],
+            out_specs=pl.BlockSpec((tile, h), of_tile),
         ),
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=VMEM_LIMIT_BYTES,
         ),
-        name="expert_ffn",
+        name="expert_ffn_grouped",
         interpret=interpret,
-    )(item_row, item_layer, item_expert, n_items, x, w, gate, up, down)
+    )(tile_layer, tile_expert, n_tiles, x, w, gate, up, down)
+
+
+def _rows_of(words):
+    """``[..., n]`` uint32 words as ``[..., 2 n]`` bf16 in ``hidden_order``:
+    a bf16 is the top half of the float32 of its value, so the low halves
+    and then the high ones are a row, by shifts alone."""
+    return jnp.concatenate(
+        [lax.bitcast_convert_type(half, jnp.float32).astype(jnp.bfloat16)
+         for half in (words << 16, words & jnp.uint32(0xFFFF0000))],
+        axis=-1)
+
+
+def _words_of(y):
+    """``[..., 2 n]`` bf16 in ``hidden_order`` packed two a word."""
+    n = y.shape[-1] // 2
+    low, high = (
+        lax.bitcast_convert_type(half.astype(jnp.float32), jnp.uint32)
+        for half in (y[..., :n], y[..., n:]))
+    return (low >> 16) | high
 
 
 class ExpertShardService:
@@ -350,11 +402,8 @@ class ExpertShardService:
         # the tokens, two bf16 a word, in ``hidden_order``: a bf16 is the
         # top half of the float32 of its value. Past the request's own lie
         # its weights, or nothing
-        words = payload[:, HEADER_WORDS : HEADER_WORDS + cap * tw].reshape(b, cap, tw)
-        x = jnp.concatenate(
-            [lax.bitcast_convert_type(half, jnp.float32).astype(jnp.bfloat16)
-             for half in (words << 16, words & jnp.uint32(0xFFFF0000))],
-            axis=2)
+        x = _rows_of(
+            payload[:, HEADER_WORDS : HEADER_WORDS + cap * tw].reshape(b, cap, tw))
         # the weights lie behind the T tokens: a row's own offset
         w = jax.vmap(
             lambda row, start: lax.dynamic_slice(row, (start,), (cap * held,))
@@ -375,27 +424,15 @@ class ExpertShardService:
         w = self.routed(jnp.where(live[:, :, None], w, 0.0))
         at = jnp.where(valid, layer, 0).astype(jnp.int32)
 
-        # the work: every (row, expert) with a token, in row order; the
-        # tail of the list repeats the last item, which costs no fetch
+        # the products: the stacked rows' tokens are one list, a token's
+        # layer its row's
         pairs = w != 0
         got = jnp.any(pairs, axis=1)  # [b, held]
-        n_items = jnp.sum(got, dtype=jnp.int32)
-        order = jnp.argsort(~got.reshape(-1), stable=True).astype(jnp.int32)
-        item = order[jnp.minimum(jnp.arange(b * held), jnp.maximum(n_items - 1, 0))]
-        item_row, item_expert = item // held, item % held
-        pad = (-cap) % 16  # whole bf16 tiles of tokens
-        y = expert_ffn(
-            state,
-            jnp.pad(x, ((0, 0), (0, pad), (0, 0))),
-            jnp.pad(w.transpose(0, 2, 1).reshape(b * held, cap)[item],
-                    ((0, 0), (0, pad)))[:, :, None],
-            item_row, self.serving_layer(at)[item_row], item_expert,
-            n_items[None], self.interpret,
-        )[:, :cap]
-        low, high = (
-            lax.bitcast_convert_type(half.astype(jnp.float32), jnp.uint32)
-            for half in (y[:, :, :tw], y[:, :, tw:]))
-        words = ((low >> 16) | high).reshape(b, cap * tw)
+        y = self._grouped_sum(
+            state, x.reshape(b * cap, h), w.reshape(b * cap, held),
+            jnp.repeat(self.serving_layer(at), cap), min(b, self.layers),
+        ).reshape(b, cap, h)
+        words = _words_of(y).reshape(b, cap * tw)
         tally = jnp.stack(
             [
                 t.astype(jnp.uint32), jnp.sum(pairs, axis=(1, 2), dtype=jnp.uint32),
@@ -410,6 +447,147 @@ class ExpertShardService:
         answer = answer.at[:, width - TALLY_WORDS :].set(tally)
         # a row that is not served was never written: whatever lies there goes
         return jnp.where(valid[:, None], answer, 0), valid
+
+    # -- a tensor operand (what a DeviceEndpoint asks for a device array) -----
+
+    def operand_words(self) -> int:
+        """Words a row of a tensor operand (and of its answer): a token's
+        bf16 and its gate weights over the experts held."""
+        return self.token_words + self.experts_held
+
+    def dispatch_tensor(self, state, row, operand, cid_lo, mid):
+        """One call whose operand is a device array: ``(state, the request's
+        words zero-padded to a row, operand, cid, mid) -> (None, answer,
+        response frame)``; the module's docstring has the layout. Jittable;
+        the state is read and not replaced. The frame is a row's (its last
+        four payload words the tally ``account`` reads) and is all of the
+        answer a host needs to see."""
+        header, payload, ok = framing.parse(
+            framing.frame(row, (cid_lo, jnp.uint32(0)), method_id=mid))
+        asked = ok & (header.method_id == jnp.uint32(FFN))
+        wide = self.operand_words()
+        shaped = (
+            operand.dtype == jnp.uint32 and operand.size > 0
+            and operand.size % wide == 0
+            and (operand.ndim == 1 or operand.shape[1:] == (wide,))
+        )
+        if shaped:  # host bytes come as the rows end to end
+            answer, valid, tally = self._serve_tensor(
+                state, payload, asked, operand.reshape(-1, wide))
+            answer = answer.reshape(operand.shape)
+        else:  # no tensor of this rank's: a bad request, without a product
+            answer = jnp.zeros((1,), jnp.uint32)
+            valid, tally = jnp.bool_(False), jnp.zeros(TALLY_WORDS, jnp.uint32)
+        err = jnp.where(
+            asked,
+            jnp.where(valid, jnp.uint32(0), jnp.uint32(EREQUEST)),
+            jnp.where(ok, jnp.uint32(ENOMETHOD), jnp.uint32(EREQUEST)),
+        )
+        result = jnp.zeros(payload.shape, jnp.uint32).at[-TALLY_WORDS:].set(
+            jnp.where(valid, tally, 0))
+        frame = framing.frame(
+            result, (header.cid_lo, header.cid_hi), method_id=header.method_id,
+            flags=framing.FLAG_RESPONSE, error_code=err)
+        return None, answer, frame
+
+    def _serve_tensor(self, state, payload, asked, operand):
+        """``(answer[R, wide] uint32, valid, tally[4])`` for ``operand[R,
+        wide]``; the answer is zero where the request is not served."""
+        rows, _wide = operand.shape
+        h, held, tw = self.hidden, self.experts_held, self.token_words
+        layer, tokens = payload[0], payload[1]
+        fits = (tokens >= 1) & (tokens <= rows)
+        t = jnp.where(fits, tokens, 0).astype(jnp.int32)
+        live = jnp.arange(rows) < t
+        w = lax.bitcast_convert_type(operand[:, tw:], jnp.float32)
+        finite = jnp.all(jnp.isfinite(w) | ~live[:, None])
+        named = jnp.all(jnp.any(w != 0, axis=1) | ~live)
+        tail = jnp.any(jnp.where(live[:, None], jnp.uint32(0), operand) != 0)
+        valid = (
+            asked & fits & finite & named & ~tail
+            & (layer < jnp.uint32(self.layers))
+            & (payload[2] == jnp.uint32(h)) & (payload[3] == jnp.uint32(held))
+            & jnp.all(payload[HEADER_WORDS:] == 0)
+        )
+        live &= valid
+        x = _rows_of(operand[:, :tw])
+        w = self.routed(jnp.where(live[:, None], w, 0.0)[None])[0]
+        at = self.serving_layer(jnp.where(valid, layer, 0).astype(jnp.int32))
+        counts = jnp.sum(w != 0, axis=0, dtype=jnp.int32)  # pairs an expert
+        y = self._grouped_sum(state, x, w, jnp.broadcast_to(at, (rows,)), 1)
+        answer = jnp.concatenate(
+            [_words_of(y), jnp.zeros((rows, held), jnp.uint32)], axis=1)
+        tally = jnp.stack([
+            t.astype(jnp.uint32), jnp.sum(counts).astype(jnp.uint32),
+            jnp.where(valid, layer, 0),
+            jnp.sum((counts > 0).astype(jnp.uint32)
+                    << jnp.arange(held, dtype=jnp.uint32), dtype=jnp.uint32),
+        ])
+        return jnp.where(valid, answer, 0), valid, tally
+
+    def _grouped_sum(self, state, x, w, layer_of, layers_named: int):
+        """``y[rows, hidden]`` bf16: row ``t`` the sum over the experts its
+        weights ``w[t]`` name (``[rows, held]`` float32, 0 where none) of the
+        weighted expert of layer ``layer_of[t]`` on ``x[t]`` (bf16);
+        ``layers_named`` is the most distinct layers the rows can name. The
+        (token, expert) pairs are sorted by (layer, expert), a group's tokens
+        in their order, each group's run padded to whole tiles, and the tiles
+        walked by ``expert_ffn_grouped``; the weighted rows are added at
+        their tokens' places in float32 and rounded once."""
+        rows, h = x.shape
+        held, groups = self.experts_held, self.layers * self.experts_held
+        # stacked rows mostly name layers of their own: a group is a row's
+        # pairs of one expert, or a few rows'
+        tile = tile_for(rows // layers_named, held)
+        # a pair's group, token-major; ``groups`` where there is no pair
+        group = jnp.where(
+            w != 0, layer_of[:, None] * held + jnp.arange(held), groups
+        ).astype(jnp.int32).reshape(-1)
+        order = jnp.argsort(group, stable=True).astype(jnp.int32)
+        bounds = jnp.searchsorted(
+            group[order], jnp.arange(groups + 1), side="left").astype(jnp.int32)
+        first_pair, counts = bounds[:-1], bounds[1:] - bounds[:-1]
+        tiles = -(-counts // tile)  # a group's run in whole tiles
+        n_tiles = jnp.sum(tiles)
+        tiles_before = jnp.cumsum(tiles)
+        first_tile = tiles_before - tiles
+        weight_of = w.reshape(-1)
+
+        # a pass takes so many tiles (a static shape: 5/4 pairs a row, and
+        # half a tile a group the rows can name for its run's ragged end);
+        # the router's splits, 1.17 pairs a token, are one pass, any other
+        # split as many as it needs
+        pass_tiles = -(-(5 * rows // 4) // tile) + -(-layers_named * held // 2)
+
+        def one_pass(nth_pass, y):
+            """Tiles ``[nth_pass * pass_tiles, ...)`` of the work, their
+            weighted rows added into ``y[rows, hidden]`` float32 at the
+            tokens' places."""
+            k = nth_pass * pass_tiles + jnp.arange(pass_tiles)
+            here = jnp.clip(n_tiles - nth_pass * pass_tiles, 0, pass_tiles)
+            group_of = jnp.minimum(
+                jnp.searchsorted(tiles_before, k, side="right"), groups - 1
+            ).astype(jnp.int32)
+            slot = jnp.arange(pass_tiles * tile)
+            g = group_of[slot // tile]
+            nth = (k[0] - first_tile[g]) * tile + slot  # of its group's run
+            real = (slot // tile < here) & (nth < counts[g])
+            pair = order[jnp.clip(first_pair[g] + nth, 0, rows * held - 1)]
+            token = jnp.where(real, pair // held, rows)  # ``rows``: no token
+            xs = x.at[token].get(mode="fill", fill_value=0)
+            ws = jnp.where(real, weight_of[pair], 0.0)
+            # tiles past the work repeat the last one's group: no fetch
+            named = group_of[jnp.minimum(jnp.arange(pass_tiles), jnp.maximum(here - 1, 0))]
+            out = expert_ffn_grouped(
+                state, xs, ws[:, None], named // held, named % held,
+                here[None], tile, self.interpret)
+            # a slot of no pair names no token: whatever lies there is dropped
+            return y.at[token].add(out, mode="drop")
+
+        return lax.fori_loop(
+            0, -(-n_tiles // pass_tiles), one_pass,
+            jnp.zeros((rows, h), jnp.float32),
+        ).astype(jnp.bfloat16)
 
     def account(self, mids: np.ndarray, frames: np.ndarray) -> None:
         """Host side, from a completed dispatch's method ids and response

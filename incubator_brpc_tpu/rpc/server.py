@@ -286,6 +286,11 @@ class _MethodMap:
 _usercode_tls = threading.local()
 
 
+def _finished(response: bytes = b"") -> None:
+    """``cntl.set_async`` / ``cntl.send_response`` of a call that has been
+    answered: nothing left to do (``Server._finish``)."""
+
+
 def thread_local_data():
     """Pooled per-thread data of the server whose handler is running on
     this thread (reference brpc::thread_local_data(), server.h:55-239).
@@ -1482,6 +1487,13 @@ class Server:
             from incubator_brpc_tpu.builtin.rpcz import end_server_span
 
             end_server_span(cntl, response_size=len(response))
+        # the closures process_request gave the controller name it, and so do
+        # the after-send hooks: a finished call would lie in a reference
+        # cycle, attachments and all (a tensor on the device among them),
+        # until the cyclic collector runs. Cut here, it dies with its last
+        # reference; a late send_response is the no-op it was
+        cntl.set_async = cntl.send_response = _finished
+        cntl._after_send = None
 
     # -- shared admission/teardown (method_status.h:90-97; used by the
     # binary path and the http gateway so the two cannot drift) -----------

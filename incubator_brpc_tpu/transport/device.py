@@ -28,7 +28,15 @@ A ``DeviceEndpoint`` is the RdmaEndpoint re-thought for XLA:
   dispatch sees its batch whole (docs/DEVICE_PLANE.md has the contract);
 - or state that a step reads and never replaces (models/expert_shard: a
   rank's expert weights): nothing is donated, no dispatch waits its turn
-  and a program that raises loses nothing.
+  and a program that raises loses nothing;
+- a call's operand may be a ``jax.Array`` that lies on the endpoint's
+  device (``call_words(..., operand=...)``; a unary call's attachment,
+  through ``server_handler``): it rides the same window, queue, drain,
+  watchers and stage recorders as a call of host words, alone in its
+  dispatch, and neither it nor its answer, a ``jax.Array`` too, is ever
+  in host memory: the host sees the request's words going in and one
+  response frame of a row's width coming back (the error code, the
+  service's tally). The operand's type selects the path, nothing else.
 
 ``DeviceEndpoint.call_bytes`` adapts the host byte world: a request's
 bytes are queued as they lie, a dispatch pads them into the bucket (or
@@ -99,6 +107,11 @@ m_dispatch_widened_rows = Adder(name="device_transport_dispatch_widened_rows")
 # tails, pad rows), and dispatches whose operand was the request's own memory
 m_dispatch_zeroed_words = Adder(name="device_transport_dispatch_zeroed_words")
 m_dispatch_borrowed = Adder(name="device_transport_dispatch_borrowed")
+# calls whose operand was a jax.Array on the endpoint's device, served where
+# it lay; and those whose tensor came as host bytes or lay on another device
+# and was put there first (and, for bytes, whose answer was read back)
+m_device_operands = Adder(name="device_transport_device_operands")
+m_device_operand_fallbacks = Adder(name="device_transport_device_operand_fallbacks")
 
 # A completed call's row, as _PendingCall.row writes it: the dispatch it
 # rode, its stamps in the order written (time.monotonic_ns()), the host
@@ -318,7 +331,7 @@ class _PendingCall:
     by the caller's thread, plus the stamps of the dispatch it rode."""
 
     __slots__ = (
-        "ready", "response_words", "error_code", "error",
+        "ready", "response_words", "operand", "response_array", "error_code", "error",
         "t_entry", "t_words", "t_credit", "t_enqueued", "dispatch",
         "t_woke", "t_exit",
     )
@@ -326,6 +339,8 @@ class _PendingCall:
     def __init__(self):
         self.ready = Butex(0)
         self.response_words = None
+        self.operand = None  # a device operand, where the call carries one
+        self.response_array = None  # its answer, on the device
         self.error_code = 0
         self.error: Optional[BaseException] = None
         self.t_entry = 0  # call_bytes entered (call_words: same as t_words)
@@ -406,11 +421,12 @@ _LOST = object()
 
 class _StepProgram:
     """The service's jitted step as the endpoint runs it: ``program(rows,
-    cids, mids) -> response frames``. State that the step replaces is
-    taken from the endpoint in turn, donated to the step, and the next one
-    put back before the turn passes on; state that it only reads (or none)
-    is handed to every call as it lies, side by side. ``dispatch`` gets
-    the moment the state was in hand."""
+    cids, mids) -> response frames`` (with a device operand: ``program(row,
+    operand, cid, mid) -> (answer, response frame)``). State that the step
+    replaces is taken from the endpoint in turn, donated to the step, and
+    the next one put back before the turn passes on; state that it only
+    reads (or none) is handed to every call as it lies, side by side.
+    ``dispatch`` gets the moment the state was in hand."""
 
     __slots__ = ("_endpoint", "_jitted")
 
@@ -418,11 +434,15 @@ class _StepProgram:
         self._endpoint, self._jitted = endpoint, jitted
 
     def __call__(self, rows, cids, mids, dispatch: Optional["_Dispatch"] = None):
+        return self.run((rows, cids, mids), dispatch)
+
+    def run(self, operands: tuple, dispatch: Optional["_Dispatch"] = None):
+        """The jitted step on ``operands``, the state handed through."""
         ep = self._endpoint
         if ep._state_turn is None:  # nothing to take in turn, nothing to lose
             if dispatch is not None:
                 dispatch.t_state = _time.monotonic_ns()
-            return self._jitted(ep._state, rows, cids, mids)[1]
+            return self._jitted(ep._state, *operands)[1]
         with ep._state_turn:
             if dispatch is not None:
                 dispatch.t_state = _time.monotonic_ns()
@@ -433,8 +453,8 @@ class _StepProgram:
                     "dispatch raised with the state donated to it"
                 )
             ep._state = _LOST  # until the step hands the next one back
-            ep._state, frames = self._jitted(state, rows, cids, mids)
-        return frames
+            ep._state, answer = self._jitted(state, *operands)
+        return answer
 
     def _cache_size(self) -> int:
         """Compiled geometries and fast-path entries (tests)."""
@@ -476,7 +496,23 @@ class DeviceEndpoint:
     stacked host rows: the call stages its numpy arguments itself, onto
     ``device`` (both programs pin ``in_shardings`` there), and nothing
     else touches the device between rows stacked and the call's return
-    (``device_transport_launch_us``)."""
+    (``device_transport_launch_us``).
+
+    **A device operand.** A service that takes a tensor also gives
+    ``dispatch_tensor(state, row, operand, cid, mid) -> (state', answer,
+    response frame)``, jittable: ``row`` is the request's words zero-padded
+    to ``MIN_BUCKET_WORDS`` (256 B, a lane tag's length), ``operand`` the
+    ``jax.Array`` the call carries, ``answer`` a ``jax.Array`` that stays on
+    the device, and the frame, ``8 + MIN_BUCKET_WORDS`` words, is all the
+    host reads of the call: its error code, and whatever the service's
+    ``account`` reads of a frame. ``call_words(words, operand=<jax.Array>)``
+    is such a call: same credit, queue, drain, watcher and stamps, a
+    dispatch of its own (a tensor fills a program), no ``np`` row of the
+    operand, no ``device_put`` and no read-back of operand or answer;
+    ``pending.response_array`` is the answer. An operand that lies on
+    another device, or came as host bytes, is put on ``device`` first and
+    counted (``device_transport_device_operand_fallbacks``); a service
+    without ``dispatch_tensor`` answers ``EREQUEST``."""
 
     def __init__(
         self,
@@ -502,7 +538,8 @@ class DeviceEndpoint:
         self._credits = Butex(window_size)
         self._cq = DeviceCompletionButex()
         # (bucket, mid_u32, the call's words as call_words keeps them,
-        # cid_u32, pending, words of the answer)
+        # cid_u32, pending, words of the answer); a device operand rides on
+        # its pending call
         self._queue = deque()
         self._qlock = threading.Lock()
         self._draining = False
@@ -539,6 +576,20 @@ class DeviceEndpoint:
             self, jax.jit(step_row, in_shardings=on_device, donate_argnums=donated))
         self._batch_program = _StepProgram(
             self, jax.jit(step_batch, in_shardings=on_device, donate_argnums=donated))
+        # the step of a call whose operand is a device array, where the
+        # service has one: jit_step_tensor. The operand is committed to
+        # ``device`` already and the answer stays there
+        self._tensor_program = None
+        if hasattr(service, "dispatch_tensor"):
+
+            def step_tensor(state, row, operand, cid_lo, mid):
+                state, answer, frame = service.dispatch_tensor(
+                    state, row, operand, cid_lo, mid)
+                return state, (answer, frame)
+
+            self._tensor_program = _StepProgram(
+                self,
+                jax.jit(step_tensor, in_shardings=on_device, donate_argnums=donated))
 
     def _step_replaces_state(self) -> bool:
         """Whether the service's step hands a next state back, from its
@@ -589,6 +640,7 @@ class DeviceEndpoint:
         method_id: int = 0,
         correlation_id: int = 1,
         timeout: Optional[float] = 10.0,
+        operand=None,
     ) -> _PendingCall:
         """Async: frame → HBM → dispatch fused step → watch completion.
         Returns a _PendingCall the caller can wait on; the credit is held
@@ -597,7 +649,11 @@ class DeviceEndpoint:
         (``call_bytes``, a length that is no multiple of 4). A read-only
         array is queued as it is and must stay unchanged until the call
         settles; a writeable one is copied here, so the caller may write to
-        it once this returns."""
+        it once this returns. ``operand``: a ``jax.Array`` on ``device`` the
+        call carries beside its words (at most ``MIN_BUCKET_WORDS`` of
+        them; host bytes, or an array that lies elsewhere, are put there first:
+        a fallback, counted); the answer is then ``pending.response_array``,
+        on the device, and ``response_words`` the frame's payload."""
         pending = _PendingCall()
         pending.t_entry = pending.t_words = _time.monotonic_ns()
         if not self._acquire_credit(timeout):
@@ -611,9 +667,21 @@ class DeviceEndpoint:
         if words.dtype not in (np.uint32, np.uint8):
             words = words.astype(np.uint32)
         sent = -(-words.nbytes // 4)
-        n = -(-self.service.answer_bytes(method_id, 4 * sent) // 4)
         try:
-            bucket = _bucket_words(max(1, n, sent))
+            if operand is None:
+                n = -(-self.service.answer_bytes(method_id, 4 * sent) // 4)
+                bucket = _bucket_words(max(1, n, sent))
+            elif self._tensor_program is None or sent > MIN_BUCKET_WORDS:
+                raise ValueError("no step for a device operand, or a frame too long")
+            else:
+                n = bucket = MIN_BUCKET_WORDS
+                if isinstance(operand, jax.Array) and operand.devices() == {self.device}:
+                    m_device_operands << 1
+                else:  # host bytes, as words; or an array that lies elsewhere
+                    if not isinstance(operand, (jax.Array, np.ndarray)):
+                        operand = np.frombuffer(operand, dtype=np.uint32)
+                    operand = jax.device_put(operand, self.device)
+                    m_device_operand_fallbacks << 1
         except ValueError:
             # oversized payload: the credit MUST come back (a leak here
             # shrinks the window forever) and the caller gets the settled-
@@ -630,6 +698,7 @@ class DeviceEndpoint:
             flags.writeable or not (flags.c_contiguous and flags.aligned)
         ):
             words = words.copy()
+        pending.operand = operand
         pending.t_enqueued = _time.monotonic_ns()
         with self._qlock:
             self._queue.append(
@@ -666,10 +735,16 @@ class DeviceEndpoint:
                 # mids/cids are per-row arguments). Calls of one bucket
                 # stack whatever their size; once buckets differ the
                 # stacked array stays under MAX_STACKED_WORDS. A prefix
-                # only: nothing is reordered, nothing can starve
+                # only: nothing is reordered, nothing can starve. A call with
+                # a device operand rides alone
                 batch = [self._queue.popleft()]
                 bucket, mixed = batch[0][0], False
-                while self._queue and len(batch) < self.max_batch:
+                while (
+                    batch[0][4].operand is None
+                    and self._queue
+                    and len(batch) < self.max_batch
+                    and self._queue[0][4].operand is None
+                ):
                     joining = self._queue[0][0]
                     if mixed or joining != bucket:
                         widest = max(bucket, joining)
@@ -712,6 +787,7 @@ class DeviceEndpoint:
             cids[i] = cid
             mids[i] = mid
             pending.dispatch = dispatch
+        operand = batch[0][4].operand
         try:
             alone = batch[0][2]
             if bpad == 1 and alone.dtype == np.uint32 and alone.size == bucket:
@@ -728,7 +804,10 @@ class DeviceEndpoint:
             # the launch is the program call alone: it stages the host
             # arrays itself. rows, cids and mids are not written again (the
             # runtime may still be reading them)
-            if bpad == 1:  # a call alone: the one-row program, one frame back
+            if operand is not None:  # (the answer on the device, one frame)
+                response = self._tensor_program.run(
+                    (rows, operand, cids[0], mids[0]), dispatch)
+            elif bpad == 1:  # a call alone: the one-row program, one frame back
                 response = self._program(rows, cids[0], mids[0], dispatch)
             else:
                 response = self._batch_program(rows, cids, mids, dispatch)
@@ -745,7 +824,9 @@ class DeviceEndpoint:
             try:
                 host = None
                 if error is None:
-                    host = np.asarray(jax.device_get(arrays))
+                    # of a device operand's answer the frame alone is read
+                    frames = arrays if operand is None else arrays[1]
+                    host = np.asarray(jax.device_get(frames))
                     if _single:
                         host = host[None]
             except Exception as e:  # noqa: BLE001 — fetch failed
@@ -760,6 +841,8 @@ class DeviceEndpoint:
                         _, words, err = _parse_response(host[i])
                         pending.error_code = int(err)
                         pending.response_words = words[:n]
+                        if operand is not None:
+                            pending.response_array = arrays[0]
                     device_latency << (
                         _time.monotonic_ns() - pending.t_credit
                     ) / 1e3
@@ -813,17 +896,23 @@ class DeviceEndpoint:
             words, method_id=method_id, correlation_id=correlation_id,
             timeout=timeout,
         )
+        pending.t_entry = t_entry
+        return self._settled(
+            pending, deadline, cntl, b"",
+            lambda: pending.response_words.tobytes()[
+                : self.service.answer_bytes(method_id, nbytes)])
+
+    @staticmethod
+    def _settled(pending, deadline, cntl, nothing, answer) -> tuple:
+        """The sync adapters' way out: wait what is left of the deadline,
+        take ``answer()`` of a call that ended well (``nothing`` otherwise),
+        stamp the exit and leave the call's row."""
         remaining = None
         if deadline is not None:
             remaining = max(0.0, deadline - _time.monotonic())
-        pending.t_entry = t_entry
         if not pending.wait(remaining):
-            return ErrorCode.ERPCTIMEDOUT, b""
-        out = b""
-        if not pending.error_code:
-            out = pending.response_words.tobytes()[
-                : self.service.answer_bytes(method_id, nbytes)
-            ]
+            return ErrorCode.ERPCTIMEDOUT, nothing
+        out = nothing if pending.error_code else answer()
         pending.t_exit = _time.monotonic_ns()
         if pending.completed():
             after_send = getattr(cntl, "_after_send", None)
@@ -834,6 +923,47 @@ class DeviceEndpoint:
                 # the way out is on the call's row too
                 after_send.append(partial(_record, pending, cntl))
         return pending.error_code, out
+
+    def call_tensor(
+        self,
+        payload: bytes,
+        tensor,
+        method_id: int = 0,
+        correlation_id: int = 1,
+        timeout: Optional[float] = 10.0,
+        cntl=None,
+    ) -> Tuple[int, object]:
+        """Sync adapter for a call that carries a tensor: ``payload`` is the
+        request's frame (at most 256 B), ``tensor`` its operand. A
+        ``jax.Array`` is answered ``(code, jax.Array on device)`` and never
+        touches the host; host bytes (a caller without a lane) are put on
+        the device, and the answer is read back as bytes: the fallback.
+        Stamps and rows as ``call_bytes``."""
+        t_entry = _time.monotonic_ns()
+        deadline = None if timeout is None else _time.monotonic() + timeout
+        as_bytes = not isinstance(tensor, jax.Array)
+        words = np.frombuffer(
+            payload, dtype=np.uint8 if len(payload) % 4 else np.uint32)
+        pending = self.call_words(
+            words, method_id=method_id, correlation_id=correlation_id,
+            timeout=timeout, operand=tensor,
+        )
+        pending.t_entry = t_entry
+
+        def answer():
+            array = pending.response_array
+            return np.asarray(array).tobytes() if as_bytes else array
+
+        return self._settled(pending, deadline, cntl, None, answer)
+
+    def warm_tensor(self, shape: tuple, dtype=np.uint32, method_id: int = 0) -> None:
+        """Compile the step for a device operand of ``shape`` and run it
+        once on a zero frame and a zero operand (answered with an error,
+        without a product): no live call of that shape compiles."""
+        operand = jax.device_put(np.zeros(shape, dtype=dtype), self.device)
+        row = np.zeros(MIN_BUCKET_WORDS, dtype=np.uint32)
+        jax.block_until_ready(self._tensor_program.run(
+            (row, operand, np.uint32(1), np.uint32(method_id))))
 
     def warm(
         self, payload_bytes: int, timeout: float = 300.0, method_id: int = 0
@@ -872,9 +1002,27 @@ class DeviceEndpoint:
         request payload goes to HBM, the fused step runs, the response
         comes back — RPC in, device compute, RPC out. ``timeout`` budgets
         credit-wait + queued-batch dispatch + completion (under bursts a
-        call may ride the second or third micro-batch)."""
+        call may ride the second or third micro-batch). A request whose
+        attachment is a ``jax.Array`` (a unary tensor call over a link's
+        lane) is a call with a device operand, where the service takes
+        one: the answer is ``cntl.response_attachment``, a ``jax.Array``."""
 
         def handler(cntl, request: bytes) -> bytes:
+            tensor = cntl.request_attachment
+            if self._tensor_program is not None and (
+                isinstance(tensor, jax.Array) or len(tensor)
+            ):
+                # the attachment is the operand, the request its frame; the
+                # answer goes back as the attachment, where it lies
+                code, answer = self.call_tensor(
+                    request, tensor, method_id=method_id,
+                    correlation_id=cntl.call_id or 1, timeout=timeout, cntl=cntl,
+                )
+                if code:
+                    cntl.set_failed(code, f"device call failed ({code})")
+                else:
+                    cntl.response_attachment = answer
+                return b""
             code, out = self.call_bytes(
                 request,
                 method_id=method_id,

@@ -27,6 +27,8 @@ CELLS = [w["name"] for w in BENCH["workloads"]]
 KV_CELL = "kv_blocks_ici_32m_c1"  # PR 39's: device blocks over the lane
 HBM_CELL = "link_echo_ici_hbm_1m_c4"  # PR 44's: a unary call carries a tensor
 EXPERT_CELL = "expert_ffn_ep32_n256_c16"  # PR 48's: a step bound on the device
+# PR 54's: the four-chip expert step, a tensor operand into DeviceEndpoint
+EXCHANGE_CELL = "expert_exchange_ep32_n8192_c4"
 # the three readers of the lane that go by adders and the trace, not by the
 # link with most trains: PR 44's cell, whose windows need no train, joined them
 LANE_BY_ADDERS = ("lane_messages_per_step", "lane_step_ici_pct", "lane_tagged_pct")
@@ -188,7 +190,7 @@ CQ = {
 CQ_CELLS = [
     "echo_256b_c16", "echo_4m_c2", "link_echo_ici_1m", "echo_mixed_c16",
     "echo_256b_c16_native", "link_stream_ici", "ycsb_b_zipf_c16", KV_CELL,
-    HBM_CELL, EXPERT_CELL,
+    HBM_CELL, EXPERT_CELL, EXCHANGE_CELL,
 ]
 # PR 44's unary calls over a window, on the busiest link by such calls (3;
 # no train crossed, so no step_rtt names it): 400 calls of 10,000 us whose
@@ -388,9 +390,13 @@ def test_new_metrics_report_in_the_cells_the_issue_gives_them():
         elif name.startswith(("lane_", "kv_")) or name == "stream_device_bytes_pct":
             # only the KV block stream sends a device array over trains too
             assert cells[name] == [KV_CELL], name
-        elif name.startswith("unary_"):
-            # only PR 44's cell makes a unary call with a device attachment
+        elif name == "unary_device_calls_pct":
+            # moves goodput, which PR 54's cell does not report
             assert cells[name] == [HBM_CELL], name
+        elif name.startswith("unary_"):
+            # PR 44's cell makes a unary call with a device attachment, and
+            # PR 54's three a layer call
+            assert cells[name] == [HBM_CELL, EXCHANGE_CELL], name
         elif name.startswith("combo_"):
             # only the partitioned deployment builds a combo channel
             assert cells[name] == ["partition_star_4"], name
@@ -579,7 +585,9 @@ def test_the_new_entries_only_follow_the_old():
         "unary_device_calls_pct", "unary_lane_launch_us", "unary_lane_ready_us",
         "unary_lane_pair_wait_us", "unary_lane_deliver_us"]
     assert all(
-        (m["workloads"], m["source"]) == ([HBM_CELL], "program_counter")
+        (m["workloads"], m["source"]) == (
+            [HBM_CELL] if m["name"] == "unary_device_calls_pct"
+            else [HBM_CELL, EXCHANGE_CELL], "program_counter")
         for m in BENCH["per_layer"][91:102])
     assert [m["layer"] for m in BENCH["per_layer"][91:102]] == [
         "link", "host plane", "link", "host plane", "host plane", "host plane",
@@ -640,13 +648,34 @@ def test_the_new_entries_only_follow_the_old():
             [EXPERT_CELL] if entry["name"] == "table_state_wait_us" else [])
     assert [c["name"] for c in BENCH["configs"]][5:] == [
         "ycsb_b_device_table", "kv_block_stream_ici", "link_performance_ici_hbm",
-        "expert_shard_dsv3_ep32"]
-    assert CELLS[7:] == ["ycsb_b_zipf_c16", KV_CELL, HBM_CELL, EXPERT_CELL]
-    assert [w["chips"] for w in BENCH["workloads"][7:]] == [1, 4, 4, 1]
+        "expert_shard_dsv3_ep32", "expert_exchange_dsv3_ep32"]
+    assert CELLS[7:] == [
+        "ycsb_b_zipf_c16", KV_CELL, HBM_CELL, EXPERT_CELL, EXCHANGE_CELL]
+    assert [w["chips"] for w in BENCH["workloads"][7:]] == [1, 4, 4, 1, 4]
+    # PR 54's nine follow PR 53's, the last, in the four-chip expert cell; the
+    # file holds 128 per-layer metrics, which is as many as it may
+    assert names[119:] == [
+        "exchange_call_us", "exchange_fanout_us", "exchange_device_operands_pct",
+        "exchange_step_kernel_us", "exchange_step_hbm_pct", "exchange_step_mxu_pct",
+        "exchange_combine_hbm_pct", "exchange_lane_ici_pct",
+        "exchange_lane_messages_per_step"]
+    assert all(m["workloads"] == [EXCHANGE_CELL] for m in BENCH["per_layer"][119:])
+    assert [(m["layer"], m["moves"], m["source"]) for m in BENCH["per_layer"][119:]] == [
+        ("expert exchange", "latency_p50_us", "program_counter"),
+        ("expert exchange", "latency_p50_us", "program_counter"),
+        ("host to HBM crossing and completion", "call_rate", "program_counter"),
+        ("device program", "latency_p50_us", "device_trace"),
+        ("device program", "call_rate", "device_trace"),
+        ("device program", "call_rate", "device_trace"),
+        ("expert exchange", "latency_p50_us", "device_trace"),
+        ("link", "call_rate", "device_trace"),
+        ("link", "call_rate", "program_counter")]
     for m in BENCH["end_to_end"] + BENCH["per_layer"][:91]:
         # an older metric gained a cell's name at the end of its list or not
         # at all: PR 48's last, PR 44's before it, PR 39's before that
         workloads = list(m.get("workloads", ()))
+        if EXCHANGE_CELL in workloads:  # PR 54's last of all
+            assert workloads.pop() == EXCHANGE_CELL, m["name"]
         if EXPERT_CELL in workloads:
             assert workloads.pop() == EXPERT_CELL, m["name"]
         if HBM_CELL in workloads:

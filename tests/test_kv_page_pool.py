@@ -327,7 +327,7 @@ def test_the_lanes_program_compiles_for_two_chips_with_a_block_and_its_tag(
 # compiler is described once a process, by this file's fixture) ------------------
 
 
-@pytest.mark.parametrize("batch", [1, 4])
+@pytest.mark.parametrize("batch", [1, 4, 16])
 def test_the_expert_step_compiles_on_the_weights_where_they_lie(topo, batch):
     """``expert_shard_dsv3_ep32`` at its published widths: 8,455,716,864 B
     of weights are the program's arguments, its Pallas kernel takes them as
@@ -355,9 +355,45 @@ def test_the_expert_step_compiles_on_the_weights_where_they_lie(topo, batch):
     compiled = jax.jit(
         lambda s, r, c, m: service.step(s, r, c, m)[1]
     ).lower(state, rows, ids, ids).compile()
-    assert "expert_ffn" in compiled.as_text()
+    assert "expert_ffn_grouped" in compiled.as_text()
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes >= service.weight_bytes
     # the frames, each padded to the chip's tile
     assert 0 <= memory.output_size_in_bytes - batch * 4 * (262144 + 8) <= batch * 512
-    assert memory.temp_size_in_bytes < 64 << 20
+    # a pass's gathered pairs, their float32 rows out and the float32 sum
+    assert memory.temp_size_in_bytes < (64 << 20) * max(1, batch // 4)
+
+
+def test_the_tensor_step_compiles_on_the_weights_where_they_lie(topo):
+    """``expert_exchange_dsv3_ep32``'s rank step (PR 54) at the published
+    widths and the operand's capacity of 2,048 rows: the weights are the
+    program's arguments, the grouped kernel takes them as they are, the
+    answer has the operand's shape, and a pass of the step holds under half
+    a gigabyte beside them (the gathered pairs' rows, their float32 rows out,
+    the float32 sum), whatever the split: a rank's chip stays under 1.25
+    times its weights with two dispatches in flight."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from incubator_brpc_tpu.models.expert_shard import ExpertShardService
+
+    chip = SingleDeviceSharding(topo.devices[1])
+    service = ExpertShardService(7168, 2048, 8, 12)
+    service.interpret = False  # the kernel itself, for the chip's compiler
+
+    def on_chip(*shape, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    state = (on_chip(12, 8, 7168, 2048), on_chip(12, 8, 7168, 2048),
+             on_chip(12, 8, 2048, 7168))
+    operand = on_chip(2048, service.operand_words(), dtype=jnp.uint32)
+    assert 4 * 2048 * service.operand_words() == 29_425_664
+    compiled = jax.jit(service.dispatch_tensor).lower(
+        state, on_chip(64, dtype=jnp.uint32), operand,
+        on_chip(dtype=jnp.uint32), on_chip(dtype=jnp.uint32)).compile()
+    assert "expert_ffn_grouped" in compiled.as_text()
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes >= service.weight_bytes
+    assert 0 <= memory.output_size_in_bytes - 29_425_664 - 4 * 72 <= 4096
+    assert memory.temp_size_in_bytes < 512 << 20
