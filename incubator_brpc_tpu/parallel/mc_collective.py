@@ -48,6 +48,12 @@ COLLECTIVE_METHOD = "collective"
 # covers validation rejections, so this only needs to span a local RPC
 # round trip — 10x under the old fixed 0.5 s grace window.
 _REJECT_WATCH_S = 0.05
+# A run proposal refused with ELIMIT is sent again after each of these waits,
+# once each: the refusal may be the proposer's own accept, whose admission
+# slot the server frees only after writing the ack (propose_collective). The
+# waits are a descheduled server thread's, not a session's: a proposal still
+# refused after them (three sent in all, ~0.3 s) is rejected.
+_OWN_ACCEPT_RESEND_S = (0.05, 0.2)
 
 # session-level observability (ISSUE: the collective plane was blind):
 # every run_collective_session — proposer and server parties alike —
@@ -328,6 +334,7 @@ def propose_collective(
 
     from incubator_brpc_tpu.rpc.controller import Controller
     from incubator_brpc_tpu.transport.device_link import HANDSHAKE_SERVICE
+    from incubator_brpc_tpu.utils.status import ErrorCode
 
     server_indexes = [i for i in range(len(party_ids)) if i != client_index]
     if len(server_indexes) != len(channels):
@@ -378,8 +385,7 @@ def propose_collective(
     # A's collective blocks on parties that were never told to start).
     # Mid-session process death stays the backend's liveness domain (the
     # coordination service / gloo timeout errors the chain group-wide).
-    pending = []
-    for ch, idx in zip(channels, server_indexes):
+    def propose_run(ch, idx):
         cntl = Controller(timeout_ms=timeout_ms)
         ev = threading.Event()
         ch.call_method(
@@ -389,7 +395,11 @@ def propose_collective(
             cntl=cntl,
             done=lambda c, _ev=ev: _ev.set(),
         )
-        pending.append((cntl, ev))
+        return cntl, ev
+
+    pending = [
+        propose_run(ch, idx) for ch, idx in zip(channels, server_indexes)
+    ]
     # Short rejection watch before committing to our own session: the
     # accept phase reserves nothing, so a run proposal can still bounce
     # instantly (admission ELIMIT from an overlapping session, a server
@@ -397,13 +407,30 @@ def propose_collective(
     # join — surface it now rather than waiting out the collective
     # backend's timeout. Bounded at _REJECT_WATCH_S (one local RPC round
     # trip), not the old always-burned 0.5 s.
+    # One ELIMIT is not an overlapping session: a server frees a call's
+    # admission slot AFTER it has written the response (Server._finish),
+    # so a run proposal that follows our own accept's ack closely can find
+    # that accept still counted against collective_max_concurrency. Such a
+    # proposal is sent again after each wait of _OWN_ACCEPT_RESEND_S; a
+    # session that really overlaps holds its slot for its whole chain and
+    # is still refused after the last.
+    resent = [0] * len(pending)
     watch_deadline = time.monotonic() + _REJECT_WATCH_S
     while time.monotonic() < watch_deadline:
-        for cntl, ev in pending:
-            if ev.is_set() and cntl.failed():
+        for i, (cntl, ev) in enumerate(pending):
+            if not (ev.is_set() and cntl.failed()):
+                continue
+            if (
+                cntl.error_code != ErrorCode.ELIMIT
+                or resent[i] == len(_OWN_ACCEPT_RESEND_S)
+            ):
                 raise RuntimeError(
                     f"collective proposal rejected: {cntl.error_text}"
                 )
+            time.sleep(_OWN_ACCEPT_RESEND_S[resent[i]])
+            resent[i] += 1
+            pending[i] = propose_run(channels[i], server_indexes[i])
+            watch_deadline = time.monotonic() + _REJECT_WATCH_S
         time.sleep(0.005)
     span = _start_session_span(party_ids, client_index, steps, width)
     own, elapsed = _run_observed_session(

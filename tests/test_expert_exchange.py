@@ -8,6 +8,7 @@ shares against the uncut layer, ``DeviceEndpoint`` with a device operand
 call over three in-process servers against the reference, and the new
 readers."""
 
+import functools
 import json
 import os
 import sys
@@ -84,13 +85,17 @@ def split(n, kind, rng):
     return w.astype(np.float32)
 
 
+def words_of(rows):
+    """bf16 rows two a word, the even column low, by numpy."""
+    return np.ascontiguousarray(np.asarray(rows).astype(ml_dtypes.bfloat16)).view(np.uint32)
+
+
 def operand_of(x, w, rows):
     """The tensor operand for token rows ``x`` and weights ``w``, by numpy."""
-    n = x.shape[0]
-    out = np.zeros((rows, WIDE), np.uint32)
-    out[:n, :TW] = np.ascontiguousarray(
-        np.asarray(x).astype(ml_dtypes.bfloat16)).view(np.uint32)
-    out[:n, TW:] = np.asarray(w, np.float32).view(np.uint32)
+    n, tw = x.shape[0], x.shape[1] // 2
+    out = np.zeros((rows, tw + HELD), np.uint32)
+    out[:n, :tw] = words_of(x)
+    out[:n, tw:] = np.asarray(w, np.float32).view(np.uint32)
     return out
 
 
@@ -101,9 +106,9 @@ def head(layer, n, hidden=HIDDEN, held=HELD):
 
 
 def answer_rows(answer, n):
-    """``float32[n, HIDDEN]`` of a tensor answer's first ``n`` rows."""
-    words = np.ascontiguousarray(np.asarray(answer)[:n, :TW])
-    return words.view(ml_dtypes.bfloat16).astype(np.float32).reshape(n, HIDDEN)
+    """``float32[n, hidden]`` of a tensor answer's first ``n`` rows."""
+    words = np.ascontiguousarray(np.asarray(answer)[:n, :-HELD])
+    return words.view(ml_dtypes.bfloat16).astype(np.float32)
 
 
 def want(layer, x, w, rank=0):
@@ -204,41 +209,237 @@ def routed(x, layer):
     return np.asarray(ref.gate_weights(MOE, SEED, layer, x))
 
 
-def test_plan_gather_and_combine_are_numpys():
-    n, capacity = 40, 32
-    x = tokens(n, salt=11)
-    weights = routed(x, 1)
-    firsts = [0, HELD, 2 * HELD]
-    plan = expert_exchange.plan_layer(weights, firsts, HELD, capacity, jax.devices()[0])
-    operands = jax.jit(expert_exchange.gather)(
-        x.astype(jnp.bfloat16), plan.index, plan.gates)
-    for r, first in enumerate(firsts):
+FIRSTS = [0, HELD, 2 * HELD]
+# (tokens, hidden): one the kernels' blocks divide (128 tokens, 128 operand
+# rows, 128 words), and one that meets none of them (the plain programs)
+SHAPES = {"in_blocks": (256, 256), "off_blocks": (48, 64)}
+
+
+def forced(n, ranks_of):
+    """Dense gate weights that send token ``t`` to the ranks ``ranks_of(t)``."""
+    weights = np.zeros((n, 3 * HELD), np.float32)
+    for t in range(n):
+        for r in ranks_of(t):
+            weights[t, r * HELD + t % HELD] = 0.25 + (t % 7) / 8
+    return weights
+
+
+def every_number_of_ranks(t):
+    return [(), (t % 3,), (t % 3, (t + 1) % 3), (0, 1, 2)][(t // 3) % 4]
+
+
+def answers_from(rng, plan, capacity, hidden, scale=lambda r: 1.0):
+    """An answer a rank: its tokens' rows random bf16; the rows past them and
+    the gates' words whatever a rank may leave there (any bits, NaNs among
+    them), which no sum may take up."""
+    answers = []
+    for r, sent in enumerate(plan.tokens):
+        answer = rng.integers(0, 1 << 32, (capacity, hidden // 2 + HELD), dtype=np.uint32)
+        rows = (rng.standard_normal((sent, hidden)) * scale(r)).astype(ml_dtypes.bfloat16)
+        answer[:sent, : hidden // 2] = words_of(rows)
+        answers.append(answer)
+    return answers
+
+
+# a case: (rng, n, hidden) -> x, the dense gate weights, whether operands
+# have pad rows, and what makes the answers from the plan (None: random)
+
+
+def case_router(rng, n, hidden):
+    x = share.micro_batch(b"tests/test_expert_exchange", 11, 0, n, hidden)
+    moe = ref.Moe(hidden_size=hidden, moe_intermediate_size=32, n_routed_experts=32,
+                  num_experts_per_tok=4, n_group=4, topk_group=2)
+    return x, np.asarray(ref.gate_weights(moe, SEED, 1, x)), True, None
+
+
+def case_no_pad_rows(rng, n, hidden):  # every rank is sent exactly a capacity of tokens
+    return rng.standard_normal((n, hidden)), forced(
+        n, lambda t: [r for r in range(3) if (t + r) % 2]), False, None
+
+
+def case_pad_rows_and_a_rank_sent_nothing(rng, n, hidden):
+    return rng.standard_normal((n, hidden)), forced(
+        n, lambda t: [r for r in (0, 2) if t % (r + 2) == 0]), True, None
+
+
+def case_tokens_sent_to_0_1_2_and_3_ranks(rng, n, hidden):
+    return rng.standard_normal((n, hidden)), forced(n, every_number_of_ranks), True, None
+
+
+def case_a_sum_that_depends_on_the_order(rng, n, hidden):
+    """2**30 + 1 - 2**30 is 0 in the order of the ranks and 1 in any other;
+    the random rows' scales are 2**12 apart, so theirs differ too."""
+
+    def answers(plan, capacity):
+        out = answers_from(rng, plan, capacity, hidden, lambda r: 4096.0 ** (1 - r))
+        index = np.asarray(plan.index)
+        for value, r in zip((2.0**30, 1.0, -(2.0**30)), range(3)):
+            row = int(np.nonzero(index[r] == 9)[0][0])  # token 9 goes to all three
+            out[r][row, : hidden // 2] = words_of(
+                np.full((1, hidden), value, ml_dtypes.bfloat16))
+        return out
+
+    return rng.standard_normal((n, hidden)), forced(n, every_number_of_ranks), True, answers
+
+
+def case_negative_zero_inf_and_nan_in_x(rng, n, hidden):
+    x = rng.standard_normal((n, hidden)).astype(np.float32)
+    x[3, 10], x[3, 11], x[5, 0], x[5, hidden - 1] = -0.0, np.inf, np.nan, -np.inf
+    x[12, :] = -0.0
+    return x, forced(n, lambda t: (0, 1, 2)), True, None
+
+
+def case_negative_zero_inf_and_nan_in_an_answer(rng, n, hidden):
+    def answers(plan, capacity):
+        out = answers_from(rng, plan, capacity, hidden)
+        for r, (row, column, value) in enumerate(
+                [(0, 7, np.inf), (2, 20, np.nan), (1, 33, -np.inf)]):
+            rows = out[r][: plan.tokens[r], : hidden // 2].view(ml_dtypes.bfloat16)
+            rows[row, column] = value
+        # token 4 goes to rank 1 alone: 0.0 + -0.0 is 0.0, as in the parent's sum
+        row = int(np.nonzero(np.asarray(plan.index)[1] == 4)[0][0])
+        out[1][row, : hidden // 2] = words_of(np.full((1, hidden), -0.0, ml_dtypes.bfloat16))
+        return out
+
+    return rng.standard_normal((n, hidden)), forced(n, every_number_of_ranks), True, answers
+
+
+CASES = {
+    name[len("case_"):]: case for name, case in sorted(globals().items())
+    if name.startswith("case_")}
+# (case, tokens, hidden): every case on both sides of the selection, and one
+# of several blocks of the sum, steps of the pack and chunks of 128 words
+RUNS = [(case, *SHAPES[shape]) for case in sorted(CASES) for shape in sorted(SHAPES)]
+RUNS.append(("tokens_sent_to_0_1_2_and_3_ranks", 384, 512))
+
+
+# the module's gather off the TPU, as ``ExpertExchange`` jits it there
+GATHER = jax.jit(functools.partial(expert_exchange.gather, interpret=True))
+
+
+def same_bits(got, want):
+    """``got`` is ``want`` bit for bit, a NaN for a NaN."""
+    got, want = np.asarray(got), np.asarray(want)
+    assert got.dtype == want.dtype and got.shape == want.shape
+    if got.dtype == np.uint32:
+        return np.testing.assert_array_equal(got, want)
+    nan = np.isnan(want.astype(np.float32))
+    assert np.array_equal(np.isnan(got.astype(np.float32)), nan)
+    np.testing.assert_array_equal(
+        np.where(nan, 0, got.view(np.uint16)), np.where(nan, 0, want.view(np.uint16)))
+
+
+@pytest.mark.parametrize("case,n,hidden", RUNS, ids=lambda v: str(v))
+def test_plan_gather_and_combine_are_numpys(case, n, hidden):
+    """The two programs against numpy and against the plain formulation
+    (the parent's programs), bit for bit (a NaN for a NaN), on shapes the
+    kernels' blocks divide and on shapes they do not."""
+    rng = np.random.default_rng(55)
+    x, weights, pad_rows, answers = CASES[case](rng, n, hidden)
+    x = np.asarray(x, np.float32).astype(ml_dtypes.bfloat16)
+    most = max(int((weights[:, f : f + HELD] != 0).any(axis=1).sum()) for f in FIRSTS)
+    in_blocks = n % 128 == 0
+    capacity = most + 7 * pad_rows
+    if in_blocks:
+        capacity = -(-capacity // 128) * 128
+        assert (capacity > most) == pad_rows
+    assert expert_exchange._in_blocks(3, n, hidden, capacity, HELD) == in_blocks
+    plan = expert_exchange.plan_layer(weights, FIRSTS, HELD, capacity, jax.devices()[0])
+    operands = GATHER(jnp.asarray(x), plan.index, plan.gates)
+    plain = jax.jit(expert_exchange.gather_plain)(jnp.asarray(x), plan.index, plan.gates)
+    sent = []
+    for r, first in enumerate(FIRSTS):
         sub = weights[:, first : first + HELD]
         rows = np.nonzero((sub != 0).any(axis=1))[0]
-        assert plan.tokens[r] == len(rows) and plan.pairs[r] == (sub != 0).sum()
-        assert np.array_equal(np.asarray(plan.index)[r, : len(rows)], rows)
-        assert (np.asarray(plan.index)[r, len(rows) :] >= n).all()
-        inverse = np.asarray(plan.inverse)[r]
-        assert np.array_equal(inverse[rows], np.arange(len(rows)))
+        sent.append(rows)
+        assert plan.tokens[r] == len(rows) and plan.pairs[r] == int((sub != 0).sum())
+        index = np.asarray(plan.index[r])
+        np.testing.assert_array_equal(index[: len(rows)], rows)
+        assert (index[len(rows) :] >= n).all()  # pad rows: no token
+        inverse = np.asarray(plan.inverse[r])
+        np.testing.assert_array_equal(inverse[rows], np.arange(len(rows)))
         assert (np.delete(inverse, rows) == capacity).all()
-        np.testing.assert_array_equal(
-            np.asarray(operands[r]), operand_of(np.asarray(x)[rows], sub[rows], capacity))
-    # the combine adds each rank's rows at their tokens' places, in float32
-    answers = [
-        jnp.asarray(operand_of(
-            np.full((plan.tokens[r], HIDDEN), r + 1.0, np.float32),
-            np.zeros((plan.tokens[r], HELD)), capacity))
-        for r in range(3)]
+        # the token's own bits two a word (a -0.0 its sign, an inf or a NaN
+        # its own half of its own word), its gate weights, zero rows after
+        same_bits(operands[r], operand_of(x[rows], sub[rows], capacity))
+        same_bits(plain[r], np.asarray(operands[r]))
+    # the combine: ((0 + a0) + a1) + a2 in float32 at each token's place, a
+    # rank that was not sent the token adding 0.0, rounded to bf16 once
+    answers = (answers or (lambda plan, capacity: answers_from(rng, plan, capacity, hidden)))(
+        plan, capacity)
     y = np.asarray(jax.jit(
-        lambda inverse, *a: expert_exchange.combine(n, HIDDEN, inverse, *a)
-    )(plan.inverse, *answers).astype(jnp.float32))
-    expect = np.zeros((n, HIDDEN), np.float32)
-    for r, first in enumerate(firsts):
-        sent = (weights[:, first : first + HELD] != 0).any(axis=1)
-        expect[sent] += r + 1.0
-    np.testing.assert_array_equal(y, expect)
-    with pytest.raises(expert_exchange.CapacityExceeded):
-        expert_exchange.plan_layer(weights, firsts, HELD, 4, jax.devices()[0])
+        lambda index, inverse, *a: expert_exchange.combine(
+            n, hidden, index, inverse, *a, interpret=True)
+    )(plan.index, plan.inverse, *map(jnp.asarray, answers)))
+    expect = np.zeros((n, hidden), np.float32)
+    with np.errstate(invalid="ignore"):
+        for rows, answer in zip(sent, answers):
+            part = np.zeros((n, hidden), np.float32)
+            part[rows] = answer_rows(answer, len(rows))
+            expect = expect + part
+    same_bits(y, expect.astype(ml_dtypes.bfloat16))
+    same_bits(jax.jit(
+        lambda inverse, *a: expert_exchange.combine_plain(n, hidden, inverse, *a)
+    )(plan.inverse, *map(jnp.asarray, answers)), y)
+    if case == "a_sum_that_depends_on_the_order":
+        assert (y[9].astype(np.float32) == 0).all()
+    if case == "negative_zero_inf_and_nan_in_an_answer":
+        assert (~np.isfinite(y.astype(np.float32))).sum() == 3
+        assert not y[4].view(np.uint16).any()  # +0.0: the sum began at 0.0
+    if case == "negative_zero_inf_and_nan_in_x":
+        assert (np.asarray(operands[0])[12, : hidden // 2] == 0x80008000).all()
+    if case == "tokens_sent_to_0_1_2_and_3_ranks":
+        assert sorted({sum(t in rows for rows in sent) for t in range(n)}) == [0, 1, 2, 3]
+        assert not y[[t for t in range(n) if not every_number_of_ranks(t)]].any()
+    if case == "pad_rows_and_a_rank_sent_nothing":
+        assert plan.tokens[1] == 0
+    if case == "no_pad_rows":
+        assert plan.tokens == (capacity,) * 3
+
+
+def test_a_rank_sent_more_than_an_operand_holds_is_refused():
+    x = tokens(40, salt=11)
+    with pytest.raises(expert_exchange.CapacityExceeded, match="rank 0 would be sent"):
+        expert_exchange.plan_layer(routed(x, 1), FIRSTS, HELD, 4, jax.devices()[0])
+
+
+@pytest.mark.parametrize("tokens_,hidden,capacity,ranks,served", [
+    (8192, 7168, 2048, 3, True),  # the cell's
+    (8192, 7168, 2000, 3, False), (8000, 7168, 2048, 3, False),
+    (8192, 7104, 2048, 3, False),  # 128 words do not divide a row's 3,552
+    (8192, 7168, 2048, 7, False),  # seven ranks' rings are more than the chip's VMEM
+    (8192, 16384, 2048, 3, False)])
+def test_the_shapes_select_the_programs_and_none_is_refused(
+        tokens_, hidden, capacity, ranks, served):
+    """PR 55's hand-in raised ``ValueError`` on the chip for shapes the
+    kernels' blocks do not divide; the plain programs serve them (compiled
+    for a v5e in ``tests/test_kv_page_pool.py``)."""
+    exchange = expert_exchange.ExpertExchange(
+        [None] * ranks, [r * HELD for r in range(ranks)], HELD, hidden, tokens_,
+        capacity, jax.devices()[0])
+    assert exchange.operand_shape == (capacity, hidden // 2 + HELD)
+    assert expert_exchange._in_blocks(ranks, tokens_, hidden, capacity, HELD) == served
+
+
+def test_the_programs_keep_the_names_the_roofline_reads():
+    """``benchmark/roofline_exchange.py`` finds the device time of the
+    source's gather and combine by their programs' names in the trace
+    (``GATHER_PROGRAM``, ``COMBINE_PROGRAM`` after ``jit_``), and
+    ``exchange_combine_hbm_pct`` is that time against the least bytes: all
+    device work of either stays inside the program of that name."""
+    n, hidden, capacity = 128, 256, 128  # the kernels' shapes
+    exchange = expert_exchange.ExpertExchange(
+        [None] * 3, FIRSTS, HELD, hidden, n, capacity, jax.devices()[0])
+    plan = exchange.plan(forced(n, every_number_of_ranks))
+    x = jnp.zeros((n, hidden), jnp.bfloat16)
+    gather = exchange._gather.lower(x, plan.index, plan.gates)
+    operands = jax.eval_shape(exchange._gather, x, plan.index, plan.gates)
+    combine = exchange._combine.lower(plan.index, plan.inverse, *operands)
+    for lowered, name in ((gather, roofline_exchange.GATHER_PROGRAM),
+                          (combine, roofline_exchange.COMBINE_PROGRAM)):
+        assert f"module @jit_{name} " in lowered.as_text()
+    assert roofline_exchange.GATHER_PROGRAM == "expert_exchange_gather"
+    assert roofline_exchange.COMBINE_PROGRAM == "expert_exchange_combine"
 
 
 def test_the_shares_add_up_to_the_uncut_layer():
@@ -250,7 +451,7 @@ def test_the_shares_add_up_to_the_uncut_layer():
     weights = routed(x, layer)
     plan = expert_exchange.plan_layer(
         weights, [0, HELD, 2 * HELD], HELD, capacity, jax.devices()[0])
-    operands = jax.jit(expert_exchange.gather)(
+    operands = GATHER(
         x.astype(jnp.bfloat16), plan.index, plan.gates)
     answers = []
     for rank in range(3):
@@ -261,7 +462,8 @@ def test_the_shares_add_up_to_the_uncut_layer():
         assert np.asarray(frame)[7] == 0
         answers.append(answer)
     combined = np.asarray(
-        expert_exchange.combine(n, HIDDEN, plan.inverse, *answers).astype(jnp.float32))
+        expert_exchange.combine(
+            n, HIDDEN, plan.index, plan.inverse, *answers, interpret=True).astype(jnp.float32))
     reference = np.asarray(
         ref.combined(MOE, SEED, layer, x, jnp.asarray(weights), [0, 1, 2], EP))
     assert within(combined, reference)

@@ -397,3 +397,64 @@ def test_the_tensor_step_compiles_on_the_weights_where_they_lie(topo):
     assert memory.argument_size_in_bytes >= service.weight_bytes
     assert 0 <= memory.output_size_in_bytes - 29_425_664 - 4 * 72 <= 4096
     assert memory.temp_size_in_bytes < 512 << 20
+
+
+def test_the_sources_gather_and_combine_compile_without_an_axis_of_two(topo):
+    """``expert_exchange_dsv3_ep32``'s source programs (PR 56) at the cell's
+    shapes: the chip lays an operand ``uint32[2048, 3592]`` column-major
+    (the layouts below are why the programs work on its transpose), neither
+    program copies an operand or an answer to turn it, and all either holds
+    beside its arguments and result is the ranks' gathered token rows (the
+    parent's combine held 940 MB: three ``uint32[8192, 7168]`` through a
+    trailing axis of 2, which the chip pads to 128 lanes)."""
+    import re
+
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from incubator_brpc_tpu.models.expert_exchange import ExpertExchange
+
+    chip = SingleDeviceSharding(topo.devices[0])
+    tokens, hidden, capacity, held = 8192, 7168, 2048, 8
+
+    def on_chip(*shape, dtype=jnp.uint32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=chip)
+
+    exchange = ExpertExchange(
+        [None] * 3, [0, held, 2 * held], held, hidden, tokens, capacity, topo.devices[0])
+    assert exchange.operand_shape == (2048, 3592)
+    operand = on_chip(*exchange.operand_shape)
+    gather = exchange._gather.lower(
+        on_chip(tokens, hidden, dtype=jnp.bfloat16), on_chip(3, capacity, dtype=jnp.int32),
+        on_chip(3, capacity, held, dtype=jnp.float32)).compile()
+    combine = exchange._combine.lower(
+        on_chip(3, capacity, dtype=jnp.int32), on_chip(3, tokens, dtype=jnp.int32),
+        operand, operand, operand).compile()
+    rows = 3 * capacity * hidden * 2  # the ranks' token rows in bf16, once
+    for compiled in (gather, combine):
+        text = compiled.as_text()
+        entry = text[text.index("ENTRY"):]
+        assert "tpu_custom_call" in entry
+        assert re.search(r"u32\[2048,3592\]\{0,1:T\(8,128\)\}", entry)  # column-major
+        assert not re.search(r"= u32\[(2048,3592|3592,2048)\]\S* (copy|transpose)\(", entry)
+        assert not re.search(r"\[\d+,\d+,2\]", entry)  # no minor axis of 2
+        assert compiled.memory_analysis().temp_size_in_bytes <= rows + (1 << 20)
+    # the micro-batch fetched into VMEM ahead of XLA's row gather (136 us
+    # there against 647 from HBM): what the pack's VMEM limit is set for
+    assert re.search(r"copy-start\(%x[.\d]*\)", gather.as_text())
+    assert gather.memory_analysis().output_size_in_bytes < 3 * 4 * 2048 * 3592 + (1 << 20)
+    assert combine.memory_analysis().output_size_in_bytes == tokens * hidden * 2
+    # a capacity the kernels' 128 rows do not divide is served on the chip too
+    # (PR 55's hand-in raised ValueError there): the plain programs, no kernel
+    odd = ExpertExchange(
+        [None] * 3, [0, held, 2 * held], held, hidden, 1024, 1000, topo.devices[0])
+    operand = on_chip(*odd.operand_shape)
+    for compiled in (
+            odd._gather.lower(
+                on_chip(1024, hidden, dtype=jnp.bfloat16), on_chip(3, 1000, dtype=jnp.int32),
+                on_chip(3, 1000, held, dtype=jnp.float32)).compile(),
+            odd._combine.lower(
+                on_chip(3, 1000, dtype=jnp.int32), on_chip(3, 1024, dtype=jnp.int32),
+                operand, operand, operand).compile()):
+        assert "tpu_custom_call" not in compiled.as_text()

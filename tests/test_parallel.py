@@ -182,3 +182,59 @@ class TestCollectiveAcceptPreAck:
         finally:
             srv.stop()
             srv.join(timeout=5)
+
+    def test_a_run_proposal_refused_for_its_own_accept_is_sent_again(
+        self, monkeypatch
+    ):
+        """A server frees a call's admission slot after it has written the
+        response, so the run proposal that follows the accept's ack can be
+        refused (ELIMIT at collective_max_concurrency=1) for the proposer's
+        own accept: tests/test_mc_link.py::test_three_process_collective_session
+        failed so beside five other workers (PR 55's last run). The slot
+        is held here 150 ms past the ack: the second resend finds it free.
+        An overlapping session, which keeps its slot, is still refused, after
+        three proposals and no more."""
+        import time as _time
+
+        from incubator_brpc_tpu.parallel import mc_collective
+        from incubator_brpc_tpu.rpc import Channel
+
+        monkeypatch.setattr(
+            mc_collective, "run_collective_session",
+            lambda parties, idx, steps, width, seed: (
+                np.zeros(width, np.float32), 0.001))
+        srv = self._server()
+        release, held = srv._release, []
+
+        def late_release(status, cntl):
+            if not held:  # the accept: the first call this server answers
+                held.append(status)
+                _time.sleep(0.15)
+            release(status, cntl)
+
+        monkeypatch.setattr(srv, "_release", late_release)
+        try:
+            ch = Channel()
+            assert ch.init(f"127.0.0.1:{srv.port}")
+            out = mc_collective.propose_collective(
+                [ch], [0, 1], client_index=0, steps=3, width=4, seed=7,
+                timeout_ms=30000)
+            assert len(out["server_checksums"]) == 1 and held
+            # a slot that stays taken: refused, after the bounded resends
+            monkeypatch.setattr(srv, "_release", lambda *call: held.append(call))
+            sent, call_method = [], ch.call_method
+            monkeypatch.setattr(
+                ch, "call_method",
+                lambda *a, **kw: sent.append(a[2]) or call_method(*a, **kw))
+            t0 = _time.monotonic()
+            with pytest.raises(RuntimeError, match="max_concurrency"):
+                mc_collective.propose_collective(
+                    [ch], [0, 1], client_index=0, steps=3, width=4, seed=7,
+                    timeout_ms=30000)
+            assert _time.monotonic() - t0 < 1.0
+            assert len(sent) == 4  # the accept, a run proposal and two resends
+        finally:
+            for call in held[1:]:
+                release(*call)
+            srv.stop()
+            srv.join(timeout=5)
